@@ -222,4 +222,12 @@ void mxtpu_prefetch_stop(void* handle) {
 // ---- misc -------------------------------------------------------------
 int32_t mxtpu_version() { return 1; }
 
+// Digest of this file as it was built (-DMXTPU_SRC_SHA256=...).  The
+// loader looks for the current source's digest in the library's bytes,
+// so a library built from other source is rebuilt whatever the mtimes.
+#ifndef MXTPU_SRC_SHA256
+#define MXTPU_SRC_SHA256 ""
+#endif
+const char* mxtpu_src_sha256() { return MXTPU_SRC_SHA256; }
+
 }  // extern "C"

@@ -41,7 +41,7 @@ def __getattr__(name):
         return mod
     raise AttributeError("module %r has no attribute %r"
                          % (__name__, name))
-from .hlo import HloCheckResult, compiled_cost, run_text_checks  # noqa: F401
+from .hlo import HloCheckResult, run_text_checks  # noqa: F401
 from .lint import (  # noqa: F401
     Diagnostic, Rule, RULES, apply_baseline, lint_paths, lint_source,
     load_baseline, rule,

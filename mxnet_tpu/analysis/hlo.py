@@ -14,15 +14,14 @@ Checks return :class:`HloCheckResult` (never raise on a finding):
 asserts ``res.ok, res.details`` and the CLI prints the same text.
 
 Everything here is pure text analysis (``re`` only — no jax import),
-so it runs wherever the lint runs.  The one jax-adjacent helper,
-:func:`compiled_cost`, only duck-types the object tests already hold.
+so it runs wherever the lint runs.
 """
 from __future__ import annotations
 
 import re
 
 __all__ = [
-    "HloCheckResult", "TEXT_CHECKS", "run_text_checks", "compiled_cost",
+    "HloCheckResult", "TEXT_CHECKS", "run_text_checks",
     "conv_signatures", "conv_dim_numbers", "conv_flops", "count_convs",
     "rank_ge3_transposes", "host_transfer_sites", "all_gather_results",
     "collective_counts",
@@ -108,7 +107,7 @@ def conv_flops(txt):
 
 def rank_ge3_transposes(txt):
     """Result shapes of every rank>=3 transpose — on TPU each is a real
-    relayout kernel the NHWC path exists to avoid."""
+    re-layout kernel the NHWC path exists to avoid."""
     return [t for t in _TRANSPOSE.findall(txt) if t.count("x") >= 3]
 
 
@@ -449,11 +448,3 @@ def run_text_checks(txt, names=None, **kwargs):
         out.append(fn(txt, **{k: v for k, v in kwargs.items()
                               if k in accepted}))
     return out
-
-
-def compiled_cost(compiled):
-    """``compiled.cost_analysis()`` across jax versions: newer jaxlibs
-    return the properties dict directly, older ones a one-element list
-    of it (one per computation)."""
-    ca = compiled.cost_analysis()
-    return ca[0] if isinstance(ca, (list, tuple)) else ca

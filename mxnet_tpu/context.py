@@ -36,11 +36,10 @@ _JAX_BACKEND_FOR = {"cpu": "cpu", "cpu_pinned": "cpu", "cpu_shared": "cpu",
 
 
 def _accelerator_platform():
-    """Best available accelerator platform name ('tpu' or fallback 'cpu')."""
-    try:
-        return jax.default_backend()
-    except Exception:  # pragma: no cover
-        return "cpu"
+    """The default backend's platform name.  A backend that fails to
+    initialize raises here: an accelerator that cannot start is never
+    the CPU."""
+    return jax.default_backend()
 
 
 class Context:

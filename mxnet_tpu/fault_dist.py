@@ -220,18 +220,10 @@ def initialize(coordinator_address=None, num_processes=None, process_id=None,
                                                op="initialize"):
                 raise _fault.InjectedFault(
                     "injected jax.distributed bootstrap failure")
-            try:
-                jax.distributed.initialize(
-                    coordinator_address=coordinator_address,
-                    num_processes=num_processes, process_id=process_id,
-                    **kwargs)
-            except TypeError:
-                # older jax without initialization_timeout
-                kwargs.pop("initialization_timeout", None)
-                jax.distributed.initialize(
-                    coordinator_address=coordinator_address,
-                    num_processes=num_processes, process_id=process_id,
-                    **kwargs)
+            jax.distributed.initialize(
+                coordinator_address=coordinator_address,
+                num_processes=num_processes, process_id=process_id,
+                **kwargs)
             log.info("jax.distributed bootstrap OK (coordinator=%s, "
                      "process %s/%s, attempt %d)", coordinator_address,
                      process_id, num_processes, attempt)
@@ -559,25 +551,22 @@ class CoordServiceComm(_RoundComm):
             # entering the barrier, so after a full barrier timeout any
             # participating rank's vote is already listed; per-rank
             # probing would stall this error path O(world * probe) on a
-            # large job.  Only when the server cannot list do we fall
+            # large job.  Only when the listing itself fails do we fall
             # back to per-rank blocking gets, with a realistic per-key
             # deadline (a 1ms get would time out on any real network and
             # misreport LIVE ranks as missing); our own vote is
             # known-set, skip probing it
             probe_ms = max(1000, min(5000, ms))
             peers = [r for r in range(self.world) if r != self.rank]
-            missing = None
-            dir_get = getattr(self._client, "key_value_dir_get", None)
-            if dir_get is not None:
-                try:
-                    prefix = "/%s_fault_ag/%d/" % (self._ns, rnd)
-                    present = {int(k.rsplit("/", 1)[-1])
-                               for k, _ in dir_get(prefix)}
-                    missing = [r for r in peers if r not in present]
-                # mxlint: disable=R4 -- feature probe (older jaxlib has
-                # no dir listing); falls back to per-rank gets below
-                except Exception:  # noqa: BLE001 — older server: no dir
-                    missing = None
+            try:
+                prefix = "/%s_fault_ag/%d/" % (self._ns, rnd)
+                present = {int(k.rsplit("/", 1)[-1]) for k, _ in
+                           self._client.key_value_dir_get(prefix)}
+                missing = [r for r in peers if r not in present]
+            # mxlint: disable=R4 -- the listing is the fast path of an
+            # error path; the per-rank gets below are authoritative
+            except Exception:  # noqa: BLE001 — grpc error types vary
+                missing = None
             if missing is None:
                 missing = []
                 for r in peers:
@@ -619,30 +608,25 @@ class CoordServiceComm(_RoundComm):
         fetches the whole round in a single coordinator round-trip —
         the success path stays O(1) in world size instead of paying
         ``world`` sequential blocking gets per collective.  Falls back
-        to per-rank gets on older jaxlib or a short dir listing."""
+        to per-rank gets on a failed or short dir listing."""
         prefix = "/%s_fault_ag/%d/" % (self._ns, rnd)
-        dir_get = getattr(self._client, "key_value_dir_get", None)
-        if dir_get is not None:
-            try:
-                votes = {int(k.rsplit("/", 1)[-1]): json.loads(v)
-                         for k, v in dir_get(prefix)}
-                return [votes[r] for r in range(self.world)]
-            # mxlint: disable=R4 -- fast-path probe; the per-rank gets
-            # below are authoritative and re-raise anything real
-            except Exception:  # noqa: BLE001 — grpc/format errors both
-                pass  # per-rank gets below are authoritative
+        try:
+            votes = {int(k.rsplit("/", 1)[-1]): json.loads(v)
+                     for k, v in self._client.key_value_dir_get(prefix)}
+            return [votes[r] for r in range(self.world)]
+        # mxlint: disable=R4 -- fast path; the per-rank gets below are
+        # authoritative and re-raise anything real
+        except Exception:  # noqa: BLE001 — grpc/format errors both
+            pass  # per-rank gets below are authoritative
         return [json.loads(self._client.blocking_key_value_get(
             self._key(rnd, r), ms)) for r in range(self.world)]
 
 
 def _coord_client():
-    try:
-        from jax._src import distributed
-        return distributed.global_state.client
-    # mxlint: disable=R4 -- probes jax internals only; absence of a
-    # coordination client is the answer, not an error
-    except Exception:  # noqa: BLE001 — internal layout varies across jax
-        return None
+    """The coordination-service client ``jax.distributed.initialize``
+    made (None before it ran)."""
+    from jax._src import distributed
+    return distributed.global_state.client
 
 
 _default_comm = None
@@ -700,13 +684,8 @@ def default_comm():
 def _backends_live():
     """True when an XLA backend has already been initialized (so
     querying ``jax.process_count()`` is free of side effects)."""
-    try:
-        from jax._src import xla_bridge
-        return bool(xla_bridge._backends)
-    # mxlint: disable=R4 -- probes jax internals only; "cannot tell" is
-    # safely treated as "no live backend"
-    except Exception:  # noqa: BLE001 — internal layout varies across jax
-        return False
+    from jax._src import xla_bridge
+    return bool(xla_bridge._backends)
 
 
 def set_default_comm(comm):

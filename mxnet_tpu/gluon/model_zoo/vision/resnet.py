@@ -5,7 +5,7 @@ survey's build-config model; V1 follows the b-variant with stride on the
 ``layout="NHWC"`` builds the channels-last variant: same architecture and
 parameter *names*, weights stored OHWI, BN over the trailing axis.  On TPU
 this is the MXU-native layout (PERF.md lever 1) — XLA:TPU skips the
-relayout passes the NCHW backward convs need.
+re-layout passes the NCHW backward convs need.
 """
 from __future__ import annotations
 

@@ -158,7 +158,7 @@ def _allreduce_fn():
     A *real* allreduce: each process contributes its local shard of a
     global (n_workers, ...) array and XLA inserts the collective — O(1)
     memory per worker, unlike the round-1 allgather+host-sum
-    (VERDICT.md "weak" #4).  Rides ICI within a slice, DCN across.
+    (VERDICT "weak" #4).  Rides ICI within a slice, DCN across.
     """
     import numpy as onp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P

@@ -904,7 +904,7 @@ def apply_along_axis(func1d, axis, arr, *args, **kw):
 
 
 # ----------------------------------------------------------------------
-# round-2 op tail (VERDICT.md "missing" probes; reference:
+# round-2 op tail (VERDICT "missing" probes; reference:
 # python/mxnet/numpy/multiarray.py + ndarray/numpy/_op.py)
 # ----------------------------------------------------------------------
 polyval = _binary(jnp.polyval, name="polyval")

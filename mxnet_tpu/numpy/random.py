@@ -233,18 +233,10 @@ def poisson(lam=1.0, size=None, ctx=None, device=None, out=None):
 
 def _multinomial_counts(key, n, pv, batch=()):
     """Multinomial counts of ``n`` draws over the last axis of ``pv``
-    (probabilities, broadcast over ``batch``).  jax.random grew a
-    native ``multinomial`` only recently — sample the categorical and
-    sum one-hots, which is exact and version-independent."""
-    fn = getattr(jax.random, "multinomial", None)
-    if fn is not None:
-        return fn(key, n, pv, shape=(tuple(batch) + pv.shape[-1:])
-                  if batch else None)
-    logits = jnp.log(jnp.maximum(jnp.asarray(pv, jnp.float32), 0))
-    idx = jax.random.categorical(key, logits,
-                                 shape=(int(n),) + tuple(batch))
-    return jax.nn.one_hot(idx, logits.shape[-1],
-                          dtype=jnp.float32).sum(0)
+    (probabilities, broadcast over ``batch``)."""
+    return jax.random.multinomial(
+        key, n, pv,
+        shape=(tuple(batch) + pv.shape[-1:]) if batch else None)
 
 
 def multinomial(n, pvals, size=None):

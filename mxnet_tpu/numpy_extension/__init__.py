@@ -463,7 +463,7 @@ def load(file):
 
 
 # ----------------------------------------------------------------------
-# round-2 op tail (VERDICT.md probes)
+# round-2 op tail (VERDICT probes)
 # ----------------------------------------------------------------------
 
 def batch_dot(lhs, rhs, transpose_a=False, transpose_b=False,

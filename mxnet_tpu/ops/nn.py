@@ -51,7 +51,7 @@ def channels_last(layout):
 
     The reference supports these on GPU only (``convolution-inl.h:107``);
     here they are first-class because XLA:TPU tiles channels-last convs
-    without the relayout passes NCHW needs (PERF.md lever 1).  This is the
+    without the re-layout passes NCHW needs (PERF.md lever 1).  This is the
     single source of truth for layout classification — gluon layers and the
     model zoo import it."""
     return layout in ("NWC", "NHWC", "NDHWC")
@@ -227,7 +227,7 @@ def batch_norm_train(x, gamma, beta, eps=1e-5, axis=1):
     the variance reduction loses ~3 decimal digits otherwise (reference
     BN uses fp32 accumulators, ``src/operator/nn/batch_norm.cc``).
     Arbitrary ``axis`` is reduced natively (no transpose) so channels-last
-    layouts stay relayout-free."""
+    layouts stay re-layout-free."""
     axis = axis % x.ndim
     axes = tuple(i for i in range(x.ndim) if i != axis)
     xf = x.astype(jnp.float32)

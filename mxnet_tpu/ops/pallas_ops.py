@@ -23,6 +23,12 @@ dS = P∘(dP - Δ) recurrence as Δ := rowsum(dO∘O) - dlse.
 On non-TPU backends everything falls back to XLA dense attention (with an
 identical lse), so tests run anywhere; set MXNET_PALLAS_INTERPRET=1 to run
 the actual kernels in interpret mode on CPU.
+
+Under a device mesh (``parallel.mesh_scope``) the model-facing entry
+points ``flash_attention`` and ``paged_attention`` run the kernel per
+shard inside a ``shard_map`` over the ``dp`` (batch) and ``tp`` (heads)
+axes: GSPMD cannot partition a Mosaic kernel, and batch rows and heads
+are independent, so the per-shard kernel is exact.
 """
 from __future__ import annotations
 
@@ -31,21 +37,40 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from .nn import dot_product_attention
 
 _INTERPRET = os.environ.get("MXNET_PALLAS_INTERPRET", "0") == "1"
 NEG_INF = float("-inf")
 
+#: VMEM the TPU compiler grants one kernel unasked, and the most these
+#: kernels ask for.  A v5e core has 128 MiB; the rest is left to the
+#: compiler's own scratch.  tests/test_chip_compile.py holds both to the
+#: described chip's compiler.
+_VMEM_DEFAULT = 16 << 20
+_VMEM_MAX = 100 << 20
+#: allowed on top of the resident rows for a kernel's own tiles
+_VMEM_SLACK = 4 << 20
+
 
 def _pallas_available():
+    """True where the kernels run: compiled on a TPU, interpreted on
+    the CPU under MXNET_PALLAS_INTERPRET=1.  On a TPU a Pallas the
+    installed JAX cannot import raises here instead of turning every
+    attention into the dense stand-in."""
+    backend = jax.default_backend()
     if _INTERPRET:
+        if backend != "cpu":
+            raise RuntimeError(
+                "MXNET_PALLAS_INTERPRET=1 runs the kernels in the Pallas "
+                "interpreter, which is for the CPU platform; unset it on "
+                "%r" % backend)
         return True
-    try:
-        import jax.experimental.pallas  # noqa: F401
-        return jax.default_backend() == "tpu"
-    except Exception:
+    if backend != "tpu":
         return False
+    from jax.experimental.pallas import tpu  # noqa: F401
+    return True
 
 
 def _shapes_ok(q, k):
@@ -53,6 +78,50 @@ def _shapes_ok(q, k):
     Tk = k.shape[-2]
     return (T >= 128 and Tk >= 128 and T % 128 == 0 and Tk % 128 == 0
             and D in (64, 128, 256))
+
+
+def _row_params(T, D, dtype, stats=False):
+    """Compiler params for a kernel that keeps two whole (T, D) rows of
+    one head in VMEM, double-buffered by the pipeline — K and V in the
+    forward and dq kernels, Q and dO (plus the lse and delta rows,
+    ``stats``) in dkv.  Resident rows are fetched once per head where a
+    grid axis would stream them once per opposite block; the price is a
+    bound on T, raised here instead of left to the compiler."""
+    from jax.experimental.pallas import tpu as pltpu
+    # a (1, T) fp32 row pads to 8 sublanes: 2 rows x 2 buffers x 32 T
+    per_token = 4 * D * jnp.dtype(dtype).itemsize + (128 if stats else 0)
+    need = T * per_token + _VMEM_SLACK
+    if need > _VMEM_MAX:
+        raise ValueError(
+            "flash_attention: a %d-token row needs %d MiB of VMEM "
+            "resident (head_dim %d, %s) and the kernels may use %d MiB; "
+            "the largest supported length is %d tokens — split the "
+            "sequence over devices (parallel.ring_attention_sharded)"
+            % (T, need >> 20, D, jnp.dtype(dtype).name, _VMEM_MAX >> 20,
+               (_VMEM_MAX - _VMEM_SLACK) // per_token // 128 * 128))
+    if need <= _VMEM_DEFAULT:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=need)
+
+
+def _shard_axes(batch, heads):
+    """``(mesh, dp, tp)`` when a kernel call has to be wrapped in a
+    ``shard_map``: a mesh of several devices is in scope and this trace
+    is not per-shard already (``parallel/ring.py`` calls the kernels
+    inside its own).  ``dp``/``tp`` are those axis names where the mesh
+    has them and they divide ``batch`` / every head count in ``heads``;
+    None leaves that dimension whole on every device."""
+    from ..parallel.mesh import current_mesh
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1 \
+            or jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+
+    def axis(name, sizes):
+        n = mesh.shape.get(name, 1)
+        return name if n > 1 and all(s % n == 0 for s in sizes) else None
+
+    return mesh, axis("dp", (batch,)), axis("tp", heads)
 
 
 # ---------------------------------------------------------------------------
@@ -137,6 +206,7 @@ def _fwd_call(q, k, v, q_off, k_off, causal, scale, bq=128, bk=128):
         ],
         out_specs=(pl.BlockSpec((1, bq, D), lambda bh, i: (bh, i, 0)),
                    pl.BlockSpec((1, 1, bq), lambda bh, i: (bh, 0, i))),
+        compiler_params=_row_params(Tk, D, k.dtype),
         interpret=_INTERPRET,
     )(q_off, k_off, q, k, v)
     return out, lse
@@ -220,6 +290,7 @@ def _bwd_dq_call(q, k, v, do, lse, delta, q_off, k_off, causal, scale,
             pl.BlockSpec((1, 1, bq), lambda bh, i: (bh, 0, i)),
         ],
         out_specs=pl.BlockSpec((1, bq, D), lambda bh, i: (bh, i, 0)),
+        compiler_params=_row_params(Tk, D, k.dtype),
         interpret=_INTERPRET,
     )(q_off, k_off, q, k, v, do, lse, delta)
 
@@ -323,6 +394,7 @@ def _bwd_dkv_call(q, k, v, do, lse, delta, q_off, k_off, causal, scale,
                    pl.BlockSpec((1, bk, D), lambda g, j, r: (g, j, 0))),
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
+        compiler_params=_row_params(T, D, q.dtype, stats=True),
         interpret=_INTERPRET,
     )(q_off, k_off, q, k, v, do, lse, delta)
 
@@ -610,14 +682,6 @@ def _paged_shapes_ok(q, k_pages):
     return psz >= 128 and psz % 128 == 0 and D in (64, 128, 256)
 
 
-def _paged_force():
-    # tools/hlo_snapshot.py AOT-compiles the decode program for a TPU
-    # topology with no live chips: jax.default_backend() is cpu there,
-    # so the kernel path needs an explicit override to land in the
-    # pinned artifact
-    return os.environ.get("MXNET_PALLAS_FORCE", "0") == "1"
-
-
 def _paged_kernel_call(q, k_pages, v_pages, page_table, lengths, scale):
     """Pallas page-table decode attention: grid (slot, kv-head, page),
     the page axis innermost so each (slot, head) accumulates an online
@@ -746,22 +810,30 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, scale=None):
     ``lengths``: (S,) int32 — tokens to attend over per slot, the new
     token included.  A slot with ``lengths == 0`` returns zeros.
 
-    On TPU (or under ``MXNET_PALLAS_FORCE=1`` — the chips-free AOT
-    snapshot path) with kernel-friendly shapes this is a Pallas
-    scalar-prefetch kernel whose page reads are driven by the page
-    table directly; elsewhere a dense gather fallback with identical
-    semantics."""
+    On TPU with kernel-friendly shapes this is a Pallas scalar-prefetch
+    kernel whose page reads are driven by the page table directly —
+    under a mesh one kernel per ``tp`` shard of the heads, each over
+    its own shard of the pools; elsewhere a dense gather fallback with
+    identical semantics."""
     if q.shape[1] % k_pages.shape[1] != 0:
         raise ValueError(
             "paged_attention: %d query heads not a multiple of %d kv "
             "heads" % (q.shape[1], k_pages.shape[1]))
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    if (_pallas_available() or _paged_force()) \
-            and _paged_shapes_ok(q, k_pages):
-        return _paged_kernel_call(q, k_pages, v_pages, page_table,
-                                  lengths, scale)
-    return _paged_dense(q, k_pages, v_pages, page_table, lengths, scale)
+    if not _pallas_available() or not _paged_shapes_ok(q, k_pages):
+        return _paged_dense(q, k_pages, v_pages, page_table, lengths,
+                            scale)
+    kernel = functools.partial(_paged_kernel_call, scale=scale)
+    sharded = _shard_axes(1, (q.shape[1], k_pages.shape[1]))
+    if sharded is not None:
+        mesh, _, tp = sharded
+        kernel = jax.shard_map(
+            kernel, mesh=mesh,
+            in_specs=(P(None, tp, None), P(None, tp, None, None),
+                      P(None, tp, None, None), P(), P()),
+            out_specs=P(None, tp, None), check_vma=False)
+    return kernel(q, k_pages, v_pages, page_table, lengths)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
@@ -769,8 +841,10 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
     """Blocked flash attention on (B, H, T, D), Pallas forward + backward.
 
     k/v may carry fewer (grouped/multi-query) heads — see
-    ``flash_attention_with_lse``.  Falls back to XLA dense attention
-    off-TPU or for unsupported shapes."""
+    ``flash_attention_with_lse``.  Under a mesh the kernels run per
+    ``dp`` shard of the batch and ``tp`` shard of the heads.  Falls back
+    to XLA dense attention off-TPU or for unsupported shapes; a row too
+    long for the kernels' VMEM raises (``_row_params``)."""
     if q.shape[1] % k.shape[1] != 0:
         raise ValueError(
             "flash_attention: %d query heads not a multiple of %d kv "
@@ -783,7 +857,16 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
             k = jnp.repeat(k, rep, axis=1)
             v = jnp.repeat(v, rep, axis=1)
         return dot_product_attention(q, k, v, causal=causal, scale=scale)
-    o, _ = _flash_lse(q, k, v, jnp.zeros((1,), jnp.int32),
-                      jnp.zeros((1,), jnp.int32), causal, scale, block_q,
-                      block_k)
-    return o
+
+    def kernel(q, k, v):
+        zero = jnp.zeros((1,), jnp.int32)
+        return _flash_lse(q, k, v, zero, zero, causal, scale, block_q,
+                          block_k)[0]
+
+    sharded = _shard_axes(q.shape[0], (q.shape[1], k.shape[1]))
+    if sharded is not None:
+        mesh, dp, tp = sharded
+        spec = P(dp, tp, None, None)
+        kernel = jax.shard_map(kernel, mesh=mesh, in_specs=(spec,) * 3,
+                               out_specs=spec, check_vma=False)
+    return kernel(q, k, v)

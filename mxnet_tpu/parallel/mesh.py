@@ -121,6 +121,12 @@ def current_mesh():
 
 @contextmanager
 def mesh_scope(mesh):
+    """Make ``mesh`` the one :func:`current_mesh` answers with (and
+    jax's own context mesh) inside the block.  ``mesh_scope(None)`` is a
+    no-op, so callers with an optional mesh need no branch."""
+    if mesh is None:
+        yield None
+        return
     prev = getattr(_STATE, "mesh", None)
     _STATE.mesh = mesh
     try:

@@ -49,8 +49,7 @@ def gpipe_forward(stage_fn, params_stacked, x_microbatches, axis_name="pp"):
     x_microbatches: (M, ...) microbatch-major input (replicated)
     Returns final-stage outputs (M, ...).
     """
-    from ._compat import axis_size
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     my_params = jax.tree_util.tree_map(lambda a: a[0], params_stacked)
     M = x_microbatches.shape[0]
@@ -449,8 +448,6 @@ def pipeline_vjp(stage_fn, params_stacked, x, gy, mesh, num_microbatches,
     coordinated/retry call; ``mutating=True`` aborts every worker on a
     mid-op failure instead of re-running the mutation).
     """
-    from ._compat import shard_map as _shard_map
-
     n = mesh.shape[axis_name]
     v = _resolve_stages(schedule, virtual_stages, params_stacked, n)
     B = x.shape[0]
@@ -470,8 +467,10 @@ def pipeline_vjp(stage_fn, params_stacked, x, gy, mesh, num_microbatches,
 
     def attempt():
         _fault.collective_check("pipeline")
-        return _shard_map(body, mesh, (pspec, P(), P()),
-                          (P(), P(), pspec))(params_dev, xm, gym)
+        return jax.shard_map(
+            body, mesh=mesh, in_specs=(pspec, P(), P()),
+            out_specs=(P(), P(), pspec),
+            check_vma=False)(params_dev, xm, gym)
 
     outs, dxs, dparams = _launch(attempt, mutating, _comm, _gen)
     y = outs.reshape((B,) + outs.shape[2:])
@@ -512,8 +511,6 @@ def pipeline_apply(stage_fn, params_stacked, x, mesh, num_microbatches,
     training path with a real 1F1B steady state is
     :func:`pipeline_vjp`.
     """
-    from ._compat import shard_map as _shard_map
-
     n = mesh.shape[axis_name]
     v = _resolve_stages(schedule, virtual_stages, params_stacked, n)
     B = x.shape[0]
@@ -541,7 +538,8 @@ def pipeline_apply(stage_fn, params_stacked, x, mesh, num_microbatches,
 
     def attempt():
         _fault.collective_check("pipeline")
-        return _shard_map(body, mesh, (pspec, P()), P())(*args)
+        return jax.shard_map(body, mesh=mesh, in_specs=(pspec, P()),
+                             out_specs=P(), check_vma=False)(*args)
 
     out = _launch(attempt, mutating, _comm, _gen)
     return out.reshape((B,) + out.shape[2:])

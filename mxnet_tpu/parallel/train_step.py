@@ -23,6 +23,7 @@ from .. import _tape
 from .. import fault as _fault
 from ..ndarray.ndarray import NDArray
 from ..numpy import random as _random
+from .mesh import mesh_scope
 from .sharding import _valid_spec, param_sharding
 
 P = PartitionSpec
@@ -155,6 +156,16 @@ class TrainStep:
             return loss_arr, mutated
 
         def step(param_arrays, opt_states, t, lr, key, *batch):
+            # the body runs once, as jit traces it: with the step's
+            # mesh in scope, so that what asks current_mesh() — the
+            # blocks' activation constraints, the per-shard wrap of the
+            # Pallas kernels — sees it without a mesh_scope of the
+            # caller's
+            with mesh_scope(self.mesh):
+                return sharded_step(param_arrays, opt_states, t, lr, key,
+                                    *batch)
+
+        def sharded_step(param_arrays, opt_states, t, lr, key, *batch):
             train_sub = {n: param_arrays[n] for n in trainable}
             frozen = {n: a for n, a in param_arrays.items()
                       if n not in train_sub}

@@ -37,7 +37,6 @@ Knobs (environment, all optional)::
     MXNET_SERVE_PAGES        page-pool budget incl. trash    (64)
     MXNET_SERVE_LADDER       prefill pad lengths, csv        (64,128,256)
     MXNET_SERVE_MAX_NEW      default per-request output cap  (64)
-    MXNET_SERVE_CACHE_DIR    persistent compile-cache dir    (unset)
     MXNET_SERVE_INT8         int8 weight path                (0)
     MXNET_SERVE_TEMP         default sampling temperature    (0 = greedy)
     MXNET_SERVE_TOP_K        default top-k cutoff            (0 = off)
@@ -92,6 +91,7 @@ from . import fault as _fault
 from . import flightrec as _flightrec
 from . import profiler as _profiler
 from . import telemetry as _telemetry
+from .utils import compile_cache as _ccache
 
 log = logging.getLogger("mxnet_tpu.serve")
 
@@ -157,8 +157,7 @@ class ServeConfig:
         self.max_new = _env_int("MXNET_SERVE_MAX_NEW", 64) \
             if max_new is None else int(max_new)
         self.eos_id = eos_id
-        self.cache_dir = env.get("MXNET_SERVE_CACHE_DIR") \
-            if cache_dir is None else cache_dir
+        self.cache_dir = cache_dir
         self.int8 = (env.get("MXNET_SERVE_INT8", "0") not in
                      ("", "0", "false", "False")) if int8 is None \
             else bool(int8)
@@ -1071,6 +1070,37 @@ def _build_copy_fn():
     return copy
 
 
+@contextlib.contextmanager
+def _cache_at(cache_dir):
+    """Point jax's persistent compile cache at ``cache_dir`` for the
+    compiles inside, admitting sub-second serving programs, and put
+    the process's settings back after — unrelated jit traffic must not
+    inherit a zero-threshold cache in the serve directory.  No-op for
+    ``cache_dir=None``."""
+    if not cache_dir:
+        yield
+        return
+    import jax
+    from jax.experimental.compilation_cache import \
+        compilation_cache as cc
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    restore = {k: getattr(jax.config, k) for k in keys}
+    for k, v in zip(keys, (cache_dir, 0, 0)):
+        jax.config.update(k, v)
+    # the cache latches its settings at the process's first compile
+    # (parameter init, usually): re-arm it for these programs, and
+    # again after, so the next compile latches the restored ones
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        for k, v in restore.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
 class WarmPool:
     """AOT-compile the serving programs for the fixed shape ladder at
     startup, behind jax's persistent compile cache.
@@ -1090,6 +1120,7 @@ class WarmPool:
         import jax.numpy as jnp
 
         from .models.kv_cache import init_pools
+        from .parallel.mesh import mesh_scope
         t0 = time.monotonic()
         cfg = net.cfg
         self.cfg = cfg
@@ -1103,40 +1134,14 @@ class WarmPool:
             params, scales = quantize_weights(params)
         self.params = params
         self.scales = scales
-        cache_dir = serve_cfg.cache_dir
-        _cc, restore = None, None
-        if cache_dir:
-            # this jax build ignores the env var; config.update is the
-            # authoritative switch (same lesson bench.py learned), and
-            # the thresholds must admit sub-second serving programs —
-            # but only for OUR compiles: the prior values are restored
-            # below so unrelated jit traffic doesn't inherit a
-            # zero-threshold cache pointed at the serve dir
-            restore = {
-                "jax_compilation_cache_dir":
-                    jax.config.jax_compilation_cache_dir,
-                "jax_persistent_cache_min_compile_time_secs":
-                    jax.config
-                    .jax_persistent_cache_min_compile_time_secs,
-                "jax_persistent_cache_min_entry_size_bytes":
-                    jax.config
-                    .jax_persistent_cache_min_entry_size_bytes,
-            }
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0)
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", 0)
-            try:
-                # the cache latches its state at the process's FIRST
-                # compile — param init above already compiled with
-                # caching off, so re-arm it for the serving programs
-                from jax.experimental.compilation_cache import \
-                    compilation_cache as _cc
-                _cc.reset_cache()
-            except Exception:  # pragma: no cover - old jax layouts
-                _cc = None
-        before = self._cache_entries(cache_dir)
+        # JAX_COMPILATION_CACHE_DIR, when set, is the cache: the pool
+        # compiles into it and counts its hits there.  Only without it
+        # does an explicit ServeConfig(cache_dir=) repoint the cache,
+        # and only around this pool's own compiles
+        own_dir = None if _ccache.placed_from_outside() \
+            else serve_cfg.cache_dir
+        cache_dir = own_dir or jax.config.jax_compilation_cache_dir
+        before = _ccache.cache_entries(cache_dir)
         dtype = jnp.dtype(cfg.dtype)
         spec = self.spec
         self.k_pages, self.v_pages = init_pools(spec)
@@ -1176,7 +1181,9 @@ class WarmPool:
                for k, v in params.items()}
         i32 = lambda *shape: aval(shape, jnp.int32, shard_rep)  # noqa: E731
         f32 = lambda *shape: aval(shape, jnp.float32, shard_rep)  # noqa: E731
-        try:
+        # the mesh is in scope while the programs trace, so the
+        # Pallas kernels wrap themselves per tp shard
+        with _cache_at(own_dir), mesh_scope(mesh):
             decode = _build_decode_fn(net, ps, spec.page_size, scales,
                                       dtype)
             S, MP = spec.slots, spec.max_pages_per_slot
@@ -1211,19 +1218,7 @@ class WarmPool:
             self._copy = jax.jit(
                 _build_copy_fn(), donate_argnums=(0, 1)).lower(
                 pool_aval, pool_aval, i32(), i32()).compile()
-        finally:
-            if restore is not None:
-                for k, v in restore.items():
-                    jax.config.update(k, v)
-                if _cc is not None:
-                    try:
-                        # drop the latched serve-dir cache instance so
-                        # the next unrelated compile re-latches from
-                        # the restored config
-                        _cc.reset_cache()
-                    except Exception:  # pragma: no cover
-                        pass
-        new = self._cache_entries(cache_dir) - before
+        new = _ccache.cache_entries(cache_dir) - before
         self.stats = {
             "compile_s": round(time.monotonic() - t0, 3),
             "programs": 2 + len(self._prefill) + len(self._chunk),
@@ -1237,12 +1232,6 @@ class WarmPool:
                  self.stats["programs"], self.stats["compile_s"],
                  " (persistent-cache hit)" if self.stats["cache_hit"]
                  else "")
-
-    @staticmethod
-    def _cache_entries(cache_dir):
-        if not cache_dir or not os.path.isdir(cache_dir):
-            return 0
-        return sum(len(files) for _, _, files in os.walk(cache_dir))
 
     def ladder_fit(self, n):
         """Smallest ladder length holding an n-token prompt (None when
@@ -1789,6 +1778,7 @@ def lower_decode_program(cfg=None, serve_cfg=None, mesh=None,
     import jax.numpy as jnp
 
     from .models import TransformerLM, tiny_config
+    from .parallel.mesh import mesh_scope
     cfg = cfg or tiny_config()
     serve_cfg = serve_cfg or ServeConfig(slots=4, page_size=128,
                                          pages=16, ladder=(128,),
@@ -1828,10 +1818,11 @@ def lower_decode_program(cfg=None, serve_cfg=None, mesh=None,
     decode = _build_decode_fn(net, ps, spec.page_size, {}, dt)
     i32 = lambda *shape: av(shape, jnp.int32, shard_rep)  # noqa: E731
     f32 = lambda *shape: av(shape, jnp.float32, shard_rep)  # noqa: E731
-    lowered = jax.jit(decode, donate_argnums=(1, 2)).lower(
-        pav, pool_aval, pool_aval, i32(S, MP), i32(S), i32(S),
-        av((S,), jnp.bool_, shard_rep),
-        i32(S), i32(S), f32(S), i32(S), f32(S))
+    with mesh_scope(mesh):
+        lowered = jax.jit(decode, donate_argnums=(1, 2)).lower(
+            pav, pool_aval, pool_aval, i32(S, MP), i32(S), i32(S),
+            av((S,), jnp.bool_, shard_rep),
+            i32(S), i32(S), f32(S), i32(S), f32(S))
     info = {"pool_shape": pool_shape, "slots": S,
             "max_pages_per_slot": MP}
     if shard_pool is not None:

@@ -130,13 +130,28 @@ class ReplicaGroup:
 
     @classmethod
     def build(cls, net, serve_cfg=None, replicas=2, mesh=None, **kw):
-        """Construct ``replicas`` warm-pool Servers over one model.
-        They share ``serve_cfg`` (and so its compile-cache dir): the
-        first replica pays any compilation, the rest spin up warm."""
+        """Construct ``replicas`` warm-pool Servers over one model,
+        sharing ``serve_cfg``.  One process drives every chip of its
+        host, so replica *i* lives on local device *i* (round-robin
+        past the device count) as a one-device mesh, with its own copy
+        of the weights and its own KV pools; a program is compiled per
+        device.  On a one-device host, or with an explicit ``mesh``
+        (replicas sharded over the same devices), they all share it."""
+        import jax
+
+        from .parallel.mesh import create_mesh
         from .serve import ServeConfig
         cfg = serve_cfg or ServeConfig()
-        servers = [Server(net, serve_cfg=cfg, mesh=mesh)
-                   for _ in range(int(replicas))]
+        devices = jax.local_devices()
+
+        def placed(i):
+            if mesh is not None or len(devices) == 1:
+                return mesh
+            return create_mesh(dp=1, tp=1,
+                               devices=[devices[i % len(devices)]])
+
+        servers = [Server(net, serve_cfg=cfg, mesh=placed(i))
+                   for i in range(int(replicas))]
         return cls(servers, **kw)
 
     # -- seams ----------------------------------------------------------

@@ -1,4 +1,3 @@
 """Utility subpackage: serialization, config/env flags, misc helpers."""
 from . import serialization  # noqa: F401
 from .config import env_bool, env_int, env_str  # noqa: F401
-from .device_probe import probe_device_count, probe_device_kind  # noqa: F401

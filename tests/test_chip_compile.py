@@ -1,0 +1,157 @@
+"""The main path's kernels, compiled for the chip they run on.
+
+The TPU's compiler is installed beside the CPU suite and compiles for a
+chip that is described, not attached (``jax.experimental.topologies``).
+It refuses what interpret mode lets through: a kernel that keeps more
+in VMEM than a v5e core grants, and a Mosaic call left for GSPMD to
+partition.  Each case compiles a kernel at Llama-3-8B head layout
+(32 query / 8 KV heads of 128, bf16) from shapes alone and asserts the
+kernel is in the compiled text — nothing runs, so nothing here is a
+result or a time.
+
+This is the ONLY test file that loads the chip's compiler: one process
+at a time may hold the library, so the topology is described inside the
+module-scoped fixture below (never at import), and every compile
+happens in the test's own process.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as onp
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+import mxnet_tpu as mx
+from mxnet_tpu import gluon, parallel
+from mxnet_tpu.gluon import nn
+from mxnet_tpu.ops import pallas_ops
+
+B, H, HKV, D = 1, 32, 8, 128
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_SKIP_MDS_QUERY", "true")  # no GCE probe
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip("no v5e:2x2 topology can be described here: %s"
+                    % str(e)[:200])
+
+
+@pytest.fixture
+def on_chip(monkeypatch):
+    """The kernel gate reads the default backend, which is the CPU in
+    this suite: answer for it as a chip would."""
+    monkeypatch.setattr(pallas_ops, "_pallas_available", lambda: True)
+    monkeypatch.setattr(pallas_ops, "_INTERPRET", False)
+
+
+def _qkv(T, sharding, batch=B):
+    return (jax.ShapeDtypeStruct((batch, H, T, D), jnp.bfloat16,
+                                 sharding=sharding),
+            jax.ShapeDtypeStruct((batch, HKV, T, D), jnp.bfloat16,
+                                 sharding=sharding),
+            jax.ShapeDtypeStruct((batch, HKV, T, D), jnp.bfloat16,
+                                 sharding=sharding))
+
+
+def _flash(q, k, v):
+    return pallas_ops.flash_attention(q, k, v, causal=True)
+
+
+def _flash_grad(q, k, v):
+    return jax.grad(lambda *a: _flash(*a).astype(jnp.float32).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+def _kernels(fn, *avals):
+    return jax.jit(fn).lower(*avals).compile().as_text() \
+        .count("tpu_custom_call")
+
+
+# 32768 is past the 16 MiB of VMEM a kernel gets unasked: K and V rows
+# of 8 MiB each, double-buffered.  The kernels ask for what they hold
+# (pallas_ops._row_params), so the advertised length compiles.
+@pytest.mark.parametrize("T", [2048, 8192, 32768])
+def test_flash_forward_compiles(topo, on_chip, T):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    assert _kernels(_flash, *_qkv(T, one_chip)) == 1
+
+
+@pytest.mark.parametrize("T", [2048, 8192, 32768])
+def test_flash_grad_compiles(topo, on_chip, T):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    # the forward, dq and dkv kernels
+    assert _kernels(_flash_grad, *_qkv(T, one_chip)) == 3
+
+
+def _paged_avals(sh):
+    S, pages, psz, MP = 8, 64, 128, 9
+    pool = jax.ShapeDtypeStruct((pages, HKV, psz, D), jnp.bfloat16,
+                                sharding=sh(P(None, "tp", None, None)))
+    return (jax.ShapeDtypeStruct((S, H, D), jnp.bfloat16,
+                                 sharding=sh(P(None, "tp", None))),
+            pool, pool,
+            jax.ShapeDtypeStruct((S, MP), jnp.int32, sharding=sh(P())),
+            jax.ShapeDtypeStruct((S,), jnp.int32, sharding=sh(P())))
+
+
+def test_paged_attention_compiles(topo, on_chip):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    assert _kernels(pallas_ops.paged_attention,
+                    *_paged_avals(lambda spec: one_chip)) == 1
+
+
+# GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+# automatically partitioned"): under a mesh the entry points wrap the
+# kernel in a shard_map over dp (batch) and tp (heads), and the kernel
+# stays in every device's program.
+@pytest.mark.parametrize("fn,n", [(_flash, 1), (_flash_grad, 3)],
+                         ids=["forward", "grad"])
+def test_flash_compiles_on_dp_tp_mesh(topo, on_chip, fn, n):
+    mesh = Mesh(onp.array(topo.devices).reshape(2, 2), ("dp", "tp"))
+    sh = NamedSharding(mesh, P("dp", "tp", None, None))
+    with parallel.mesh_scope(mesh):
+        assert _kernels(fn, *_qkv(2048, sh, batch=2)) == n
+
+
+def test_paged_attention_compiles_on_tp_mesh(topo, on_chip):
+    mesh = Mesh(onp.array(topo.devices[:2]), ("tp",))
+    with parallel.mesh_scope(mesh):
+        assert _kernels(
+            pallas_ops.paged_attention,
+            *_paged_avals(lambda spec: NamedSharding(mesh, spec))) == 1
+
+
+def test_train_step_aot_topology_mesh():
+    """TrainStep(aot=True) compiles against a TPU *topology description*
+    with zero chips: the lowered+compiled artifact is the real TPU
+    executable text (the HLO ratchet's evidence source).  Skips when the
+    AOT client is unavailable in this environment."""
+    import os
+    os.environ.setdefault("TPU_SKIP_MDS_QUERY", "true")  # no GCE probe
+    mx.np.random.seed(0)
+    try:
+        from jax.experimental import topologies
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x4")
+    except Exception as e:  # env-dependent: no libtpu/AOT support
+        pytest.skip("TPU AOT topology client unavailable: %s"
+                    % str(e)[:120])
+    mesh = jax.sharding.Mesh(onp.array(topo.devices), ("dp",))
+    net = nn.Dense(16, in_units=32)
+    net.initialize()
+    step = parallel.TrainStep(net, gluon.loss.L2Loss(),
+                              mx.optimizer.SGD(learning_rate=0.1),
+                              mesh=mesh, zero1=True, aot=True)
+    x = mx.np.random.uniform(-1, 1, (16, 32))
+    y = mx.np.random.uniform(-1, 1, (16, 16))
+    txt = step.lower(x, y).compile().as_text()
+    assert "all-gather" in txt  # the sharded update's param gather
+    with pytest.raises(RuntimeError, match="aot"):
+        step(x, y)

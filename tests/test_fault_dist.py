@@ -1256,6 +1256,25 @@ def test_launch_all_ok_returns_zero():
     assert rc == 0
 
 
+def test_launch_refuses_local_workers_that_would_share_tpu_chips(
+        monkeypatch):
+    """One process per chip: -n 2 on a TPU host is refused with a
+    message that says why, unless the workers are CPU by environment;
+    one worker, or a host with no chip, launches as ever."""
+    import sys
+    launch = _launch()
+    monkeypatch.setattr(launch, "_host_tpu_chips", lambda: 4)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(SystemExit, match="one process per chip"):
+        launch.launch_local(2, [sys.executable, "-c", "pass"])
+    launch.check_one_process_per_chip(1)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert launch.launch_local(2, [sys.executable, "-c", "pass"]) == 0
+    monkeypatch.delenv("JAX_PLATFORMS")
+    monkeypatch.setattr(launch, "_host_tpu_chips", lambda: 0)
+    launch.check_one_process_per_chip(2)
+
+
 def test_launch_relays_worker_lines_untorn():
     """Two workers blasting long lines concurrently: every relayed line
     must arrive whole, never spliced with another rank's bytes — workers

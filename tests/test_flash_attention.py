@@ -305,3 +305,84 @@ def test_chunked_fallback_threshold_and_divisibility_gate():
     assert pallas_ops._chunk_for(8192) == 4096
     assert pallas_ops._chunk_for(640) == 128   # falls to a divisor
     assert pallas_ops._chunk_for(60) is None   # no >=128 pow2 divides
+
+
+# ---------------------------------------------------------------------------
+# under a mesh: one kernel per (dp, tp) shard, wrapped in a shard_map
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("axes,hkv", [({"dp": 2, "tp": 2}, 2),
+                                      ({"dp": 2, "tp": 2}, 1),
+                                      ({"tp": 4}, 4)],
+                         ids=["dp2xtp2", "tp-drops-for-mqa", "tp4"])
+def test_flash_per_shard_under_mesh_matches_dense(interpret_kernels, axes,
+                                                  hkv):
+    """GQA forward and grads through the per-shard wrap equal the dense
+    reference: heads and batch rows are independent, so sharding them
+    changes nothing.  With 1 KV head the tp axis cannot split the heads
+    and drops out of the wrap (every tp shard holds them all)."""
+    from mxnet_tpu import parallel
+    B, Hq, T, D = 2, 4, 128, 64
+    q = _rand((B, Hq, T, D), 0)
+    k, v = (_rand((B, hkv, T, D), s) for s in (1, 2))
+    w = jnp.cos(jnp.arange(D, dtype=jnp.float32))
+    rep = Hq // hkv
+
+    def loss_f(q, k, v):
+        return (pallas_ops.flash_attention(q, k, v, causal=True) * w).sum()
+
+    def loss_d(q, k, v):
+        return (dot_product_attention(q, jnp.repeat(k, rep, axis=1),
+                                      jnp.repeat(v, rep, axis=1),
+                                      causal=True) * w).sum()
+
+    mesh = parallel.create_mesh(**axes)
+    with parallel.mesh_scope(mesh):
+        lowered = jax.jit(jax.value_and_grad(loss_f, (0, 1, 2))) \
+            .lower(q, k, v)
+        assert "sdy.manual_computation" in lowered.as_text()
+        lf, gf = lowered.compile()(q, k, v)
+    ld, gd = jax.value_and_grad(loss_d, (0, 1, 2))(q, k, v)
+    assert_almost_equal(onp.asarray(lf), onp.asarray(ld), rtol=2e-4,
+                        atol=2e-4)
+    for a, b in zip(gf, gd):
+        assert_almost_equal(onp.asarray(a), onp.asarray(b), rtol=2e-3,
+                            atol=2e-3)
+
+
+def test_paged_attention_per_shard_under_mesh(interpret_kernels):
+    from mxnet_tpu import parallel
+    S, Hq, Hkv, D, psz, pages, MP = 3, 8, 2, 64, 128, 7, 3
+    rng = onp.random.RandomState(2)
+    q = jnp.asarray(rng.randn(S, Hq, D).astype(onp.float32))
+    kp, vp = (jnp.asarray(rng.randn(pages, Hkv, psz, D)
+                          .astype(onp.float32)) for _ in range(2))
+    pt = jnp.asarray(rng.randint(1, pages, (S, MP)).astype(onp.int32))
+    lens = jnp.asarray(onp.array([5, 3 * psz, 0], onp.int32))
+    dense = pallas_ops._paged_dense(q, kp, vp, pt, lens, D ** -0.5)
+    with parallel.mesh_scope(parallel.create_mesh(tp=2)):
+        lowered = jax.jit(pallas_ops.paged_attention).lower(
+            q, kp, vp, pt, lens)
+        assert "sdy.manual_computation" in lowered.as_text()
+        got = lowered.compile()(q, kp, vp, pt, lens)
+    onp.testing.assert_allclose(onp.asarray(got), onp.asarray(dense),
+                                atol=2e-5)
+
+
+def test_flash_row_past_vmem_raises_with_the_bound(monkeypatch):
+    """The kernels keep a head's whole K/V (dkv: Q/dO) row in VMEM.  A
+    row that cannot fit is the repo's own error, naming the bound and
+    the longest row that does fit — never another implementation."""
+    monkeypatch.setattr(pallas_ops, "_pallas_available", lambda: True)
+    T = 131072
+    q = jax.ShapeDtypeStruct((1, 8, T, 128), jnp.bfloat16)
+    with pytest.raises(ValueError, match=r"100 MiB.*98304 tokens"):
+        jax.eval_shape(
+            lambda q: pallas_ops.flash_attention(q, q, q, causal=True), q)
+
+
+def test_interpret_mode_is_for_the_cpu_platform(monkeypatch):
+    monkeypatch.setattr(pallas_ops, "_INTERPRET", True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="MXNET_PALLAS_INTERPRET"):
+        pallas_ops._pallas_available()

@@ -68,3 +68,32 @@ def test_record_dataset_uses_native(pack):
         "RecordFileDataset did not take the native path"
     assert len(ds) == len(payloads)
     assert bytes(ds[5]) == payloads[5]
+
+
+def test_native_lib_staleness_is_judged_by_content(tmp_path, monkeypatch):
+    """A copied tree has arbitrary mtimes: the library is rebuilt when
+    io_core.cpp's digest is not the one compiled into it, and only
+    then."""
+    import os
+    import shutil
+
+    from mxnet_tpu import _native
+    src = str(tmp_path / "io_core.cpp")
+    out = str(tmp_path / "libmxtpu_io.local.so")
+    shutil.copy(_native._SRC, src)
+    monkeypatch.setattr(_native, "_SRC", src)
+    monkeypatch.setattr(_native, "_OUT", out)
+    builds = []
+    real_build = _native._build
+    monkeypatch.setattr(_native, "_build",
+                        lambda *a: (builds.append(a), real_build(*a)))
+    _native._load()
+    assert len(builds) == 1
+    os.utime(out, (1, 1))           # library looks ancient: still fresh
+    _native._load()
+    assert len(builds) == 1
+    with open(src, "a") as f:       # source changed: rebuilt
+        f.write("\n// edited\n")
+    os.utime(src, (1, 1))           # ... though it looks older than ever
+    assert _native._load().mxtpu_version() == 1
+    assert len(builds) == 2

@@ -2,7 +2,7 @@
 
 The reference supports NHWC/NDHWC convolution on GPU only
 (``src/operator/nn/convolution-inl.h:107``); here it is first-class on TPU
-(PERF.md lever 1: XLA:TPU tiles channels-last convs without the relayout
+(PERF.md lever 1: XLA:TPU tiles channels-last convs without the re-layout
 passes NCHW backward convs need).  Every test asserts exact agreement with
 the NCHW path on the same math.
 """

@@ -336,35 +336,6 @@ def test_pipeline_vjp_1f1b_stash_is_smaller_in_the_program():
     assert "tensor<1x8x%dx%dxf32>" % (mbs, D) in lower("gpipe")
 
 
-def test_train_step_aot_topology_mesh():
-    """TrainStep(aot=True) compiles against a TPU *topology description*
-    with zero chips: the lowered+compiled artifact is the real TPU
-    executable text (the HLO ratchet's evidence source).  Skips when the
-    AOT client is unavailable in this environment."""
-    import os
-    os.environ.setdefault("TPU_SKIP_MDS_QUERY", "true")  # no GCE probe
-    mx.np.random.seed(0)
-    try:
-        from jax.experimental import topologies
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x4")
-    except Exception as e:  # env-dependent: no libtpu/AOT support
-        pytest.skip("TPU AOT topology client unavailable: %s"
-                    % str(e)[:120])
-    mesh = jax.sharding.Mesh(onp.array(topo.devices), ("dp",))
-    net = nn.Dense(16, in_units=32)
-    net.initialize()
-    step = parallel.TrainStep(net, gluon.loss.L2Loss(),
-                              mx.optimizer.SGD(learning_rate=0.1),
-                              mesh=mesh, zero1=True, aot=True)
-    x = mx.np.random.uniform(-1, 1, (16, 32))
-    y = mx.np.random.uniform(-1, 1, (16, 16))
-    txt = step.lower(x, y).compile().as_text()
-    assert "all-gather" in txt  # the sharded update's param gather
-    with pytest.raises(RuntimeError, match="aot"):
-        step(x, y)
-
-
 def test_kvstore_trainer_on_mesh_batch():
     # classic reference-style DP loop: split_and_load over 'device' list
     ctxs = [mx.cpu(0)]

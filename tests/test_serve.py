@@ -2,9 +2,9 @@
 
 Decode correctness is the load-bearing half: prefill + N decode steps
 through the paged KV cache must reproduce the full-sequence forward's
-logits EXACTLY (same dtype, same reduction shapes — the tiny config is
-fp32, so the comparison is bitwise), paged and contiguous layouts must
-agree bit-for-bit, and the lowered decode program must be
+logits (the tiny config is fp32: to a few ulp, XLA being free to order
+a (1, T0) and a (1, T) reduction differently, and the greedy token
+exactly), paged and contiguous layouts must agree bit-for-bit, and the lowered decode program must be
 host-transfer-free with every KV buffer at the fixed pool shape (the
 O(1)-in-generated-length property).  The scheduler half mirrors how
 the fault runtime is tested: protocol unit tests plus the mxverify
@@ -67,11 +67,19 @@ def _spec(cfg, page_size=4, slots=2, pages=12, mp=6):
 # ----------------------------------------------------------------------
 # decode correctness
 # ----------------------------------------------------------------------
+def _assert_same_logits(got, want, what):
+    """fp32 logits of O(1) magnitude equal to a few ulp (2**-23 each),
+    and the greedy token they pick exactly."""
+    onp.testing.assert_allclose(got, want, rtol=0, atol=16 * 2.0 ** -23,
+                                err_msg=what)
+    assert onp.array_equal(got.argmax(-1), want.argmax(-1)), what
+
+
 def test_prefill_plus_decode_matches_full_forward_exactly():
     """The parity criterion: prefill(T0) + (T-T0) paged decode steps
-    produce, token by token, the SAME logits as the full-sequence
-    forward — GQA heads, per-slot RoPE offsets, page-crossing writes
-    and all.  fp32 tiny config, so the match is bitwise."""
+    produce, token by token, the full-sequence forward's logits — GQA
+    heads, per-slot RoPE offsets, page-crossing writes and all.  fp32
+    tiny config: equal to a few ulp, greedy tokens exactly equal."""
     cfg, net = _net()
     spec = _spec(cfg)
     rng = onp.random.RandomState(0)
@@ -82,7 +90,8 @@ def test_prefill_plus_decode_matches_full_forward_exactly():
     k, v = init_pools(spec)
     row = onp.array([1, 2, 3, 4, 5, 6], onp.int32)
     pre, k, v = _prefill(net, spec, k, v, row, toks[:, :T0], T0)
-    assert onp.array_equal(onp.asarray(pre)[0, :T0], full[0, :T0])
+    _assert_same_logits(onp.asarray(pre)[0, :T0], full[0, :T0],
+                        "prefill diverged from the full forward")
 
     page_table = onp.zeros((2, spec.max_pages_per_slot), onp.int32)
     page_table[0] = row
@@ -92,8 +101,9 @@ def test_prefill_plus_decode_matches_full_forward_exactly():
         step = onp.array([[toks[0, t]], [0]], onp.int32)
         logits, k, v = _decode(net, spec, k, v, page_table, lengths,
                                active, step)
-        assert onp.array_equal(onp.asarray(logits)[0, 0], full[0, t]), \
-            "decode step %d diverged from the full forward" % t
+        _assert_same_logits(
+            onp.asarray(logits)[0, 0], full[0, t],
+            "decode step %d diverged from the full forward" % t)
         lengths = lengths + active.astype(onp.int32)
 
 

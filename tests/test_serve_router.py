@@ -60,6 +60,18 @@ def _unstarted_group(n_servers=1, **kw):
     return ReplicaGroup(servers, threaded=False, **kw)
 
 
+def test_build_gives_each_replica_its_own_device():
+    """One process drives every chip of a host: replica i's weights
+    and KV pools live on local device i, not all on the first."""
+    import jax
+    _, net = _net()
+    group = ReplicaGroup.build(net, serve_cfg=_scfg(), replicas=3)
+    homes = [set(srv.pool.k_pages.devices()).union(
+        *(p.devices() for p in srv.pool.params.values()))
+        for srv in group.servers]
+    assert homes == [{d} for d in jax.local_devices()[:3]]
+
+
 # ----------------------------------------------------------------------
 # failover: exactly-once, bitwise vs fault-free control
 # ----------------------------------------------------------------------

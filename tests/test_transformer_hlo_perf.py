@@ -36,9 +36,6 @@ CFG = dict(vocab_size=1024, dim=256, n_layers=2, n_heads=4, n_kv_heads=4,
            hidden_dim=512, max_seq_len=T, dtype="bfloat16")
 
 
-from test_hlo_perf import _cost  # noqa: E402 — shared jax-version shim
-
-
 def _net_and_params(attn_impl):
     net = TransformerLM(LlamaConfig(attn_impl=attn_impl, **CFG))
     return net, net.collect_params()
@@ -119,7 +116,7 @@ def test_dense_train_flops_match_analytic():
     params, toks = _abstract_args(ps)
     compiled = jax.jit(jax.grad(lm_loss_fn(net, ps))).trace(
         params, toks, toks).lower().compile()
-    flops = _cost(compiled)["flops"]
+    flops = compiled.cost_analysis()["flops"]
     ratio = flops / _analytic_fwd_matmul_flops()
     assert 2.7 <= ratio <= 3.6, \
         "train flops = %.2fx analytic fwd matmuls (expect ~3x)" % ratio

@@ -183,14 +183,15 @@ def _serve_decode_text(mesh=None, force_pallas=False, kv_heads=1):
     params via ``serve.lower_decode_program`` — the serving analog of
     the ``TrainStep(aot=True)`` seam.  ``force_pallas`` compiles the
     Pallas page-table kernel into the TPU artifact (the topology
-    client reports a cpu default backend, so the kernel gating needs
-    the explicit override).  A mesh with a ``tp`` axis shards the
-    weights by annotation and the pools over Hkv — pass ``kv_heads``
-    divisible by the axis size (and never ``force_pallas``:
-    pallas_call under GSPMD partitioning is unsupported, the kernel
-    path stays a single-replica specialization)."""
+    client reports a cpu default backend, so the kernel gate is
+    answered here as a chip would).  A mesh with a ``tp`` axis shards
+    the weights by annotation and the pools over Hkv — pass
+    ``kv_heads`` divisible by the axis size.  The pinned tp programs
+    are the dense stand-in's; the kernel under a mesh is compiled by
+    tests/test_chip_compile.py."""
     from mxnet_tpu import serve
     from mxnet_tpu.models import tiny_config
+    from mxnet_tpu.ops import pallas_ops
 
     # kernel-shaped decode config: head_dim 128, page_size 128 (the
     # Mosaic tiling the paged-attention kernel wants)
@@ -199,17 +200,15 @@ def _serve_decode_text(mesh=None, force_pallas=False, kv_heads=1):
     scfg = serve.ServeConfig(slots=4, page_size=128, pages=16,
                              ladder=(128,), max_new=128,
                              cache_dir=None, int8=False)
-    prev = os.environ.get("MXNET_PALLAS_FORCE")
-    os.environ["MXNET_PALLAS_FORCE"] = "1" if force_pallas else "0"
+    gate = pallas_ops._pallas_available
+    if force_pallas:
+        pallas_ops._pallas_available = lambda: True
     try:
         lowered, _ = serve.lower_decode_program(cfg=cfg, serve_cfg=scfg,
                                                 mesh=mesh)
         return lowered.compile().as_text()
     finally:
-        if prev is None:
-            os.environ.pop("MXNET_PALLAS_FORCE", None)
-        else:
-            os.environ["MXNET_PALLAS_FORCE"] = prev
+        pallas_ops._pallas_available = gate
 
 
 def build_artifacts(out_dir):

@@ -419,10 +419,42 @@ def print_postmortem(dump_dir, sink=None):
     return report
 
 
+def _host_tpu_chips():
+    """TPU chips on this host's PCI bus, counted the way jax decides
+    whether to try the TPU — without loading libtpu, so the launcher
+    itself never holds a chip."""
+    from jax._src import hardware_utils
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
+
+
+def check_one_process_per_chip(n):
+    """Refuse ``-n > 1`` local workers that would open this host's TPU.
+
+    A chip belongs to one process at a time.  Local workers inherit this
+    environment and nothing binds worker *i* to chip *i*: each would
+    open every chip, the first would get them and the rest would fail
+    or hang.  Several chips of one host are driven by ONE process over
+    a mesh (``parallel.create_mesh``); several local processes are for
+    CPU jobs (``JAX_PLATFORMS=cpu``)."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if n <= 1 or (platforms and "tpu" not in platforms.split(",")):
+        return
+    chips = _host_tpu_chips()
+    if chips:
+        raise SystemExit(
+            "launch.py: refusing -n %d local workers on a host with %d "
+            "TPU chip(s): every worker would open all of them and only "
+            "the first could (one process per chip; nothing here binds "
+            "worker i to chip i). Drive the host's chips from one "
+            "process over a mesh, or set JAX_PLATFORMS=cpu for a "
+            "multi-process CPU job." % (n, chips))
+
+
 def launch_local(n, command, server_count=0, timeout=None, elastic=False,
                  spawn_replacement=False, flightrec_dir=None,
                  respawn_budget=1, respawn_backoff=0.0,
                  autoscale_dir=None):
+    check_one_process_per_chip(n)
     port = free_port()
     coord = "127.0.0.1:%d" % port
     procs, pumps = [], []
