@@ -19,7 +19,8 @@ dispatch/sync overhead.
 Every phase runs in a child process of its own, one at a time: the
 parent never imports jax, so the one process a chip admits is always
 the phase's.  Device phases need an accelerator and fail without one;
-the phases that are CPU by design run with ``JAX_PLATFORMS=cpu`` and say
+the phases that are CPU by design run with ``JAX_PLATFORMS=cpu`` on an
+8-device virtual mesh the phase child sets up before jax starts, and say
 ``"platform": "cpu"`` in their own output.  A phase that fails is named
 and makes the run exit non-zero; nothing is rerun elsewhere or zeroed.
 Every result carries the platform, device kind and device count it was
@@ -406,11 +407,6 @@ def bench_attention_ring():
     on-chip variant rides the same code path over ICI when multi-chip
     hardware exists (``parallel/ring.py``, SURVEY §5 / BASELINE ladder 5).
     CPU by design: a proxy for scaling shape, never a device number."""
-    import os
-    prev = os.environ.get("XLA_FLAGS", "")
-    if "host_platform_device_count" not in prev:
-        os.environ["XLA_FLAGS"] = \
-            prev + " --xla_force_host_platform_device_count=8"
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -424,6 +420,9 @@ def bench_attention_ring():
     # dominant over the ring's ppermute overhead while finishing in ~2min
     H, D, seq = 2, 64, 4096
     devs = jax.devices()
+    if len(devs) != 8:
+        raise RuntimeError("the ring8_* keys are an 8-device ring; "
+                           "jax.devices() has %d" % len(devs))
     mesh = Mesh(devs, ("cp",))
     key = jax.random.PRNGKey(0)
     q, k, v = (jax.random.normal(jax.random.fold_in(key, i),
@@ -510,13 +509,7 @@ def bench_long_context():
     gated: the 1M rung needs ~T² CPU work, so it records only when
     MXNET_BENCH_LC_BUDGET_S grants it (skips are recorded, never
     silent)."""
-    import os
-    prev = os.environ.get("XLA_FLAGS", "")
-    if "host_platform_device_count" not in prev:
-        os.environ["XLA_FLAGS"] = \
-            prev + " --xla_force_host_platform_device_count=8"
     import jax
-    jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     import numpy as onp
 
@@ -611,13 +604,7 @@ def bench_pipeline_bubble():
     memory win: n instead of M microbatches in flight).  On the shared
     CPU the schedules time nearly identically — the stash/bubble numbers
     are the trajectory, the timing is the regression canary."""
-    import os
-    prev = os.environ.get("XLA_FLAGS", "")
-    if "host_platform_device_count" not in prev:
-        os.environ["XLA_FLAGS"] = \
-            prev + " --xla_force_host_platform_device_count=8"
     import jax
-    jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
 
     from mxnet_tpu import parallel
@@ -1029,14 +1016,8 @@ def bench_serve(n_requests=36, slots=4, seed=7):
     step — static batching burns steps padding finished slots until
     the batch barrier), which is chip-independent.
     """
-    import os
     import threading
 
-    # the sharded A/B needs a tp=2 mesh on the virtual CPU device grid
-    prev = os.environ.get("XLA_FLAGS", "")
-    if "host_platform_device_count" not in prev:
-        os.environ["XLA_FLAGS"] = \
-            prev + " --xla_force_host_platform_device_count=8"
     import numpy as onp
 
     from mxnet_tpu import serve
@@ -1436,6 +1417,9 @@ def bench_serve(n_requests=36, slots=4, seed=7):
     }
 
 
+#: devices of the virtual CPU mesh every "cpu" phase runs on
+CPU_MESH_DEVICES = 8
+
 #: name -> (function, where it runs).  "device" phases need an
 #: accelerator; "cpu" phases are CPU by design — scheduling, protocol and
 #: layout-balance proxies on the virtual mesh, host-side overheads —
@@ -1470,7 +1454,14 @@ def run_phase(which):
     import sys
     fn, where = PHASES[which]
     if where == "cpu":
+        # before jax starts its backend: the CPU phases build their
+        # meshes (cp=8, pp=4, tp=2) on the virtual device grid
         os.environ["JAX_PLATFORMS"] = "cpu"
+        flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
+                 if "host_platform_device_count" not in f]
+        os.environ["XLA_FLAGS"] = " ".join(
+            flags + ["--xla_force_host_platform_device_count=%d"
+                     % CPU_MESH_DEVICES])
     import jax
 
     from mxnet_tpu.utils import compile_cache
@@ -1479,6 +1470,10 @@ def run_phase(which):
     if where == "device" and d.platform == "cpu":
         sys.exit("bench %s is a device phase and jax.devices() is %r: "
                  "no accelerator, no number" % (which, jax.devices()))
+    if where == "cpu" and len(jax.devices()) != CPU_MESH_DEVICES:
+        sys.exit("bench %s needs the %d-device virtual CPU mesh, "
+                 "jax.devices() is %r"
+                 % (which, CPU_MESH_DEVICES, jax.devices()))
     res = fn()
     if isinstance(res, dict) and where == "cpu":
         res = {"platform": "cpu", **res}

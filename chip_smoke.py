@@ -198,28 +198,64 @@ def _cached_logits_fns(net, page_size):
     return prefill, decode
 
 
-def _dense_logits(net, params, tokens, pad_to):
-    """The reference: full-sequence forward with XLA dense attention.
+def _dense_reference(net, pad_to):
+    """The reference: ``ref(params, tokens)`` -> fp32 logits of the
+    full-sequence forward with XLA dense attention, on the device.
     ``tokens`` are padded to ``pad_to`` so that every call of a phase is
     one program (causal: padding behind a position cannot reach it)."""
     import jax
     import jax.numpy as jnp
     import numpy as onp
-    forward = _forward_logits(net)
-    padded = onp.zeros((1, pad_to), onp.int32)
-    padded[0, :len(tokens)] = tokens
-    impl, net.cfg.attn_impl = net.cfg.attn_impl, "dense"
-    try:
-        return jax.jit(forward)(params, jnp.asarray(padded))[0, :len(tokens)]
-    finally:
-        net.cfg.attn_impl = impl
+    forward = jax.jit(_forward_logits(net))
+
+    def ref(params, tokens):
+        padded = onp.zeros((1, pad_to), onp.int32)
+        padded[0, :len(tokens)] = tokens
+        impl, net.cfg.attn_impl = net.cfg.attn_impl, "dense"
+        try:
+            return forward(params, jnp.asarray(padded))[0, :len(tokens)]
+        finally:
+            net.cfg.attn_impl = impl
+
+    return ref
 
 
-def _cached_vs_dense_logits(srv, net, prompt, generated, decode_steps):
+def _engine_vs_dense_tokens(dense, params, prompts, tokens, tol):
+    """Every token the engine returned against the greedy argmax of the
+    dense reference run on that request's own prompt + tokens, so each
+    step is judged on the context the engine really had.  A token that
+    is not the argmax but within ``tol`` of it in logit is a near-tie
+    that the kernels' rounding can flip: reported with its margin.
+    Anything further is the engine's fault — slots, page tables,
+    sampling or requests mixed up — and fails.  Returns the near-ties
+    and request 0's reference logits."""
+    import numpy as onp
+    near_ties, first = [], None
+    for i, (prompt, toks) in enumerate(zip(prompts, tokens)):
+        ref = dense(params, list(prompt) + list(toks[:-1]))
+        if first is None:
+            first = ref
+        rows = onp.asarray(ref[len(prompt) - 1:])  # row j chose toks[j]
+        for j, (tok, top) in enumerate(zip(toks, rows.argmax(-1))):
+            if tok == top:
+                continue
+            margin = float(rows[j, top] - rows[j, tok])
+            check(margin <= tol,
+                  "request %d, step %d: the engine returned token %d, the "
+                  "dense forward of the same context picks %d, %.4g apart "
+                  "in logit (tolerance %.4g) — no near-tie"
+                  % (i, j, tok, int(top), margin, tol))
+            near_ties.append({"request": i, "step": j,
+                              "tokens": [int(tok), int(top)],
+                              "margin": margin})
+    return near_ties, first
+
+
+def _cached_vs_dense_logits(srv, net, prompt, generated, decode_steps, ref):
     """Max |difference| between the cached path's logits — prefill of
     ``prompt`` at its ladder rung (flash kernel), then ``decode_steps``
-    paged-decode steps feeding the tokens the server generated — and the
-    dense full-sequence forward of the same tokens."""
+    paged-decode steps feeding the tokens the server generated — and
+    ``ref``, the dense full-sequence forward of the same tokens."""
     import jax.numpy as jnp
     import numpy as onp
 
@@ -229,8 +265,7 @@ def _cached_vs_dense_logits(srv, net, prompt, generated, decode_steps):
     prefill, decode = _cached_logits_fns(net, spec.page_size)
     L, T = len(prompt), pool.ladder_fit(len(prompt))
     seq = list(prompt) + list(generated[:decode_steps])
-    ref = onp.asarray(_dense_logits(net, params, seq,
-                                    spec.max_context))
+    ref = onp.asarray(ref[:len(seq)])
 
     k, v = init_pools(spec)
     MP = spec.max_pages_per_slot
@@ -310,7 +345,8 @@ def phase_serve(device, seed, cfg=None, serve_cfg=None, n_requests=6,
         # pages for 8 slots of 1024 + 32 tokens, plus the trash page
         serve_cfg = serve.ServeConfig(slots=8, page_size=128,
                                       pages=8 * 9 + 1, ladder=(512, 1024),
-                                      max_new=32, int8=False)
+                                      max_new=32, int8=False,
+                                      temperature=0.0)
     t0 = time.perf_counter()
     net = _inference_net(cfg, seed)
     t_init = time.perf_counter() - t0
@@ -332,11 +368,15 @@ def phase_serve(device, seed, cfg=None, serve_cfg=None, n_requests=6,
     check(srv._error is None and not any(
         t.name == "mxserve-engine" for t in threading.enumerate()),
           "the engine thread did not shut down cleanly: %r" % srv._error)
-    t0 = time.perf_counter()
-    diff, ref_max = _cached_vs_dense_logits(srv, net, prompts[0],
-                                            tokens[0], decode_steps)
-    t_logits = time.perf_counter() - t0
     tol = BF16_LOGIT_TOL if cfg.dtype == "bfloat16" else 1e-4
+    t0 = time.perf_counter()
+    dense = _dense_reference(net, srv.pool.spec.max_context)
+    near_ties, ref = _engine_vs_dense_tokens(dense, srv.pool.params,
+                                             prompts, tokens, tol)
+    t_tokens = time.perf_counter() - t0
+    diff, ref_max = _cached_vs_dense_logits(srv, net, prompts[0],
+                                            tokens[0], decode_steps, ref)
+    t_logits = time.perf_counter() - t0 - t_tokens
     check(diff <= tol,
           "prefill + paged decode logits differ from the dense full "
           "forward by %.4g (tolerance %.4g, max |logit| %.3g)"
@@ -350,13 +390,17 @@ def phase_serve(device, seed, cfg=None, serve_cfg=None, n_requests=6,
          prompt_lens=[len(p) for p in prompts],
          max_new=serve_cfg.max_new, init_s=round(t_init, 3),
          compile_s=srv.pool.stats["compile_s"], run_s=round(t_run, 3),
+         tokens_check_s=round(t_tokens, 3),
          logits_check_s=round(t_logits, 3), **watch.verdict(),
          **hbm([device]), hbm_peak_after_init_bytes=peak_after_init,
          kernels_in=kernels,
+         tokens_equal_dense_argmax=not near_ties, near_tie_tokens=near_ties,
          max_abs_logit_diff=diff, logit_tolerance=tol,
          max_abs_logit=ref_max,
          checked=["all requests done with max_new tokens",
                   "Pallas kernels in decode and prefill programs",
+                  "every engine token vs the dense forward's argmax "
+                  "(near-ties reported)",
                   "cached logits vs dense full forward",
                   "clean shutdown"])
 
@@ -383,7 +427,7 @@ def _mesh_serve_config(serve_cfg):
     # four-chip phases build six servers.
     return serve.ServeConfig(slots=4, page_size=128, pages=4 * 9 + 1,
                              ladder=(1024,), max_new=16, int8=False,
-                             prefix_cache=False)
+                             temperature=0.0, prefix_cache=False)
 
 
 def _substantial(devices, floor):
@@ -513,6 +557,9 @@ def phase_mesh_serve(devices, seed, cfg=None, serve_cfg=None, n_requests=4,
                         n_requests, *prompt_range)
     mesh = parallel.create_mesh(tp=2, devices=devices[:2])
     tol = BF16_LOGIT_TOL if cfg.dtype == "bfloat16" else 1e-4
+    dense = _dense_reference(net, serve_cfg.max_pages_per_slot
+                             * serve_cfg.page_size)
+    near_ties = []
 
     out = {}
     for name, m in (("one_device", None), ("tp2", mesh)):
@@ -527,6 +574,11 @@ def phase_mesh_serve(devices, seed, cfg=None, serve_cfg=None, n_requests=4,
             toks = _serve_all(srv, prompts, serve_cfg.max_new)
         run_s = time.perf_counter() - t0
         check(srv._error is None, "%s engine died: %r" % (name, srv._error))
+        if m is None:
+            # the two servers run one engine, so agreeing with each other
+            # is not enough: the unsharded one is held to the dense forward
+            near_ties, _ = _engine_vs_dense_tokens(
+                dense, srv.pool.params, prompts, toks, tol)
         logits = [_first_step_logits(net, srv.pool, p) for p in prompts]
         _substantial(devices[:2] if m is not None else devices[:1],
                      min_bytes)
@@ -546,13 +598,13 @@ def phase_mesh_serve(devices, seed, cfg=None, serve_cfg=None, n_requests=4,
     # can explain it — a near-tie is reported with its margin, a real
     # divergence fails
     flips = []
+    params = {k: p.data()._data for k, p in net.collect_params().items()}
     for i, (a, b) in enumerate(zip(t_one, t_tp)):
         if a == b:
             continue
         j = next(n for n, (x, y) in enumerate(zip(a, b)) if x != y)
-        margin = _flip_margin(net, prompts[i] + a[:j], a[j], b[j],
-                              serve_cfg.max_pages_per_slot
-                              * serve_cfg.page_size)
+        last = onp.asarray(dense(params, prompts[i] + a[:j])[-1])
+        margin = float(abs(last[a[j]] - last[b[j]]))
         flips.append({"request": i, "step": j, "tokens": [a[j], b[j]],
                       "margin": margin})
         check(margin <= tol,
@@ -563,20 +615,15 @@ def phase_mesh_serve(devices, seed, cfg=None, serve_cfg=None, n_requests=4,
          **({"reduced": reduced} if reduced else {}), requests=n_requests,
          max_new=serve_cfg.max_new, max_abs_first_logit_diff=diff,
          logit_tolerance=tol, tokens_equal=not flips, near_tie_flips=flips,
+         one_device_tokens_equal_dense_argmax=not near_ties,
+         one_device_near_tie_tokens=near_ties,
          compile_s=c_tp, run_s=r_tp, one_device_compile_s=c_one,
          one_device_run_s=r_one, **watch.verdict(), **mem,
-         checked=["first-step logits vs one device",
+         checked=["one-device engine tokens vs the dense forward's argmax",
+                  "first-step logits vs one device",
                   "greedy tokens equal (near-ties reported)",
                   "Pallas kernels in tp=2 decode and prefill programs",
                   "memory in use on both devices"])
-
-
-def _flip_margin(net, tokens, tok_a, tok_b, pad_to):
-    """|logit(tok_a) - logit(tok_b)| after ``tokens``, dense forward."""
-    import numpy as onp
-    params = {k: p.data()._data for k, p in net.collect_params().items()}
-    last = onp.asarray(_dense_logits(net, params, tokens, pad_to))[-1]
-    return float(abs(last[tok_a] - last[tok_b]))
 
 
 def phase_replicas(devices, seed, cfg=None, serve_cfg=None, n_requests=12,
@@ -635,8 +682,6 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    import jax
-
     import mxnet_tpu  # noqa: F401 — nothing of the repo, nothing to smoke
     from mxnet_tpu.utils import compile_cache
     compile_cache.place_compile_cache()
@@ -652,11 +697,11 @@ def main(argv=None):
         phase_mesh_serve(devices, args.seed)
         gc.collect()
         phase_replicas(devices, args.seed)
-    d = jax.devices()[0]
+    d = devices[0]
     print(json.dumps({"ok": True,
                       "device": {"platform": d.platform,
                                  "kind": d.device_kind,
-                                 "count": len(jax.devices())}}),
+                                 "count": len(devices)}}),
           flush=True)
     return 0
 

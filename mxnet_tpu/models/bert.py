@@ -80,7 +80,9 @@ class BertSelfAttention(HybridBlock):
                 # pretrain/inference); falls back to dense off-TPU or
                 # for unaligned seq (ops/pallas_ops.py gating)
                 from ..ops.pallas_ops import flash_attention
-                o = flash_attention(q, k, v, causal=False)
+                from ..parallel.sharding import kernel_shard
+                o = flash_attention(q, k, v, causal=False,
+                                    shard=kernel_shard(B, nh))
             return jnp.swapaxes(o, 1, 2).reshape(B, T, H)
 
         ins = [qkv] + ([mask] if mask is not None else [])

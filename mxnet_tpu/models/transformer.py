@@ -174,7 +174,9 @@ class Attention(HybridBlock):
                     o = dot_product_attention(q, k, v, causal=True)
             elif impl == "flash":
                 from ..ops.pallas_ops import flash_attention
-                o = flash_attention(q, k, v, causal=True)
+                from ..parallel.sharding import kernel_shard
+                o = flash_attention(q, k, v, causal=True,
+                                    shard=kernel_shard(B, nkv))
             else:
                 from ..ops.nn import dot_product_attention
                 o = dot_product_attention(q, k, v, causal=True)
@@ -214,9 +216,11 @@ class Attention(HybridBlock):
                 vp = _kvc.write_prompt(vp, layer, page_row, v[0],
                                        true_len, psz)
                 from ..ops.pallas_ops import flash_attention
+                from ..parallel.sharding import kernel_shard
                 o = flash_attention(jnp.swapaxes(q, 1, 2),
                                     jnp.swapaxes(k, 1, 2),
-                                    jnp.swapaxes(v, 1, 2), causal=True)
+                                    jnp.swapaxes(v, 1, 2), causal=True,
+                                    shard=kernel_shard(B, nkv))
                 return jnp.swapaxes(o, 1, 2).reshape(B, T, nh * hd), kp, vp
 
             o, new_k, new_v = apply_op(
@@ -283,9 +287,12 @@ class Attention(HybridBlock):
                 vp = _kvc.write_token(vp, layer, page_table, lengths,
                                       v[:, 0], active, psz)
                 from ..ops.pallas_ops import paged_attention
+                from ..parallel.sharding import kernel_shard
                 ctx = jnp.where(active, lengths + 1, lengths)
-                o = paged_attention(q[:, 0], kp[layer], vp[layer],
-                                    page_table, ctx)
+                # any slot may read any page: the pools shard by head only
+                o = paged_attention(
+                    q[:, 0], kp[layer], vp[layer], page_table, ctx,
+                    shard=kernel_shard(B, nkv, batch_axis=None))
                 return o.reshape(B, T, nh * hd), kp, vp
 
             o, new_k, new_v = apply_op(

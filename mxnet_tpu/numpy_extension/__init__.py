@@ -424,7 +424,9 @@ def flash_attention(query, key, value, causal=False, scale=None,
     fewer heads than query, mapped as ``h -> h // (Hq // Hkv)`` without
     materializing repeated K/V); elsewhere it transparently computes the
     same values with dense XLA attention.  Differentiable under
-    ``autograd.record()`` either way.
+    ``autograd.record()`` either way.  Under ``parallel.mesh_scope`` the
+    kernels run per ``dp`` shard of the batch and ``tp`` shard of the
+    heads (``parallel.kernel_shard``).
 
     The TPU-native successor to the reference's fused attention matmuls
     (``src/operator/contrib/transformer.cc``,
@@ -432,9 +434,11 @@ def flash_attention(query, key, value, causal=False, scale=None,
     their legacy names in this namespace).
     """
     from ..ops.pallas_ops import flash_attention as _fa
+    from ..parallel.sharding import kernel_shard
     return apply_op(
         lambda q, k, v: _fa(q, k, v, causal=causal, scale=scale,
-                            block_q=block_q, block_k=block_k),
+                            block_q=block_q, block_k=block_k,
+                            shard=kernel_shard(q.shape[0], k.shape[1])),
         [query, key, value], name="flash_attention")
 
 

@@ -24,15 +24,17 @@ On non-TPU backends everything falls back to XLA dense attention (with an
 identical lse), so tests run anywhere; set MXNET_PALLAS_INTERPRET=1 to run
 the actual kernels in interpret mode on CPU.
 
-Under a device mesh (``parallel.mesh_scope``) the model-facing entry
-points ``flash_attention`` and ``paged_attention`` run the kernel per
-shard inside a ``shard_map`` over the ``dp`` (batch) and ``tp`` (heads)
-axes: GSPMD cannot partition a Mosaic kernel, and batch rows and heads
-are independent, so the per-shard kernel is exact.
+GSPMD cannot partition a Mosaic kernel, so under a device mesh the
+caller of ``flash_attention`` / ``paged_attention`` says how the kernel
+is split (``shard=``, from ``parallel.sharding.kernel_shard``): the
+kernel then runs per shard inside a ``shard_map`` over the mesh axes
+that shard batch rows and heads.  Both are independent, so the
+per-shard kernel is exact.
 """
 from __future__ import annotations
 
 import functools
+import logging
 import os
 
 import jax
@@ -42,12 +44,14 @@ from jax.sharding import PartitionSpec as P
 from .nn import dot_product_attention
 
 _INTERPRET = os.environ.get("MXNET_PALLAS_INTERPRET", "0") == "1"
+_logger = logging.getLogger(__name__)
+_warned_whole = set()  # mesh shapes already told about, once per process
 NEG_INF = float("-inf")
 
 #: VMEM the TPU compiler grants one kernel unasked, and the most these
 #: kernels ask for.  A v5e core has 128 MiB; the rest is left to the
-#: compiler's own scratch.  tests/test_chip_compile.py holds both to the
-#: described chip's compiler.
+#: compiler's own scratch.  tests/test_chip_compile.py compiles the
+#: longest rows this admits (forward and backward) for the described chip.
 _VMEM_DEFAULT = 16 << 20
 _VMEM_MAX = 100 << 20
 #: allowed on top of the resident rows for a kernel's own tiles
@@ -80,48 +84,68 @@ def _shapes_ok(q, k):
             and D in (64, 128, 256))
 
 
+def _row_bytes(D, dtype, stats):
+    """VMEM per token of a kernel's two resident (T, D) rows, double-
+    buffered; ``stats`` adds dkv's lse and delta rows — a (1, T) fp32 row
+    pads to 8 sublanes: 2 rows x 2 buffers x 32 T."""
+    return 4 * D * jnp.dtype(dtype).itemsize + (128 if stats else 0)
+
+
+def _max_row(D, dtype, stats):
+    """The longest row (tokens, a multiple of 128) a kernel takes: at
+    D=128 bf16, 98,304 in the forward and dq kernels and 87,296 in dkv —
+    so 87,296 wherever the backward runs."""
+    return (_VMEM_MAX - _VMEM_SLACK) // _row_bytes(D, dtype, stats) \
+        // 128 * 128
+
+
 def _row_params(T, D, dtype, stats=False):
     """Compiler params for a kernel that keeps two whole (T, D) rows of
     one head in VMEM, double-buffered by the pipeline — K and V in the
     forward and dq kernels, Q and dO (plus the lse and delta rows,
     ``stats``) in dkv.  Resident rows are fetched once per head where a
     grid axis would stream them once per opposite block; the price is a
-    bound on T, raised here instead of left to the compiler."""
+    bound on T (``_max_row``), raised here instead of left to the
+    compiler."""
     from jax.experimental.pallas import tpu as pltpu
-    # a (1, T) fp32 row pads to 8 sublanes: 2 rows x 2 buffers x 32 T
-    per_token = 4 * D * jnp.dtype(dtype).itemsize + (128 if stats else 0)
-    need = T * per_token + _VMEM_SLACK
+    need = T * _row_bytes(D, dtype, stats) + _VMEM_SLACK
     if need > _VMEM_MAX:
         raise ValueError(
             "flash_attention: a %d-token row needs %d MiB of VMEM "
-            "resident (head_dim %d, %s) and the kernels may use %d MiB; "
-            "the largest supported length is %d tokens — split the "
-            "sequence over devices (parallel.ring_attention_sharded)"
-            % (T, need >> 20, D, jnp.dtype(dtype).name, _VMEM_MAX >> 20,
-               (_VMEM_MAX - _VMEM_SLACK) // per_token // 128 * 128))
+            "resident in the %s kernel (head_dim %d, %s) and the kernels "
+            "may use %d MiB; the largest supported length is %d tokens "
+            "forward and %d with the backward — split the sequence over "
+            "devices (parallel.ring_attention_sharded)"
+            % (T, need >> 20, "dkv" if stats else "forward/dq", D,
+               jnp.dtype(dtype).name, _VMEM_MAX >> 20,
+               _max_row(D, dtype, False), _max_row(D, dtype, True)))
     if need <= _VMEM_DEFAULT:
         return None
     return pltpu.CompilerParams(vmem_limit_bytes=need)
 
 
-def _shard_axes(batch, heads):
-    """``(mesh, dp, tp)`` when a kernel call has to be wrapped in a
-    ``shard_map``: a mesh of several devices is in scope and this trace
-    is not per-shard already (``parallel/ring.py`` calls the kernels
-    inside its own).  ``dp``/``tp`` are those axis names where the mesh
-    has them and they divide ``batch`` / every head count in ``heads``;
-    None leaves that dimension whole on every device."""
-    from ..parallel.mesh import current_mesh
-    mesh = current_mesh()
-    if mesh is None or mesh.size == 1 \
-            or jax.sharding.get_abstract_mesh().manual_axes:
-        return None
-
-    def axis(name, sizes):
-        n = mesh.shape.get(name, 1)
-        return name if n > 1 and all(s % n == 0 for s in sizes) else None
-
-    return mesh, axis("dp", (batch,)), axis("tp", heads)
+def _per_shard(kernel, shard, in_specs, out_specs):
+    """``kernel`` as one call per shard of ``shard = (mesh, batch_axis,
+    head_axis)`` — what ``parallel.sharding.kernel_shard`` answers for
+    the mesh in scope; the specs name those axes.  In a trace that is
+    manual over some of the mesh's axes already (a pipeline body over
+    ``pp`` with ``tp`` left to GSPMD) the ``shard_map`` nests and takes
+    the rest."""
+    mesh, batch_axis, head_axis = shard
+    if batch_axis is None and head_axis is None \
+            and tuple(mesh.shape.items()) not in _warned_whole:
+        _warned_whole.add(tuple(mesh.shape.items()))
+        _logger.warning(
+            "attention kernel: no axis of mesh %s shards its batch rows "
+            "or heads — every device runs the WHOLE kernel", dict(mesh.shape))
+    ctx = jax.sharding.get_abstract_mesh()
+    if ctx.manual_axes:
+        return jax.shard_map(
+            kernel, mesh=ctx, in_specs=in_specs, out_specs=out_specs,
+            axis_names=set(mesh.axis_names) - set(ctx.manual_axes),
+            check_vma=False)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -799,7 +823,8 @@ def _paged_dense(q, k_pages, v_pages, page_table, lengths, scale):
     return o.astype(q.dtype)
 
 
-def paged_attention(q, k_pages, v_pages, page_table, lengths, scale=None):
+def paged_attention(q, k_pages, v_pages, page_table, lengths, scale=None,
+                    shard=None):
     """One decode step's attention read over a paged KV cache.
 
     ``q``: (S, H, D) — one query token per batch slot; ``k_pages`` /
@@ -812,9 +837,10 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, scale=None):
 
     On TPU with kernel-friendly shapes this is a Pallas scalar-prefetch
     kernel whose page reads are driven by the page table directly —
-    under a mesh one kernel per ``tp`` shard of the heads, each over
-    its own shard of the pools; elsewhere a dense gather fallback with
-    identical semantics."""
+    with ``shard`` (``parallel.sharding.kernel_shard``; required under
+    a mesh) one kernel per shard of the heads, each over its own shard
+    of the pools; elsewhere a dense gather fallback with identical
+    semantics."""
     if q.shape[1] % k_pages.shape[1] != 0:
         raise ValueError(
             "paged_attention: %d query heads not a multiple of %d kv "
@@ -825,25 +851,25 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, scale=None):
         return _paged_dense(q, k_pages, v_pages, page_table, lengths,
                             scale)
     kernel = functools.partial(_paged_kernel_call, scale=scale)
-    sharded = _shard_axes(1, (q.shape[1], k_pages.shape[1]))
-    if sharded is not None:
-        mesh, _, tp = sharded
-        kernel = jax.shard_map(
-            kernel, mesh=mesh,
-            in_specs=(P(None, tp, None), P(None, tp, None, None),
-                      P(None, tp, None, None), P(), P()),
-            out_specs=P(None, tp, None), check_vma=False)
+    if shard is not None:
+        heads = shard[2]
+        kernel = _per_shard(
+            kernel, shard,
+            (P(None, heads, None), P(None, heads, None, None),
+             P(None, heads, None, None), P(), P()),
+            P(None, heads, None))
     return kernel(q, k_pages, v_pages, page_table, lengths)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
-                    block_k=128):
+                    block_k=128, shard=None):
     """Blocked flash attention on (B, H, T, D), Pallas forward + backward.
 
     k/v may carry fewer (grouped/multi-query) heads — see
-    ``flash_attention_with_lse``.  Under a mesh the kernels run per
-    ``dp`` shard of the batch and ``tp`` shard of the heads.  Falls back
-    to XLA dense attention off-TPU or for unsupported shapes; a row too
+    ``flash_attention_with_lse``.  With ``shard``
+    (``parallel.sharding.kernel_shard``; required under a mesh) the
+    kernels run per shard of the batch and of the heads.  Falls back to
+    XLA dense attention off-TPU or for unsupported shapes; a row too
     long for the kernels' VMEM raises (``_row_params``)."""
     if q.shape[1] % k.shape[1] != 0:
         raise ValueError(
@@ -863,10 +889,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
         return _flash_lse(q, k, v, zero, zero, causal, scale, block_q,
                           block_k)[0]
 
-    sharded = _shard_axes(q.shape[0], (q.shape[1], k.shape[1]))
-    if sharded is not None:
-        mesh, dp, tp = sharded
-        spec = P(dp, tp, None, None)
-        kernel = jax.shard_map(kernel, mesh=mesh, in_specs=(spec,) * 3,
-                               out_specs=spec, check_vma=False)
+    if shard is not None:
+        spec = P(shard[1], shard[2], None, None)
+        kernel = _per_shard(kernel, shard, (spec,) * 3, spec)
     return kernel(q, k, v)

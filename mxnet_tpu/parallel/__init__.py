@@ -19,8 +19,8 @@ collectives over a ``jax.sharding.Mesh`` — and parallelism strategies are
 """
 from .mesh import (create_mesh, current_mesh, mesh_scope, local_mesh,
                    shrink_mesh, grow_mesh)
-from .sharding import (P, apply_sharding_rules, param_sharding, shard_params,
-                       replicate)
+from .sharding import (P, apply_sharding_rules, kernel_shard,
+                       param_sharding, shard_params, replicate)
 from .train_step import TrainStep
 from .ring import (ring_attention_sharded, causal_balance,
                    stripe_sequence, unstripe_sequence)

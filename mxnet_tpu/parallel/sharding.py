@@ -14,6 +14,8 @@ import re
 import jax
 from jax.sharding import NamedSharding, PartitionSpec
 
+from .mesh import current_mesh
+
 P = PartitionSpec
 
 _logger = logging.getLogger(__name__)
@@ -82,6 +84,29 @@ def _valid_spec(spec, shape, mesh, param_name=None, warn=True):
                   % (dim, size))
             out.append(None)
     return PartitionSpec(*out)
+
+
+def kernel_shard(batch, heads, batch_axis="dp", head_axis="tp"):
+    """How a hand-written kernel over independent batch rows and heads
+    is split under the mesh in scope: ``(mesh, batch_axis, head_axis)``,
+    the ``shard=`` argument of ``ops.pallas_ops.flash_attention`` /
+    ``paged_attention`` (GSPMD cannot partition a Mosaic kernel; the
+    caller, who knows which axes shard what, has to say).  ``heads`` is
+    the KV head count (it divides the query heads).  An axis that is
+    None, missing from the mesh, does not divide its dimension, or that
+    the trace is manual over already comes back None: that dimension
+    stays whole on every device.  Returns None when there is nothing to
+    split — no mesh in scope, one device, or a trace that is per-shard
+    over every axis already (ring attention and pipeline bodies)."""
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return None
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
+    if manual >= set(mesh.axis_names):
+        return None
+    kept = _valid_spec((batch_axis, head_axis), (batch, heads), mesh,
+                       warn=False)
+    return (mesh,) + tuple(None if a in manual else a for a in kept)
 
 
 def param_sharding(params, mesh, rules=None, default=PartitionSpec()):

@@ -135,8 +135,8 @@ class ReplicaGroup:
         host, so replica *i* lives on local device *i* (round-robin
         past the device count) as a one-device mesh, with its own copy
         of the weights and its own KV pools; a program is compiled per
-        device.  On a one-device host, or with an explicit ``mesh``
-        (replicas sharded over the same devices), they all share it."""
+        device.  With an explicit ``mesh`` (replicas sharded over the
+        same devices) they all share it."""
         import jax
 
         from .parallel.mesh import create_mesh
@@ -145,7 +145,7 @@ class ReplicaGroup:
         devices = jax.local_devices()
 
         def placed(i):
-            if mesh is not None or len(devices) == 1:
+            if mesh is not None:
                 return mesh
             return create_mesh(dp=1, tp=1,
                                devices=[devices[i % len(devices)]])

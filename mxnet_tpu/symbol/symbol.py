@@ -1200,7 +1200,9 @@ def _sym_flash_attention(q, k, v, scale=1.0, causal=False):
     in for matched softmax-attention patterns (Pallas kernel on TPU, XLA
     dense fallback elsewhere — ``ops/pallas_ops.py``)."""
     from ..ops.pallas_ops import flash_attention as _fa
-    return _fa(q, k, v, causal=causal, scale=scale)
+    from ..parallel.sharding import kernel_shard
+    return _fa(q, k, v, causal=causal, scale=scale,
+               shard=kernel_shard(q.shape[0], k.shape[1]))
 
 
 register_sym_op("FlashAttention", _sym_flash_attention)

@@ -73,3 +73,18 @@ def test_a_device_phase_refuses_the_cpu():
                        timeout=300, cwd=ROOT)
     assert r.returncode != 0 and r.stdout == ""
     assert "device phase" in r.stderr
+
+
+def test_a_cpu_phase_runs_for_real_on_its_own_virtual_mesh():
+    """No stub: the phase child must give its phase the 8-device CPU mesh
+    itself (pp=4 here), whatever XLA_FLAGS the caller had."""
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    r = subprocess.run([sys.executable, os.path.join(ROOT, "bench.py"),
+                        "--only", "pipeline_bubble"], capture_output=True,
+                       text=True, env=env, timeout=300, cwd=ROOT)
+    assert r.returncode == 0, r.stderr[-2000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    assert (out["platform"], out["device_count"]) == ("cpu", 8)
+    assert out["result"]["platform"] == "cpu"
+    assert out["result"]["stages"] == 4
+    assert out["result"]["pipeline_1f1b_bubble_frac"] > 0

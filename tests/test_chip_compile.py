@@ -61,7 +61,10 @@ def _qkv(T, sharding, batch=B):
 
 
 def _flash(q, k, v):
-    return pallas_ops.flash_attention(q, k, v, causal=True)
+    # as the models call it: the caller says how the kernel is split
+    return pallas_ops.flash_attention(
+        q, k, v, causal=True,
+        shard=parallel.kernel_shard(q.shape[0], k.shape[1]))
 
 
 def _flash_grad(q, k, v):
@@ -76,15 +79,20 @@ def _kernels(fn, *avals):
 
 # 32768 is past the 16 MiB of VMEM a kernel gets unasked: K and V rows
 # of 8 MiB each, double-buffered.  The kernels ask for what they hold
-# (pallas_ops._row_params), so the advertised length compiles.
-@pytest.mark.parametrize("T", [2048, 8192, 32768])
+# (pallas_ops._row_params), so the advertised length compiles — and so
+# does the longest row _row_params admits, which is what holds its bound
+# (_VMEM_MAX) to the chip's compiler: 98,304 tokens forward, 87,296 where
+# the backward kernels run too.
+@pytest.mark.parametrize("T", [2048, 8192, 32768, 98304])
 def test_flash_forward_compiles(topo, on_chip, T):
+    assert T <= pallas_ops._max_row(D, jnp.bfloat16, False) == 98304
     one_chip = SingleDeviceSharding(topo.devices[0])
     assert _kernels(_flash, *_qkv(T, one_chip)) == 1
 
 
-@pytest.mark.parametrize("T", [2048, 8192, 32768])
+@pytest.mark.parametrize("T", [2048, 8192, 32768, 87296])
 def test_flash_grad_compiles(topo, on_chip, T):
+    assert T <= pallas_ops._max_row(D, jnp.bfloat16, True) == 87296
     one_chip = SingleDeviceSharding(topo.devices[0])
     # the forward, dq and dkv kernels
     assert _kernels(_flash_grad, *_qkv(T, one_chip)) == 3
@@ -101,16 +109,23 @@ def _paged_avals(sh):
             jax.ShapeDtypeStruct((S,), jnp.int32, sharding=sh(P())))
 
 
+def _paged(q, k_pages, v_pages, page_table, lengths):
+    return pallas_ops.paged_attention(
+        q, k_pages, v_pages, page_table, lengths,
+        shard=parallel.kernel_shard(q.shape[0], k_pages.shape[1],
+                                    batch_axis=None))
+
+
 def test_paged_attention_compiles(topo, on_chip):
     one_chip = SingleDeviceSharding(topo.devices[0])
-    assert _kernels(pallas_ops.paged_attention,
-                    *_paged_avals(lambda spec: one_chip)) == 1
+    assert _kernels(_paged, *_paged_avals(lambda spec: one_chip)) == 1
 
 
 # GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
-# automatically partitioned"): under a mesh the entry points wrap the
-# kernel in a shard_map over dp (batch) and tp (heads), and the kernel
-# stays in every device's program.
+# automatically partitioned"): under a mesh the caller passes the axes
+# that shard batch and heads (parallel.kernel_shard), the entry points
+# wrap the kernel in a shard_map over them, and the kernel stays in
+# every device's program.
 @pytest.mark.parametrize("fn,n", [(_flash, 1), (_flash_grad, 3)],
                          ids=["forward", "grad"])
 def test_flash_compiles_on_dp_tp_mesh(topo, on_chip, fn, n):
@@ -124,8 +139,20 @@ def test_paged_attention_compiles_on_tp_mesh(topo, on_chip):
     mesh = Mesh(onp.array(topo.devices[:2]), ("tp",))
     with parallel.mesh_scope(mesh):
         assert _kernels(
-            pallas_ops.paged_attention,
+            _paged,
             *_paged_avals(lambda spec: NamedSharding(mesh, spec))) == 1
+
+
+def test_flash_compiles_inside_a_partly_manual_trace(topo, on_chip):
+    """A pipeline-style body, manual over ``pp`` with ``tp`` left to
+    GSPMD: the kernel's shard_map nests and takes the rest."""
+    mesh = Mesh(onp.array(topo.devices).reshape(2, 2), ("pp", "tp"))
+    stage = jax.shard_map(_flash, mesh=mesh, in_specs=(P("pp"),) * 3,
+                          out_specs=P("pp"), axis_names={"pp"},
+                          check_vma=False)
+    sh = NamedSharding(mesh, P("pp", "tp", None, None))
+    with parallel.mesh_scope(mesh):
+        assert _kernels(stage, *_qkv(2048, sh, batch=2)) == 1
 
 
 def test_train_step_aot_topology_mesh():

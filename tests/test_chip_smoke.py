@@ -57,7 +57,28 @@ def test_serve_phase_tiny_config(capsys):
     (line,) = _lines(capsys)
     assert line["phase"] == "serve" and line["requests"] == 3
     assert line["max_abs_logit_diff"] <= line["logit_tolerance"]
+    assert line["tokens_equal_dense_argmax"] == (not line["near_tie_tokens"])
+    assert all(t["margin"] <= line["logit_tolerance"]
+               for t in line["near_tie_tokens"])
     assert "reduced" not in line  # nothing was cut from tiny_config
+
+
+def test_serve_phase_fails_when_the_engine_mixes_requests_up(monkeypatch):
+    """Token ids of the right count are not enough: what the engine
+    returns for a request must be what the dense forward of that
+    request's own context picks."""
+    real = chip_smoke._serve_all
+
+    def mixed_up(srv, prompts, max_new):
+        tokens = real(srv, prompts, max_new)
+        return tokens[1:] + tokens[:1]
+
+    monkeypatch.setattr(chip_smoke, "_serve_all", mixed_up)
+    with pytest.raises(chip_smoke.SmokeFailure, match="no near-tie"):
+        chip_smoke.phase_serve(jax.devices()[0], seed=0, cfg=tiny_config(),
+                               serve_cfg=_tiny_serve_cfg(), n_requests=3,
+                               prompt_range=(4, 30), decode_steps=3,
+                               kernel_marker=None)
 
 
 def test_serve_phase_fails_when_the_kernel_is_not_in_the_program():
@@ -86,6 +107,7 @@ def test_four_chip_phases_on_the_virtual_mesh(capsys):
     assert train["phase"] == "mesh_train"
     assert train["max_rel_loss_diff"] <= train["loss_rtol"]
     assert served["phase"] == "mesh_serve" and served["tokens_equal"]
+    assert "one_device_tokens_equal_dense_argmax" in served
     assert replicas["phase"] == "replicas"
     assert sum(replicas["dispatched_to"].values()) == 6
 
@@ -114,6 +136,8 @@ def test_chips_option_picks_only_its_own_phases(monkeypatch, capsys, argv,
     last = _lines(capsys)[-1]
     assert last["ok"] is True and set(last) == {"ok", "device"}
     assert set(last["device"]) == {"platform", "kind", "count"}
+    # the chips the run used, not the host's (eight virtual ones here)
+    assert last["device"]["count"] == (4 if argv else 1)
 
 
 def test_without_an_accelerator_the_script_fails_and_prints_no_ok():

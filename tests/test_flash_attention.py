@@ -311,6 +311,14 @@ def test_chunked_fallback_threshold_and_divisibility_gate():
 # under a mesh: one kernel per (dp, tp) shard, wrapped in a shard_map
 # ---------------------------------------------------------------------------
 
+def _flash_sharded(q, k, v):
+    """As the models call it: the caller says how the kernel is split."""
+    from mxnet_tpu import parallel
+    return pallas_ops.flash_attention(
+        q, k, v, causal=True,
+        shard=parallel.kernel_shard(q.shape[0], k.shape[1]))
+
+
 @pytest.mark.parametrize("axes,hkv", [({"dp": 2, "tp": 2}, 2),
                                       ({"dp": 2, "tp": 2}, 1),
                                       ({"tp": 4}, 4)],
@@ -329,7 +337,7 @@ def test_flash_per_shard_under_mesh_matches_dense(interpret_kernels, axes,
     rep = Hq // hkv
 
     def loss_f(q, k, v):
-        return (pallas_ops.flash_attention(q, k, v, causal=True) * w).sum()
+        return (_flash_sharded(q, k, v) * w).sum()
 
     def loss_d(q, k, v):
         return (dot_product_attention(q, jnp.repeat(k, rep, axis=1),
@@ -360,13 +368,86 @@ def test_paged_attention_per_shard_under_mesh(interpret_kernels):
     pt = jnp.asarray(rng.randint(1, pages, (S, MP)).astype(onp.int32))
     lens = jnp.asarray(onp.array([5, 3 * psz, 0], onp.int32))
     dense = pallas_ops._paged_dense(q, kp, vp, pt, lens, D ** -0.5)
+    def paged(q, kp, vp, pt, lens):
+        return pallas_ops.paged_attention(
+            q, kp, vp, pt, lens,
+            shard=parallel.kernel_shard(S, Hkv, batch_axis=None))
+
     with parallel.mesh_scope(parallel.create_mesh(tp=2)):
-        lowered = jax.jit(pallas_ops.paged_attention).lower(
-            q, kp, vp, pt, lens)
+        lowered = jax.jit(paged).lower(q, kp, vp, pt, lens)
         assert "sdy.manual_computation" in lowered.as_text()
         got = lowered.compile()(q, kp, vp, pt, lens)
     onp.testing.assert_allclose(onp.asarray(got), onp.asarray(dense),
                                 atol=2e-5)
+
+
+def test_kernel_shard_keeps_what_the_mesh_can_split():
+    """The caller's axis names, kept where the mesh has them, they
+    divide, and the trace is not manual over them already."""
+    from jax.sharding import PartitionSpec as P
+    from mxnet_tpu import parallel
+    assert parallel.kernel_shard(2, 2) is None  # no mesh in scope
+    mesh = parallel.create_mesh(dp=2, tp=2)
+    with parallel.mesh_scope(parallel.create_mesh(dp=1)):
+        assert parallel.kernel_shard(2, 2) is None  # one device
+    with parallel.mesh_scope(mesh):
+        assert parallel.kernel_shard(2, 2) == (mesh, "dp", "tp")
+        assert parallel.kernel_shard(2, 1) == (mesh, "dp", None)
+        assert parallel.kernel_shard(3, 2) == (mesh, None, "tp")
+        assert parallel.kernel_shard(2, 2, batch_axis=None) == \
+            (mesh, None, "tp")
+        assert parallel.kernel_shard(2, 2, "rows", "tp") == \
+            (mesh, None, "tp")
+        seen = []
+
+        def body(x):
+            seen.append(parallel.kernel_shard(2, 2))
+            return x
+
+        jax.eval_shape(jax.shard_map(body, mesh=mesh, in_specs=P(),
+                                     out_specs=P(), axis_names={"dp"},
+                                     check_vma=False), jnp.zeros(2))
+        jax.eval_shape(jax.shard_map(body, mesh=mesh, in_specs=P(),
+                                     out_specs=P(), check_vma=False),
+                       jnp.zeros(2))
+    assert seen == [(mesh, None, "tp"), None]
+
+
+def test_flash_per_shard_inside_a_partly_manual_trace(interpret_kernels):
+    """A pipeline-style body, manual over ``pp`` with ``tp`` left to
+    GSPMD: the kernel's shard_map nests and takes the rest."""
+    from jax.sharding import PartitionSpec as P
+    from mxnet_tpu import parallel
+    q = _rand((2, 4, 128, 64), 0)
+    k, v = (_rand((2, 2, 128, 64), s) for s in (1, 2))
+    mesh = parallel.create_mesh(pp=2, tp=2)
+    stage = jax.shard_map(_flash_sharded, mesh=mesh,
+                          in_specs=(P("pp"),) * 3, out_specs=P("pp"),
+                          axis_names={"pp"}, check_vma=False)
+    with parallel.mesh_scope(mesh):
+        got = jax.jit(stage)(q, k, v)
+    want = dot_product_attention(q, jnp.repeat(k, 2, axis=1),
+                                 jnp.repeat(v, 2, axis=1), causal=True)
+    assert_almost_equal(onp.asarray(got), onp.asarray(want), rtol=2e-4,
+                        atol=2e-4)
+
+
+def test_a_mesh_that_splits_nothing_is_said_once(interpret_kernels, caplog):
+    """No axis of the mesh shards batch rows or heads: still a shard_map
+    (GSPMD cannot take the kernel), every device runs all of it, and the
+    log says so — once."""
+    from mxnet_tpu import parallel
+    q = _rand((1, 2, 128, 64), 0)
+    pallas_ops._warned_whole.clear()
+    with parallel.mesh_scope(parallel.create_mesh(cp=2)), \
+            caplog.at_level("WARNING", logger=pallas_ops.__name__):
+        for _ in range(2):  # two traces: a fresh jit each time
+            got = jax.jit(lambda *a: _flash_sharded(*a))(q, q, q)
+    assert len([r for r in caplog.records
+                if "WHOLE" in r.getMessage()]) == 1
+    want = dot_product_attention(q, q, q, causal=True)
+    assert_almost_equal(onp.asarray(got), onp.asarray(want), rtol=2e-4,
+                        atol=2e-4)
 
 
 def test_flash_row_past_vmem_raises_with_the_bound(monkeypatch):
@@ -374,11 +455,22 @@ def test_flash_row_past_vmem_raises_with_the_bound(monkeypatch):
     row that cannot fit is the repo's own error, naming the bound and
     the longest row that does fit — never another implementation."""
     monkeypatch.setattr(pallas_ops, "_pallas_available", lambda: True)
-    T = 131072
-    q = jax.ShapeDtypeStruct((1, 8, T, 128), jnp.bfloat16)
-    with pytest.raises(ValueError, match=r"100 MiB.*98304 tokens"):
-        jax.eval_shape(
-            lambda q: pallas_ops.flash_attention(q, q, q, causal=True), q)
+    both = r"100 MiB.*98304 tokens forward and 87296 with the backward"
+
+    def flash(q):
+        return pallas_ops.flash_attention(q, q, q, causal=True)
+
+    def row(T):
+        return jax.ShapeDtypeStruct((1, 8, T, 128), jnp.bfloat16)
+
+    with pytest.raises(ValueError, match="forward/dq kernel.*" + both):
+        jax.eval_shape(flash, row(131072))
+    # a row the forward takes and the backward does not: dkv also keeps
+    # the lse and delta rows
+    jax.eval_shape(flash, row(98304))
+    with pytest.raises(ValueError, match="dkv kernel.*" + both):
+        jax.eval_shape(jax.grad(lambda q: flash(q).astype(jnp.float32).sum()),
+                       row(98304))
 
 
 def test_interpret_mode_is_for_the_cpu_platform(monkeypatch):

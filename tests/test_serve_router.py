@@ -60,16 +60,22 @@ def _unstarted_group(n_servers=1, **kw):
     return ReplicaGroup(servers, threaded=False, **kw)
 
 
-def test_build_gives_each_replica_its_own_device():
+@pytest.mark.parametrize("host_devices", [8, 2, 1])
+def test_build_gives_each_replica_its_own_device(monkeypatch, host_devices):
     """One process drives every chip of a host: replica i's weights
-    and KV pools live on local device i, not all on the first."""
+    and KV pools live on local device i (round-robin past the device
+    count), not all on the first — and the program is the same one on
+    every host: a one-device mesh, also where the host has one device."""
     import jax
+    devices = jax.local_devices()[:host_devices]
+    monkeypatch.setattr(jax, "local_devices", lambda: devices)
     _, net = _net()
     group = ReplicaGroup.build(net, serve_cfg=_scfg(), replicas=3)
     homes = [set(srv.pool.k_pages.devices()).union(
         *(p.devices() for p in srv.pool.params.values()))
         for srv in group.servers]
-    assert homes == [{d} for d in jax.local_devices()[:3]]
+    assert homes == [{devices[i % host_devices]} for i in range(3)]
+    assert [srv.pool.mesh.devices.size for srv in group.servers] == [1] * 3
 
 
 # ----------------------------------------------------------------------
