@@ -39,6 +39,12 @@ from .parameter import Parameter, DeferredInitializationError
 class Block:
     """Base class for all neural network layers and models."""
 
+    #: the name this block's device ops carry (``jax.named_scope`` in
+    #: ``__call__``): the name its parent registered it under — the
+    #: attribute name, or ``<index>_<Type>`` for a child a container
+    #: numbered — and the type's name at the root
+    _scope_name = None
+
     def __init__(self):
         self._children = OrderedDict()
         self._reg_params = OrderedDict()
@@ -52,6 +58,7 @@ class Block:
             existing = self.__dict__.get("_children")
             if existing is not None:
                 existing[name] = value
+                value.__dict__["_scope_name"] = name
         elif isinstance(value, Parameter):
             existing = self.__dict__.get("_reg_params")
             if existing is not None:
@@ -66,7 +73,10 @@ class Block:
         super().__delattr__(name)
 
     def register_child(self, block, name=None):
-        name = name or str(len(self._children))
+        index = str(len(self._children))
+        block.__dict__["_scope_name"] = name or "%s_%s" % (
+            index, type(block).__name__)
+        name = name or index
         self._children[name] = block
         super().__setattr__("_child_" + name, block)
 
@@ -293,7 +303,8 @@ class Block:
         prof_t0 = _profiler._now_us() if _profiler._STEP else None
         for hook in self._forward_pre_hooks.values():
             hook(self, args)
-        out = self.forward(*args, **kwargs)
+        with jax.named_scope(self._scope_name or type(self).__name__):
+            out = self.forward(*args, **kwargs)
         for hook in self._forward_hooks.values():
             hook(self, args, out)
         if prof_t0 is not None:
@@ -495,7 +506,8 @@ class HybridBlock(Block):
         if self._active:
             for hook in self._forward_pre_hooks.values():
                 hook(self, args)
-            out = self._call_cached(args, kwargs)
+            with jax.named_scope(self._scope_name or type(self).__name__):
+                out = self._call_cached(args, kwargs)
             for hook in self._forward_hooks.values():
                 hook(self, args, out)
             return out

@@ -49,6 +49,7 @@ def default_mp_batchify_fn(data):
 
 
 _worker_dataset = None
+_END = object()  # a batch iterator ran out
 
 
 def _worker_initializer(dataset):
@@ -184,7 +185,18 @@ class DataLoader:
         # work between iterations) and count batches/samples through the
         # loader; one flag read per batch when profiling is off
         t_fetch = _profiler._now_us() if _profiler._DATA else None
-        for batch in self._iter_batches():
+        host_batches = self._iter_batches()
+        while True:
+            # mx.data.next: the wait for a batch (dataset reads and
+            # batchify, or a worker's result); mx.data.h2d: the upload
+            # of what came as NumPy (the default single-process batchify
+            # uploads inside mx.data.next)
+            with _profiler.span("mx.data.next"):
+                batch = next(host_batches, _END)
+            if batch is _END:
+                return
+            with _profiler.span("mx.data.h2d"):
+                batch = _as_nd(batch)
             if _profiler._DATA:
                 if t_fetch is not None:
                     _profiler.record_duration(
@@ -199,8 +211,8 @@ class DataLoader:
     def _iter_batches(self):
         if self._pool is None:
             for batch in self._batch_sampler:
-                yield _as_nd(self._batchify_fn(
-                    [self._dataset[i] for i in batch]))
+                yield self._batchify_fn(
+                    [self._dataset[i] for i in batch])
             return
 
         batchify = self._batchify_fn
@@ -231,7 +243,7 @@ class DataLoader:
                 payload = self._supervised_get(res)
             except _WorkerLost:
                 payload = self._recover(samples, pending)
-            yield _as_nd(pickle.loads(payload))
+            yield pickle.loads(payload)
 
     def _supervised_get(self, res):
         """Wait for a batch, watching the pool's workers: a worker that

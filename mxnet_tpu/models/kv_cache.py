@@ -30,6 +30,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 
 #: page id every masked (padding / inactive-slot) write is routed to
@@ -69,6 +70,7 @@ def init_pools(spec: CacheSpec):
     return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
 
 
+@jax.named_scope("kv_write")
 def write_prompt(pages, layer, page_row, kv, true_len, page_size):
     """Scatter one prompt's per-layer K (or V) into its slot's pages.
 
@@ -85,6 +87,7 @@ def write_prompt(pages, layer, page_row, kv, true_len, page_size):
     return pages.at[layer, dest, :, t % page_size].set(kv)
 
 
+@jax.named_scope("kv_write")
 def write_chunk(pages, layer, page_row, kv, true_len, page_size, start):
     """Scatter a prompt SUFFIX (chunk prefill — the prefix-cache path
     where positions below ``start`` already sit in cached pages).
@@ -105,6 +108,7 @@ def write_chunk(pages, layer, page_row, kv, true_len, page_size, start):
     return pages.at[layer, dest, :, pos % page_size].set(kv)
 
 
+@jax.named_scope("kv_write")
 def write_token(pages, layer, page_table, lengths, kv, active, page_size):
     """Scatter one decode step's per-layer K (or V), one token per slot.
 

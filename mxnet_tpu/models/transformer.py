@@ -217,10 +217,11 @@ class Attention(HybridBlock):
                                        true_len, psz)
                 from ..ops.pallas_ops import flash_attention
                 from ..parallel.sharding import kernel_shard
-                o = flash_attention(jnp.swapaxes(q, 1, 2),
-                                    jnp.swapaxes(k, 1, 2),
-                                    jnp.swapaxes(v, 1, 2), causal=True,
-                                    shard=kernel_shard(B, nkv))
+                with jax.named_scope("attention"):
+                    o = flash_attention(jnp.swapaxes(q, 1, 2),
+                                        jnp.swapaxes(k, 1, 2),
+                                        jnp.swapaxes(v, 1, 2), causal=True,
+                                        shard=kernel_shard(B, nkv))
                 return jnp.swapaxes(o, 1, 2).reshape(B, T, nh * hd), kp, vp
 
             o, new_k, new_v = apply_op(
@@ -240,35 +241,36 @@ class Attention(HybridBlock):
                                       true_len, psz, start)
                 vp = _kvc.write_chunk(vp, layer, page_row, v[0],
                                       true_len, psz, start)
-                MP = page_row.shape[0]
-                # gather the slot's pages; row i covers absolute
-                # positions [i*psz, (i+1)*psz) so masking kpos < start
-                # keeps exactly the cached prefix (our own chunk
-                # writes and trash rows land at kpos >= start)
-                kpre = kp[layer, page_row].swapaxes(1, 2) \
-                    .reshape(MP * psz, nkv, hd)
-                vpre = vp[layer, page_row].swapaxes(1, 2) \
-                    .reshape(MP * psz, nkv, hd)
-                kk = jnp.concatenate([kpre, k[0]], axis=0)
-                vv = jnp.concatenate([vpre, v[0]], axis=0)
-                if nkv != nh:
-                    rep = nh // nkv
-                    kk = jnp.repeat(kk, rep, axis=1)
-                    vv = jnp.repeat(vv, rep, axis=1)
-                qf = q[0].astype(jnp.float32)       # (T, nh, hd)
-                kf = kk.astype(jnp.float32)         # (N, nh, hd)
-                scores = jnp.einsum("tnd,snd->nts", qf, kf) \
-                    / math.sqrt(hd)
-                kpos = jnp.arange(MP * psz)
-                qpos = pos[:, None]                 # (T, 1)
-                pmask = jnp.broadcast_to(kpos[None, :] < start,
-                                         (T, MP * psz))
-                cmask = pos[None, :] <= qpos        # causal over chunk
-                mask = jnp.concatenate([pmask, cmask], axis=1)
-                scores = jnp.where(mask[None, :, :], scores, -1e30)
-                probs = jax.nn.softmax(scores, axis=-1)
-                o = jnp.einsum("nts,snd->tnd", probs,
-                               vv.astype(jnp.float32))
+                with jax.named_scope("attention"):
+                    MP = page_row.shape[0]
+                    # gather the slot's pages; row i covers absolute
+                    # positions [i*psz, (i+1)*psz) so masking kpos < start
+                    # keeps exactly the cached prefix (our own chunk
+                    # writes and trash rows land at kpos >= start)
+                    kpre = kp[layer, page_row].swapaxes(1, 2) \
+                        .reshape(MP * psz, nkv, hd)
+                    vpre = vp[layer, page_row].swapaxes(1, 2) \
+                        .reshape(MP * psz, nkv, hd)
+                    kk = jnp.concatenate([kpre, k[0]], axis=0)
+                    vv = jnp.concatenate([vpre, v[0]], axis=0)
+                    if nkv != nh:
+                        rep = nh // nkv
+                        kk = jnp.repeat(kk, rep, axis=1)
+                        vv = jnp.repeat(vv, rep, axis=1)
+                    qf = q[0].astype(jnp.float32)       # (T, nh, hd)
+                    kf = kk.astype(jnp.float32)         # (N, nh, hd)
+                    scores = jnp.einsum("tnd,snd->nts", qf, kf) \
+                        / math.sqrt(hd)
+                    kpos = jnp.arange(MP * psz)
+                    qpos = pos[:, None]                 # (T, 1)
+                    pmask = jnp.broadcast_to(kpos[None, :] < start,
+                                             (T, MP * psz))
+                    cmask = pos[None, :] <= qpos        # causal over chunk
+                    mask = jnp.concatenate([pmask, cmask], axis=1)
+                    scores = jnp.where(mask[None, :, :], scores, -1e30)
+                    probs = jax.nn.softmax(scores, axis=-1)
+                    o = jnp.einsum("nts,snd->tnd", probs,
+                                   vv.astype(jnp.float32))
                 return (o.astype(v.dtype).reshape(B, T, nh * hd),
                         kp, vp)
 
@@ -290,9 +292,10 @@ class Attention(HybridBlock):
                 from ..parallel.sharding import kernel_shard
                 ctx = jnp.where(active, lengths + 1, lengths)
                 # any slot may read any page: the pools shard by head only
-                o = paged_attention(
-                    q[:, 0], kp[layer], vp[layer], page_table, ctx,
-                    shard=kernel_shard(B, nkv, batch_axis=None))
+                with jax.named_scope("attention"):
+                    o = paged_attention(
+                        q[:, 0], kp[layer], vp[layer], page_table, ctx,
+                        shard=kernel_shard(B, nkv, batch_axis=None))
                 return o.reshape(B, T, nh * hd), kp, vp
 
             o, new_k, new_v = apply_op(

@@ -218,6 +218,7 @@ def _fwd_call(q, k, v, q_off, k_off, causal, scale, bq=128, bk=128):
     grid = (BH, nq)
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         out_shape=(jax.ShapeDtypeStruct((BH, T, D), q.dtype),
                    jax.ShapeDtypeStruct((BH, 1, T), jnp.float32)),
         grid=grid,
@@ -301,6 +302,7 @@ def _bwd_dq_call(q, k, v, do, lse, delta, q_off, k_off, causal, scale,
     grid = (BH, nq)
     return pl.pallas_call(
         kernel,
+        name="flash_bwd_dq",
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
         grid=grid,
         in_specs=[
@@ -401,6 +403,7 @@ def _bwd_dkv_call(q, k, v, do, lse, delta, q_off, k_off, causal, scale,
     grid = (BHkv, nk, rep)
     return pl.pallas_call(
         kernel,
+        name="flash_bwd_dkv",
         out_shape=(jax.ShapeDtypeStruct((BHkv, Tk, D), k.dtype),
                    jax.ShapeDtypeStruct((BHkv, Tk, D), v.dtype)),
         grid=grid,
@@ -783,6 +786,7 @@ def _paged_kernel_call(q, k_pages, v_pages, page_table, lengths, scale):
     )
     out = pl.pallas_call(
         kernel,
+        name="paged_attention",
         out_shape=jax.ShapeDtypeStruct((S, Hkv, rep, D), q.dtype),
         grid_spec=grid_spec,
         interpret=_INTERPRET,

@@ -15,12 +15,15 @@ the reference's server-side update sharding (``kvstore_dist_server.h:346``).
 """
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
 from .. import _tape
 from .. import fault as _fault
+from .. import profiler as _profiler
 from ..ndarray.ndarray import NDArray
 from ..numpy import random as _random
 from .mesh import mesh_scope
@@ -80,6 +83,7 @@ class TrainStep:
         self._t = 0
         self._batch_spec = batch_spec
         self._jitted = None
+        self._cold = True   # the next call builds and compiles
         self._states = None
         self._shardings = None
         self._setup()
@@ -171,7 +175,12 @@ class TrainStep:
                       if n not in train_sub}
 
             def loss_of(tr):
-                loss_arr, mutated = run_forward({**frozen, **tr}, key, batch)
+                # device names: under value_and_grad jax writes this
+                # scope's ops as jvp(forward)/... and their backward as
+                # transpose(jvp(forward))/...
+                with jax.named_scope("forward"):
+                    loss_arr, mutated = run_forward({**frozen, **tr}, key,
+                                                    batch)
                 return loss_arr, mutated
 
             if self.remat:
@@ -181,40 +190,45 @@ class TrainStep:
             new_params = dict(frozen)
             new_states = {}
             tf = t.astype(jnp.int32)
-            for name in trainable:
-                i = name_to_idx[name]
-                w = param_arrays[name]
-                g = grads[name].astype(jnp.float32)
-                if self.zero1 and self.mesh is not None:
-                    # ZeRO-1 comm/compute overlap: pin each param's grad
-                    # to the dp-sharded state spec BEFORE the update.
-                    # The sharded update then lives in the PROGRAM, not
-                    # in inferred propagation from the state
-                    # out_shardings: each parameter's reduce chain is an
-                    # independent op issuable as soon as that grad is
-                    # ready (never one combined tail collective), the
-                    # update runs on the 1/dp shard, and the only
-                    # post-update traffic is the updated-param
-                    # all-gather — which the TPU scheduler pairs into
-                    # async start/done around remaining backward compute
-                    # (asserted by hlo.check_collective_overlap /
-                    # check_overlap_window on the AOT artifact).
-                    # Partitioners with partial->tiled resharding lower
-                    # the pinned reduce to a true reduce-scatter.
-                    gspec = self._state_spec(name, params[i][1], w.shape)
-                    g = jax.lax.with_sharding_constraint(
-                        g, NamedSharding(self.mesh, gspec))
-                if opt.clip_gradient is not None:
-                    g = jnp.clip(g, -opt.clip_gradient, opt.clip_gradient)
-                wd = jnp.float32(opt._get_wd(i))
-                lr_i = lr * jnp.float32(
-                    params[i][1].lr_mult if hasattr(params[i][1], "lr_mult")
-                    else 1.0)
-                scalars = tuple(opt._scalar_args(i))
-                res = opt._rule(w, g, lr_i, wd, tf, scalars,
-                                opt_states.get(name, ()))
-                new_params[name] = res[0]
-                new_states[name] = res[1]
+            with jax.named_scope("optimizer"):
+                for name in trainable:
+                    i = name_to_idx[name]
+                    w = param_arrays[name]
+                    g = grads[name].astype(jnp.float32)
+                    if self.zero1 and self.mesh is not None:
+                        # ZeRO-1 comm/compute overlap: pin each param's
+                        # grad to the dp-sharded state spec BEFORE the
+                        # update.  The sharded update then lives in the
+                        # PROGRAM, not in inferred propagation from the
+                        # state out_shardings: each parameter's reduce
+                        # chain is an independent op issuable as soon as
+                        # that grad is ready (never one combined tail
+                        # collective), the update runs on the 1/dp shard,
+                        # and the only post-update traffic is the
+                        # updated-param all-gather — which the TPU
+                        # scheduler pairs into async start/done around
+                        # remaining backward compute (asserted by
+                        # hlo.check_collective_overlap /
+                        # check_overlap_window on the AOT artifact).
+                        # Partitioners with partial->tiled resharding
+                        # lower the pinned reduce to a true
+                        # reduce-scatter.
+                        gspec = self._state_spec(name, params[i][1],
+                                                 w.shape)
+                        g = jax.lax.with_sharding_constraint(
+                            g, NamedSharding(self.mesh, gspec))
+                    if opt.clip_gradient is not None:
+                        g = jnp.clip(g, -opt.clip_gradient,
+                                     opt.clip_gradient)
+                    wd = jnp.float32(opt._get_wd(i))
+                    lr_i = lr * jnp.float32(
+                        params[i][1].lr_mult
+                        if hasattr(params[i][1], "lr_mult") else 1.0)
+                    scalars = tuple(opt._scalar_args(i))
+                    res = opt._rule(w, g, lr_i, wd, tf, scalars,
+                                    opt_states.get(name, ()))
+                    new_params[name] = res[0]
+                    new_states[name] = res[1]
             # frozen params mutated in forward (BN stats) propagate
             for name, val in mutated.items():
                 if name not in trainable:
@@ -258,6 +272,16 @@ class TrainStep:
             # step-boundary peer health (mx.fault.dist): detect a hung
             # peer before launching the next cross-process program
             _fault._DIST_HEARTBEAT.beat(step=self._t)
+        # first call: the program is built here and compiled in the
+        # dispatch the build span encloses
+        build = _profiler.span("mx.train.step.build") if self._cold \
+            else contextlib.nullcontext()
+        with _profiler.step_span("mx.train.step", self._t + 1), build:
+            loss = self._step(batch)
+        self._cold = False
+        return loss
+
+    def _step(self, batch):
         batch_arrays = tuple(b._data if isinstance(b, NDArray)
                              else jnp.asarray(b) for b in batch)
         if self._jitted is None:
@@ -267,9 +291,10 @@ class TrainStep:
         lr = jnp.float32(self.optimizer.learning_rate)
         key = _random.new_key()
         param_arrays = {name: p._data._data for name, p in self._params}
-        loss, new_params, new_states = self._jitted(
-            param_arrays, self._states, jnp.int32(self._t), lr, key,
-            *batch_arrays)
+        with _profiler.span("mx.train.step.dispatch"):
+            loss, new_params, new_states = self._jitted(
+                param_arrays, self._states, jnp.int32(self._t), lr, key,
+                *batch_arrays)
         for name, p in self._params:
             p._data._data = new_params[name]
         self._states = new_states
@@ -369,6 +394,7 @@ class TrainStep:
         """
         self.mesh = mesh
         self._jitted = None
+        self._cold = True
         self._setup()
         if checkpoint is not None:
             self.load_checkpoint(checkpoint)

@@ -5,21 +5,36 @@ Reference parity: ``python/mxnet/profiler.py`` (``set_config``,
 ``Domain/Task/Frame/Counter/Marker`` at :228-287) over
 ``src/profiler/profiler.h:256`` and ``aggregate_stats.cc``.
 
-Two recording planes:
+Two recording planes, on two clocks:
 
-1. **Device plane** — ``set_state('run')`` starts an XLA trace
-   (``jax.profiler.start_trace``) into ``<filename stem>_trace``; user
-   scopes additionally map onto ``jax.profiler.TraceAnnotation`` so they
-   appear on the device timeline in TensorBoard/Perfetto.
-2. **Host plane** — a central event recorder in this module.  Framework
-   seams (op dispatch in ``ndarray.apply_op``, KVStore push/pull,
-   Trainer step phases, DataLoader/DataIter batches) and user scopes
-   emit events with real wall-clock begin/end timestamps; ``dump()``
-   writes them as valid chrome://tracing JSON (``ph:"X"`` complete
-   events plus ``ph:"C"`` counter events) next to the XLA trace dir.
+1. **Device plane** — the ``.xplane.pb`` of a ``jax.profiler`` session,
+   whoever started it: ``set_state('run')`` (``jax.profiler.start_trace``
+   into ``<filename stem>_trace``), ``jax.profiler.trace(dir)`` in user
+   code, or a benchmark's tracer.  Its clock is the profiler session's:
+   host threads and the device's ``XLA Ops`` share it.  :func:`span` and
+   :func:`step_span` put a ``jax.profiler.TraceAnnotation`` /
+   ``StepTraceAnnotation`` there with their arguments, nested under
+   whatever span the thread is already in; outside a session an
+   annotation is a flag test.  **``span`` is the only way code on the
+   device path (``TrainStep``, ``Server.engine_step``, the data loader)
+   marks time**: all such names start ``mx.``.  Inside a jitted program
+   nothing can mark time; there ``jax.named_scope`` (every gluon block
+   under its registered name, ``forward`` / ``optimizer`` in
+   ``TrainStep``, ``kv_write`` / ``attention`` / ``sample`` in the serving
+   programs) and a ``pl.pallas_call``'s ``name=`` put the program's names
+   on the device's ops.
+2. **Host plane** — a central event recorder in this module, on its own
+   clock: ``time.perf_counter`` microseconds since this module's import,
+   which shares nothing with the device plane's.  Framework seams (op
+   dispatch in ``ndarray.apply_op``, KVStore push/pull, Trainer step
+   phases, DataLoader/DataIter batches), user scopes and, while
+   ``mx.profiler`` itself runs, the ``mx.*`` spans emit events with real
+   begin/end timestamps; ``dump()`` writes them as valid
+   chrome://tracing JSON (``ph:"X"`` complete events plus ``ph:"C"``
+   counter events) next to the XLA trace dir.
 
 Hot paths are gated by module-level flags (``_IMPERATIVE``, ``_KVSTORE``,
-``_STEP``, ``_DATA``, ``_MEMORY``) recomputed on every config/state
+``_STEP``, ``_DATA``, ``_MEMORY``, ``_SPAN``) recomputed on every config/state
 change, so with profiling off an instrumented call site pays exactly one
 attribute read + falsy branch.
 
@@ -105,13 +120,15 @@ _STEP = False         # Trainer phases, Block forward, autograd backward
 _KVSTORE = False      # KVStore byte/time counters
 _DATA = False         # DataLoader / DataIter throughput
 _MEMORY = False       # device memory_stats() counter sampling
+_SPAN = False         # host-plane copy of the mx.* spans (span, step_span)
 
 
 def _recompute_flags():
-    global _IMPERATIVE, _STEP, _KVSTORE, _DATA, _MEMORY
+    global _IMPERATIVE, _STEP, _KVSTORE, _DATA, _MEMORY, _SPAN
     with _rec_lock:
         cfg = _state["config"]
         base = _state["running"] and not _state["paused"]
+        _SPAN = base
         all_ = cfg.get("profile_all", False)
         _IMPERATIVE = base and (all_ or cfg.get("profile_imperative",
                                                 True))
@@ -377,39 +394,61 @@ def reset():
 class _Scope:
     """Timed + device-annotated scope.
 
-    The aggregate table is fed whenever the profiler is not paused (the
-    pre-existing behavior user code relies on); trace events additionally
-    require the profiler to be running.  Both decisions are latched at
-    ``__enter__`` so a pause mid-scope keeps reference semantics: what
-    matters is the state when the scope was entered."""
+    The annotation (``jax.profiler.TraceAnnotation`` with ``args``, a
+    ``StepTraceAnnotation`` for a ``step``) is entered whenever the scope
+    is; it records only inside a ``jax.profiler`` session.
 
-    def __init__(self, name, cat="scope"):
+    Host plane, user scopes (``gated=False``): the aggregate table is
+    fed whenever the profiler is not paused (the pre-existing behavior
+    user code relies on); trace events additionally require the profiler
+    to be running.  Both decisions are latched at ``__enter__`` so a
+    pause mid-scope keeps reference semantics: what matters is the state
+    when the scope was entered.  Spans on the device path
+    (``gated=True``) read ``_SPAN`` first and, with ``mx.profiler`` not
+    recording, take no lock, read no clock and feed no table."""
+
+    def __init__(self, name, cat="scope", args=None, step=False,
+                 gated=False):
         self._name = name
         self._cat = cat
+        self._args = args or {}
+        self._step = step
+        self._gated = gated
         self._ann = None
         self._rec = False
         self._agg = False
 
     def __enter__(self):
-        with _rec_lock:
-            self._agg = not _state["paused"]
-            self._rec = self._agg and _state["running"]
-        self._t0 = _now_us()
-        try:
-            self._ann = jax.profiler.TraceAnnotation(self._name)
-            self._ann.__enter__()
-        except Exception:
-            self._ann = None
+        if self._gated and not _SPAN:
+            self._agg = self._rec = False
+        else:
+            with _rec_lock:
+                self._agg = not _state["paused"]
+                self._rec = self._agg and _state["running"]
+            self._t0 = _now_us()
+        annotation = jax.profiler.StepTraceAnnotation if self._step \
+            else jax.profiler.TraceAnnotation
+        self._ann = annotation(self._name, **self._args)
+        self._ann.__enter__()
         return self
 
+    def set(self, **args):
+        """Arguments known only once the scope is open (a step span's
+        batch size after scheduling): appended to the annotation and to
+        the host-plane record."""
+        self._ann.set_metadata(**args)
+        if self._rec:
+            self._args = dict(self._args, **args)
+
     def __exit__(self, *exc):
-        if self._ann is not None:
+        if self._ann is not None:  # a stop() with no start()
             self._ann.__exit__(*exc)
         if not self._agg:
             return
         t1 = _now_us()
         if self._rec:
-            record_duration(self._name, self._cat, self._t0, t1 - self._t0)
+            record_duration(self._name, self._cat, self._t0, t1 - self._t0,
+                            args=self._args or None)
         else:
             with _rec_lock:
                 entry = _state["agg"][self._name]
@@ -526,6 +565,24 @@ class Marker:
 def annotate(name):
     """Decorator/context annotating device timeline (TPU extension)."""
     return _Scope(name)
+
+
+def span(name, **args):
+    """The one seam for marking time on the device path: a
+    ``jax.profiler.TraceAnnotation(name, **args)`` — in the ``.xplane.pb``
+    of whatever ``jax.profiler`` session is open, on the device trace's
+    clock, nested under the span the thread is in — plus a host-plane
+    record while ``mx.profiler`` itself runs.  No switch: a span is live
+    exactly when a profiler session is."""
+    return _Scope(name, cat="span", args=args, gated=True)
+
+
+def step_span(name, step, **args):
+    """:func:`span` over ``jax.profiler.StepTraceAnnotation``: one
+    iteration of a loop (a training step, an engine step), numbered
+    ``step_num`` for the profiler's per-step analysis."""
+    return _Scope(name, cat="step", args=dict(args, step_num=step),
+                  step=True, gated=True)
 
 
 # reference parity: MXNET_PROFILER_AUTOSTART starts the profiler in the

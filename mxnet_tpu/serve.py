@@ -387,7 +387,11 @@ class SlotScheduler:
                                     else tuple(int(t) for t in prompt)),
                          "sampling": _norm_sampling(sampling, rid),
                          "t_submit": time.monotonic(), "t_admit": None,
-                         "t_first": None, "t_done": None, "preempts": 0}
+                         "t_first": None, "t_done": None, "preempts": 0,
+                         # one monotonic time per entry of "tokens":
+                         # t_tokens[0] == t_first; a preempted request
+                         # keeps the times of the tokens it had
+                         "t_tokens": ()}
             s["reqs"] = reqs
             s["queue"] = s["queue"] + (rid,)
         _telemetry.bump("serve::submitted")
@@ -619,15 +623,17 @@ class SlotScheduler:
             fin = done or len(tokens) >= req["max_new"] or capped
             now = time.monotonic()
             t_first = req.get("t_first") or now
+            t_tokens = req["t_tokens"] + (now,)
             if fin:
                 self._release_slot(s, plan["slot"])
                 self._set_req(s, rid, state="done", tokens=tokens,
-                              slot=None, epoch=None, t_first=t_first,
-                              t_done=now)
+                              t_tokens=t_tokens, slot=None, epoch=None,
+                              t_first=t_first, t_done=now)
             else:
                 s["slots"][plan["slot"]] = dict(
                     ent, last_tok=first_token)
-                self._set_req(s, rid, tokens=tokens, t_first=t_first)
+                self._set_req(s, rid, tokens=tokens, t_tokens=t_tokens,
+                              t_first=t_first)
         return rid if fin else None
 
     def fail(self, plan):
@@ -738,6 +744,7 @@ class SlotScheduler:
         with self._lock:
             s = self._s
             s["slots"] = dict(s["slots"])
+            now = time.monotonic()
             for entry, (token, done) in zip(snapshot, results):
                 slot, epoch = entry["slot"], entry["epoch"]
                 ent = s["slots"].get(slot)
@@ -752,6 +759,7 @@ class SlotScheduler:
                 rid = ent["rid"]
                 req = s["reqs"][rid]
                 tokens = req["tokens"] + (token,)
+                t_tokens = req["t_tokens"] + (now,)
                 new_len = ent["len"] + 1
                 capped = new_len + 1 > self.max_pages_per_slot \
                     * self.page_size
@@ -759,13 +767,14 @@ class SlotScheduler:
                 if fin:
                     self._release_slot(s, slot)
                     self._set_req(s, rid, state="done", tokens=tokens,
-                                  slot=None, epoch=None,
-                                  t_done=time.monotonic())
+                                  t_tokens=t_tokens, slot=None,
+                                  epoch=None, t_done=now)
                     finished.append(rid)
                 else:
                     s["slots"][slot] = dict(ent, len=new_len,
                                             last_tok=token)
-                    self._set_req(s, rid, tokens=tokens)
+                    self._set_req(s, rid, tokens=tokens,
+                                  t_tokens=t_tokens)
         if finished:
             _telemetry.bump("serve::finished", len(finished))
         return finished
@@ -973,8 +982,9 @@ def _sample_batch(logits, seeds, steps, temps, top_ks, top_ps):
     (own key, own mask), so a batched slot samples bitwise-identically
     to a solo run of the same request."""
     import jax
-    return jax.vmap(_sample_one)(logits, seeds, steps, temps, top_ks,
-                                 top_ps)
+    with jax.named_scope("sample"):
+        return jax.vmap(_sample_one)(logits, seeds, steps, temps,
+                                     top_ks, top_ps)
 
 
 # ----------------------------------------------------------------------
@@ -1017,6 +1027,7 @@ def _build_decode_fn(net, ps, page_size, scales, dtype):
 
 
 def _build_prefill_fn(net, ps, page_size, scales, dtype):
+    import jax
     import jax.numpy as jnp
 
     from . import _tape
@@ -1031,13 +1042,15 @@ def _build_prefill_fn(net, ps, page_size, scales, dtype):
         with _tape.suspend_recording(), _swapped_params(ps, params):
             logits = net.forward(NDArray(tokens), cache=view)._data
         last = logits[0, true_len - 1, :].astype(jnp.float32)
-        return (_sample_one(last, seed, step, temp, top_k, top_p),
-                view.k, view.v)
+        with jax.named_scope("sample"):
+            tok = _sample_one(last, seed, step, temp, top_k, top_p)
+        return tok, view.k, view.v
 
     return prefill
 
 
 def _build_chunk_fn(net, ps, page_size, scales, dtype):
+    import jax
     import jax.numpy as jnp
 
     from . import _tape
@@ -1053,8 +1066,9 @@ def _build_chunk_fn(net, ps, page_size, scales, dtype):
         with _tape.suspend_recording(), _swapped_params(ps, params):
             logits = net.forward(NDArray(tokens), cache=view)._data
         last = logits[0, true_len - 1, :].astype(jnp.float32)
-        return (_sample_one(last, seed, step, temp, top_k, top_p),
-                view.k, view.v)
+        with jax.named_scope("sample"):
+            tok = _sample_one(last, seed, step, temp, top_k, top_p)
+        return tok, view.k, view.v
 
     return chunk
 
@@ -1181,26 +1195,32 @@ class WarmPool:
                for k, v in params.items()}
         i32 = lambda *shape: aval(shape, jnp.int32, shard_rep)  # noqa: E731
         f32 = lambda *shape: aval(shape, jnp.float32, shard_rep)  # noqa: E731
+
+        def compiled(program, fn, donate, *avals):
+            with _profiler.span("mx.serve.compile", program=program):
+                return jax.jit(fn, donate_argnums=donate).lower(
+                    *avals).compile()
+
         # the mesh is in scope while the programs trace, so the
         # Pallas kernels wrap themselves per tp shard
         with _cache_at(own_dir), mesh_scope(mesh):
             decode = _build_decode_fn(net, ps, spec.page_size, scales,
                                       dtype)
             S, MP = spec.slots, spec.max_pages_per_slot
-            self._decode = jax.jit(
-                decode, donate_argnums=(1, 2)).lower(
+            self._decode = compiled(
+                "decode", decode, (1, 2),
                 pav, pool_aval, pool_aval, i32(S, MP), i32(S), i32(S),
                 aval((S,), jnp.bool_, shard_rep),
-                i32(S), i32(S), f32(S), i32(S), f32(S)).compile()
+                i32(S), i32(S), f32(S), i32(S), f32(S))
             prefill = _build_prefill_fn(net, ps, spec.page_size,
                                         scales, dtype)
             samp = (i32(), i32(), f32(), i32(), f32())
             self._prefill = {}
             for T in serve_cfg.ladder:
-                self._prefill[T] = jax.jit(
-                    prefill, donate_argnums=(1, 2)).lower(
+                self._prefill[T] = compiled(
+                    "prefill%d" % T, prefill, (1, 2),
                     pav, pool_aval, pool_aval, i32(MP), i32(1, T),
-                    i32(), *samp).compile()
+                    i32(), *samp)
             # the chunk ladder (prefix-cache suffix prefill) reuses
             # the same rungs; the plain prefill programs above stay
             # bitwise-unchanged for the start==0 path
@@ -1209,15 +1229,15 @@ class WarmPool:
                 chunk = _build_chunk_fn(net, ps, spec.page_size,
                                         scales, dtype)
                 for T in serve_cfg.ladder:
-                    self._chunk[T] = jax.jit(
-                        chunk, donate_argnums=(1, 2)).lower(
+                    self._chunk[T] = compiled(
+                        "chunk%d" % T, chunk, (1, 2),
                         pav, pool_aval, pool_aval, i32(MP), i32(1, T),
-                        i32(), i32(), *samp).compile()
+                        i32(), i32(), *samp)
             # pool page copy — the COW step that makes a shared page
             # privately writable
-            self._copy = jax.jit(
-                _build_copy_fn(), donate_argnums=(0, 1)).lower(
-                pool_aval, pool_aval, i32(), i32()).compile()
+            self._copy = compiled(
+                "copy", _build_copy_fn(), (0, 1),
+                pool_aval, pool_aval, i32(), i32())
         new = _ccache.cache_entries(cache_dir) - before
         self.stats = {
             "compile_s": round(time.monotonic() - t0, 3),
@@ -1343,6 +1363,7 @@ class Server:
         self._stop = threading.Event()
         self._work = threading.Event()
         self._thread = None
+        self._steps = 0                 # engine iterations (span number)
         self._error = None              # engine-thread death, if any
         # streaming SLO sketches, fed at terminal delivery — mergeable
         # across replicas, O(buckets) to ship on the heartbeat
@@ -1650,48 +1671,59 @@ class Server:
         """One engine iteration; returns False when idle.  Public so
         tests (and single-threaded drivers) can pump the engine without
         the background thread."""
+        self._steps += 1
+        with _profiler.step_span("mx.serve.step", self._steps) as step:
+            return self._engine_step(step)
+
+    def _engine_step(self, step):
         import numpy as onp
-        # chaos seam: serve_engine_kill fires here, on the engine
-        # thread — the replica-death offense ReplicaGroup fails over
-        _fault.serve_engine_check("engine_step")
-        self._sweep_deadlines()
         sched, pool = self.sched, self.pool
         spec = pool.spec
         eos = self.cfg.eos_id
-        snapshot = sched.begin_step()
+        with _profiler.span("mx.serve.schedule"):
+            # chaos seam: serve_engine_kill fires here, on the engine
+            # thread — the replica-death offense ReplicaGroup fails over
+            _fault.serve_engine_check("engine_step")
+            self._sweep_deadlines()
+            snapshot = sched.begin_step()
+            step.set(active=len(snapshot),
+                     context_tokens=sum(e["len"] + 1 for e in snapshot))
+            if snapshot:
+                S, MP = spec.slots, spec.max_pages_per_slot
+                page_table = onp.zeros((S, MP), onp.int32)
+                lengths = onp.zeros((S,), onp.int32)
+                tokens = onp.zeros((S,), onp.int32)
+                active = onp.zeros((S,), bool)
+                seeds = onp.zeros((S,), onp.int32)
+                steps = onp.zeros((S,), onp.int32)
+                temps = onp.zeros((S,), onp.float32)
+                top_ks = onp.zeros((S,), onp.int32)
+                top_ps = onp.ones((S,), onp.float32)
+                for e in snapshot:
+                    row = list(e["pages"])[:MP]
+                    page_table[e["slot"], :len(row)] = row
+                    lengths[e["slot"]] = e["len"]
+                    tokens[e["slot"]] = e["last_tok"]
+                    active[e["slot"]] = True
+                    sp = e.get("sampling") or {}
+                    seeds[e["slot"]] = sp.get("seed", 0)
+                    steps[e["slot"]] = e.get("step", 0)
+                    temps[e["slot"]] = sp.get("temperature", 0.0)
+                    top_ks[e["slot"]] = sp.get("top_k", 0)
+                    top_ps[e["slot"]] = sp.get("top_p", 1.0)
         toks = None
         if snapshot:
-            S, MP = spec.slots, spec.max_pages_per_slot
-            page_table = onp.zeros((S, MP), onp.int32)
-            lengths = onp.zeros((S,), onp.int32)
-            tokens = onp.zeros((S,), onp.int32)
-            active = onp.zeros((S,), bool)
-            seeds = onp.zeros((S,), onp.int32)
-            steps = onp.zeros((S,), onp.int32)
-            temps = onp.zeros((S,), onp.float32)
-            top_ks = onp.zeros((S,), onp.int32)
-            top_ps = onp.ones((S,), onp.float32)
-            for e in snapshot:
-                row = list(e["pages"])[:MP]
-                page_table[e["slot"], :len(row)] = row
-                lengths[e["slot"]] = e["len"]
-                tokens[e["slot"]] = e["last_tok"]
-                active[e["slot"]] = True
-                sp = e.get("sampling") or {}
-                seeds[e["slot"]] = sp.get("seed", 0)
-                steps[e["slot"]] = e.get("step", 0)
-                temps[e["slot"]] = sp.get("temperature", 0.0)
-                top_ks[e["slot"]] = sp.get("top_k", 0)
-                top_ps[e["slot"]] = sp.get("top_p", 1.0)
             # async dispatch: the device crunches the decode while the
             # host runs admissions/prefills below (their programs chain
             # on the pool arrays, so ordering is functional, not timed)
-            toks = pool.run_decode(page_table, lengths, tokens, active,
-                                   sampling={"seeds": seeds,
-                                             "steps": steps,
-                                             "temps": temps,
-                                             "top_ks": top_ks,
-                                             "top_ps": top_ps})
+            with _profiler.span("mx.serve.decode.dispatch"):
+                toks = pool.run_decode(page_table, lengths, tokens,
+                                       active,
+                                       sampling={"seeds": seeds,
+                                                 "steps": steps,
+                                                 "temps": temps,
+                                                 "top_ks": top_ks,
+                                                 "top_ps": top_ps})
         admitted = False
         while True:
             plan = sched.admit_next()
@@ -1719,25 +1751,32 @@ class Server:
                 # a preempted request regrew past the ladder: terminal
                 sched.fail(plan)
                 continue
-            if plan.get("cow"):
-                # the first computed position lands in a shared page:
-                # privatize it before any write can touch it
-                pool.copy_page(*plan["cow"])
-            padded = onp.zeros((T,), onp.int32)
-            padded[:len(chunk)] = chunk
-            row = onp.zeros((spec.max_pages_per_slot,), onp.int32)
-            row[:len(plan["pages"])] = plan["pages"]
-            first = int(pool.run_prefill(
-                padded, row, len(chunk), start=start,
-                sampling=plan.get("sampling"),
-                step=plan.get("ntok", 0)))
-            sched.commit_prefill(plan, first,
-                                 done=(eos is not None
-                                       and first == eos))
+            what = dict(rid=plan["rid"], padded=T, true_len=len(chunk),
+                        start=start)
+            with _profiler.span("mx.serve.admit", **what):
+                if plan.get("cow"):
+                    # the first computed position lands in a shared
+                    # page: privatize it before any write can touch it
+                    pool.copy_page(*plan["cow"])
+                padded = onp.zeros((T,), onp.int32)
+                padded[:len(chunk)] = chunk
+                row = onp.zeros((spec.max_pages_per_slot,), onp.int32)
+                row[:len(plan["pages"])] = plan["pages"]
+                # the prefill blocks: int() waits for its token
+                with _profiler.span("mx.serve.prefill", **what):
+                    first = int(pool.run_prefill(
+                        padded, row, len(chunk), start=start,
+                        sampling=plan.get("sampling"),
+                        step=plan.get("ntok", 0)))
+                sched.commit_prefill(plan, first,
+                                     done=(eos is not None
+                                           and first == eos))
         if snapshot:
             try:
                 _fault.serve_decode_check()
-                out = onp.asarray(toks)
+                # the host waits here for the decode it dispatched
+                with _profiler.span("mx.serve.readback"):
+                    out = onp.asarray(toks)
             except Exception as exc:  # noqa: BLE001 -- classification filter
                 from . import fault_dist as _fdist
                 if _fdist.classify_xla_error(exc) != "transient":
@@ -1753,11 +1792,14 @@ class Server:
                             "dropped for deterministic replay: %s", exc)
                 self._finish_terminal()
                 return True
-            results = [(int(out[e["slot"]]),
-                        eos is not None and int(out[e["slot"]]) == eos)
-                       for e in snapshot]
-            sched.commit_step(snapshot, results)
-        self._finish_terminal()
+        with _profiler.span("mx.serve.commit"):
+            if snapshot:
+                results = [(int(out[e["slot"]]),
+                            eos is not None
+                            and int(out[e["slot"]]) == eos)
+                           for e in snapshot]
+                sched.commit_step(snapshot, results)
+            self._finish_terminal()
         return bool(snapshot) or admitted
 
 

@@ -214,33 +214,15 @@ def _stamp(rank=None, step=None, gen=None, extra=None):
     return args
 
 
-class span:
-    """Context manager recording one host-plane span stamped with
-    (rank, step, generation) — the fleet-correlation fields
-    ``tools/trace_merge.py`` aligns per-rank traces on.  Rides the
-    profiler's recording gate exactly like ``profiler.annotate``:
-    with the profiler off it costs one lock-free attribute read."""
-
-    __slots__ = ("_name", "_cat", "_args", "_rec", "_t0")
-
-    def __init__(self, name, cat="span", **stamp_kw):
-        self._name = name
-        self._cat = cat
-        self._args = stamp_kw
-
-    def __enter__(self):
-        self._rec = _profiler._recording()
-        if self._rec:
-            self._t0 = _profiler._now_us()
-        return self
-
-    def __exit__(self, *exc):
-        if self._rec:
-            t1 = _profiler._now_us()
-            _profiler.record_duration(
-                self._name, self._cat, self._t0, t1 - self._t0,
-                args=_stamp(**self._args))
-        return False
+def span(name, cat="span", **stamp_kw):
+    """Context manager recording one span stamped with (rank, step,
+    generation) — the fleet-correlation fields ``tools/trace_merge.py``
+    aligns per-rank traces on.  A ``profiler.span``: it reaches the
+    device timeline of an open ``jax.profiler`` session with its stamp,
+    and the profiler's host plane while that records; with both off it
+    costs one inactive annotation."""
+    return _profiler._Scope(name, cat=cat, args=_stamp(**stamp_kw),
+                            gated=True)
 
 
 def step_mark(step, rank=None, gen=None):

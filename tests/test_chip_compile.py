@@ -15,6 +15,7 @@ module-scoped fixture below (never at import), and every compile
 happens in the test's own process.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -182,3 +183,48 @@ def test_train_step_aot_topology_mesh():
     assert "all-gather" in txt  # the sharded update's param gather
     with pytest.raises(RuntimeError, match="aot"):
         step(x, y)
+
+
+# The names the device trace is read by (PERF.md section 3): a kernel's
+# ``name=`` becomes the custom call's instruction name and its op_name,
+# a ``jax.named_scope`` a component of the op_name of every op under it.
+def _kernel_lines(fn, *avals):
+    text = jax.jit(fn).lower(*avals).compile().as_text()
+    return [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+
+
+@pytest.mark.parametrize("fn,names", [
+    (_flash, ["flash_fwd"]),
+    (_flash_grad, ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]),
+    (_paged, ["paged_attention"])], ids=["forward", "grad", "paged"])
+def test_kernels_carry_their_names(topo, on_chip, fn, names):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    avals = _paged_avals(lambda spec: one_chip) if fn is _paged \
+        else _qkv(2048, one_chip)
+    lines = _kernel_lines(fn, *avals)
+    assert len(lines) == len(names)
+    for name in names:     # .../jvp(flash_fwd)/pallas_call
+        named = [ln for ln in lines if re.search(
+            r'op_name="[^"]*\b%s\)*/pallas_call"' % name, ln)]
+        assert len(named) == 1, name
+
+
+def test_decode_program_carries_its_scopes(topo, on_chip):
+    """The serving decode program at one layer of 128-wide heads: the
+    K/V write, the attention read (the paged kernel under it, by name)
+    and the sampler are told apart in the compiled text."""
+    from mxnet_tpu import serve
+    from mxnet_tpu.models import tiny_config
+    mesh = Mesh(onp.array(topo.devices[:1]), ("dp",))
+    cfg = tiny_config(dim=512, n_heads=4, n_kv_heads=2, n_layers=1,
+                      dtype="bfloat16")
+    lowered, _ = serve.lower_decode_program(cfg=cfg, mesh=mesh)
+    text = lowered.compile().as_text()
+    assert "HloModule jit_decode" in text
+    for scope in ("layer0/attention/kv_write/",
+                  "layer0/attention/attention/", "/sample/"):
+        assert 'op_name="jit(decode)/' in text and scope in text, scope
+    (kernel,) = [ln for ln in text.splitlines()
+                 if "tpu_custom_call" in ln]
+    assert re.search(r'op_name="jit\(decode\)/layer0/attention/attention/'
+                     r'[^"]*paged_attention/pallas_call"', kernel)

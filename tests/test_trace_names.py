@@ -1,0 +1,392 @@
+"""The program's own names: ``mx.*`` host spans on the profiler's clock,
+named scopes on the device's ops, per-token times in the request record.
+
+One ``jax.profiler`` session at a time in a process: every session here
+is opened inside a test (never at import), and the tests that open one
+live in this one file so that a single xdist worker runs them in turn.
+The host plane of the CPU profile is read with ``jax.profiler.ProfileData``
+alone.
+"""
+import glob
+import os
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as onp
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu import gluon, parallel, profiler, serve
+from mxnet_tpu import telemetry as tel
+from mxnet_tpu.gluon.model_zoo import vision
+from mxnet_tpu.models import TransformerLM, tiny_config
+
+
+# ----------------------------------------------------------------------
+# helpers
+# ----------------------------------------------------------------------
+def _mx_spans(trace_dir):
+    """``[(name, start ns, end ns, args)]`` of every ``mx.*`` event on the
+    host plane of the one profile under ``trace_dir``, by start."""
+    paths = glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    assert len(paths) == 1, paths
+    data = jax.profiler.ProfileData.from_file(paths[0])
+    out = []
+    for plane in data.planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("mx."):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns,
+                                {k: v for k, v in e.stats}))
+    return sorted(out, key=lambda s: (s[1], -s[2]))
+
+
+def _children(spans, parent):
+    return [s for s in spans if s is not parent
+            and parent[1] <= s[1] and s[2] <= parent[2]]
+
+
+def _tiny_step(seed=0):
+    mx.np.random.seed(seed)
+    net = vision.resnet18_v1(classes=10, thumbnail=True)
+    net.initialize()
+    x = mx.np.array(onp.random.RandomState(seed).randn(2, 3, 32, 32)
+                    .astype("float32"))
+    y = mx.np.array(onp.array([1, 7], "int32"))
+    net(x)
+    step = parallel.TrainStep(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(),
+        mx.optimizer.SGD(learning_rate=0.1, momentum=0.9), mesh=None)
+    return step, x, y
+
+
+def _tiny_server(**kw):
+    net = TransformerLM(tiny_config())
+    net.initialize()
+    args = dict(slots=3, page_size=8, pages=24, ladder=(16, 32),
+                max_new=10, cache_dir=None, int8=False)
+    args.update(kw)
+    return serve.Server(net, serve.ServeConfig(**args))
+
+
+def _pump(srv, rids, limit=200):
+    """``engine_step`` by hand until every request is terminal; returns
+    the number of calls."""
+    done = [srv._done[r] for r in rids]
+    for calls in range(1, limit + 1):
+        srv.engine_step()
+        if all(ev.is_set() for ev in done):
+            return calls
+    raise AssertionError("requests not finished in %d steps" % limit)
+
+
+@pytest.fixture(scope="module")
+def lowered_step():
+    step, x, y = _tiny_step()
+    return step.lower(x, y)
+
+
+@pytest.fixture(scope="module")
+def step_names(lowered_step):
+    return re.findall(r'loc\("(jit\(step\)/[^"]*)"',
+                      lowered_step.as_text(debug_info=True))
+
+
+# ----------------------------------------------------------------------
+# (1) device names of the training step
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("phase", ["jvp(forward)/",
+                                   "transpose(jvp(forward))/",
+                                   "optimizer/"])
+def test_train_step_ops_carry_their_phase(step_names, phase):
+    assert any(n.startswith("jit(step)/" + phase) for n in step_names)
+
+
+def test_train_step_ops_carry_the_blocks_registered_names(step_names):
+    # a Sequential's child is numbered: <index>_<Type>; an attribute
+    # keeps its name (``features``, ``body``)
+    bn = [n for n in step_names if "/1_BatchNorm/" in n]
+    assert any(n.startswith("jit(step)/jvp(forward)/features/") for n in bn)
+    assert any(n.startswith("jit(step)/transpose(jvp(forward))/features/")
+               for n in bn)
+    assert any("/0_BasicBlockV1/body/0_Conv2D/" in n for n in step_names)
+    assert any("jvp(forward)/output/" in n for n in step_names)
+
+
+def test_train_step_program_keeps_its_name(lowered_step, step_names):
+    # the benchmark's match rules name the program jit_step
+    assert re.search(r"HloModule jit_step\b",
+                     lowered_step.compile().as_text())
+    scoped = sum(n.startswith(("jit(step)/jvp(forward)/",
+                               "jit(step)/transpose(jvp(forward))/",
+                               "jit(step)/optimizer/"))
+                 for n in step_names)
+    assert scoped >= 0.95 * len(step_names)
+
+
+def test_block_scope_names():
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Dense(4, in_units=3), gluon.nn.Activation("relu"))
+    assert [c._scope_name for c in net] == ["0_Dense", "1_Activation"]
+
+    class Pair(gluon.HybridBlock):
+        def __init__(self):
+            super().__init__()
+            self.left = gluon.nn.Dense(2, in_units=3)
+
+        def forward(self, x):
+            return self.left(x)
+
+    pair = Pair()
+    assert pair.left._scope_name == "left" and pair._scope_name is None
+    pair.initialize()
+    text = jax.jit(lambda a: pair(mx.np.array(a))._data).lower(
+        jnp.ones((1, 3))).as_text(debug_info=True)
+    assert "/Pair/left/" in text       # the type's name at the root
+
+
+# ----------------------------------------------------------------------
+# (2) spans of the serving engine
+# ----------------------------------------------------------------------
+ORDER = ["mx.serve.schedule", "mx.serve.decode.dispatch", "mx.serve.admit",
+         "mx.serve.readback", "mx.serve.commit"]
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """A tiny server pumped by hand under one profiler session: the
+    spans, what ``begin_step`` returned each step, and the records."""
+    tmp = tmp_path_factory.mktemp("serve_trace")
+    srv = _tiny_server()
+    begin, snaps = srv.sched.begin_step, []
+
+    def watched():
+        snap = begin()
+        snaps.append(snap)
+        return snap
+
+    srv.sched.begin_step = watched
+    with jax.profiler.trace(str(tmp)):
+        a = srv.submit([1, 2, 3, 4, 5], max_new=4)
+        b = srv.submit(list(range(1, 20)), max_new=3)
+        calls = _pump(srv, (a, b))
+        srv.engine_step()           # an idle step has its span too
+        calls += 1
+    records = {r: srv.result(r, timeout=5) for r in (a, b)}
+    return {"spans": _mx_spans(tmp), "snaps": snaps, "calls": calls,
+            "records": records, "rids": (a, b)}
+
+
+def test_engine_step_has_one_step_span_per_call(served):
+    steps = [s for s in served["spans"] if s[0] == "mx.serve.step"]
+    assert len(steps) == served["calls"]
+    assert [s[3]["step_num"] for s in steps] == \
+        list(range(1, served["calls"] + 1))
+
+
+def test_engine_step_children_are_nested_in_order(served):
+    spans = served["spans"]
+    steps = [s for s in spans if s[0] == "mx.serve.step"]
+    inner = [s for s in spans if s[0] != "mx.serve.step"
+             and s[0].startswith("mx.serve.")]
+    assert inner
+    claimed = 0
+    for step in steps:
+        kids = [s for s in _children(spans, step)
+                if s[0] in ORDER]       # prefill nests one deeper
+        claimed += len(_children(spans, step))
+        ranks = [ORDER.index(s[0]) for s in kids]
+        assert ranks == sorted(ranks), [s[0] for s in kids]
+        assert kids[0][0] == "mx.serve.schedule"
+        assert kids[-1][0] == "mx.serve.commit"
+    assert claimed == len(inner)        # no engine span outside a step
+    busy = [s for s in steps if s[3]["active"]]
+    assert busy
+    for step in busy:
+        names = [s[0] for s in _children(spans, step)]
+        assert "mx.serve.decode.dispatch" in names
+        assert "mx.serve.readback" in names
+
+
+def test_prefill_span_carries_the_admission(served):
+    spans = served["spans"]
+    admits = [s for s in spans if s[0] == "mx.serve.admit"]
+    prefills = [s for s in spans if s[0] == "mx.serve.prefill"]
+    assert len(admits) == len(prefills) == 2
+    want = {served["rids"][0]: (16, 5), served["rids"][1]: (32, 19)}
+    for admit, prefill in zip(admits, prefills):
+        assert prefill in _children(spans, admit)
+        args = prefill[3]
+        assert (args["padded"], args["true_len"]) == want[args["rid"]]
+        assert args["start"] == 0
+        assert admit[3]["rid"] == args["rid"]
+
+
+def test_step_span_counts_what_begin_step_returned(served):
+    steps = [s for s in served["spans"] if s[0] == "mx.serve.step"]
+    assert len(steps) == len(served["snaps"])
+    for step, snap in zip(steps, served["snaps"]):
+        assert step[3]["active"] == len(snap)
+        assert step[3]["context_tokens"] == sum(e["len"] + 1 for e in snap)
+    assert max(s[3]["active"] for s in steps) == 2
+
+
+def test_warm_pool_compiles_sit_under_compile_spans(tmp_path):
+    with jax.profiler.trace(str(tmp_path)):
+        _tiny_server(prefix_cache=False)
+    programs = [s[3]["program"] for s in _mx_spans(tmp_path)
+                if s[0] == "mx.serve.compile"]
+    assert programs == ["decode", "prefill16", "prefill32", "copy"]
+
+
+# ----------------------------------------------------------------------
+# (3) spans of the training step
+# ----------------------------------------------------------------------
+def test_train_step_spans(tmp_path):
+    step, x, y = _tiny_step()
+    with jax.profiler.trace(str(tmp_path)):
+        for _ in range(3):
+            step(x, y)
+    spans = _mx_spans(tmp_path)
+    steps = [s for s in spans if s[0] == "mx.train.step"]
+    assert [s[3]["step_num"] for s in steps] == [1, 2, 3]
+    builds = [s for s in spans if s[0] == "mx.train.step.build"]
+    assert len(builds) == 1 and builds[0] in _children(spans, steps[0])
+    dispatches = [s for s in spans if s[0] == "mx.train.step.dispatch"]
+    assert len(dispatches) == 3
+    for s, d in zip(steps, dispatches):
+        assert d in _children(spans, s)
+    assert dispatches[0] in _children(spans, builds[0])   # the compile
+
+
+def test_data_loader_spans(tmp_path):
+    data = gluon.data.ArrayDataset(onp.arange(12, dtype="float32")
+                                   .reshape(6, 2))
+    loader = gluon.data.DataLoader(data, batch_size=2)
+    with jax.profiler.trace(str(tmp_path)):
+        batches = list(loader)
+    assert len(batches) == 3
+    names = [s[0] for s in _mx_spans(tmp_path)]
+    assert names.count("mx.data.h2d") == 3
+    assert names.count("mx.data.next") == 4     # the last finds the end
+
+
+def test_telemetry_span_reaches_the_device_timeline(tmp_path):
+    tel.set_step_context(rank=2, step=9, gen=1)
+    with jax.profiler.trace(str(tmp_path)):
+        with tel.span("mx.test.fleet"):
+            pass
+    (span,) = _mx_spans(tmp_path)
+    assert span[0] == "mx.test.fleet"
+    assert (span[3]["rank"], span[3]["step"], span[3]["gen"]) == (2, 9, 1)
+
+
+# ----------------------------------------------------------------------
+# (4) per-token times
+# ----------------------------------------------------------------------
+def test_t_tokens_follow_the_tokens(served):
+    for rid, rec in served["records"].items():
+        assert rec["state"] == "done"
+        tt = rec["t_tokens"]
+        assert len(tt) == len(rec["tokens"]) > 1
+        assert list(tt) == sorted(tt)
+        assert tt[0] == rec["t_first"]
+        assert tt[-1] == rec["t_done"]
+        assert rec["t_submit"] <= rec["t_admit"] <= tt[0]
+
+
+def test_t_tokens_survive_a_preemption():
+    s = serve.SlotScheduler(2, 5, 2, 4)
+    a = s.submit(4, 6)
+    b = s.submit(4, 6)
+    for _ in range(2):
+        s.commit_prefill(s.admit_next(), 5)
+    first = s.request(b)["t_tokens"]
+    assert len(first) == 1 and first[0] == s.request(b)["t_first"]
+    time.sleep(0.002)
+    snap = s.begin_step()               # page pressure: b is preempted
+    assert [e["rid"] for e in snap] == [a]
+    assert s.request(b)["state"] == "waiting"
+    assert s.request(b)["t_tokens"] == first
+    s.commit_step(snap, [(6, False)])
+    assert len(s.request(a)["t_tokens"]) == 2
+    s.cancel(a)                         # room for b again
+    plan = s.admit_next()
+    assert plan["rid"] == b and plan["ntok"] == 1
+    s.commit_prefill(plan, 7)
+    rec = s.request(b)
+    assert rec["tokens"] == (5, 7) and rec["preempts"] == 1
+    assert rec["t_tokens"][0] == first[0] == rec["t_first"]
+    assert rec["t_tokens"][1] > first[0]
+
+
+# ----------------------------------------------------------------------
+# (5) with no profiler session: nothing recorded, nothing changed
+# ----------------------------------------------------------------------
+def test_span_records_nothing_while_the_profiler_is_off():
+    assert profiler.state() == "stop"
+    before = len(profiler._state["events"])
+    agg = dict(profiler._state["agg"])
+    with profiler.span("mx.test.off", rid=1) as s:
+        s.set(active=2)
+    with profiler.step_span("mx.test.off.step", 3):
+        pass
+    assert len(profiler._state["events"]) == before
+    assert dict(profiler._state["agg"]) == agg
+
+
+def test_span_feeds_the_host_plane_while_mx_profiler_runs(tmp_path):
+    profiler.set_config(filename=str(tmp_path / "p.json"))
+    profiler.set_state("run")
+    try:
+        with profiler.step_span("mx.test.on", 4, kind="x") as s:
+            s.set(active=2)
+            with profiler.span("mx.test.on.child", rid=8):
+                pass
+    finally:
+        profiler.set_state("stop")
+    events = {e[1]: e for e in profiler._state["events"]
+              if e[0] == "X" and e[1].startswith("mx.test.on")}
+    assert events["mx.test.on"][6] == {"kind": "x", "active": 2,
+                                       "step_num": 4}
+    assert events["mx.test.on.child"][6] == {"rid": 8}
+    assert profiler._state["agg"]["mx.test.on"][0] >= 1
+    profiler.reset()
+
+
+def test_annotate_keeps_feeding_the_aggregate_table():
+    profiler.reset()
+    with profiler.annotate("user_scope"):
+        pass
+    assert profiler._state["agg"]["user_scope"][0] == 1
+    assert "user_scope" in profiler.dumps()
+    profiler.reset()
+
+
+def test_outputs_do_not_depend_on_a_profiler_session(tmp_path):
+    """Bitwise the same losses and tokens with a session open and with
+    none: a span changes nothing it encloses."""
+    def losses():
+        step, x, y = _tiny_step(seed=3)
+        return [float(step(x, y)) for _ in range(3)]
+
+    def tokens():
+        mx.np.random.seed(5)
+        srv = _tiny_server()
+        rids = [srv.submit([3, 1, 4, 1, 5], max_new=5,
+                           sampling={"seed": 11, "temperature": 0.8}),
+                srv.submit([2, 7, 1, 8], max_new=4)]
+        _pump(srv, rids)
+        return [srv.result(r, timeout=5)["tokens"] for r in rids]
+
+    plain = losses(), tokens()
+    with jax.profiler.trace(str(tmp_path)):
+        traced = losses(), tokens()
+    assert plain == traced
+    assert all(len(t) >= 4 for t in plain[1])
