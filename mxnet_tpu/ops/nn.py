@@ -16,6 +16,8 @@ control flow only).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -220,26 +222,94 @@ def _bn_param_shape(ndim, axis):
     return tuple(shape)
 
 
+def _bn_geometry(x, axis):
+    """(axes reduced, the per-channel broadcast shape, elements a channel)."""
+    axes = tuple(i for i in range(x.ndim) if i != axis)
+    return axes, _bn_param_shape(x.ndim, axis), x.size // x.shape[axis]
+
+
+def _bn_forward(x, gamma, beta, eps, axis):
+    """((out, mean, var), residuals of the backward)."""
+    axes, shape, n = _bn_geometry(x, axis)
+    xf = x.astype(jnp.float32)
+    # both moments from one read of x: two sums with no dependence between
+    # them, so XLA can carry both in the epilogue of the op that produced x
+    mean = jnp.sum(xf, axis=axes) / n
+    var = jnp.maximum(jnp.sum(xf * xf, axis=axes) / n - mean * mean, 0.0)
+    inv = lax.rsqrt(var + eps)
+    out = (xf - mean.reshape(shape)) * inv.reshape(shape) \
+        * gamma.astype(jnp.float32).reshape(shape) \
+        + beta.astype(jnp.float32).reshape(shape)
+    outs = (out.astype(x.dtype), mean.astype(gamma.dtype),
+            var.astype(gamma.dtype))
+    # beta rides along for its dtype only
+    return outs, (x, mean, inv, gamma, beta)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _batch_norm_train(x, gamma, beta, eps, axis):
+    return _bn_forward(x, gamma, beta, eps, axis)[0]
+
+
+def _batch_norm_train_fwd(x, gamma, beta, eps, axis):
+    # ``symbolic_zeros`` hands the differentiable arguments over wrapped
+    return _bn_forward(x.value, gamma.value, beta.value, eps, axis)
+
+
+def _batch_norm_train_bwd(eps, axis, res, cts):
+    x, mean, inv, gamma, beta = res
+    dout, dmean, dvar = cts
+    axes, shape, n = _bn_geometry(x, axis)
+    zero = jax.custom_derivatives.SymbolicZero
+    g = jnp.zeros(x.shape, jnp.float32) if isinstance(dout, zero) \
+        else dout.astype(jnp.float32)
+    xc = x.astype(jnp.float32) - mean.reshape(shape)
+    xhat = xc * inv.reshape(shape)
+    dbeta = jnp.sum(g, axis=axes)
+    dgamma = jnp.sum(g * xhat, axis=axes)
+    dx = (gamma.astype(jnp.float32) * inv).reshape(shape) * (
+        g - (dbeta / n).reshape(shape) - xhat * (dgamma / n).reshape(shape))
+    # mean and var are outputs too.  In a training step they only feed the
+    # running statistics: their cotangents are symbolic zeros and these
+    # terms are never traced.
+    if not isinstance(dmean, zero):
+        dx = dx + (dmean.astype(jnp.float32) / n).reshape(shape)
+    if not isinstance(dvar, zero):
+        dx = dx + xc * (dvar.astype(jnp.float32) * (2.0 / n)).reshape(shape)
+    return dx.astype(x.dtype), dgamma.astype(gamma.dtype), \
+        dbeta.astype(beta.dtype)
+
+
+_batch_norm_train.defvjp(_batch_norm_train_fwd, _batch_norm_train_bwd,
+                         symbolic_zeros=True)
+
+
 def batch_norm_train(x, gamma, beta, eps=1e-5, axis=1):
     """Training-mode BN over ``axis``; returns (out, batch_mean, batch_var).
 
-    Stats accumulate in fp32 regardless of input dtype — at bf16 x b256
-    the variance reduction loses ~3 decimal digits otherwise (reference
-    BN uses fp32 accumulators, ``src/operator/nn/batch_norm.cc``).
-    Arbitrary ``axis`` is reduced natively (no transpose) so channels-last
-    layouts stay re-layout-free."""
-    axis = axis % x.ndim
-    axes = tuple(i for i in range(x.ndim) if i != axis)
-    xf = x.astype(jnp.float32)
-    mean = jnp.mean(xf, axis=axes)
-    var = jnp.var(xf, axis=axes)
-    shape = _bn_param_shape(x.ndim, axis)
-    inv = lax.rsqrt(var + eps).reshape(shape)
-    out = (xf - mean.reshape(shape)) * inv \
-        * gamma.astype(jnp.float32).reshape(shape) \
-        + beta.astype(jnp.float32).reshape(shape)
-    return out.astype(x.dtype), mean.astype(gamma.dtype), \
-        var.astype(gamma.dtype)
+    One op with its own backward (``jax.custom_vjp``), one formulation for
+    every caller.  Statistics are float32 whatever the input's dtype (at
+    bf16 x b256 the variance loses ~3 decimal digits otherwise; reference
+    BN uses fp32 accumulators, ``src/operator/nn/batch_norm.cc``), and any
+    ``axis`` is reduced in place (no transpose), so channels-last layouts
+    stay re-layout-free.
+
+    Forward: ``s1 = sum(x)``, ``s2 = sum(x*x)`` in one pass, ``mean = s1/N``,
+    ``var = max(s2/N - mean**2, 0)`` (the biased variance, as XLA's
+    BatchNormExpander and flax's ``use_fast_variance`` compute it),
+    ``out = (x - mean) * rsqrt(var + eps) * gamma + beta`` cast to
+    ``x.dtype``.  ``jnp.var`` would centre on the mean and read x again.
+
+    Backward, from the residuals (x as stored, mean, inv, gamma), with
+    ``xhat = (x - mean) * inv`` and ``g = dout`` in float32:
+    ``dbeta = sum(g)``, ``dgamma = sum(g * xhat)`` (one pass, two sums),
+    ``dx = gamma * inv * (g - dbeta/N - xhat * dgamma/N)``, plus
+    ``dmean/N + dvar * 2 * (x - mean)/N`` where ``mean`` / ``var`` carry a
+    cotangent.  It has its own backward because autodiff of the forward
+    transposes the mean *inside* the variance into one more full pass over
+    the activation that sums ``c * (x - mean)``: zero but for rounding.
+    """
+    return _batch_norm_train(x, gamma, beta, eps, axis % x.ndim)
 
 
 def batch_norm_inference(x, gamma, beta, moving_mean, moving_var, eps=1e-5,

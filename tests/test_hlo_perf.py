@@ -116,6 +116,23 @@ def test_nhwc_train_step_is_transpose_free(nhwc_lowered):
     assert res.ok, res.details
 
 
+def test_batch_norm_reads_each_activation_twice_a_direction(nhwc_lowered):
+    """Batch norm is one op with its own backward (``ops/nn.py``): per
+    layer the lowered step reduces an activation-sized operand four
+    times — ``sum(x)`` and ``sum(x*x)`` of one forward read, ``sum(dy)``
+    and ``sum(dy*xhat)`` of one backward read — and nothing reduces under
+    a ``jit(_var)`` scope (``jnp.var``'s centred second pass, whose
+    transpose summed ``c*(x - mean)``: zero but for rounding).  Beside
+    the 53 layers' sums stand the global pool's and the loss's."""
+    txt = nhwc_lowered.as_text(debug_info=True)
+    names = re.findall(r'loc\("(jit\(step\)/[^"]*)"', txt)
+    assert names and not [n for n in names if "_var" in n]
+    reduces = re.findall(r"stablehlo\.reduce\(.*?: \(tensor<([^>]*)>", txt)
+    assert len(reduces) >= 4 * 53
+    activation_sized = [t for t in reduces if t.count("x") == 4]
+    assert len(activation_sized) <= 4 * 53 + 2, len(activation_sized)
+
+
 def test_compiled_flops_match_analytic(nhwc_compiled):
     """XLA's cost model agrees with the analytic conv FLOP count: the
     compiled train step does ~3x forward conv work (fwd + dgrad + wgrad;

@@ -119,6 +119,21 @@ def test_train_step_ops_carry_the_blocks_registered_names(step_names):
     assert any("jvp(forward)/output/" in n for n in step_names)
 
 
+def test_batch_norm_sums_lie_under_their_block_in_both_phases(step_names):
+    # batch norm is a custom_vjp: its forward sums (and rsqrt) and its
+    # backward's sums still carry the block's path, which is what
+    # ``bn_device_pct.train`` matches; no wrapper's name is put between
+    def blocks(phase, prim):
+        return {m.group(1) for m in (
+            re.fullmatch(r"jit\(step\)/%s/(.*/\d+_BatchNorm)/%s"
+                         % (re.escape(phase), prim), n)
+            for n in step_names) if m}
+    fwd = blocks("jvp(forward)", "reduce_sum")
+    assert len(fwd) == 19    # 16 in the blocks' bodies, 3 downsamples
+    assert fwd == blocks("jvp(forward)", "rsqrt")
+    assert fwd == blocks("transpose(jvp(forward))", "reduce_sum")
+
+
 def test_train_step_program_keeps_its_name(lowered_step, step_names):
     # the benchmark's match rules name the program jit_step
     assert re.search(r"HloModule jit_step\b",
