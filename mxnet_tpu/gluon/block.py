@@ -20,6 +20,7 @@ equivalent of the reference's in-place aux-state update.
 """
 from __future__ import annotations
 
+import contextlib
 import re
 from collections import OrderedDict
 
@@ -36,6 +37,26 @@ from ..utils import serialization
 from .parameter import Parameter, DeferredInitializationError
 
 
+@contextlib.contextmanager
+def swapped_params(handles, arrays):
+    """Inside, each parameter handle of ``handles`` (a Parameter's
+    ``_data``) holds the array given for it — a tracer of the function
+    being traced — and on leaving what it held before.  Yields a list
+    that, once left, holds ``(index, value)`` of every handle written
+    inside (running statistics)."""
+    originals = [h._data for h in handles]
+    for h, a in zip(handles, arrays):
+        h._data = a
+    written = []
+    try:
+        yield written
+    finally:
+        for i, (h, a, orig) in enumerate(zip(handles, arrays, originals)):
+            if h._data is not a:
+                written.append((i, h._data))
+            h._data = orig
+
+
 class Block:
     """Base class for all neural network layers and models."""
 
@@ -44,6 +65,9 @@ class Block:
     #: attribute name, or ``<index>_<Type>`` for a child a container
     #: numbered — and the type's name at the root
     _scope_name = None
+
+    #: set by :meth:`recompute`
+    _recompute = False
 
     def __init__(self):
         self._children = OrderedDict()
@@ -118,6 +142,17 @@ class Block:
         activate tracing on every hybridizable descendant)."""
         for child in self._children.values():
             child.hybridize(active, **kwargs)
+
+    def recompute(self, active=True):
+        """Mark this block for recomputation: inside a traced training
+        step (``parallel.TrainStep``, a hybridized parent under
+        ``autograd.record``) its forward runs again in the backward and
+        only its inputs are kept (``jax.checkpoint`` round this block's
+        call).  Values and gradients do not change; the step holds one
+        input a marked block instead of every activation inside it.
+        Outside a trace, and in inference, the mark does nothing."""
+        self._recompute = bool(active)
+        return self
 
     def zero_grad(self):
         for p in self.collect_params().values():
@@ -304,7 +339,12 @@ class Block:
         for hook in self._forward_pre_hooks.values():
             hook(self, args)
         with jax.named_scope(self._scope_name or type(self).__name__):
-            out = self.forward(*args, **kwargs)
+            if self._recompute and _tape.is_training() and any(
+                    isinstance(a, NDArray)
+                    and isinstance(a._data, jax.core.Tracer) for a in args):
+                out = self._forward_recomputed(args, kwargs)
+            else:
+                out = self.forward(*args, **kwargs)
         for hook in self._forward_hooks.values():
             hook(self, args, out)
         if prof_t0 is not None:
@@ -315,6 +355,45 @@ class Block:
 
     def forward(self, *args, **kwargs):
         raise NotImplementedError
+
+    def _forward_recomputed(self, args, kwargs):
+        """``forward`` as a pure function of this block's parameter
+        arrays and its NDArray arguments, under ``jax.checkpoint``.  The
+        parameters' handles hold the enclosing trace's values; inside
+        the checkpointed function they hold that function's own
+        arguments (``swapped_params``), so that nothing of the block's
+        interior is closed over.  A handle written inside
+        (running statistics) comes out as an output and is written
+        back.  Random keys derive from one key drawn outside."""
+        handles = list({id(p._data): p._data
+                        for p in self.collect_params().values()
+                        if p._data is not None}.values())
+        where = [i for i, a in enumerate(args) if isinstance(a, NDArray)]
+        key = _random.new_key() if _random._STATE.trace_stack else None
+        meta = {}
+
+        def pure(arrays, key, *xs):
+            call = list(args)
+            for i, x in zip(where, xs):
+                call[i] = NDArray(x)
+            with swapped_params(handles, arrays) as written, \
+                    _random.trace_scope(key) if key is not None \
+                    else contextlib.nullcontext():
+                out = self.forward(*call, **kwargs)
+            outs, meta["tree"] = _flatten_out(out)
+            if not all(isinstance(o, NDArray) for o in outs):
+                raise TypeError("a recomputed block returns NDArrays; %s "
+                                "returned %r" % (type(self).__name__, outs))
+            meta["written"] = [i for i, _ in written]
+            return (tuple(o._data for o in outs),
+                    tuple(v for _, v in written))
+
+        outs, written = jax.checkpoint(pure)(
+            [h._data for h in handles], key,
+            *[args[i]._data for i in where])
+        for i, v in zip(meta["written"], written):
+            handles[i]._data = v
+        return _unflatten_out([NDArray(o) for o in outs], meta["tree"])
 
     def __repr__(self):
         s = "{name}(\n{modstr}\n)"
@@ -424,20 +503,10 @@ class HybridBlock(Block):
         meta = {}
 
         def jit_body(key, param_list, *xs):
-            handles = [p._data for _, p in params]
-            originals = [h._data for h in handles]
-            for h, arr in zip(handles, param_list):
-                h._data = arr
-            try:
-                with _tape.suspend_recording(), _random.trace_scope(key):
-                    out = block.forward(*[NDArray(a) for a in xs], **kwargs)
-            finally:
-                mutated = []
-                for i, (h, orig, arr) in enumerate(
-                        zip(handles, originals, param_list)):
-                    if h._data is not arr:
-                        mutated.append((i, h._data))
-                    h._data = orig
+            with swapped_params([p._data for _, p in params],
+                                param_list) as mutated, \
+                    _tape.suspend_recording(), _random.trace_scope(key):
+                out = block.forward(*[NDArray(a) for a in xs], **kwargs)
             outs, tree = _flatten_out(out)
             meta["out_tree"] = tree
             meta["n_out"] = len(outs)
@@ -544,16 +613,9 @@ class HybridBlock(Block):
         block = self
 
         def deploy_fn(param_list, *inputs):
-            handles = [params[n]._data for n in names]
-            originals = [h._data for h in handles]
-            for h, arr in zip(handles, param_list):
-                h._data = arr
-            try:
-                with _tape.suspend_recording():
-                    out = block.forward(*[NDArray(a) for a in inputs])
-            finally:
-                for h, orig in zip(handles, originals):
-                    h._data = orig
+            with swapped_params([params[n]._data for n in names],
+                                param_list), _tape.suspend_recording():
+                out = block.forward(*[NDArray(a) for a in inputs])
             outs, _ = _flatten_out(out)
             return tuple(o._data if isinstance(o, NDArray) else o
                          for o in outs)

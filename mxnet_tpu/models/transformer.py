@@ -50,11 +50,32 @@ class LlamaConfig:
     moe_num_experts: int = 0
     moe_every: int = 2
     moe_capacity_factor: float = 1.25
+    # sandwich norms: each branch's output is normed too, before the
+    # residual add (four RMSNorms a block, not two)
+    sandwich_norm: bool = False
+    # looped decoders (``models.looped.LoopedLM``): the stack of layers
+    # runs this many times over the same weights
+    passes: int = 1
 
 
 def llama3_8b_config(**over):
     cfg = LlamaConfig(vocab_size=128256, dim=4096, n_layers=32, n_heads=32,
                       n_kv_heads=8, hidden_dim=14336, rope_theta=500000.0)
+    for k, v in over.items():
+        setattr(cfg, k, v)
+    return cfg
+
+
+def ouro_2p6b_config(**over):
+    """Ouro-2.6B (ByteDance Seed, arXiv:2510.25741; ``config.json`` of
+    ``ByteDance/Ouro-2.6B``): 48 shared layers run in 4 passes
+    (``total_ut_steps``), sandwich norms, 16 heads of 128 with as many
+    K/V heads, SwiGLU 5632, untied embedding and head.  For
+    :class:`~.looped.LoopedLM`."""
+    cfg = LlamaConfig(vocab_size=49152, dim=2048, n_layers=48, n_heads=16,
+                      n_kv_heads=16, hidden_dim=5632, max_seq_len=65536,
+                      rope_theta=1000000.0, norm_eps=1e-6,
+                      sandwich_norm=True, passes=4)
     for k, v in over.items():
         setattr(cfg, k, v)
     return cfg
@@ -372,20 +393,34 @@ class TransformerBlock(HybridBlock):
                    and layer_idx % max(1, cfg.moe_every) == 0)
         self.feed_forward = MoEFeedForward(cfg) if use_moe \
             else FeedForward(cfg)
+        self._sandwich = cfg.sandwich_norm
+        if cfg.sandwich_norm:
+            self.attention_post_norm = RMSNorm(epsilon=cfg.norm_eps,
+                                               in_channels=cfg.dim)
+            self.ffn_post_norm = RMSNorm(epsilon=cfg.norm_eps,
+                                         in_channels=cfg.dim)
 
     def forward(self, x, cache=None):
-        x = x + self.attention(self.attention_norm(x), cache=cache)
-        x = x + self.feed_forward(self.ffn_norm(x))
-        return x
+        a = self.attention(self.attention_norm(x), cache=cache)
+        x = x + (self.attention_post_norm(a) if self._sandwich else a)
+        f = self.feed_forward(self.ffn_norm(x))
+        return x + (self.ffn_post_norm(f) if self._sandwich else f)
 
 
 class TransformerLM(HybridBlock):
     """Decoder-only LM.  Input: (B, T) int tokens; output: (B, T, vocab)."""
 
+    # whether ``forward`` runs the layers ``cfg.passes`` times
+    _loops = False
+
     def __init__(self, cfg: LlamaConfig = None, **kwargs):
         super().__init__()
         if cfg is None:
             cfg = LlamaConfig(**kwargs)
+        if cfg.passes != 1 and not self._loops:
+            raise ValueError("%s runs its layers once; passes=%d needs "
+                             "models.LoopedLM"
+                             % (type(self).__name__, cfg.passes))
         self.cfg = cfg
         self.tok_embeddings = Embedding(cfg.vocab_size, cfg.dim,
                                         dtype=cfg.dtype)
@@ -412,13 +447,16 @@ class TransformerLM(HybridBlock):
             ff = blk.feed_forward
             if isinstance(ff, MoEFeedForward):
                 ff.last_aux_loss = None
-        h = self.tok_embeddings(tokens)
-        h = apply_op(lambda a: _sp_constraint(a, ("dp", "sp", None)), [h],
-                     name="sp_shard")
+        h = self._embed(tokens)
         for blk in self.layers:
             h = blk(h, cache=cache)
         h = self.norm(h)
         return self.output(h)
+
+    def _embed(self, tokens):
+        h = self.tok_embeddings(tokens)
+        return apply_op(lambda a: _sp_constraint(a, ("dp", "sp", None)), [h],
+                        name="sp_shard")
 
     def num_params(self):
         total = 0

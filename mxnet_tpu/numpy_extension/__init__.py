@@ -28,6 +28,7 @@ __all__ = [
     "shape_array", "cast", "arange_like", "broadcast_like", "smooth_l1",
     "erf", "erfinv", "gamma", "gammaln", "digamma", "slice", "slice_axis",
     "slice_like", "clip_global_norm", "multi_sum_sq", "flash_attention",
+    "chunked_softmax_cross_entropy",
 ]
 
 
@@ -94,6 +95,14 @@ def log_softmax(data, axis=-1, temperature=None, dtype=None):
                                              temperature=temperature),
                    [data], name="log_softmax")
     return out.astype(dtype) if dtype is not None else out
+
+
+def chunked_softmax_cross_entropy(hidden, head, label, chunk=2048):
+    """Per-token cross-entropy of ``softmax(hidden @ head.T)`` a chunk of
+    tokens at a time (``ops.nn.chunked_softmax_cross_entropy``)."""
+    return apply_op(
+        lambda h, w, y: _nn.chunked_softmax_cross_entropy(h, w, y, chunk),
+        [hidden, head, label], name="chunked_softmax_cross_entropy")
 
 
 def masked_softmax(data, mask, axis=-1, temperature=1.0):

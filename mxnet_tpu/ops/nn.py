@@ -395,6 +395,79 @@ def log_softmax(x, axis=-1, temperature=None):
     return jax.nn.log_softmax(x, axis=axis)
 
 
+def _chunk_logits(h, head):
+    return lax.dot_general(h, head, (((1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _chunks(x, n, chunk):
+    """``x`` (N, ...) as (n, chunk, ...), zero rows appended to fill."""
+    pad = n * chunk - x.shape[0]
+    if pad:
+        x = jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1))
+    return x.reshape((n, chunk) + x.shape[1:])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _chunked_ce(hidden, head, labels, chunk):
+    return _chunked_ce_fwd(hidden, head, labels, chunk)[0]
+
+
+def _chunked_ce_fwd(hidden, head, labels, chunk):
+    N = hidden.shape[0]
+    n = -(-N // chunk)
+
+    def one(args):
+        h, y = args
+        logits = _chunk_logits(h, head)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, y[:, None], axis=1)[:, 0]
+        return lse - picked, lse
+
+    ce, lse = lax.map(one, (_chunks(hidden, n, chunk),
+                            _chunks(labels, n, chunk)))
+    return ce.reshape(-1)[:N], (hidden, head, labels, lse)
+
+
+def _chunked_ce_bwd(chunk, res, g):
+    hidden, head, labels, lse = res
+    N = hidden.shape[0]
+    n = lse.shape[0]
+
+    def one(dw, args):
+        h, y, l, gc = args
+        # the chunk's logits again: the only copy alive in the backward
+        p = jnp.exp(_chunk_logits(h, head) - l[:, None])
+        hit = lax.broadcasted_iota(jnp.int32, p.shape, 1) == y[:, None]
+        dlogits = ((p - hit.astype(p.dtype)) * gc[:, None]).astype(h.dtype)
+        dh = jnp.matmul(dlogits, head,
+                        preferred_element_type=jnp.float32).astype(h.dtype)
+        dw = dw + lax.dot_general(dlogits, h, (((0,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        return dw, dh
+
+    dw, dh = lax.scan(one, jnp.zeros(head.shape, jnp.float32),
+                      (_chunks(hidden, n, chunk), _chunks(labels, n, chunk),
+                       lse, _chunks(g.astype(jnp.float32), n, chunk)))
+    return dh.reshape(-1, hidden.shape[1])[:N], dw.astype(head.dtype), None
+
+
+_chunked_ce.defvjp(_chunked_ce_fwd, _chunked_ce_bwd)
+
+
+def chunked_softmax_cross_entropy(hidden, head, labels, chunk=2048):
+    """Per-token cross-entropy of ``softmax(hidden @ head.T)`` without the
+    whole logits: ``hidden`` (N, d), ``head`` (vocab, d), ``labels`` (N,)
+    -> (N,) float32.  The tokens go a ``chunk`` at a time; a chunk's
+    (chunk, vocab) logits are float32, live only while that chunk is
+    worked on, and are computed again in the backward (which keeps the
+    per-token logsumexp and sums the head's gradient in float32).
+    Equals ``SoftmaxCrossEntropyLoss`` on the whole logits; ``chunk`` need
+    not divide N."""
+    return _chunked_ce(hidden, head, labels.astype(jnp.int32),
+                       int(min(chunk, hidden.shape[0])))
+
+
 def masked_softmax(x, mask, axis=-1, temperature=1.0):
     if temperature != 1.0:
         x = x / temperature

@@ -32,6 +32,14 @@ from .sharding import _valid_spec, param_sharding
 P = PartitionSpec
 
 
+def _is_ndarray(x):
+    return isinstance(x, NDArray)
+
+
+def _raw(x):
+    return x._data if isinstance(x, NDArray) else x
+
+
 class TrainStep:
     """Compile ``(params, states, batch) -> (loss, params', states')``.
 
@@ -45,7 +53,19 @@ class TrainStep:
     batch_spec : PartitionSpec for each batch input (default P('dp'))
     zero1 : shard optimizer states over 'dp'
     forward_fn : optional callable(net, *batch)->scalar loss overriding the
-        default ``loss_fn(net(x), y).mean()`` convention
+        default ``loss_fn(net(x), y).mean()`` convention.  It may return
+        ``(loss, aux)`` instead, ``aux`` any pytree of arrays computed on
+        the way (per-exit losses, statistics to log): the step then
+        returns ``(loss, aux)``, the aux not differentiated.
+    remat : recompute the WHOLE forward in the backward (``jax.checkpoint``
+        round the loss).  It trades FLOPs for activation re-reads and
+        saves nothing at the peak: the recomputed forward holds every
+        activation at once (PERF.md section 6, PR 27).  To save memory mark
+        blocks instead — ``Block.recompute()`` — which this step always
+        honours, whatever ``remat`` says: a marked block keeps only its
+        input and runs again in the backward.  A network with no marked
+        block and ``remat=False`` lowers exactly as it did before marks
+        existed.
     """
 
     def __init__(self, net, loss_fn, optimizer, mesh=None, param_rules=None,
@@ -67,10 +87,9 @@ class TrainStep:
         # produce the exact TPU executable text a real slice would run,
         # which is what tools/hlo_snapshot.py pins; ``__call__`` raises.
         self.aot = aot
-        # remat=True rematerializes forward activations in the backward
-        # pass (jax.checkpoint) — trades FLOPs for HBM bandwidth on
-        # activation re-reads (PERF.md lever 3; the reference's analog is
-        # mxnet memonger / MXNET_BACKWARD_DO_MIRROR)
+        # the whole forward again in the backward (the reference's analog
+        # is MXNET_BACKWARD_DO_MIRROR); block marks are the finer switch,
+        # see the class docstring
         self.remat = remat
         self._params = list(net.collect_params().items())
         for name, p in self._params:
@@ -140,9 +159,12 @@ class TrainStep:
                 with _tape.suspend_recording(), _random.trace_scope(key):
                     _tape.set_training(True)
                     try:
+                        aux = None
                         if forward_fn is not None:
                             loss = forward_fn(net, *[NDArray(b)
                                                      for b in batch])
+                            if isinstance(loss, tuple):
+                                loss, aux = loss
                         else:
                             data = NDArray(batch[0])
                             label = NDArray(batch[1])
@@ -156,8 +178,9 @@ class TrainStep:
                     if h._data is not all_arrays[name]:
                         mutated[name] = h._data
                     h._data = orig
-            loss_arr = loss._data if isinstance(loss, NDArray) else loss
-            return loss_arr, mutated
+            loss_arr, aux = jax.tree_util.tree_map(
+                _raw, (loss, aux), is_leaf=_is_ndarray)
+            return loss_arr, (mutated, aux)
 
         def step(param_arrays, opt_states, t, lr, key, *batch):
             # the body runs once, as jit traces it: with the step's
@@ -179,13 +202,11 @@ class TrainStep:
                 # scope's ops as jvp(forward)/... and their backward as
                 # transpose(jvp(forward))/...
                 with jax.named_scope("forward"):
-                    loss_arr, mutated = run_forward({**frozen, **tr}, key,
-                                                    batch)
-                return loss_arr, mutated
+                    return run_forward({**frozen, **tr}, key, batch)
 
             if self.remat:
                 loss_of = jax.checkpoint(loss_of)
-            (loss, mutated), grads = jax.value_and_grad(
+            (loss, (mutated, aux)), grads = jax.value_and_grad(
                 loss_of, has_aux=True)(train_sub)
             new_params = dict(frozen)
             new_states = {}
@@ -233,6 +254,8 @@ class TrainStep:
             for name, val in mutated.items():
                 if name not in trainable:
                     new_params[name] = val
+            if aux is not None:
+                loss = (loss, aux)
             return loss, new_params, new_states
 
         donate = (0, 1) if self.donate else ()
@@ -298,7 +321,7 @@ class TrainStep:
         for name, p in self._params:
             p._data._data = new_params[name]
         self._states = new_states
-        return NDArray(loss)
+        return jax.tree_util.tree_map(NDArray, loss)
 
     def save_checkpoint(self, path):
         """Sharded checkpoint of the FULL training state — params,

@@ -145,6 +145,88 @@ def test_train_step_program_keeps_its_name(lowered_step, step_names):
     assert scoped >= 0.95 * len(step_names)
 
 
+@pytest.fixture(scope="module")
+def looped_names():
+    """Op names of the compiled looped training step (the passes are a
+    scan, whose body's names are whole only in the compiled text), the
+    flash kernels interpreted (``MXNET_PALLAS_INTERPRET``'s switch, set
+    for this lowering alone): 128 tokens, heads of 64."""
+    from mxnet_tpu.models import LoopedLM
+    from mxnet_tpu.ndarray.ndarray import NDArray
+    from mxnet_tpu.ops import pallas_ops
+    cfg = tiny_config(dim=128, n_heads=2, n_kv_heads=2, hidden_dim=256,
+                      n_layers=2, vocab_size=256, max_seq_len=128,
+                      sandwich_norm=True, passes=4)
+    net = LoopedLM(cfg)
+    net.initialize()
+    tok = NDArray(jnp.zeros((1, 128), jnp.int32))
+    step = parallel.TrainStep(
+        net, None, mx.optimizer.AdamW(learning_rate=1e-3), mesh=None,
+        forward_fn=lambda net, t, l: net.loss(t, l, chunk=64))
+    was = pallas_ops._INTERPRET
+    pallas_ops._INTERPRET = True
+    try:
+        text = step.lower(tok, tok).compile().as_text()
+    finally:
+        pallas_ops._INTERPRET = was
+    return set(re.findall(r'op_name="(jit\(step\)/[^"]*)"', text))
+
+
+def test_looped_step_carries_loop_exit_loss_and_block_names(looped_names):
+    fwd = "jit(step)/jvp(forward)/"
+    bwd = "jit(step)/transpose(jvp(forward))/"
+    # the passes are a scan under ``loop``; the blocks' registered names
+    # lie beneath it, forward and backward
+    for phase in (fwd, bwd):
+        assert any(n.startswith(phase + "loop/") and "/layer1/" in n
+                   and "/feed_forward/w2/" in n for n in looped_names)
+        assert any(n.startswith(phase + "exit_loss/") for n in looped_names)
+    assert any(n.startswith(fwd + "loop/") and "/attention_post_norm/" in n
+               for n in looped_names)
+    assert any(n.startswith(fwd + "loop/while/body/")
+               and n.endswith("/norm/rsqrt") for n in looped_names)
+    assert any(n.startswith(fwd + "exit_loss/exit_gate/")
+               for n in looped_names)
+    scoped = sum(n.startswith((fwd + "loop/", bwd + "loop/",
+                               fwd + "exit_loss/", bwd + "exit_loss/",
+                               fwd + "tok_embeddings/",
+                               bwd + "tok_embeddings/",
+                               "jit(step)/optimizer/"))
+                 for n in looped_names)
+    assert scoped >= 0.9 * len(looped_names)
+
+
+def test_looped_step_tells_recomputed_ops_from_first_time_ops(looped_names):
+    # jax writes checkpoint/rematted_computation into a recomputed op's
+    # path; the backward of a marked block carries checkpoint alone, its
+    # first forward neither (``recompute_device_pct.train`` reads this)
+    again = {n for n in looped_names if "/rematted_computation/" in n}
+    assert again and all("/checkpoint/rematted_computation/" in n
+                         and n.startswith("jit(step)/transpose(")
+                         for n in again)
+    first = {n for n in looped_names
+             if n.startswith("jit(step)/jvp(forward)/loop/")}
+    assert first and not any("checkpoint" in n for n in first)
+    assert any("/checkpoint/feed_forward/" in n for n in looped_names)
+
+
+@pytest.mark.parametrize("kernel,phase", [
+    ("flash_fwd", "jit(step)/jvp(forward)/loop/"),
+    ("flash_fwd", "/checkpoint/rematted_computation/attention/"),
+    ("flash_bwd_dq", "/checkpoint/attention/"),
+    ("flash_bwd_dkv", "/checkpoint/attention/")],
+    ids=["forward", "recomputed", "dq", "dkv"])
+def test_looped_step_keeps_the_flash_kernels_names(looped_names, kernel,
+                                                   phase):
+    # the kernel's ``name=`` is a component of the path under the
+    # block's ``attention`` (interpreted here, the kernel's own ops lie
+    # beneath it; on the chip it is .../<kernel>/pallas_call)
+    hits = [n for n in looped_names
+            if re.search(r"/layer\d/[^ ]*attention/%s\)*/" % kernel, n)
+            and phase in n and "/loop/while/body/" in n]
+    assert hits, (kernel, phase)
+
+
 def test_block_scope_names():
     net = gluon.nn.HybridSequential()
     net.add(gluon.nn.Dense(4, in_units=3), gluon.nn.Activation("relu"))
