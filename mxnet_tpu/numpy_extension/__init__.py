@@ -425,7 +425,7 @@ _builtins_sum = _b.sum
 
 
 def flash_attention(query, key, value, causal=False, scale=None,
-                    block_q=128, block_k=128):
+                    block_q=None, block_k=None):
     """Fused online-softmax attention over ``(B, H, S, D)`` tensors.
 
     On TPU with 128-aligned sequence and D in {64, 128, 256} this runs

@@ -10,7 +10,11 @@ reserved for attention, where manual VMEM blocking beats materializing the
 backward as Pallas kernels — the backward is recompute-based (FlashAttention
 -2 style): the forward stashes only O and the per-row logsumexp; the
 backward re-forms each (block_q × block_k) score tile in VMEM to produce
-dq/dk/dv, so training memory stays O(T) like the forward.
+dq/dk/dv, so training memory stays O(T) like the forward.  The tile is
+chosen per kernel from the shape (``_pick_tiles``: 512 × 512 where the
+row divides by it and the resident rows leave the room — a 128 × 128
+tile's fixed cost a step, not its arithmetic, held the kernels at a
+fifth of the MXU); ``block_q`` / ``block_k`` only override it.
 
 ``flash_attention_with_lse`` additionally returns the logsumexp and takes
 dynamic *global position offsets* for the causal mask — the building block
@@ -54,8 +58,10 @@ NEG_INF = float("-inf")
 #: longest rows this admits (forward and backward) for the described chip.
 _VMEM_DEFAULT = 16 << 20
 _VMEM_MAX = 100 << 20
-#: allowed on top of the resident rows for a kernel's own tiles
-_VMEM_SLACK = 4 << 20
+#: what the smallest tile (128 x 128) may take on top of the resident
+#: rows.  It bounds the longest row (``_max_row``); a larger tile is
+#: chosen only where the rows leave it room (``_pick_tiles``).
+_VMEM_TILE_MIN = 4 << 20
 
 
 def _pallas_available():
@@ -92,36 +98,129 @@ def _row_bytes(D, dtype, stats):
 
 
 def _max_row(D, dtype, stats):
-    """The longest row (tokens, a multiple of 128) a kernel takes: at
-    D=128 bf16, 98,304 in the forward and dq kernels and 87,296 in dkv —
-    so 87,296 wherever the backward runs."""
-    return (_VMEM_MAX - _VMEM_SLACK) // _row_bytes(D, dtype, stats) \
+    """The longest row (tokens, a multiple of 128) a kernel takes, at
+    the smallest tile: at D=128 bf16, 98,304 in the forward and dq
+    kernels and 87,296 in dkv — so 87,296 wherever the backward runs."""
+    return (_VMEM_MAX - _VMEM_TILE_MIN) // _row_bytes(D, dtype, stats) \
         // 128 * 128
 
 
-def _row_params(T, D, dtype, stats=False):
+def _tile_bytes(kind, bq, bk, D):
+    """VMEM a kernel's own (bq, bk) tile loop may take beside the
+    resident rows: the float32 score-sized tiles alive at once (forward:
+    s, p, p as stored and the mask; backward: s, p, dp, ds and the two
+    casts besides), the blocks the pipeline double-buffers with
+    their float32 accumulators, and 1 MiB for the compiler.  An upper
+    estimate: it only sets the kernel's ``vmem_limit_bytes``."""
+    tiles = 6 if kind == "fwd" else 8
+    return tiles * bq * bk * 4 + 8 * max(bq, bk) * D * 4 + (1 << 20)
+
+
+def _tile_target(kind, loop_row):
+    """The (block_q, block_k) a kernel would run if every row divided by
+    it and VMEM had the room, by the length of the row its loop walks —
+    what the sweep on the chip found (PERF.md section 6, PR 29; bf16,
+    head_dim 64 and 128, rows of 128 to 16,384): a row of up to 1,024
+    tokens is one tile (no loop is left), a longer one takes 512 x 512,
+    and from 8,192 tokens the backward kernels take 1,024 x 1,024."""
+    if loop_row <= 1024 or (kind != "fwd" and loop_row >= 8192):
+        return 1024, 1024
+    return 512, 512
+
+
+def _pick_tiles(kind, T, Tk, D, dtype, block_q=None, block_k=None):
+    """(block_q, block_k) of one kernel (``kind``: fwd, dq, dkv) from
+    what it can see: the largest multiples of 128 that divide the rows,
+    up to ``_tile_target``, shrunk (the larger side first) until the
+    tile loop fits the VMEM the resident rows leave.  An explicit block
+    wins and has to divide its row."""
+    stats = kind == "dkv"
+    resident = T if stats else Tk  # the row the kernel's loop walks
+    room = _VMEM_MAX - resident * _row_bytes(D, dtype, stats)
+
+    def candidates(n, explicit, target):
+        if explicit is not None:
+            explicit = min(explicit, n)
+            if n % explicit:
+                raise ValueError(
+                    "flash_attention: a block of %d does not divide a "
+                    "row of %d tokens" % (explicit, n))
+            return [explicit]
+        return [c for c in range(min(target, n), 127, -128)
+                if n % c == 0] or [n]
+
+    want_q, want_k = _tile_target(kind, resident)
+    qs = candidates(T, block_q, want_q)
+    ks = candidates(Tk, block_k, want_k)
+    while _tile_bytes(kind, qs[0], ks[0], D) > max(room, _VMEM_TILE_MIN):
+        if len(qs) > 1 and (qs[0] >= ks[0] or len(ks) == 1):
+            qs.pop(0)
+        elif len(ks) > 1:
+            ks.pop(0)
+        else:
+            break
+    return qs[0], ks[0]
+
+
+def _row_params(kind, T, D, dtype, bq, bk):
     """Compiler params for a kernel that keeps two whole (T, D) rows of
     one head in VMEM, double-buffered by the pipeline — K and V in the
-    forward and dq kernels, Q and dO (plus the lse and delta rows,
-    ``stats``) in dkv.  Resident rows are fetched once per head where a
-    grid axis would stream them once per opposite block; the price is a
-    bound on T (``_max_row``), raised here instead of left to the
-    compiler."""
+    forward and dq kernels, Q and dO (plus the lse and delta rows) in
+    dkv — beside its (bq, bk) tile loop.  Resident rows are fetched once
+    per head where a grid axis would stream them once per opposite
+    block; the price is a bound on T (``_max_row``), raised here instead
+    of left to the compiler."""
     from jax.experimental.pallas import tpu as pltpu
-    need = T * _row_bytes(D, dtype, stats) + _VMEM_SLACK
-    if need > _VMEM_MAX:
+    stats = kind == "dkv"
+    rows = T * _row_bytes(D, dtype, stats)
+    if rows + _VMEM_TILE_MIN > _VMEM_MAX:
         raise ValueError(
             "flash_attention: a %d-token row needs %d MiB of VMEM "
             "resident in the %s kernel (head_dim %d, %s) and the kernels "
             "may use %d MiB; the largest supported length is %d tokens "
             "forward and %d with the backward — split the sequence over "
             "devices (parallel.ring_attention_sharded)"
-            % (T, need >> 20, "dkv" if stats else "forward/dq", D,
+            % (T, (rows + _VMEM_TILE_MIN) >> 20,
+               "dkv" if stats else "forward/dq", D,
                jnp.dtype(dtype).name, _VMEM_MAX >> 20,
                _max_row(D, dtype, False), _max_row(D, dtype, True)))
+    need = min(rows + max(_tile_bytes(kind, bq, bk, D), _VMEM_TILE_MIN),
+               _VMEM_MAX)
     if need <= _VMEM_DEFAULT:
         return None
     return pltpu.CompilerParams(vmem_limit_bytes=need)
+
+
+def _visible(q0, k0, shape, q_axis):
+    """True where the query at position ``q0 + i`` may see the key at
+    ``k0 + j``; queries run along ``q_axis`` of ``shape``."""
+    qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    return qpos >= kpos
+
+
+def _visited(q_last, k0, bk, nk):
+    """Key tiles of ``bk`` from ``k0`` that the query at ``q_last``
+    sees any of: the tiles past them lie in the future, and are
+    skipped."""
+    return jnp.clip((q_last - k0) // bk + 1, 0, nk).astype(jnp.int32)
+
+
+def _walk(tile, carry, lower, upper, n):
+    """``tile(i, carry)`` over tiles ``[lower, upper)`` of a row of ``n``;
+    a row that is one tile takes no loop (its tile masks what it must)."""
+    if n == 1:
+        return tile(0, carry)
+    return jax.lax.fori_loop(lower, upper, tile, carry)
+
+
+_NT = (((1,), (1,)), ((), ()))   # (m, d) x (n, d) -> (m, n)
+_NN = (((1,), (0,)), ((), ()))   # (m, n) x (n, d) -> (m, d)
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
 
 
 def _per_shard(kernel, shard, in_specs, out_specs):
@@ -151,8 +250,20 @@ def _per_shard(kernel, shard, in_specs, out_specs):
 # ---------------------------------------------------------------------------
 # forward kernel: (o, lse)
 # ---------------------------------------------------------------------------
+#
+# All three kernels walk the opposite row tile by tile; causal, the tiles
+# in the future are not visited and every visited one is masked (on the
+# chip the mask hides behind the tile's other passes: a loop of its own
+# for the tiles the diagonal spares measured 3-7% slower at 2,048 and
+# 4,096 tokens, PERF.md section 6, PR 29).  Q, K, V and dO reach the MXU
+# in the dtype they are stored in and every product accumulates in
+# float32; p and ds are float32 and are cast to that dtype only as the
+# operand of the product that consumes them.  Row statistics stay
+# float32: the forward carries its running maximum and sum as (bq, 1)
+# columns, and dkv's tile is the transpose of the others' so that lse and
+# delta meet it as the (1, bq) rows they are stored as.
 
-def _fwd_call(q, k, v, q_off, k_off, causal, scale, bq=128, bk=128):
+def _fwd_call(q, k, v, q_off, k_off, causal, scale, bq=None, bk=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -162,78 +273,66 @@ def _fwd_call(q, k, v, q_off, k_off, causal, scale, bq=128, bk=128):
     # via the BlockSpec index map — the repeated K/V are never
     # materialized in HBM (4x activation saving for 32q/8kv models)
     rep = BH // k.shape[0]
-    bq = min(bq, T)
-    bk = min(bk, Tk)
-    nq = pl.cdiv(T, bq)
-    nk = pl.cdiv(Tk, bk)
+    bq, bk = _pick_tiles("fwd", T, Tk, D, k.dtype, bq, bk)
+    nq, nk = T // bq, Tk // bk
 
     def kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref):
-        qi = pl.program_id(1)
-        q_off_v = qo_ref[0]
-        k_off_v = ko_ref[0]
-        qblk = q_ref[0].astype(jnp.float32) * scale
+        q0 = qo_ref[0] + pl.program_id(1) * bq  # the block's first query
+        k0 = ko_ref[0]
+        qblk = q_ref[0]
 
-        def body(j, carry):
+        def tile(j, carry):
             acc, m_prev, l_prev = carry
-            kblk = k_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)
-            vblk = v_ref[0, pl.ds(j * bk, bk), :]
-            s = jax.lax.dot_general(
-                qblk, kblk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)  # (bq, bk)
+            at = pl.multiple_of(j * bk, bk)
+            kblk = k_ref[0, pl.ds(at, bk), :]
+            vblk = v_ref[0, pl.ds(at, bk), :]
+            s = _dot(qblk, kblk, _NT) * scale  # (bq, bk)
             if causal:
-                qpos = q_off_v + qi * bq + jax.lax.broadcasted_iota(
-                    jnp.int32, (bq, bk), 0)
-                kpos = k_off_v + j * bk + jax.lax.broadcasted_iota(
-                    jnp.int32, (bq, bk), 1)
-                s = jnp.where(qpos >= kpos, s, NEG_INF)
-            m_cur = jnp.max(s, axis=1)
-            m_new = jnp.maximum(m_prev, m_cur)
-            m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
-            p = jnp.exp(s - m_safe[:, None])
-            if causal:
-                p = jnp.where(qpos >= kpos, p, 0.0)
-            alpha = jnp.where(jnp.isneginf(m_prev), 0.0,
-                              jnp.exp(m_prev - m_safe))
-            l_new = l_prev * alpha + jnp.sum(p, axis=1)
-            acc = acc * alpha[:, None] + jax.lax.dot_general(
-                p.astype(vblk.dtype), vblk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                s = jnp.where(_visible(q0, k0 + j * bk, (bq, bk), 0), s,
+                              NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            if causal:  # a row may have seen no key yet
+                m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
+                alpha = jnp.where(jnp.isneginf(m_prev), 0.0,
+                                  jnp.exp(m_prev - m_safe))
+            else:       # m_new is finite: exp(-inf - m_new) is 0
+                m_safe = m_new
+                alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_safe)  # 0 where masked: s is -inf
+            l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc = acc * alpha + _dot(p.astype(vblk.dtype), vblk, _NN)
             return acc, m_new, l_new
 
-        acc0 = jnp.zeros((bq, D), jnp.float32)
-        m0 = jnp.full((bq,), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((bq,), jnp.float32)
-        if causal:
-            # skip key blocks strictly in this query block's future
-            qmax = q_off_v + (qi + 1) * bq - 1
-            upper = jnp.clip(
-                (qmax - k_off_v) // bk + 1, 0, nk).astype(jnp.int32)
-        else:
-            upper = nk
-        acc, m, l = jax.lax.fori_loop(0, upper, body, (acc0, m0, l0))
+        carry = (jnp.zeros((bq, D), jnp.float32),
+                 jnp.full((bq, 1), NEG_INF, jnp.float32),
+                 jnp.zeros((bq, 1), jnp.float32))
+        acc, m, l = _walk(
+            tile, carry, 0,
+            _visited(q0 + bq - 1, k0, bk, nk) if causal else nk, nk)
         l_safe = jnp.where(l == 0, 1.0, l)
-        o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = jnp.where(l == 0, NEG_INF, m + jnp.log(l_safe))
+        o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
+        lse = jnp.where(l == 0, NEG_INF, m + jnp.log(l_safe))
+        lse_ref[0, 0] = lse[:, 0]
 
-    grid = (BH, nq)
-    out, lse = pl.pallas_call(
-        kernel,
-        name="flash_fwd",
-        out_shape=(jax.ShapeDtypeStruct((BH, T, D), q.dtype),
-                   jax.ShapeDtypeStruct((BH, 1, T), jnp.float32)),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, bq, D), lambda bh, i: (bh, i, 0)),
-            pl.BlockSpec((1, Tk, D), lambda bh, i: (bh // rep, 0, 0)),
-            pl.BlockSpec((1, Tk, D), lambda bh, i: (bh // rep, 0, 0)),
-        ],
-        out_specs=(pl.BlockSpec((1, bq, D), lambda bh, i: (bh, i, 0)),
-                   pl.BlockSpec((1, 1, bq), lambda bh, i: (bh, 0, i))),
-        compiler_params=_row_params(Tk, D, k.dtype),
-        interpret=_INTERPRET,
-    )(q_off, k_off, q, k, v)
+    with jax.named_scope("tiles_q%d_k%d" % (bq, bk)):
+        out, lse = pl.pallas_call(
+            kernel,
+            name="flash_fwd",
+            out_shape=(jax.ShapeDtypeStruct((BH, T, D), q.dtype),
+                       jax.ShapeDtypeStruct((BH, 1, T), jnp.float32)),
+            grid=(BH, nq),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec((1, bq, D), lambda bh, i: (bh, i, 0)),
+                pl.BlockSpec((1, Tk, D), lambda bh, i: (bh // rep, 0, 0)),
+                pl.BlockSpec((1, Tk, D), lambda bh, i: (bh // rep, 0, 0)),
+            ],
+            out_specs=(pl.BlockSpec((1, bq, D), lambda bh, i: (bh, i, 0)),
+                       pl.BlockSpec((1, 1, bq), lambda bh, i: (bh, 0, i))),
+            compiler_params=_row_params("fwd", Tk, D, k.dtype, bq, bk),
+            interpret=_INTERPRET,
+        )(q_off, k_off, q, k, v)
     return out, lse
 
 
@@ -242,87 +341,74 @@ def _fwd_call(q, k, v, q_off, k_off, causal, scale, bq=128, bk=128):
 # ---------------------------------------------------------------------------
 
 def _bwd_dq_call(q, k, v, do, lse, delta, q_off, k_off, causal, scale,
-                 bq=128, bk=128):
+                 bq=None, bk=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     BH, T, D = q.shape
     Tk = k.shape[1]
     rep = BH // k.shape[0]  # GQA (see _fwd_call)
-    bq = min(bq, T)
-    bk = min(bk, Tk)
-    nq = pl.cdiv(T, bq)
-    nk = pl.cdiv(Tk, bk)
+    bq, bk = _pick_tiles("dq", T, Tk, D, k.dtype, bq, bk)
+    nq, nk = T // bq, Tk // bk
 
     def kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                delta_ref, dq_ref):
-        qi = pl.program_id(1)
-        q_off_v = qo_ref[0]
-        k_off_v = ko_ref[0]
-        qblk = q_ref[0].astype(jnp.float32)
-        doblk = do_ref[0].astype(jnp.float32)
+        q0 = qo_ref[0] + pl.program_id(1) * bq
+        k0 = ko_ref[0]
+        qblk = q_ref[0]
+        doblk = do_ref[0]
         lse_b = lse_ref[0, 0]       # (bq,)
-        dlt_b = delta_ref[0, 0]     # (bq,)
         # fully-masked rows have lse=-inf AND all scores -inf; substituting
         # a finite lse keeps exp(s - lse) = exp(-inf) = 0 for them (a 2-D
         # bool mask would need an i1 reshape Mosaic doesn't support)
         lse_b = jnp.where(jnp.isneginf(lse_b), 0.0, lse_b)
+        dlt_b = delta_ref[0, 0]
 
-        def body(j, acc):
-            kblk = k_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)
-            vblk = v_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)
-            s = jax.lax.dot_general(
-                qblk, kblk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
+        def tile(j, acc):
+            at = pl.multiple_of(j * bk, bk)
+            kblk = k_ref[0, pl.ds(at, bk), :]
+            vblk = v_ref[0, pl.ds(at, bk), :]
+            s = _dot(qblk, kblk, _NT) * scale  # (bq, bk)
             if causal:
-                qpos = q_off_v + qi * bq + jax.lax.broadcasted_iota(
-                    jnp.int32, (bq, bk), 0)
-                kpos = k_off_v + j * bk + jax.lax.broadcasted_iota(
-                    jnp.int32, (bq, bk), 1)
-                s = jnp.where(qpos >= kpos, s, NEG_INF)
+                s = jnp.where(_visible(q0, k0 + j * bk, (bq, bk), 0), s,
+                              NEG_INF)
+            # the rows turn into columns tile by tile: held as columns
+            # across the loop they are 64 vregs each and spill (the dq
+            # kernel ran 10% slower so, PERF.md section 6, PR 29)
             p = jnp.exp(s - lse_b[:, None])
-            dp = jax.lax.dot_general(
-                doblk, vblk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)  # (bq, bk)
-            ds = p * (dp - dlt_b[:, None]) * scale
-            return acc + jax.lax.dot_general(
-                ds, kblk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            dp = _dot(doblk, vblk, _NT)        # (bq, bk)
+            ds = p * (dp - dlt_b[:, None])  # the scale waits for the sum
+            return acc + _dot(ds.astype(kblk.dtype), kblk, _NN)
 
-        if causal:
-            qmax = q_off_v + (qi + 1) * bq - 1
-            upper = jnp.clip(
-                (qmax - k_off_v) // bk + 1, 0, nk).astype(jnp.int32)
-        else:
-            upper = nk
-        acc = jax.lax.fori_loop(0, upper, body,
-                                jnp.zeros((bq, D), jnp.float32))
-        dq_ref[0] = acc.astype(dq_ref.dtype)
+        acc = _walk(
+            tile, jnp.zeros((bq, D), jnp.float32), 0,
+            _visited(q0 + bq - 1, k0, bk, nk) if causal else nk, nk)
+        dq_ref[0] = (acc * scale).astype(dq_ref.dtype)
 
-    grid = (BH, nq)
-    return pl.pallas_call(
-        kernel,
-        name="flash_bwd_dq",
-        out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, bq, D), lambda bh, i: (bh, i, 0)),
-            pl.BlockSpec((1, Tk, D), lambda bh, i: (bh // rep, 0, 0)),
-            pl.BlockSpec((1, Tk, D), lambda bh, i: (bh // rep, 0, 0)),
-            pl.BlockSpec((1, bq, D), lambda bh, i: (bh, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda bh, i: (bh, 0, i)),
-            pl.BlockSpec((1, 1, bq), lambda bh, i: (bh, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, D), lambda bh, i: (bh, i, 0)),
-        compiler_params=_row_params(Tk, D, k.dtype),
-        interpret=_INTERPRET,
-    )(q_off, k_off, q, k, v, do, lse, delta)
+    with jax.named_scope("tiles_q%d_k%d" % (bq, bk)):
+        return pl.pallas_call(
+            kernel,
+            name="flash_bwd_dq",
+            out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
+            grid=(BH, nq),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec((1, bq, D), lambda bh, i: (bh, i, 0)),
+                pl.BlockSpec((1, Tk, D), lambda bh, i: (bh // rep, 0, 0)),
+                pl.BlockSpec((1, Tk, D), lambda bh, i: (bh // rep, 0, 0)),
+                pl.BlockSpec((1, bq, D), lambda bh, i: (bh, i, 0)),
+                pl.BlockSpec((1, 1, bq), lambda bh, i: (bh, 0, i)),
+                pl.BlockSpec((1, 1, bq), lambda bh, i: (bh, 0, i)),
+            ],
+            out_specs=pl.BlockSpec((1, bq, D), lambda bh, i: (bh, i, 0)),
+            compiler_params=_row_params("dq", Tk, D, k.dtype, bq, bk),
+            interpret=_INTERPRET,
+        )(q_off, k_off, q, k, v, do, lse, delta)
 
 
 def _bwd_dkv_call(q, k, v, do, lse, delta, q_off, k_off, causal, scale,
-                  bq=128, bk=128):
+                  bq=None, bk=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -330,58 +416,48 @@ def _bwd_dkv_call(q, k, v, do, lse, delta, q_off, k_off, causal, scale,
     Tk = k.shape[1]
     BHkv = k.shape[0]
     rep = BH // BHkv  # GQA: each kv head serves `rep` query heads
-    bq = min(bq, T)
-    bk = min(bk, Tk)
-    nq = pl.cdiv(T, bq)
-    nk = pl.cdiv(Tk, bk)
+    bq, bk = _pick_tiles("dkv", T, Tk, D, q.dtype, bq, bk)
+    nq, nk = T // bq, Tk // bk
 
     def kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                delta_ref, dk_ref, dv_ref, dk_s, dv_s):
-        kj = pl.program_id(1)
         r = pl.program_id(2)  # query-head index within the kv group
-        q_off_v = qo_ref[0]
-        k_off_v = ko_ref[0]
-        kblk = k_ref[0].astype(jnp.float32)
-        vblk = v_ref[0].astype(jnp.float32)
+        q0 = qo_ref[0]
+        k0 = ko_ref[0] + pl.program_id(1) * bk  # the block's first key
+        kblk = k_ref[0]
+        vblk = v_ref[0]
 
-        def body(i, carry):
+        # the tile is the transpose of the other kernels', keys down and
+        # queries across: lse and delta arrive as rows over the queries,
+        # and both sums are plain (bk, bq) x (bq, D) products
+        def tile(i, carry):
             dk_acc, dv_acc = carry
-            qblk = q_ref[0, pl.ds(i * bq, bq), :].astype(jnp.float32)
-            doblk = do_ref[0, pl.ds(i * bq, bq), :].astype(jnp.float32)
-            lse_b = lse_ref[0, 0, pl.ds(i * bq, bq)]
-            dlt_b = delta_ref[0, 0, pl.ds(i * bq, bq)]
-            lse_b = jnp.where(jnp.isneginf(lse_b), 0.0, lse_b)
-            s = jax.lax.dot_general(
-                qblk, kblk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # (bq, bk)
+            at = pl.multiple_of(i * bq, bq)
+            qblk = q_ref[0, pl.ds(at, bq), :]
+            doblk = do_ref[0, pl.ds(at, bq), :]
+            lse_r = lse_ref[0, :, pl.ds(at, bq)]    # (1, bq)
+            dlt_r = delta_ref[0, :, pl.ds(at, bq)]
+            # fully-masked rows: see the dq kernel
+            lse_r = jnp.where(jnp.isneginf(lse_r), 0.0, lse_r)
+            st = _dot(kblk, qblk, _NT) * scale       # (bk, bq)
             if causal:
-                qpos = q_off_v + i * bq + jax.lax.broadcasted_iota(
-                    jnp.int32, (bq, bk), 0)
-                kpos = k_off_v + kj * bk + jax.lax.broadcasted_iota(
-                    jnp.int32, (bq, bk), 1)
-                s = jnp.where(qpos >= kpos, s, NEG_INF)
-            p = jnp.exp(s - lse_b[:, None])
-            dv_acc = dv_acc + jax.lax.dot_general(
-                p, doblk, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)  # (bk, D)
-            dp = jax.lax.dot_general(
-                doblk, vblk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)  # (bq, bk)
-            ds = p * (dp - dlt_b[:, None]) * scale
-            dk_acc = dk_acc + jax.lax.dot_general(
-                ds, qblk, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)  # (bk, D)
+                st = jnp.where(_visible(q0 + i * bq, k0, (bk, bq), 1), st,
+                               NEG_INF)
+            pt = jnp.exp(st - lse_r)
+            dv_acc = dv_acc + _dot(pt.astype(doblk.dtype), doblk, _NN)
+            dpt = _dot(vblk, doblk, _NT)             # (bk, bq)
+            dst = pt * (dpt - dlt_r)  # the scale waits for the sum
+            dk_acc = dk_acc + _dot(dst.astype(qblk.dtype), qblk, _NN)
             return dk_acc, dv_acc
 
-        if causal:
-            # first query block that can see this key block
-            kmin = k_off_v + kj * bk
-            lower = jnp.clip((kmin - q_off_v) // bq, 0, nq).astype(jnp.int32)
-        else:
-            lower = 0
-        dk0 = jnp.zeros((bk, D), jnp.float32)
-        dv0 = jnp.zeros((bk, D), jnp.float32)
-        dk_acc, dv_acc = jax.lax.fori_loop(lower, nq, body, (dk0, dv0))
+        carry = (jnp.zeros((bk, D), jnp.float32),
+                 jnp.zeros((bk, D), jnp.float32))
+        # causal: from the first query tile that sees the block's first key
+        lower = jnp.clip((k0 - q0) // bq, 0, nq).astype(jnp.int32) \
+            if causal else 0
+        dk_acc, dv_acc = _walk(tile, carry, lower, nq, nq)
+        dk_acc = dk_acc * scale
+
         # accumulate the rep query heads of this kv group in fp32
         # scratch (the innermost grid dim revisits the same output
         # block), flush on the last one
@@ -400,30 +476,30 @@ def _bwd_dkv_call(q, k, v, do, lse, delta, q_off, k_off, causal, scale,
             dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
             dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
 
-    grid = (BHkv, nk, rep)
-    return pl.pallas_call(
-        kernel,
-        name="flash_bwd_dkv",
-        out_shape=(jax.ShapeDtypeStruct((BHkv, Tk, D), k.dtype),
-                   jax.ShapeDtypeStruct((BHkv, Tk, D), v.dtype)),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, T, D), lambda g, j, r: (g * rep + r, 0, 0)),
-            pl.BlockSpec((1, bk, D), lambda g, j, r: (g, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda g, j, r: (g, j, 0)),
-            pl.BlockSpec((1, T, D), lambda g, j, r: (g * rep + r, 0, 0)),
-            pl.BlockSpec((1, 1, T), lambda g, j, r: (g * rep + r, 0, 0)),
-            pl.BlockSpec((1, 1, T), lambda g, j, r: (g * rep + r, 0, 0)),
-        ],
-        out_specs=(pl.BlockSpec((1, bk, D), lambda g, j, r: (g, j, 0)),
-                   pl.BlockSpec((1, bk, D), lambda g, j, r: (g, j, 0))),
-        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, D), jnp.float32)],
-        compiler_params=_row_params(T, D, q.dtype, stats=True),
-        interpret=_INTERPRET,
-    )(q_off, k_off, q, k, v, do, lse, delta)
+    with jax.named_scope("tiles_q%d_k%d" % (bq, bk)):
+        return pl.pallas_call(
+            kernel,
+            name="flash_bwd_dkv",
+            out_shape=(jax.ShapeDtypeStruct((BHkv, Tk, D), k.dtype),
+                       jax.ShapeDtypeStruct((BHkv, Tk, D), v.dtype)),
+            grid=(BHkv, nk, rep),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec((1, T, D), lambda g, j, r: (g * rep + r, 0, 0)),
+                pl.BlockSpec((1, bk, D), lambda g, j, r: (g, j, 0)),
+                pl.BlockSpec((1, bk, D), lambda g, j, r: (g, j, 0)),
+                pl.BlockSpec((1, T, D), lambda g, j, r: (g * rep + r, 0, 0)),
+                pl.BlockSpec((1, 1, T), lambda g, j, r: (g * rep + r, 0, 0)),
+                pl.BlockSpec((1, 1, T), lambda g, j, r: (g * rep + r, 0, 0)),
+            ],
+            out_specs=(pl.BlockSpec((1, bk, D), lambda g, j, r: (g, j, 0)),
+                       pl.BlockSpec((1, bk, D), lambda g, j, r: (g, j, 0))),
+            scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
+                            pltpu.VMEM((bk, D), jnp.float32)],
+            compiler_params=_row_params("dkv", T, D, q.dtype, bq, bk),
+            interpret=_INTERPRET,
+        )(q_off, k_off, q, k, v, do, lse, delta)
 
 
 # ---------------------------------------------------------------------------
@@ -431,12 +507,12 @@ def _bwd_dkv_call(q, k, v, do, lse, delta, q_off, k_off, causal, scale,
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _flash_lse(q, k, v, q_off, k_off, causal, scale, bq=128, bk=128):
+def _flash_lse(q, k, v, q_off, k_off, causal, scale, bq=None, bk=None):
     o, lse = _flash_lse_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk)[0]
     return o, lse
 
 
-def _flash_lse_fwd(q, k, v, q_off, k_off, causal, scale, bq=128, bk=128):
+def _flash_lse_fwd(q, k, v, q_off, k_off, causal, scale, bq=None, bk=None):
     B, H, T, D = q.shape
     Hkv, Tk = k.shape[1], k.shape[2]
     o, lse = _fwd_call(q.reshape(B * H, T, D), k.reshape(B * Hkv, Tk, D),
@@ -600,8 +676,8 @@ def _chunked_with_lse(q, k, v, q_off, k_off, causal, scale, cq, ck):
 
 
 def flash_attention_with_lse(q, k, v, causal=False, scale=None,
-                             q_offset=None, k_offset=None, block_q=128,
-                             block_k=128):
+                             q_offset=None, k_offset=None, block_q=None,
+                             block_k=None):
     """Blocked attention returning (output, logsumexp) on (B, H, T, D).
 
     GQA/MQA: ``k``/``v`` may carry fewer heads (H % H_kv == 0); the
@@ -638,7 +714,7 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
 
 def flash_attention_block_bwd(q, k, v, do, lse, delta, causal=False,
                               scale=None, q_offset=None, k_offset=None,
-                              block_q=128, block_k=128):
+                              block_q=None, block_k=None):
     """(dq, dk, dv) of ONE attention block against the GLOBAL merged
     logsumexp — the ring-attention backward primitive.
 
@@ -865,8 +941,8 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, scale=None,
     return kernel(q, k_pages, v_pages, page_table, lengths)
 
 
-def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
-                    block_k=128, shard=None):
+def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
+                    block_k=None, shard=None):
     """Blocked flash attention on (B, H, T, D), Pallas forward + backward.
 
     k/v may carry fewer (grouped/multi-query) heads — see

@@ -209,6 +209,28 @@ def test_kernels_carry_their_names(topo, on_chip, fn, names):
         assert len(named) == 1, name
 
 
+@pytest.mark.parametrize("fn,kinds", [
+    (_flash, {"fwd": "flash_fwd"}),
+    (_flash_grad, {"fwd": "flash_fwd", "dq": "flash_bwd_dq",
+                   "dkv": "flash_bwd_dkv"})], ids=["forward", "grad"])
+def test_flash_compiles_at_the_token_cells_shape(topo, on_chip, fn, kinds):
+    """``ouro_2p6b_train_2x4096``'s attention, (2, 16, 4096, 128) bf16,
+    with the tiles the picker gives it: each kernel once, under its old
+    name, the tile it runs as the scope component right above it."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    row = jax.ShapeDtypeStruct((2, 16, 4096, 128), jnp.bfloat16,
+                               sharding=one_chip)
+    lines = _kernel_lines(fn, row, row, row)
+    assert len(lines) == len(kinds)
+    for kind, name in kinds.items():
+        bq, bk = pallas_ops._pick_tiles(kind, 4096, 4096, 128, jnp.bfloat16)
+        assert (bq, bk) != (128, 128)
+        named = [ln for ln in lines if re.search(
+            r'op_name="[^"]*\btiles_q%d_k%d\)*/%s/pallas_call"'
+            % (bq, bk, name), ln)]
+        assert len(named) == 1, (name, bq, bk)
+
+
 def test_looped_step_compiles_with_flash_under_block_recompute(topo,
                                                                on_chip):
     """The looped decoder's training step at the published widths, two

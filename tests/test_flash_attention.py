@@ -54,6 +54,148 @@ def test_flash_backward_matches_dense(interpret_kernels, causal):
                             atol=2e-3)
 
 
+# ---------------------------------------------------------------------------
+# the tile loop: every tile the kernels may run, and the picker's own
+# ---------------------------------------------------------------------------
+
+_TILE_CASES = [(128, 128), (256, 512), (512, 256), (512, 512), None]
+# (T, Tk, q_offset, k_offset): a square row whose tiles are fully
+# visible, on the diagonal and skipped; and a block as the ring hands it
+# over, T != Tk, the diagonal off every tile's corner, keys in the future
+_ROW_CASES = {"square": (1024, 1024, 0, 0), "offset": (512, 1024, 400, 130)}
+# relative distance (Frobenius) of the PARENT's 128 x 128 kernels' bf16
+# results from the float32 dense reference over these cases, read before
+# the tile loop changed: one bf16 rounding, the result's own
+_PARENT_BF16_GAP = {"o": 2.25e-3, "dq": 2.27e-3, "dk": 2.17e-3,
+                    "dv": 2.16e-3}
+# o and dv keep that; dq and dk are now sums over ds rounded to bf16, a
+# second independent rounding of the same size (measured: 2.82e-3, 2.36e-3)
+_BF16_ROOM = {"o": 1.05, "dv": 1.05, "dq": 2 ** 0.5, "dk": 2 ** 0.5}
+
+_tile_runs = {}
+
+
+def _tile_run(tiles, rows, rep, causal, dtype):
+    """(o, lse, dq, dk, dv) of the kernels and of the float32 dense form
+    for one case; the forward and the backward test share the run."""
+    key = (tiles, rows, rep, causal, dtype)
+    if key in _tile_runs:
+        return _tile_runs[key]
+    T, Tk, q_off, k_off = _ROW_CASES[rows]
+    H, D = 4 if rep == 4 else 2, 64
+    q = _rand((1, H, T, D), 40).astype(dtype)
+    k, v = (_rand((1, H // rep, Tk, D), s).astype(dtype) for s in (41, 42))
+    w = jnp.cos(jnp.arange(D, dtype=jnp.float32))
+    blocks = {} if tiles is None else {"block_q": tiles[0],
+                                       "block_k": tiles[1]}
+
+    def flash(q, k, v):
+        o, lse = pallas_ops.flash_attention_with_lse(
+            q, k, v, causal=causal, q_offset=q_off, k_offset=k_off,
+            **blocks)
+        return (o.astype(jnp.float32) * w).sum() + 0.7 * lse.sum(), (o, lse)
+
+    def dense(q, k, v):
+        o, lse = pallas_ops._dense_with_lse(
+            q, k, v, jnp.asarray([q_off], jnp.int32),
+            jnp.asarray([k_off], jnp.int32), causal, D ** -0.5)
+        return (o * w).sum() + 0.7 * lse.sum(), (o, lse)
+
+    def run(fn, *args):
+        (_, (o, lse)), g = jax.jit(jax.value_and_grad(
+            fn, (0, 1, 2), has_aux=True))(*args)
+        return [onp.asarray(a, dtype=onp.float32) for a in (o, lse) + g]
+
+    got = run(flash, q, k, v)
+    assert got[0].dtype == onp.float32 and got[2].shape == q.shape
+    want = run(dense, *(a.astype(jnp.float32) for a in (q, k, v)))
+    _tile_runs[key] = dict(zip(("o", "lse", "dq", "dk", "dv"),
+                               zip(got, want)))
+    return _tile_runs[key]
+
+
+def _check_tile_case(run, names, dtype):
+    for name in names:
+        got, want = run[name]
+        if dtype == "float32" or name == "lse":
+            assert_almost_equal(got, want, rtol=2e-5, atol=2e-5)
+        else:
+            gap = onp.linalg.norm(got - want) / onp.linalg.norm(want)
+            assert gap <= _PARENT_BF16_GAP[name] * _BF16_ROOM[name], \
+                (name, gap)
+
+
+_tile_params = [
+    pytest.mark.parametrize("dtype", ["float32", "bfloat16"]),
+    pytest.mark.parametrize("causal", [True, False],
+                            ids=["causal", "full"]),
+    pytest.mark.parametrize("rep", [1, 4], ids=["mha", "gqa4"]),
+    pytest.mark.parametrize("rows", list(_ROW_CASES)),
+    pytest.mark.parametrize(
+        "tiles", _TILE_CASES,
+        ids=["picked" if t is None else "%dx%d" % t for t in _TILE_CASES]),
+]
+
+
+def _tile_cases(fn):
+    for mark in _tile_params:
+        fn = mark(fn)
+    return fn
+
+
+@_tile_cases
+def test_flash_tiles_forward_matches_dense(interpret_kernels, tiles, rows,
+                                           rep, causal, dtype):
+    _check_tile_case(_tile_run(tiles, rows, rep, causal, dtype),
+                     ("o", "lse"), dtype)
+
+
+@_tile_cases
+def test_flash_tiles_backward_matches_dense(interpret_kernels, tiles, rows,
+                                            rep, causal, dtype):
+    _check_tile_case(_tile_run(tiles, rows, rep, causal, dtype),
+                     ("dq", "dk", "dv"), dtype)
+
+
+@pytest.mark.parametrize("kind", ["fwd", "dq", "dkv"])
+def test_picker_chooses_from_the_shape(kind):
+    pick = pallas_ops._pick_tiles
+    bf16 = jnp.bfloat16
+    for T, Tk, D in [(1024, 1024, 128), (4096, 4096, 128), (1152, 4096, 64),
+                     (16384, 16384, 128), (2048, 640, 256),
+                     (87296, 87296, 128)]:
+        bq, bk = pick(kind, T, Tk, D, bf16)
+        assert bq % 128 == 0 and bk % 128 == 0 and T % bq == 0 \
+            and Tk % bk == 0 and max(bq, bk) <= 1024, (T, Tk, D, bq, bk)
+    # what the chip measured: a row of up to 1,024 tokens (the serving
+    # prefill's ladder, BERT's rows) is one tile; 512 x 512 past that;
+    # the backward kernels 1,024 x 1,024 from 8,192 tokens
+    for T in (128, 256, 384, 512, 1024):
+        assert pick(kind, T, T, 128, bf16) == (T, T)
+    # the row a kernel's loop walks decides: K/V's, in dkv the queries'
+    assert pick(kind, 128, 4096, 128, bf16) == \
+        ((128, 1024) if kind == "dkv" else (128, 512))
+    assert pick(kind, 4096, 4096, 128, bf16) == (512, 512)
+    assert pick(kind, 16384, 16384, 128, bf16) == \
+        ((512, 512) if kind == "fwd" else (1024, 1024))
+    # a long row leaves the tile what _max_row kept for the smallest
+    longest = pallas_ops._max_row(128, bf16, kind == "dkv")
+    bq, bk = pick(kind, longest, longest, 128, bf16)
+    assert pallas_ops._tile_bytes(kind, bq, bk, 128) \
+        <= pallas_ops._VMEM_TILE_MIN
+    # an explicit block wins, one side or both, and has to divide its row
+    assert pick(kind, 4096, 4096, 128, bf16, 256, 128) == (256, 128)
+    assert pick(kind, 4096, 4096, 128, bf16, None, 256)[1] == 256
+    assert pick(kind, 128, 128, 64, bf16, 512, 512) == (128, 128)
+    with pytest.raises(ValueError, match="does not divide"):
+        pick(kind, 4096, 4096, 128, bf16, 384, None)
+
+
+def test_longest_rows_unchanged_by_the_tile_budget():
+    assert pallas_ops._max_row(128, jnp.bfloat16, False) == 98304
+    assert pallas_ops._max_row(128, jnp.bfloat16, True) == 87296
+
+
 def test_flash_with_lse_offsets_and_lse_grad(interpret_kernels):
     """Offset-aware causal masking and the lse cotangent path — exactly
     what ring attention needs per step."""

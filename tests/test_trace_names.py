@@ -219,10 +219,12 @@ def test_looped_step_tells_recomputed_ops_from_first_time_ops(looped_names):
 def test_looped_step_keeps_the_flash_kernels_names(looped_names, kernel,
                                                    phase):
     # the kernel's ``name=`` is a component of the path under the
-    # block's ``attention`` (interpreted here, the kernel's own ops lie
-    # beneath it; on the chip it is .../<kernel>/pallas_call)
+    # block's ``attention``, the tile it runs the one right above it
+    # (interpreted here, the kernel's own ops lie beneath it; on the
+    # chip it is .../tiles_q<bq>_k<bk>/<kernel>/pallas_call)
     hits = [n for n in looped_names
-            if re.search(r"/layer\d/[^ ]*attention/%s\)*/" % kernel, n)
+            if re.search(r"/layer\d/[^ ]*attention/tiles_q\d+_k\d+/%s\)*/"
+                         % kernel, n)
             and phase in n and "/loop/while/body/" in n]
     assert hits, (kernel, phase)
 

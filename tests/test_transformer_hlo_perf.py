@@ -20,6 +20,7 @@ What determines transformer TPU throughput, asserted on the artifact:
 import re
 
 import numpy as onp
+import pytest
 
 import jax
 import jax.numpy as jnp
@@ -148,3 +149,87 @@ def test_lm_train_step_donates_buffers():
     assert ma.alias_size_in_bytes >= 3 * param_bytes, \
         "aliased %.1f MB < 3x param bytes %.1f MB" % (
             ma.alias_size_in_bytes / 1e6, 3 * param_bytes / 1e6)
+
+
+# ---------------------------------------------------------------------------
+# the flash kernels' tile loop, read from the kernels' own jaxprs
+# ---------------------------------------------------------------------------
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for j in (v if isinstance(v, (list, tuple)) else (v,)):
+            j = getattr(j, "jaxpr", j)
+            if hasattr(j, "eqns"):
+                yield j
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of what it holds (loops, branches)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in _sub_jaxprs(eqn):
+            yield from _eqns(sub)
+
+
+def _flash_kernel_jaxpr(kind, causal=True):
+    """The kernel body of one flash kernel at 2 head-rows of 1,024 x 128
+    bf16 with 256 x 256 tiles, and its (block, D) operand shapes."""
+    from mxnet_tpu.ops import pallas_ops
+    BH, T, D, blk = 2, 1024, 128, 256
+    row = jax.ShapeDtypeStruct((BH, T, D), jnp.bfloat16)
+    stat = jax.ShapeDtypeStruct((BH, 1, T), jnp.float32)
+    off = jax.ShapeDtypeStruct((1,), jnp.int32)
+    if kind == "fwd":
+        def call(q, k, v, qo, ko):
+            return pallas_ops._fwd_call(q, k, v, qo, ko, causal, 0.1,
+                                        bq=blk, bk=blk)
+        outer = jax.make_jaxpr(call)(row, row, row, off, off)
+    else:
+        fn = pallas_ops._bwd_dq_call if kind == "dq" \
+            else pallas_ops._bwd_dkv_call
+
+        def call(q, k, v, do, lse, delta, qo, ko):
+            return fn(q, k, v, do, lse, delta, qo, ko, causal, 0.1,
+                      bq=blk, bk=blk)
+        outer = jax.make_jaxpr(call)(row, row, row, row, stat, stat, off,
+                                     off)
+    (pallas,) = [e for e in _eqns(outer.jaxpr)
+                 if e.primitive.name == "pallas_call"]
+    return pallas.params["jaxpr"], (blk, D)
+
+
+@pytest.mark.parametrize("kind,products", [("fwd", 2), ("dq", 3),
+                                           ("dkv", 4)])
+def test_flash_kernel_feeds_the_mxu_as_stored(kind, products):
+    """bf16 in: every product of every tile takes bf16 operands and
+    yields float32, and no (block, D) operand tile is widened first."""
+    kernel, tile = _flash_kernel_jaxpr(kind)
+    dots = [e for e in _eqns(kernel) if e.primitive.name == "dot_general"]
+    assert len(dots) == products
+    for e in dots:
+        assert [v.aval.dtype for v in e.invars] == [jnp.bfloat16] * 2, e
+        assert e.outvars[0].aval.dtype == jnp.float32, e
+    widened = [e for e in _eqns(kernel)
+               if e.primitive.name == "convert_element_type"
+               and e.params["new_dtype"] == jnp.float32
+               and e.invars[0].aval.shape == tile]
+    assert not widened, widened
+
+
+@pytest.mark.parametrize("kind", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_flash_kernel_has_one_tile_loop(kind, causal):
+    """One loop over the opposite row with every product in its body;
+    the mask (iota, compare, a select over the whole score tile) is
+    built there when causal and not at all when not."""
+    kernel, (blk, _) = _flash_kernel_jaxpr(kind, causal)
+    # a fori_loop is a while where its bounds are traced, a scan where not
+    (body,) = [list(_eqns(e.params["body_jaxpr" if e.primitive.name == "while"
+                                   else "jaxpr"].jaxpr))
+               for e in _eqns(kernel) if e.primitive.name in ("while", "scan")]
+    names = {x.primitive.name for x in body}
+    assert "dot_general" in names and "exp" in names
+    on_tile = {x.primitive.name for x in body
+               if x.primitive.name in ("select_n", "ge", "gt", "lt", "le")
+               and x.outvars[0].aval.shape == (blk, blk)}
+    assert bool(on_tile) == causal and ("iota" in names) == causal, names
