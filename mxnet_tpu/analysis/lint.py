@@ -327,7 +327,7 @@ def _is_os_commit_call(ctx, call):
 @rule("R2", "atomic-artifact-write",
       "files are written via serialization.atomic_write (or an explicit "
       "os.replace commit point) so a crash never leaves a torn artifact",
-      scope=("mxnet_tpu/", "tools/", "bench.py", "examples/"),
+      scope=("mxnet_tpu/", "tools/", "examples/"),
       exclude=("mxnet_tpu/utils/serialization.py",))
 def _check_r2(ctx):
     for c in _calls(ctx.tree):
@@ -389,7 +389,7 @@ def _mutating_context(ctx, call):
       "retry wrappers reachable by mutating ops pass entry_only_policy() "
       "(a mid-op retry double-applies the mutation) and never a "
       "per-attempt timeout (an abandoned attempt thread races its retry)",
-      scope=("mxnet_tpu/", "tools/", "bench.py"),
+      scope=("mxnet_tpu/", "tools/"),
       exclude=("mxnet_tpu/fault.py",))
 def _check_r3(ctx):
     for c in _calls(ctx.tree):
@@ -717,7 +717,7 @@ def _r8_root_key(call, tail):
       "service carry distinct namespaces — implicit construction-order "
       "namespaces cross-consume rounds when any rank orders its "
       "constructions differently (the PR-5 heartbeat-vs-kvstore bug)",
-      scope=("mxnet_tpu/", "tools/", "bench.py", "examples/"),
+      scope=("mxnet_tpu/", "tools/", "examples/"),
       exclude=("mxnet_tpu/analysis/",))
 def _check_r8(ctx):
     groups = {}
@@ -830,7 +830,7 @@ def lint_source(text, relpath, rules=None):
 
 
 #: What a bare ``mxlint`` run scans, relative to the repo root.
-DEFAULT_TARGETS = ("mxnet_tpu", "tools", "tests", "bench.py", "examples")
+DEFAULT_TARGETS = ("mxnet_tpu", "tools", "tests", "examples")
 _SKIP_DIRS = {"__pycache__", "_native", ".git"}
 
 

@@ -677,8 +677,8 @@ def _oracle_lease_amortized(variant, sim):
     """The perf property as a protocol invariant: on a fault-free,
     fully-clean schedule the success path pays EXACTLY one comm round
     per step (the piggybacked beat) and ZERO rounds on the op comm —
-    a per-op vote sneaking back in is a regression the bench would
-    show but this catches structurally."""
+    a per-op vote sneaking back in is a regression this catches
+    structurally."""
     if sim.faults_used:
         return None  # injected crash/hangs legitimately change rounds
     if any(rs.status != "done" or rs.error is not None

@@ -631,7 +631,7 @@ def _coord_client():
 
 _default_comm = None
 # the ambient comm and the shared generation are resolved lazily from
-# whichever thread first needs them (heartbeat, poller, bench worker
+# whichever thread first needs them (heartbeat, poller, worker
 # threads all can) — without the lock two first-callers could install
 # two different singletons and split the job's vote rounds / recovery
 # epochs between them (mxrace R9)
@@ -837,7 +837,7 @@ def coordinated_call(fn, comm=None, op=None, policy=None, mutating=False,
     ``lease`` opts the op into step-granularity consensus: ``True``
     rides the process-wide :class:`StepLease` (when one is ACTIVE —
     see :func:`enable_step_lease`), a :class:`StepLease` instance rides
-    that lease (tests, bench), ``None``/``False`` always votes per-op.
+    that lease (tests), ``None``/``False`` always votes per-op.
     Under an active lease the success path pays ZERO vote rounds (the
     aggregate vote piggybacks on the step-boundary heartbeat) and ANY
     local failure revokes the lease and aborts the step on every worker

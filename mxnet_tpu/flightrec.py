@@ -17,8 +17,8 @@ Design rules (the StepLease/telemetry shape, mxrace-clean):
   values, guarded by ONE reentrant ``_lock``; ring slots are integer
   keys of that same dict, so the race analyzer sees a single named
   shared variable.  ``record()`` is three dict operations under an
-  uncontended lock — sub-microsecond (``bench.py flightrec_overhead``
-  measures it).
+  uncontended lock (``tests/test_flightrec.py`` holds a loose ceiling
+  on it).
 - ``record()`` never calls out (no profiler, no providers, no I/O)
   while holding ``_lock``; ``dump()`` snapshots under the lock and
   serializes/writes OUTSIDE it, like the profiler's trace writer.

@@ -241,9 +241,9 @@ def schedule_info(schedule, n, num_microbatches, virtual_stages=1,
                   with_backward=True):
     """Analytic schedule statistics (slots, bubble fraction, stash
     depths, peak in-flight microbatches) for a pipeline of ``n`` devices
-    × ``virtual_stages`` running ``num_microbatches`` — the numbers
-    ``bench.py``'s ``pipeline_bubble`` phase records and the 1F1B memory
-    claim is asserted against."""
+    × ``virtual_stages`` running ``num_microbatches`` — the numbers the
+    1F1B memory claim is asserted against
+    (``tests/test_parallel.py``)."""
     sim = _simulate(schedule, n, num_microbatches, virtual_stages,
                     with_backward)
     return {k: sim[k] for k in ("slots", "act_buf", "cot_buf",

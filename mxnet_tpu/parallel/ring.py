@@ -145,7 +145,7 @@ def _mask_offsets(layout, my, owner, T, Tk):
 
 def causal_balance(layout, inner, outer=1, block_tokens=128):
     """Analytic causal work balance of one full ring pass (host-side;
-    bench/test evidence).  Work per (rank, step) is the number of
+    ``tests/test_parallel.py`` holds it).  Work per (rank, step) is the number of
     unmasked score entries of that block in the given layout.  Returns
     per-step ``max/mean`` across ranks and the overall critical-path
     factor (sum of per-step maxima vs a perfectly balanced ring, 1.0 =
@@ -478,8 +478,8 @@ def ring_attention_local(q, k, v, axis_name, causal=False, scale=None,
     instead of autodiff stashing all n rotated blocks (the full
     sequence's K/V on every rank).
     ``double_buffer=False`` keeps the original two-collective autodiff
-    formulation for A/B measurement (``bench.py --only attention_ring``);
-    it exists for the flat ring only.
+    formulation, the reference ``tests/test_parallel.py`` holds the
+    fused form to; it exists for the flat ring only.
 
     ``layout`` names the token layout the causal mask assumes —
     "striped" expects the sequence axis already in striped order

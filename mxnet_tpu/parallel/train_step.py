@@ -24,6 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from .. import _tape
 from .. import fault as _fault
 from .. import profiler as _profiler
+from ..gluon.block import swapped_params
 from ..ndarray.ndarray import NDArray
 from ..numpy import random as _random
 from .mesh import mesh_scope
@@ -57,20 +58,14 @@ class TrainStep:
         ``(loss, aux)`` instead, ``aux`` any pytree of arrays computed on
         the way (per-exit losses, statistics to log): the step then
         returns ``(loss, aux)``, the aux not differentiated.
-    remat : recompute the WHOLE forward in the backward (``jax.checkpoint``
-        round the loss).  It trades FLOPs for activation re-reads and
-        saves nothing at the peak: the recomputed forward holds every
-        activation at once (PERF.md section 6, PR 27).  To save memory mark
-        blocks instead — ``Block.recompute()`` — which this step always
-        honours, whatever ``remat`` says: a marked block keeps only its
-        input and runs again in the backward.  A network with no marked
-        block and ``remat=False`` lowers exactly as it did before marks
-        existed.
+
+    A block marked with ``Block.recompute()`` keeps only its input and
+    runs again in the backward.  Parameters and optimizer states are
+    donated to the step and updated in place.
     """
 
     def __init__(self, net, loss_fn, optimizer, mesh=None, param_rules=None,
-                 batch_spec=None, zero1=False, forward_fn=None, donate=True,
-                 remat=False, aot=False):
+                 batch_spec=None, zero1=False, forward_fn=None, aot=False):
         self.net = net
         self.loss_fn = loss_fn
         self.optimizer = optimizer
@@ -78,7 +73,6 @@ class TrainStep:
         self.param_rules = param_rules
         self.zero1 = zero1
         self.forward_fn = forward_fn
-        self.donate = donate
         # aot=True: ``mesh`` may be built from a PJRT *topology
         # description* (jax.experimental.topologies) instead of live
         # devices — params/states are never placed on the mesh, only
@@ -87,10 +81,6 @@ class TrainStep:
         # produce the exact TPU executable text a real slice would run,
         # which is what tools/hlo_snapshot.py pins; ``__call__`` raises.
         self.aot = aot
-        # the whole forward again in the backward (the reference's analog
-        # is MXNET_BACKWARD_DO_MIRROR); block marks are the finer switch,
-        # see the class docstring
-        self.remat = remat
         self._params = list(net.collect_params().items())
         for name, p in self._params:
             if p._data is None:
@@ -151,33 +141,30 @@ class TrainStep:
         name_to_idx = {name: i for i, (name, _) in enumerate(params)}
 
         def run_forward(all_arrays, key, batch):
-            handles = [p._data for _, p in params]
-            originals = [h._data for h in handles]
-            for h, (name, _) in zip(handles, params):
-                h._data = all_arrays[name]
-            try:
-                with _tape.suspend_recording(), _random.trace_scope(key):
-                    _tape.set_training(True)
-                    try:
-                        aux = None
-                        if forward_fn is not None:
-                            loss = forward_fn(net, *[NDArray(b)
-                                                     for b in batch])
-                            if isinstance(loss, tuple):
-                                loss, aux = loss
-                        else:
-                            data = NDArray(batch[0])
-                            label = NDArray(batch[1])
-                            out = net.forward(data)
-                            loss = loss_fn(out, label).mean()
-                    finally:
-                        _tape.set_training(False)
-            finally:
-                mutated = {}
-                for h, orig, (name, _) in zip(handles, originals, params):
-                    if h._data is not all_arrays[name]:
-                        mutated[name] = h._data
-                    h._data = orig
+            with swapped_params(
+                    [p._data for _, p in params],
+                    [all_arrays[name] for name, _ in params]) as written, \
+                    _tape.suspend_recording(), _random.trace_scope(key):
+                _tape.set_training(True)
+                try:
+                    aux = None
+                    if forward_fn is not None:
+                        loss = forward_fn(net, *[NDArray(b)
+                                                 for b in batch])
+                        if isinstance(loss, tuple):
+                            loss, aux = loss
+                    else:
+                        data = NDArray(batch[0])
+                        label = NDArray(batch[1])
+                        # forward, not __call__: a hybridized net's
+                        # cached program is not the step's; the root's
+                        # own recompute mark is honoured here instead
+                        out = net._forward_recomputed((data,), {}) \
+                            if net._recompute else net.forward(data)
+                        loss = loss_fn(out, label).mean()
+                finally:
+                    _tape.set_training(False)
+            mutated = {params[i][0]: v for i, v in written}
             loss_arr, aux = jax.tree_util.tree_map(
                 _raw, (loss, aux), is_leaf=_is_ndarray)
             return loss_arr, (mutated, aux)
@@ -204,8 +191,6 @@ class TrainStep:
                 with jax.named_scope("forward"):
                     return run_forward({**frozen, **tr}, key, batch)
 
-            if self.remat:
-                loss_of = jax.checkpoint(loss_of)
             (loss, (mutated, aux)), grads = jax.value_and_grad(
                 loss_of, has_aux=True)(train_sub)
             new_params = dict(frozen)
@@ -258,7 +243,6 @@ class TrainStep:
                 loss = (loss, aux)
             return loss, new_params, new_states
 
-        donate = (0, 1) if self.donate else ()
         in_shardings = None
         out_shardings = None
         if self.mesh is not None:
@@ -281,7 +265,7 @@ class TrainStep:
                 {n: sh(pspec[n]) for n, _ in params},
                 {n: tuple(sh(s) for s in st_spec[n]) for n in self._states},
             )
-        return jax.jit(step, donate_argnums=donate,
+        return jax.jit(step, donate_argnums=(0, 1),
                        in_shardings=in_shardings,
                        out_shardings=out_shardings)
 

@@ -40,8 +40,8 @@ attribute read + falsy branch.
 
 The recorder is thread-safe: every ``_state`` touch happens under the
 reentrant ``_rec_lock`` — ``fault::*`` counters are bumped concurrently
-from the step loop, the heartbeat, the maintenance poller, signal
-handlers, and bench worker threads, and the counter update is a
+from the step loop, the heartbeat, the maintenance poller and signal
+handlers, and the counter update is a
 read-modify-write that silently lost updates before the lock (found by
 ``tools/mxrace.py``; confirmed by its vector-clock harness).
 
@@ -88,10 +88,10 @@ _state = {
 
 # One recorder lock for every ``_state`` touch.  The host plane is fed
 # from genuinely concurrent threads — ``fault::*`` counters bump from
-# the step heartbeat, the maintenance poller, signal handlers, and
-# bench worker threads at once — and the counter path is a
-# read-modify-write, so the unlocked recorder lost updates (mxrace R9's
-# first real catch; tests/test_mxrace.py holds the regression).
+# the step heartbeat, the maintenance poller and signal handlers at
+# once — and the counter path is a read-modify-write, so the unlocked
+# recorder lost updates (mxrace R9's first real catch;
+# tests/test_mxrace.py holds the regression).
 # Reentrant because _append -> _write_trace (continuous_dump) and
 # dump -> set_state re-enter on the same thread.
 _rec_lock = threading.RLock()

@@ -32,7 +32,7 @@ discipline the training side already has:
    everything sheds, and ``low`` sheds early once the worst replica
    p99 breaches ``slo_target_ms``.  Rejected submits raise a typed
    :class:`~mxnet_tpu.serve.OverloadedError` instead of queueing
-   without bound (the bench A/B: bounded admitted-p99 vs collapse).
+   without bound.
 
 Knobs (environment, all optional)::
 

@@ -1,7 +1,7 @@
 """Where jax's persistent compilation cache lives.
 
 One rule for every entry point that compiles for the device
-(``chip_smoke.py``, ``bench.py``'s phase children, ``mx.serve``'s warm
+(``chip_smoke.py``, ``benchmark/chip/run.py``, ``mx.serve``'s warm
 pool): ``JAX_COMPILATION_CACHE_DIR``, when set, is the cache, and jax
 reads the variable itself — nothing in the program sets a directory
 over it.  When it is not set, the cache is ``<checkout>/.jax_cache``.

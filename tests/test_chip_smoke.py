@@ -193,8 +193,8 @@ def test_compile_cache_is_the_checkout_s_when_not_placed(monkeypatch):
 
 
 def test_importing_the_framework_initialises_no_backend():
-    """bench.py's parent and the launcher stay off the chip their
-    children need: ``import mxnet_tpu`` must not touch a backend."""
+    """The launcher stays off the chip its children need: ``import
+    mxnet_tpu`` must not touch a backend."""
     code = ("import mxnet_tpu\n"
             "from jax._src import xla_bridge\n"
             "assert not xla_bridge._backends, xla_bridge._backends\n")

@@ -8,9 +8,9 @@ before any backend init and provides the 8-device virtual mesh the
 multi-chip examples expect.
 
 ``train_resnet_spmd.py`` is exercised indirectly instead (its TrainStep-
-on-mesh path is tests/test_parallel.py and its model is the bench): a
-batch-256 ResNet-50 compile is minutes of XLA CPU time the suite cannot
-afford per run.
+on-mesh path is tests/test_parallel.py and its model is the benchmark's
+ResNet cell): a batch-256 ResNet-50 compile is minutes of XLA CPU time
+the suite cannot afford per run.
 """
 import os
 import subprocess

@@ -11,7 +11,7 @@ never a speed claim):
 1. Layout: the NHWC ResNet-50 program hands XLA every convolution already
    in the TPU-native ``[b,0,1,f]x[o,0,1,i]->[b,0,1,f]`` form with ZERO
    rank-4 transposes — TPU layout assignment is the identity, so no
-   transpose kernels can appear on-chip (PERF.md lever 1, f42f8e3).
+   transpose kernels can appear on-chip.
 2. FLOPs: XLA's own ``cost_analysis()`` of the compiled forward matches
    the analytic hardware-FLOP count of ResNet-50 (8.18 GFLOP/img conv
    FLOPs = 4.089 GMACs x 2; He et al.'s "3.8-4.1 GFLOPs" counts
@@ -19,16 +19,12 @@ never a speed claim):
    fused train step costs ~3x forward — i.e. the program does the work the
    roofline assumes, no more (a 2x flop inflation would halve MFU; this
    pins it).
-3. Remat: ``jax.checkpoint`` strictly lowers XLA's temp-buffer estimate
-   (the activation stash) while raising FLOPs — the advertised
-   bandwidth<->compute trade is real in the compiled artifact, not just
-   in the flag (reference analog MXNET_BACKWARD_DO_MIRROR,
-   ``docs/.../env_var.md``).
+3. Recomputation: a block marked with ``Block.recompute()`` runs again
+   in the backward behind an optimization barrier and FLOPs rise — the
+   bandwidth<->compute trade is in the program, not just in the mark
+   (reference analog MXNET_BACKWARD_DO_MIRROR, ``docs/.../env_var.md``).
 4. Donation: param/state buffers are aliased in-place (donate_argnums
    worked), so the step's HBM footprint is ~1x weights, not 2x.
-
-Numbers measured here are committed to PERF.md §"Compiled-artifact
-evidence".
 """
 import re
 
@@ -51,7 +47,9 @@ RESNET50_CONV_GFLOP_HW = 2 * 4.089
 # artifacts
 
 
-def _build_step(layout="NHWC", remat=False, batch=BATCH):
+def _build_step(layout="NHWC", recompute=False, batch=BATCH):
+    """``recompute`` marks ``net.features``: stem, the four stages and
+    the pool, all 53 convolutions; the classifier is outside it."""
     mx.np.random.seed(0)
     net = vision.resnet50_v1(layout=layout)
     net.cast("bfloat16")
@@ -61,15 +59,16 @@ def _build_step(layout="NHWC", remat=False, batch=BATCH):
     x = mx.np.random.uniform(0, 1, shape).astype("bfloat16")
     y = mx.np.random.randint(0, 1000, (batch,), dtype="int32")
     net(x)  # materialize deferred shapes
+    net.features.recompute(recompute)
     opt = mx.optimizer.SGD(learning_rate=0.1, momentum=0.9, wd=1e-4)
     step = parallel.TrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
-                              opt, mesh=None, remat=remat)
+                              opt, mesh=None)
     return step, x, y
 
 
 @pytest.fixture(scope="module")
 def nhwc_lowered():
-    step, x, y = _build_step("NHWC", remat=False)
+    step, x, y = _build_step("NHWC")
     return step.lower(x, y)
 
 
@@ -80,7 +79,7 @@ def nhwc_compiled(nhwc_lowered):
 
 @pytest.fixture(scope="module")
 def nhwc_remat_lowered():
-    step, x, y = _build_step("NHWC", remat=True)
+    step, x, y = _build_step("NHWC", recompute=True)
     return step.lower(x, y)
 
 
@@ -138,8 +137,7 @@ def test_compiled_flops_match_analytic(nhwc_compiled):
     compiled train step does ~3x forward conv work (fwd + dgrad + wgrad;
     the stem's elided d/dinput and BN/loss/SGD noise keep it near but not
     exactly 3).  A layout or trace regression that duplicated the forward
-    (the failure mode PERF.md §"structurally minimal" guards) would land
-    at >= 4x and fail here."""
+    would land at >= 4x and fail here."""
     analytic_fwd = RESNET50_CONV_GFLOP_HW * 1e9 * BATCH
     flops = nhwc_compiled.cost_analysis()["flops"]
     ratio = flops / analytic_fwd
@@ -149,9 +147,11 @@ def test_compiled_flops_match_analytic(nhwc_compiled):
 
 def test_forward_flops_match_analytic():
     """Inference module: compiled FLOPs within 5% of the 8.18 GFLOP/img
-    hardware count — the number bench.py's MFU derives from."""
+    hardware count — the number ``model_mfu_pct.train`` derives from
+    (``benchmark/chip/counts/resnet.py``)."""
     import jax
 
+    from mxnet_tpu.gluon.block import swapped_params
     from mxnet_tpu.ndarray.ndarray import NDArray
 
     mx.np.random.seed(0)
@@ -164,14 +164,9 @@ def test_forward_flops_match_analytic():
     params = {n: p.data()._data for n, p in items}
 
     def fwd(params, xa):
-        handles = [(p._data, p._data._data) for _, p in items]
-        for (h, _), (n, _) in zip(handles, items):
-            h._data = params[n]
-        try:
+        with swapped_params([p._data for _, p in items],
+                            [params[n] for n, _ in items]):
             return net.forward(NDArray(xa))._data
-        finally:
-            for h, orig in handles:
-                h._data = orig
 
     lowered = jax.jit(fwd).lower(params, x._data)
     analytic = RESNET50_CONV_GFLOP_HW * 1e9 * BATCH
@@ -188,8 +183,9 @@ def test_forward_flops_match_analytic():
 
 def test_remat_rebuilds_forward_in_backward(nhwc_lowered,
                                             nhwc_remat_lowered):
-    """jax.checkpoint changes the PROGRAM: the remat train step contains
-    the 53 forward convs a second time (recompute-in-backward) behind an
+    """``net.features.recompute()`` changes the PROGRAM: the train step
+    contains the 53 forward convs of the marked block (stem and four
+    stages) a second time (recompute-in-backward) behind an
     optimization barrier.  This is the chip-independent form of the
     claim — on TPU the scheduler honors the barrier and trades the
     activation stash for recompute; CPU's compiler may CSE it back, which
@@ -263,40 +259,17 @@ def test_nchw_also_transpose_free_at_program_level():
     Python-level transposes) — layout is carried in conv dim_numbers, so
     the only transpose in the program is the rank-2 dense-weight one.
     On TPU the backend then picks layouts; NHWC is the variant whose
-    on-chip layout assignment is the identity (PERF.md lever 1)."""
-    step, x, y = _build_step("NCHW", remat=False, batch=2)
+    on-chip layout assignment is the identity."""
+    step, x, y = _build_step("NCHW", batch=2)
     res = hlo.check_transpose_free(step.lower(x, y).as_text())
     assert res.ok, res.details[:5]
-
-
-def test_perf_md_numbers_are_current(nhwc_compiled, nhwc_remat_compiled):
-    """PERF.md's committed compiled-artifact table must match what the
-    toolchain actually produces (ledger-hygiene guard: VERDICT r4 weak #7
-    flagged stale counts; this test makes staleness impossible for the
-    perf evidence)."""
-    import os
-    perf = open(os.path.join(os.path.dirname(__file__), "..",
-                             "PERF.md")).read()
-    flops = nhwc_compiled.cost_analysis()["flops"] / BATCH / 1e9
-    base_mb = nhwc_compiled.memory_analysis().temp_size_in_bytes / 1e6
-    remat_mb = \
-        nhwc_remat_compiled.memory_analysis().temp_size_in_bytes / 1e6
-    for tag, val in [("train-step GFLOP/img", flops),
-                     ("base temp MB/img", base_mb / BATCH),
-                     ("remat temp MB/img", remat_mb / BATCH)]:
-        m = re.search(r"%s[^0-9]*([0-9.]+)" % re.escape(tag), perf)
-        assert m, "PERF.md missing committed number for %r" % tag
-        committed = float(m.group(1))
-        assert onp.isclose(committed, val, rtol=0.15), \
-            "PERF.md %s = %s but artifact says %.2f" % (tag, m.group(1), val)
 
 
 def test_int8_path_is_int8_in_the_program():
     """The quantized net's compiled program really computes in int8:
     conv/dot operands are i8 with i32 accumulation (the MXU double-rate
     int8 path; reference analog: oneDNN/cuDNN int8 kernels,
-    ``src/operator/quantization/``).  Chip-free twin of bench.py's
-    infer_int8 phase."""
+    ``src/operator/quantization/``)."""
     import jax
 
     from mxnet_tpu.contrib import quantization as q

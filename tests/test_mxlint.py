@@ -637,12 +637,11 @@ def test_self_scan_repo_clean_modulo_baseline():
     diags = lint.lint_paths(ROOT)
     baseline = lint.load_baseline(
         os.path.join(ROOT, "tools", "mxlint_baseline.txt"))
-    un, kept, stale = lint.apply_baseline(diags, baseline)
+    un, _, stale = lint.apply_baseline(diags, baseline)
     assert not un, "unbaselined diagnostics:\n%s" % "\n".join(
         d.format() for d in un)
     assert not stale, ("stale baseline entries — the code improved, "
                        "ratchet the baseline down: %s" % stale)
-    assert kept, "baseline lists entries the scan no longer produces"
 
 
 def test_every_rule_is_live():
@@ -693,7 +692,8 @@ def test_mxlint_cli_standalone(tmp_path):
 
 
 @pytest.mark.integration
-def test_mxlint_cli_stale_baseline_and_github_format(tmp_path):
+def test_mxlint_cli_stale_baseline_and_github_format(tmp_path, monkeypatch,
+                                                     capsys):
     """A stale baseline entry fails the gate and is printed entry-by-
     entry (with its justification); --format github emits workflow
     commands for diagnostics."""
@@ -709,15 +709,22 @@ def test_mxlint_cli_stale_baseline_and_github_format(tmp_path):
     assert "stale baseline entry 'R2 tools/gone.py 3" in r.stderr
     assert "torn writer long since fixed" in r.stderr
     # github format: diagnostics become ::error workflow commands (the
-    # two deliberately-baselined R5 findings surface under
-    # --no-baseline, so the repo itself is the fixture)
-    r = subprocess.run([sys.executable, cli, "--format", "github",
-                        "--no-baseline", "--rules", "R5",
-                        "mxnet_tpu/parallel"],
-                       cwd=ROOT, capture_output=True, text=True,
-                       timeout=120)
-    assert r.returncode == 1
-    assert "::error file=" in r.stdout and "title=mxlint R5" in r.stdout
+    # repo's own baseline is empty, so the fixture is a virtual tree
+    # holding one R5 violation, scanned by the tool's own main)
+    import importlib.util
+    spec = importlib.util.spec_from_file_location("mxlint_cli", cli)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    fx = tmp_path / "mxnet_tpu" / "parallel"
+    fx.mkdir(parents=True)
+    (fx / "fx.py").write_text(R5_BAD_STORE)
+    monkeypatch.setattr(tool, "ROOT", str(tmp_path))
+    rc = tool.main(["--format", "github", "--no-baseline", "--rules", "R5",
+                    "mxnet_tpu/parallel"])
+    out = capsys.readouterr().out
+    assert rc == 1
+    assert "::error file=mxnet_tpu/parallel/fx.py" in out
+    assert "title=mxlint R5" in out
 
 
 @pytest.mark.integration

@@ -486,7 +486,7 @@ def test_strip_lock_static_liveness():
     diags = race.scan_paths(
         ROOT,
         targets=("mxnet_tpu/profiler.py", "mxnet_tpu/fault.py",
-                 "mxnet_tpu/fault_dist.py", "bench.py"),
+                 "mxnet_tpu/fault_dist.py"),
         rules={"R9"},
         override={"mxnet_tpu/profiler.py": stripped})
     hits = [d for d in diags
@@ -763,11 +763,11 @@ def test_mxrace_cli_github_format_and_stale_baseline(tmp_path):
     finding as a ::error workflow command; a stale baseline entry
     fails the gate and is printed with its justification."""
     cli = os.path.join(ROOT, "tools", "mxrace.py")
-    # the subset spanning the poller/bench roots and fault.py surfaces
+    # the subset spanning the poller's root and fault.py surfaces
     # the deliberately-baselined _ACTIVE finding without a full scan
     r = subprocess.run([sys.executable, cli, "--format", "github",
                         "--no-baseline", "mxnet_tpu/fault.py",
-                        "mxnet_tpu/fault_dist.py", "bench.py"],
+                        "mxnet_tpu/fault_dist.py"],
                        cwd=ROOT, capture_output=True, text=True,
                        timeout=180)
     assert r.returncode == 1

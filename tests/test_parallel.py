@@ -401,9 +401,11 @@ def test_train_step_param_rules_applied():
     assert onp.isfinite(float(loss))
 
 
-def test_train_step_remat_matches_plain():
-    """remat=True recomputes activations in backward; losses must match
-    the plain step bit-for-bit over several steps."""
+@pytest.mark.parametrize("marked", ["net", "first_child"])
+def test_train_step_remat_matches_plain(marked):
+    """``Block.recompute()`` runs the marked blocks again in the
+    backward; losses must match the plain step over several steps,
+    whether the whole net or only its first child is marked."""
     import mxnet_tpu as mx
     from mxnet_tpu import gluon, parallel
 
@@ -421,9 +423,11 @@ def test_train_step_remat_matches_plain():
     plain = parallel.TrainStep(build(), loss,
                                mx.optimizer.SGD(learning_rate=0.1),
                                mesh=None)
-    ck = parallel.TrainStep(build(), loss,
-                            mx.optimizer.SGD(learning_rate=0.1),
-                            mesh=None, remat=True)
+    net = build()
+    (net if marked == "net" else net[0]).recompute()
+    ck = parallel.TrainStep(net, loss, mx.optimizer.SGD(learning_rate=0.1),
+                            mesh=None)
+    assert "rematted_computation" in ck.lower(x, y).as_text(debug_info=True)
     for _ in range(3):
         l1 = float(plain(x, y))
         l2 = float(ck(x, y))
@@ -918,7 +922,7 @@ def test_ring_prestriped_inputs_skip_the_permutation():
 
 
 def test_causal_balance_striped_near_one_roundrobin_skewed():
-    """The chip-independent balance claim the bench ladder stands on:
+    """The chip-independent balance claim the striped layout stands on:
     striped keeps every ring step's max/mean block work ~1.0 (flat AND
     2-level), while the contiguous roundrobin layout's critical path
     grows toward ~2x as rank 0 idles."""
@@ -931,6 +935,11 @@ def test_causal_balance_striped_near_one_roundrobin_skewed():
         assert max(st["per_step_max_over_mean"]) <= 1.05, st
         assert rr["critical_path_x"] >= 1.5, rr
         assert rr["critical_path_x"] > st["critical_path_x"] * 1.4
+        # zigzag is scored only: flat, no better than striped by 1%,
+        # which is why it never grew an execution path
+        zz = ring.causal_balance("zigzag", inner, outer)
+        assert zz["critical_path_x"] <= st["critical_path_x"], zz
+        assert st["critical_path_x"] - zz["critical_path_x"] < 0.01
     with pytest.raises(ValueError):
         ring.causal_balance("diagonal", 8)
 

@@ -111,8 +111,7 @@ def test_quantize_net_cnn_end_to_end(calib_mode):
 
 def test_quantize_resnet18_top1_parity():
     """CNN INT8 flagship case at CI scale: quantized ResNet-18 keeps
-    argmax agreement with fp32 on synthetic calibration (the bench runs
-    ResNet-50 on the chip)."""
+    argmax agreement with fp32 on synthetic calibration."""
     from mxnet_tpu.gluon.model_zoo import vision
     mx.np.random.seed(6)
     net = vision.resnet18_v1()
@@ -135,7 +134,7 @@ def _walk_blocks(block):
 
 
 def test_quantized_net_hybridizes():
-    """The INT8 bench path: quantize then hybridize(static_alloc) must
+    """The INT8 deployment path: quantize then hybridize(static_alloc) must
     trace the int8 convs into one compiled program."""
     from mxnet_tpu.gluon.model_zoo import vision
     mx.np.random.seed(8)

@@ -1,5 +1,5 @@
 """Training with the real input pipeline (recordio -> ImageRecordIter ->
-TrainStep), CI-scale version of bench.py's train_io metric.
+TrainStep) at CI scale.
 
 Reference parity: the ``ImageRecordIter2`` + prefetcher + training-loop
 composition (``src/io/iter_image_recordio_2.cc:715``,
