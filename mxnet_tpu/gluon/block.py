@@ -33,6 +33,7 @@ from .. import profiler as _profiler
 from ..context import current_context
 from ..ndarray.ndarray import NDArray, apply_op
 from ..numpy import random as _random
+from ..ops.pallas_ops import ATTENTION_KERNEL_OUT
 from ..utils import serialization
 from .parameter import Parameter, DeferredInitializationError
 
@@ -68,6 +69,11 @@ class Block:
 
     #: set by :meth:`recompute`
     _recompute = False
+
+    #: the values a marked block keeps beside its inputs, by the name
+    #: the op that makes them gives them (``checkpoint_name``): what is
+    #: dear to make again for its size.  Filled by the ops that mark.
+    _recompute_keeps = (ATTENTION_KERNEL_OUT,)
 
     def __init__(self):
         self._children = OrderedDict()
@@ -146,11 +152,16 @@ class Block:
     def recompute(self, active=True):
         """Mark this block for recomputation: inside a traced training
         step (``parallel.TrainStep``, a hybridized parent under
-        ``autograd.record``) its forward runs again in the backward and
-        only its inputs are kept (``jax.checkpoint`` round this block's
-        call).  Values and gradients do not change; the step holds one
-        input a marked block instead of every activation inside it.
-        Outside a trace, and in inference, the mark does nothing."""
+        ``autograd.record``) its forward runs again in the backward
+        (``jax.checkpoint`` round this block's call).  Kept are its
+        inputs and, of its interior, only what an op names as dear to
+        make again (``_recompute_keeps``): the flash attention kernel's
+        output and row sums, so that the kernel runs once — two tensors
+        the size of the block's input an attention call where the
+        unmarked block holds every activation.  A block with no such op
+        inside keeps its inputs alone.  Values and gradients do not
+        change.  Outside a trace, and in inference, the mark does
+        nothing."""
         self._recompute = bool(active)
         return self
 
@@ -358,7 +369,8 @@ class Block:
 
     def _forward_recomputed(self, args, kwargs):
         """``forward`` as a pure function of this block's parameter
-        arrays and its NDArray arguments, under ``jax.checkpoint``.  The
+        arrays and its NDArray arguments, under ``jax.checkpoint``, which
+        keeps the values named in ``_recompute_keeps`` and no other.  The
         parameters' handles hold the enclosing trace's values; inside
         the checkpointed function they hold that function's own
         arguments (``swapped_params``), so that nothing of the block's
@@ -388,7 +400,9 @@ class Block:
             return (tuple(o._data for o in outs),
                     tuple(v for _, v in written))
 
-        outs, written = jax.checkpoint(pure)(
+        outs, written = jax.checkpoint(
+            pure, policy=jax.checkpoint_policies.save_only_these_names(
+                *self._recompute_keeps))(
             [h._data for h in handles], key,
             *[args[i]._data for i in where])
         for i, v in zip(meta["written"], written):

@@ -15,9 +15,11 @@ every pass.  The parameters do not depend on ``passes``; with one pass
 and no sandwich the logits are :class:`~.transformer.TransformerLM`'s.
 Every block application is a call of the same gluon block, so a weight's
 gradient is the sum over its uses.  Each block is marked for
-recomputation (``Block.recompute``): a training step keeps one input a
-block application, not its interior.  The head never makes whole logits
-in the loss (``ops.nn.chunked_softmax_cross_entropy``).
+recomputation (``Block.recompute``): a training step keeps, a block
+application, its input and the attention kernel's output and row sums
+(so the kernel runs once), and makes the rest of the interior again.
+The head never makes whole logits in the loss
+(``ops.nn.chunked_softmax_cross_entropy``).
 """
 from __future__ import annotations
 

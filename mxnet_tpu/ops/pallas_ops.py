@@ -43,11 +43,19 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from .nn import dot_product_attention
 
 _INTERPRET = os.environ.get("MXNET_PALLAS_INTERPRET", "0") == "1"
+
+#: the name the flash forward's ``o`` and ``lse`` carry for
+#: ``jax.checkpoint`` policies: a block marked with ``Block.recompute``
+#: keeps them (``gluon.Block._recompute_keeps``), so its backward
+#: does not run the forward kernel a second time.  Outside a checkpoint
+#: the name is an identity and lowers to nothing.
+ATTENTION_KERNEL_OUT = "attention_kernel_out"
 _logger = logging.getLogger(__name__)
 _warned_whole = set()  # mesh shapes already told about, once per process
 NEG_INF = float("-inf")
@@ -518,8 +526,10 @@ def _flash_lse_fwd(q, k, v, q_off, k_off, causal, scale, bq=None, bk=None):
     o, lse = _fwd_call(q.reshape(B * H, T, D), k.reshape(B * Hkv, Tk, D),
                        v.reshape(B * Hkv, Tk, D), q_off, k_off, causal,
                        scale, bq=bq, bk=bk)
-    o = o.reshape(B, H, T, D)
-    lse = lse.reshape(B, H, T)
+    # named here, before they part into outputs and residuals: the
+    # residuals are what a checkpoint policy has to see by name
+    o = checkpoint_name(o.reshape(B, H, T, D), ATTENTION_KERNEL_OUT)
+    lse = checkpoint_name(lse.reshape(B, H, T), ATTENTION_KERNEL_OUT)
     return (o, lse), (q, k, v, o, lse, q_off, k_off)
 
 

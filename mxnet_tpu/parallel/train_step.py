@@ -59,9 +59,11 @@ class TrainStep:
         the way (per-exit losses, statistics to log): the step then
         returns ``(loss, aux)``, the aux not differentiated.
 
-    A block marked with ``Block.recompute()`` keeps only its input and
-    runs again in the backward.  Parameters and optimizer states are
-    donated to the step and updated in place.
+    A block marked with ``Block.recompute()`` runs again in the backward
+    and keeps its input and what its ops name as dear to make again (a
+    flash attention kernel's output and row sums), nothing else of its
+    interior.  Parameters and optimizer states are donated to the step
+    and updated in place.
     """
 
     def __init__(self, net, loss_fn, optimizer, mesh=None, param_rules=None,

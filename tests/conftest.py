@@ -86,3 +86,11 @@ def pytest_runtest_makereport(item, call):
     outcome = yield
     rep = outcome.get_result()
     setattr(item, "rep_" + rep.when, rep)
+
+
+@pytest.fixture()
+def interpret_kernels(monkeypatch):
+    """The Pallas kernels' own code in the interpreter, on the CPU
+    (``MXNET_PALLAS_INTERPRET``'s switch) instead of the dense stand-in."""
+    from mxnet_tpu.ops import pallas_ops
+    monkeypatch.setattr(pallas_ops, "_INTERPRET", True)

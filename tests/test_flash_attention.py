@@ -15,11 +15,6 @@ from mxnet_tpu.ops.nn import dot_product_attention
 from mxnet_tpu.test_utils import assert_almost_equal
 
 
-@pytest.fixture()
-def interpret_kernels(monkeypatch):
-    monkeypatch.setattr(pallas_ops, "_INTERPRET", True)
-
-
 def _rand(shape, seed):
     return jnp.asarray(onp.random.RandomState(seed).normal(0, 1, shape),
                        jnp.float32)

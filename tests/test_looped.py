@@ -441,3 +441,141 @@ def test_recomputation_carries_written_state_and_random_keys():
         seen[mark] = ps["bn.running_mean"].data()._data
     assert float(jnp.abs(seen[True]).sum()) > 0            # written back
     onp.testing.assert_allclose(seen[True], seen[False], rtol=1e-6)
+
+
+# ----------------------------------------------------------------------
+# what a marked block keeps: the flash kernel's output and row sums
+# (pallas_ops.ATTENTION_KERNEL_OUT), so the kernel runs once.  The
+# kernels run in the interpreter at the smallest shape they take (rows
+# of 128, heads of 64); "bare" is the parent's form, jax.checkpoint
+# with no policy.
+# ----------------------------------------------------------------------
+@pytest.fixture()
+def bare_checkpoint(monkeypatch):
+    """Returns a switch: called, every ``jax.checkpoint`` from then on
+    drops its policy (what ``_forward_recomputed`` was before it kept
+    anything)."""
+    real = jax.checkpoint
+
+    def switch():
+        monkeypatch.setattr(jax, "checkpoint",
+                            lambda fun, **kw: real(fun))
+    return switch
+
+
+def _kernel_config(**over):
+    return _config(dim=128, n_heads=2, n_kv_heads=2, hidden_dim=256, **over)
+
+
+class _TwoBlocks(gluon.HybridBlock):
+    def __init__(self):
+        super().__init__()
+        from mxnet_tpu.models.transformer import TransformerBlock
+        self.a = TransformerBlock(_kernel_config())
+        self.b = TransformerBlock(_kernel_config())
+
+    def forward(self, x):
+        return self.b(self.a(x))
+
+
+# (the parent marked, its two blocks marked, bare) -> flash_fwd calls in
+# the gradient's jaxpr; dq and dkv are always one a block
+@pytest.mark.parametrize("outer,inner,bare,forwards", [
+    (False, False, False, 2),       # unmarked: one a block application
+    (False, True, False, 2),        # marked: still one
+    (False, True, True, 4),         # the parent's form: made again
+    (True, False, False, 2),        # the root's mark alone
+    (True, True, False, 2),         # a marked block in a marked parent
+    (True, True, True, 5),          # bare, nested: again and again
+])
+def test_a_marked_block_runs_the_attention_kernel_once(
+        interpret_kernels, bare_checkpoint, outer, inner, bare, forwards):
+    net = _TwoBlocks()
+    net.initialize()
+    net.recompute(outer)
+    net.a.recompute(inner)
+    net.b.recompute(inner)
+    if bare:
+        bare_checkpoint()
+
+    def loss(x):
+        with mx.autograd.train_mode():
+            return net(NDArray(x))._data.sum()
+
+    text = str(jax.make_jaxpr(jax.grad(loss))(jnp.ones((1, 128, 128))))
+    assert text.count("name=flash_fwd") == forwards
+    assert text.count("name=flash_bwd_dq") == 2
+    assert text.count("name=flash_bwd_dkv") == 2
+    assert ("remat2" in text) == (outer or inner)
+
+
+def test_keeping_the_kernels_output_changes_no_bit(interpret_kernels,
+                                                   bare_checkpoint):
+    """The looped step with the kernels inside, marked as ``LoopedLM``
+    marks it, against the same step under the bare checkpoint: the kept
+    ``o`` and ``lse`` are the bits a second run of the kernel makes, so
+    the loss, both moments and every parameter after the step are the
+    same to the bit."""
+    ids = onp.random.RandomState(3).randint(0, 256, (B, 129))
+    tok, lab = NDArray(jnp.asarray(ids[:, :-1])), \
+        NDArray(jnp.asarray(ids[:, 1:]))
+
+    def run():
+        mx.np.random.seed(5)
+        net = LoopedLM(_kernel_config())
+        net.initialize()
+        step = _step(net, chunk=96)
+        text = step.lower(tok, lab).as_text()
+        loss = float(step(tok, lab))
+        return loss, step._states, {
+            n: p.data()._data for n, p in net.collect_params().items()}, text
+
+    loss_a, states_a, params_a, text_a = run()
+    bare_checkpoint()
+    loss_b, states_b, params_b, text_b = run()
+    assert text_a != text_b                  # two programs, not one twice
+    assert loss_a == loss_b
+    assert states_a.keys() == states_b.keys() and len(params_a) > 10
+    for name, st in states_a.items():
+        for a, b in zip(st, states_b[name]):
+            assert bool(jnp.all(a == b)), name
+    for name, a in params_a.items():
+        assert bool(jnp.all(a == params_b[name])), name
+
+
+def _marked_mlp():
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Dense(32, activation="relu", in_units=16),
+            gluon.nn.Dense(8, in_units=32))
+    x = mx.np.array(onp.ones((4, 16), "float32"))
+    return net, net, x, mx.np.array(onp.zeros((4,), "int32"))
+
+
+def _marked_resnet_features():
+    from mxnet_tpu.gluon.model_zoo import vision
+    net = vision.resnet18_v1(layout="NHWC")
+    x = mx.np.array(onp.ones((2, 32, 32, 3), "float32"))
+    return net, net.features, x, mx.np.array(onp.zeros((2,), "int32"))
+
+
+@pytest.mark.parametrize("build", [_marked_mlp, _marked_resnet_features])
+def test_a_marked_block_without_the_kernel_lowers_as_the_bare_checkpoint(
+        bare_checkpoint, build):
+    """No named value inside, nothing more kept: the step's text is the
+    bare checkpoint's, letter for letter."""
+    mx.np.random.seed(2)
+    net, marked, x, y = build()
+    net.initialize()
+    net(x)
+    marked.recompute()
+
+    def lower():
+        return parallel.TrainStep(
+            net, gluon.loss.SoftmaxCrossEntropyLoss(),
+            mx.optimizer.SGD(learning_rate=0.1), mesh=None) \
+            .lower(x, y).as_text()
+
+    with_policy = lower()
+    assert "optimization_barrier" in with_policy
+    bare_checkpoint()
+    assert lower() == with_policy
