@@ -301,21 +301,29 @@ class LayerNorm(HybridBlock):
 
 
 class RMSNorm(HybridBlock):
-    """RMS normalization (TPU-native extension for LLM blocks)."""
+    """RMS normalization (TPU-native extension for LLM blocks).  With
+    ``unit_offset`` ``gamma`` holds the gain's distance from one
+    (initially 0); ``out_dtype`` is the dtype of the result where it is
+    not the input's (a float32 stream normed for bf16 matmuls)."""
 
-    def __init__(self, axis=-1, epsilon=1e-6, gamma_initializer="ones",
-                 in_channels=0):
+    def __init__(self, axis=-1, epsilon=1e-6, gamma_initializer=None,
+                 in_channels=0, unit_offset=False, out_dtype=None):
         super().__init__()
         self._axis = axis
         self._epsilon = epsilon
-        self.gamma = Parameter(shape=(in_channels,), init=gamma_initializer,
-                               allow_deferred_init=True, name="gamma")
+        self._unit_offset = unit_offset
+        self._out_dtype = out_dtype
+        self.gamma = Parameter(
+            shape=(in_channels,), allow_deferred_init=True, name="gamma",
+            init=gamma_initializer or ("zeros" if unit_offset else "ones"))
 
     def forward(self, x):
         if self.gamma._data is None:
             self.gamma._finish_deferred_init((x.shape[self._axis],))
         return npx.rms_norm(x, self.gamma.data(), axis=self._axis,
-                            eps=self._epsilon)
+                            eps=self._epsilon,
+                            unit_offset=self._unit_offset,
+                            out_dtype=self._out_dtype)
 
 
 class GroupNorm(HybridBlock):

@@ -9,7 +9,9 @@ return one-element lists for API compatibility.  Deferred init (shape with
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+import numpy as onp
 
 from .. import initializer as init_mod
 from ..context import Context, current_context
@@ -21,6 +23,61 @@ from .. import _tape
 class DeferredInitializationError(RuntimeError):
     """Parameter accessed before shape was inferred (parameter.py raises the
     same)."""
+
+
+class _GradBuffer(NDArray):
+    """A parameter's gradient buffer: zeros that take device memory only
+    once somebody reads or writes them.  The tape holds this handle from
+    the moment the weight is marked (``_tape.mark_variable``), so the
+    handle exists at once; the array behind it is made by the first
+    ``backward`` that writes it or the first reader (``grad().asnumpy()``,
+    ``Trainer``, an accumulation under ``grad_req='add'``).  A network that
+    is only ever stepped by ``parallel.TrainStep``, which differentiates
+    inside its own program, never makes one."""
+
+    __slots__ = ("_aval",)
+    _slot = NDArray._data   # the storage this class's property guards
+
+    def __init__(self, shape, dtype):
+        self._aval = (tuple(shape), jnp.dtype(dtype))
+        self._ag = None
+        self._fresh = False
+
+    @property
+    def live(self):
+        """Whether the buffer holds an array yet."""
+        try:
+            _GradBuffer._slot.__get__(self)
+        except AttributeError:
+            return False
+        return True
+
+    @property
+    def _data(self):
+        if not self.live:
+            # concrete even when first read while something is traced
+            with jax.ensure_compile_time_eval():
+                _GradBuffer._slot.__set__(self, jnp.zeros(*self._aval))
+        return _GradBuffer._slot.__get__(self)
+
+    @_data.setter
+    def _data(self, value):
+        _GradBuffer._slot.__set__(self, value)
+
+    @property
+    def shape(self):
+        return tuple(self._data.shape) if self.live else self._aval[0]
+
+    @property
+    def dtype(self):
+        return onp.dtype(self._data.dtype if self.live else self._aval[1])
+
+    def retype(self, dtype):
+        """The buffer in another dtype, in place (the tape holds this
+        very object)."""
+        if self.live:
+            self._data = self._data.astype(dtype)
+        self._aval = (self._aval[0], jnp.dtype(dtype))
 
 
 class Parameter:
@@ -157,7 +214,7 @@ class Parameter:
         self._finish_init(init, ctx, default_init)
 
     def _init_grad(self):
-        self._grad = NDArray(jnp.zeros(self._data.shape, self._data.dtype))
+        self._grad = _GradBuffer(self._data.shape, self._data.dtype)
         _tape.mark_variable(self._data, self._grad, self._grad_req)
 
     # -- access -----------------------------------------------------------
@@ -243,7 +300,7 @@ class Parameter:
             _tape.mark_variable(self._data, self._grad, self._grad_req)
 
     def zero_grad(self):
-        if self._grad is not None:
+        if self._grad is not None and self._grad.live:
             self._grad._data = jnp.zeros_like(self._grad._data)
 
     def reset_ctx(self, ctx):
@@ -253,11 +310,10 @@ class Parameter:
                 # in-place device move, same buffer object: a record-
                 # time tape holds this exact object as its grad_buf
                 # (see cast)
-                import jax
-                from ..context import Context
                 c = Context(ctx) if not isinstance(ctx, Context) else ctx
-                self._grad._data = jax.device_put(self._grad._data,
-                                                  c.jax_device)
+                if self._grad.live:
+                    self._grad._data = jax.device_put(self._grad._data,
+                                                      c.jax_device)
                 self._grad._ag = None
                 _tape.mark_variable(self._data, self._grad, self._grad_req)
 
@@ -271,7 +327,7 @@ class Parameter:
                 # mutate the grad buffer IN PLACE: a record-time tape
                 # holds this exact object as its grad_buf — replacing it
                 # would orphan both the gradient and its freshness mark
-                self._grad._data = self._grad._data.astype(dtype)
+                self._grad.retype(dtype)
                 self._grad._ag = None
                 _tape.mark_variable(self._data, self._grad, self._grad_req)
 

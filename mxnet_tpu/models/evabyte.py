@@ -1,0 +1,139 @@
+"""EvaByte, a tokenizer-free byte-level LM (``config.json`` and modelling
+code of ``EvaByte/EvaByte``): a Llama-class stack whose attention is EVA
+(Zheng et al., "Efficient Attention via Control Variates", ICLR 2023) in
+its causal chunked form, with a head of several byte predictors.
+
+    chunk j = tokens [c j, c (j + 1)), in window floor(c j / W)
+    k~_j = sum_m softmax_m(k_m . mu_h) k_m     m over the chunk, per head
+    v~_j = sum_m softmax_m(k_m . phi_h) v_m
+    query i in window w sees, under ONE softmax, the tokens m <= i of its
+    own window exactly and the pairs (k~_j, v~_j) of every chunk of the
+    windows before w.  Window 0 sees no summary.
+
+    logits[t, k] = z_t W_head[k]   (float32), predictor k for byte t + 1 + k
+    loss = mean over k of mean over t of CE(logits[t, k], byte[t + 1 + k])
+
+The stack is :class:`~.transformer.TransformerBlock` as it is: the
+configuration selects the attention (``attn_impl="eva"``), the norms'
+unit offset and the float32 residual stream.  The attention is made of
+parts that are merged by logsumexp, each part a call of the flash
+kernels (``ops/pallas_ops.py``): the local part is the causal kernel
+over the windows as so many more heads, the remote part the non-causal
+kernel of a window's queries against the summaries before it.  Every
+block is marked for recomputation and keeps each kernel call's output
+and row sums (``Block._recompute_keeps``).
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from ..ndarray.ndarray import apply_op
+from ..ops.pallas_ops import flash_attention_with_lse, merge_attention_parts
+from .transformer import LlamaConfig, TransformerLM
+
+
+def chunk_summaries(k, v, mu, phi, chunk):
+    """``(k~, v~)``, each (B, H, T / chunk, D) in ``k``'s dtype: every
+    chunk's keys pooled by ``softmax(k . mu)`` and its values by
+    ``softmax(k . phi)`` over the chunk's tokens, softmax and sums in
+    float32.  ``k``, ``v`` (B, H, T, D), ``k`` after RoPE; ``mu``, ``phi``
+    (H, D).  The pooling logits carry no further scale."""
+    B, H, T, D = k.shape
+    kc = k.reshape(B, H, T // chunk, chunk, D)
+    vc = v.reshape(B, H, T // chunk, chunk, D)
+
+    def pooled(x, w):
+        logits = jnp.einsum("bhncd,hd->bhnc", kc, w,
+                            preferred_element_type=jnp.float32)
+        p = jax.nn.softmax(logits, axis=-1)
+        return jnp.sum(p[..., None] * x.astype(jnp.float32),
+                       axis=3).astype(k.dtype)
+
+    return pooled(kc, mu), pooled(vc, phi)
+
+
+def eva_attention(q, k, v, mu, phi, window, chunk, shard=None):
+    """EVA attention on (B, H, T, D), ``q`` and ``k`` after RoPE.  ``T``
+    is at most ``window`` (one window: plain causal attention) or a
+    multiple of it; ``window`` is a multiple of ``chunk``."""
+    B, H, T, D = q.shape
+    if T <= window:
+        window = T
+    if T % window or window % chunk:
+        raise ValueError(
+            "eva attention: %d tokens are not whole windows of %d, or the "
+            "window not whole chunks of %d" % (T, window, chunk))
+    nw, per = T // window, window // chunk
+    with jax.named_scope("eva"):
+        with jax.named_scope("eva_local"):
+            # contiguous in T: a window is one more head, at no copy
+            local = [a.reshape(B, H * nw, window, D) for a in (q, k, v)]
+            o, lse = flash_attention_with_lse(*local, causal=True,
+                                              shard=shard)
+            o = o.reshape(B, H, nw, window, D)
+            lse = lse.reshape(B, H, nw, window)
+        if nw == 1:
+            return o.reshape(B, H, T, D)
+        with jax.named_scope("eva_prep"):
+            # the last window's chunks are seen by nobody
+            ks, vs = chunk_summaries(k[:, :, :T - window],
+                                     v[:, :, :T - window], mu, phi, chunk)
+        qw = q.reshape(B, H, nw, window, D)
+        merged = [o[:, :, 0]]
+        for w in range(1, nw):
+            with jax.named_scope("eva_remote"):
+                o_r, lse_r = flash_attention_with_lse(
+                    qw[:, :, w], ks[:, :, :per * w], vs[:, :, :per * w],
+                    causal=False, shard=shard)
+            with jax.named_scope("eva_merge"):
+                merged.append(merge_attention_parts(
+                    o[:, :, w], lse[:, :, w], o_r, lse_r)[0]
+                    .astype(q.dtype))
+        with jax.named_scope("eva_merge"):
+            return jnp.stack(merged, axis=2).reshape(B, H, T, D)
+
+
+class EvaByteLM(TransformerLM):
+    """Input (B, T) int byte ids.  ``forward`` gives the float32 logits
+    (B, T, heads, vocab); ``loss`` the multi-byte prediction loss for
+    ``TrainStep(forward_fn=...)``."""
+
+    def __init__(self, cfg: LlamaConfig = None, **kwargs):
+        super().__init__(cfg, **kwargs)
+        for blk in self.layers:
+            blk.recompute()
+
+    def forward(self, tokens, cache=None):
+        return self._logits(self.hidden(tokens, cache=cache))
+
+    def _logits(self, z):
+        heads, vocab = self.cfg.num_pred_heads, self.cfg.vocab_size
+
+        def head(z, w):
+            out = jnp.einsum("btd,vd->btv", z, w,
+                             preferred_element_type=jnp.float32)
+            return out.reshape(out.shape[:2] + (heads, vocab))
+
+        return apply_op(head, [z, self.output.weight.data()],
+                        name="byte_heads")
+
+    def loss(self, tokens, labels, heads=False):
+        """The mean over the predictors of each one's mean cross-entropy;
+        ``labels`` (B, T, heads) hold, for predictor k, the byte at
+        ``t + 1 + k``.  With ``heads`` also ``{"ce": (heads,)}``, every
+        predictor's mean cross-entropy (``TrainStep`` hands it through
+        as the step's aux)."""
+        z = self.hidden(tokens)
+        with jax.named_scope("mbp_loss"):
+            logits = self._logits(z)
+
+            def mean_ce(logits, y):
+                logp = jax.nn.log_softmax(logits, axis=-1)
+                picked = jnp.take_along_axis(
+                    logp, y.astype(jnp.int32)[..., None], axis=-1)[..., 0]
+                return -jnp.mean(picked, axis=(0, 1))
+
+            ce = apply_op(mean_ce, [logits, labels], name="byte_mean_ce")
+            loss = apply_op(jnp.mean, [ce], name="mbp_loss")
+        return (loss, {"ce": ce}) if heads else loss

@@ -23,6 +23,7 @@ from .. import numpy_extension as npx
 from ..gluon.block import HybridBlock
 from ..gluon.nn import Dense, Embedding, RMSNorm
 from ..gluon.parameter import Parameter
+from ..initializer import Normal
 from ..ndarray.ndarray import NDArray, apply_op
 
 
@@ -42,7 +43,10 @@ class LlamaConfig:
     # 128-aligned seq and D in {64,128,256}, and transparently falls
     # back to dense XLA attention elsewhere (ops/pallas_ops.py gating) —
     # so dense is never worse and long-seq TPU runs get the fused kernel
-    attn_impl: str = "flash"  # dense | flash | ring
+    # eva (``models/evabyte.py``): exact causal attention inside a window
+    # of ``window_size`` tokens, one softmax shared with a learned summary
+    # of every ``chunk_size``-token chunk of all earlier windows
+    attn_impl: str = "flash"  # dense | flash | ring | eva
     cp_axis: str = "cp"       # mesh axis for ring attention
     # mixture-of-experts (0 = dense FFN everywhere): every
     # ``moe_every``-th block uses a switch-MoE FFN with this many
@@ -56,6 +60,17 @@ class LlamaConfig:
     # looped decoders (``models.looped.LoopedLM``): the stack of layers
     # runs this many times over the same weights
     passes: int = 1
+    # attn_impl="eva": the window and the chunk one summary stands for
+    window_size: int = 0
+    chunk_size: int = 0
+    # linear predictors over the one final hidden state; predictor k is
+    # for the token at t + 1 + k (``models.evabyte.EvaByteLM``)
+    num_pred_heads: int = 1
+    # the norms store their gain as its distance from one
+    norm_unit_offset: bool = False
+    # the residual stream's dtype where it is not ``dtype``: branches
+    # compute in ``dtype`` and are added to the stream in this one
+    residual_dtype: str = None
 
 
 def llama3_8b_config(**over):
@@ -76,6 +91,23 @@ def ouro_2p6b_config(**over):
                       n_kv_heads=16, hidden_dim=5632, max_seq_len=65536,
                       rope_theta=1000000.0, norm_eps=1e-6,
                       sandwich_norm=True, passes=4)
+    for k, v in over.items():
+        setattr(cfg, k, v)
+    return cfg
+
+
+def evabyte_6p5b_config(**over):
+    """EvaByte 6.5B (``config.json`` of ``EvaByte/EvaByte``), a
+    tokenizer-free byte-level LM: 32 layers of EVA attention (Zheng et
+    al., ICLR 2023, causal chunked form: window 2,048, chunk 16) with 32
+    heads of 128, SwiGLU 11,008, 320 byte ids, eight byte-prediction
+    heads, norm gains stored as an offset from one, a float32 residual
+    stream.  For :class:`~.evabyte.EvaByteLM`."""
+    cfg = LlamaConfig(vocab_size=320, dim=4096, n_layers=32, n_heads=32,
+                      n_kv_heads=32, hidden_dim=11008, max_seq_len=32768,
+                      rope_theta=100000.0, norm_eps=1e-5, attn_impl="eva",
+                      window_size=2048, chunk_size=16, num_pred_heads=8,
+                      norm_unit_offset=True, residual_dtype="float32")
     for k, v in over.items():
         setattr(cfg, k, v)
     return cfg
@@ -127,6 +159,14 @@ def _sp_constraint(x, spec):
         return x
 
 
+def _norm(cfg):
+    """One of the model's RMSNorms: over a stream held in another dtype
+    than the matmuls' it gives the matmuls' dtype."""
+    return RMSNorm(epsilon=cfg.norm_eps, in_channels=cfg.dim,
+                   unit_offset=cfg.norm_unit_offset,
+                   out_dtype=cfg.dtype if cfg.residual_dtype else None)
+
+
 class Attention(HybridBlock):
     def __init__(self, cfg: LlamaConfig, layer_idx=0):
         super().__init__()
@@ -147,6 +187,17 @@ class Attention(HybridBlock):
         self.wk.weight.shard(("tp", None))
         self.wv.weight.shard(("tp", None))
         self.wo.weight.shard((None, "tp"))
+        if cfg.attn_impl == "eva":
+            # a head's two pooling vectors: a chunk's keys are weighed by
+            # softmax(k . mu), its values by softmax(k . phi)
+            if cfg.n_kv_heads != cfg.n_heads:
+                raise ValueError("eva attention pools a head's own keys: "
+                                 "n_kv_heads must equal n_heads")
+            for name in ("adaptive_mu_k", "adaptive_phi"):
+                setattr(self, name, Parameter(
+                    shape=(cfg.n_heads, head_dim), dtype=cfg.dtype,
+                    init=Normal(head_dim ** -0.5), name=name)
+                    .shard(("tp", None)))
 
     def forward(self, x, cache=None):
         cfg = self.cfg
@@ -157,9 +208,14 @@ class Attention(HybridBlock):
         hd, nh, nkv = self.head_dim, cfg.n_heads, cfg.n_kv_heads
         impl, theta, cp_axis = cfg.attn_impl, cfg.rope_theta, cfg.cp_axis
         if cache is not None:
+            if impl == "eva":
+                raise NotImplementedError(
+                    "eva attention has no cached path: a slot's state "
+                    "would be its window's K/V and the summaries of the "
+                    "windows before it (ROADMAP N7)")
             return self._forward_cached(x, q, k, v, cache)
 
-        def attn(q, k, v):
+        def attn(q, k, v, *pool):
             q = q.reshape(B, T, nh, hd)
             k = k.reshape(B, T, nkv, hd)
             v = v.reshape(B, T, nkv, hd)
@@ -198,13 +254,21 @@ class Attention(HybridBlock):
                 from ..parallel.sharding import kernel_shard
                 o = flash_attention(q, k, v, causal=True,
                                     shard=kernel_shard(B, nkv))
+            elif impl == "eva":
+                from ..parallel.sharding import kernel_shard
+                from .evabyte import eva_attention
+                o = eva_attention(q, k, v, *pool, cfg.window_size,
+                                  cfg.chunk_size,
+                                  shard=kernel_shard(B, nkv))
             else:
                 from ..ops.nn import dot_product_attention
                 o = dot_product_attention(q, k, v, causal=True)
             o = jnp.swapaxes(o, 1, 2).reshape(B, T, nh * hd)
             return o
 
-        o = apply_op(attn, [q, k, v], name="attention")
+        pool = [self.adaptive_mu_k.data(), self.adaptive_phi.data()] \
+            if impl == "eva" else []
+        o = apply_op(attn, [q, k, v] + pool, name="attention")
         return self.wo(o)
 
     def _forward_cached(self, x, q, k, v, cache):
@@ -385,20 +449,17 @@ class MoEFeedForward(HybridBlock):
 class TransformerBlock(HybridBlock):
     def __init__(self, cfg: LlamaConfig, layer_idx=0):
         super().__init__()
-        self.attention_norm = RMSNorm(epsilon=cfg.norm_eps,
-                                      in_channels=cfg.dim)
+        self.attention_norm = _norm(cfg)
         self.attention = Attention(cfg, layer_idx=layer_idx)
-        self.ffn_norm = RMSNorm(epsilon=cfg.norm_eps, in_channels=cfg.dim)
+        self.ffn_norm = _norm(cfg)
         use_moe = (cfg.moe_num_experts > 0
                    and layer_idx % max(1, cfg.moe_every) == 0)
         self.feed_forward = MoEFeedForward(cfg) if use_moe \
             else FeedForward(cfg)
         self._sandwich = cfg.sandwich_norm
         if cfg.sandwich_norm:
-            self.attention_post_norm = RMSNorm(epsilon=cfg.norm_eps,
-                                               in_channels=cfg.dim)
-            self.ffn_post_norm = RMSNorm(epsilon=cfg.norm_eps,
-                                         in_channels=cfg.dim)
+            self.attention_post_norm = _norm(cfg)
+            self.ffn_post_norm = _norm(cfg)
 
     def forward(self, x, cache=None):
         a = self.attention(self.attention_norm(x), cache=cache)
@@ -430,8 +491,10 @@ class TransformerLM(HybridBlock):
             blk = TransformerBlock(cfg, layer_idx=i)
             setattr(self, "layer%d" % i, blk)
             self.layers.append(blk)
-        self.norm = RMSNorm(epsilon=cfg.norm_eps, in_channels=cfg.dim)
-        self.output = Dense(cfg.vocab_size, use_bias=False, flatten=False,
+        self.norm = _norm(cfg)
+        # predictor k's rows are [k * vocab, (k + 1) * vocab)
+        self.output = Dense(cfg.vocab_size * cfg.num_pred_heads,
+                            use_bias=False, flatten=False,
                             in_units=cfg.dim, dtype=cfg.dtype)
         self.output.weight.shard(("tp", None))
 
@@ -447,14 +510,19 @@ class TransformerLM(HybridBlock):
             ff = blk.feed_forward
             if isinstance(ff, MoEFeedForward):
                 ff.last_aux_loss = None
+        return self.output(self.hidden(tokens, cache=cache))
+
+    def hidden(self, tokens, cache=None):
+        """The final norm's output (B, T, dim), what the head reads."""
         h = self._embed(tokens)
         for blk in self.layers:
             h = blk(h, cache=cache)
-        h = self.norm(h)
-        return self.output(h)
+        return self.norm(h)
 
     def _embed(self, tokens):
         h = self.tok_embeddings(tokens)
+        if self.cfg.residual_dtype:
+            h = h.astype(self.cfg.residual_dtype)
         return apply_op(lambda a: _sp_constraint(a, ("dp", "sp", None)), [h],
                         name="sp_shard")
 

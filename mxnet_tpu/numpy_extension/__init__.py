@@ -241,8 +241,10 @@ def instance_norm(data, gamma, beta, eps=1e-5):
                     [data, gamma, beta], name="instance_norm")
 
 
-def rms_norm(data, gamma, axis=-1, eps=1e-6):
-    return apply_op(lambda x, g: _nn.rms_norm(x, g, axis, eps),
+def rms_norm(data, gamma, axis=-1, eps=1e-6, unit_offset=False,
+             out_dtype=None):
+    return apply_op(lambda x, g: _nn.rms_norm(x, g, axis, eps, unit_offset,
+                                              out_dtype),
                     [data, gamma], name="rms_norm")
 
 

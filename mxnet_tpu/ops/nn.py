@@ -329,12 +329,20 @@ def layer_norm(x, gamma, beta, axis=-1, eps=1e-5):
     return out * gamma.reshape(shape) + beta.reshape(shape)
 
 
-def rms_norm(x, gamma, axis=-1, eps=1e-6):
+def rms_norm(x, gamma, axis=-1, eps=1e-6, unit_offset=False,
+             out_dtype=None):
+    """``x / rms(x) * gamma``.  With ``unit_offset`` the stored ``gamma``
+    is the gain's distance from one (the gain is ``1 + gamma``, summed
+    in float32); ``out_dtype`` casts the result (a float32 residual
+    stream normed into the dtype of the matmuls that follow)."""
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=axis, keepdims=True)
     out = x * lax.rsqrt(var + eps).astype(x.dtype)
     shape = [1] * x.ndim
     shape[axis] = x.shape[axis]
-    return out * gamma.reshape(shape)
+    if unit_offset:
+        gamma = 1.0 + gamma.astype(jnp.float32)
+    out = out * gamma.reshape(shape)
+    return out if out_dtype is None else out.astype(out_dtype)
 
 
 def group_norm(x, gamma, beta, num_groups, eps=1e-5):

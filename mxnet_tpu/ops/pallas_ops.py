@@ -685,9 +685,26 @@ def _chunked_with_lse(q, k, v, q_off, k_off, causal, scale, cq, ck):
     return o, lse
 
 
+def merge_attention_parts(acc_o, acc_lse, o_s, lse_s):
+    """Exact combine of two normalized partial attentions over disjoint
+    key sets: o = (o1·e^l1 + o2·e^l2)/(e^l1+e^l2), max-shifted; float32
+    ``(o, lse)``.  What the ring does a step (``parallel/ring.py``) and
+    EVA attention does once a window (``models/evabyte.py``)."""
+    m = jnp.maximum(acc_lse, lse_s)
+    m_safe = jnp.where(jnp.isneginf(m), 0.0, m)
+    w1 = jnp.where(jnp.isneginf(acc_lse), 0.0, jnp.exp(acc_lse - m_safe))
+    w2 = jnp.where(jnp.isneginf(lse_s), 0.0, jnp.exp(lse_s - m_safe))
+    tot = w1 + w2
+    tot_safe = jnp.where(tot == 0.0, 1.0, tot)
+    o = (acc_o * w1[..., None] + o_s.astype(jnp.float32) * w2[..., None]) \
+        / tot_safe[..., None]
+    lse = jnp.where(tot == 0.0, -jnp.inf, m_safe + jnp.log(tot_safe))
+    return o, lse
+
+
 def flash_attention_with_lse(q, k, v, causal=False, scale=None,
                              q_offset=None, k_offset=None, block_q=None,
-                             block_k=None):
+                             block_k=None, shard=None):
     """Blocked attention returning (output, logsumexp) on (B, H, T, D).
 
     GQA/MQA: ``k``/``v`` may carry fewer heads (H % H_kv == 0); the
@@ -697,7 +714,9 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
 
     ``q_offset``/``k_offset`` are dynamic global position offsets for the
     causal mask (int32 scalars or shape-(1,) arrays) — pass the ring-step
-    block offsets here.  Gradients flow through both outputs.
+    block offsets here.  Gradients flow through both outputs.  With
+    ``shard`` (``parallel.sharding.kernel_shard``; required for a bare
+    call under a mesh) the kernels run per shard of batch and heads.
     """
     if q.shape[1] % k.shape[1] != 0:
         raise ValueError(
@@ -718,8 +737,15 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
                 return _chunked_with_lse(q, k, v, q_off, k_off, causal,
                                          scale, cq, ck)
         return _dense_with_lse(q, k, v, q_off, k_off, causal, scale)
-    return _flash_lse(q, k, v, q_off, k_off, causal, scale, block_q,
-                      block_k)
+    def kernel(q, k, v, q_off, k_off):
+        return _flash_lse(q, k, v, q_off, k_off, causal, scale, block_q,
+                          block_k)
+
+    if shard is not None:
+        spec = P(shard[1], shard[2], None, None)
+        kernel = _per_shard(kernel, shard, (spec,) * 3 + (P(), P()),
+                            (spec, P(shard[1], shard[2], None)))
+    return kernel(q, k, v, q_off, k_off)
 
 
 def flash_attention_block_bwd(q, k, v, do, lse, delta, causal=False,

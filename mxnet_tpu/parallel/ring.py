@@ -53,6 +53,7 @@ from jax.sharding import PartitionSpec as P
 from .. import fault as _fault
 from ..ops.pallas_ops import (flash_attention_block_bwd,
                               flash_attention_with_lse)
+from ..ops.pallas_ops import merge_attention_parts as _merge
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
@@ -191,21 +192,6 @@ def causal_balance(layout, inner, outer=1, block_tokens=128):
     crit = sum(max(w) for w in steps) * n / total
     return {"per_step_max_over_mean": [round(x, 4) for x in per_step],
             "critical_path_x": round(crit, 4)}
-
-
-def _merge(acc_o, acc_lse, o_s, lse_s):
-    """Exact combine of two normalized partial attentions over disjoint
-    key sets: o = (o1·e^l1 + o2·e^l2)/(e^l1+e^l2), max-shifted."""
-    m = jnp.maximum(acc_lse, lse_s)
-    m_safe = jnp.where(jnp.isneginf(m), 0.0, m)
-    w1 = jnp.where(jnp.isneginf(acc_lse), 0.0, jnp.exp(acc_lse - m_safe))
-    w2 = jnp.where(jnp.isneginf(lse_s), 0.0, jnp.exp(lse_s - m_safe))
-    tot = w1 + w2
-    tot_safe = jnp.where(tot == 0.0, 1.0, tot)
-    o = (acc_o * w1[..., None] + o_s.astype(jnp.float32) * w2[..., None]) \
-        / tot_safe[..., None]
-    lse = jnp.where(tot == 0.0, -jnp.inf, m_safe + jnp.log(tot_safe))
-    return o, lse
 
 
 # ---------------------------------------------------------------------------

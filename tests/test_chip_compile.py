@@ -293,6 +293,36 @@ def test_looped_step_compiles_with_flash_under_block_recompute(topo,
     assert temp - bare.memory_analysis().temp_size_in_bytes <= 1.1 * kept
 
 
+def test_eva_attention_compiles_at_the_byte_cells_shape(topo, on_chip):
+    """``evabyte_6p5b_train_1x8192``'s attention, (1, 32, 8192, 128) bf16
+    with a head's two pooling vectors, forward and gradient: the causal
+    kernel once over the 4 x 32 windows (rows of 2,048) and the
+    non-causal one three times (2,048 queries against 128, 256 and 384
+    summaries), each of them forward, dq and dkv, under the part that
+    calls it and the tile the picker gives it."""
+    from mxnet_tpu.models import eva_attention
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    row = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16,
+                               sharding=one_chip)
+    vec = jax.ShapeDtypeStruct((32, 128), jnp.bfloat16, sharding=one_chip)
+
+    def grad(q, k, v, mu, phi):
+        return jax.grad(lambda *a: eva_attention(*a, 2048, 16)
+                        .astype(jnp.float32).sum(), range(5))(
+                            q, k, v, mu, phi)
+
+    lines = _kernel_lines(grad, row, row, row, vec, vec)
+    assert len(lines) == 4 * 3
+    for part, n in (("eva_local", 1), ("eva_remote", 3)):
+        for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+            named = [ln for ln in lines if re.search(
+                r'op_name="[^"]*\beva\)*/%s/tiles_q\d+_k\d+/%s/pallas_call"'
+                % (part, name), ln)]
+            assert len(named) == n, (part, name, len(named))
+    bq, bk = pallas_ops._pick_tiles("fwd", 2048, 384, 128, jnp.bfloat16)
+    assert (bq, bk) == (1024, 384)           # a short row is one tile
+
+
 def test_decode_program_carries_its_scopes(topo, on_chip):
     """The serving decode program at one layer of 128-wide heads: the
     K/V write, the attention read (the paged kernel under it, by name)

@@ -231,6 +231,78 @@ def test_looped_step_keeps_the_flash_kernels_names(looped_names, kernel,
     assert bool(hits) == there, (kernel, phase, hits[:3])
 
 
+@pytest.fixture(scope="module")
+def eva_names():
+    """Op names of the compiled EvaByte training step, the flash kernels
+    interpreted: three windows of 256 bytes, chunks of 2, heads of 64."""
+    from mxnet_tpu.models import EvaByteLM, evabyte_6p5b_config
+    from mxnet_tpu.ndarray.ndarray import NDArray
+    from mxnet_tpu.ops import pallas_ops
+    cfg = evabyte_6p5b_config(dim=128, n_layers=2, n_heads=2, n_kv_heads=2,
+                              hidden_dim=256, window_size=256, chunk_size=2,
+                              max_seq_len=1024, dtype="float32")
+    net = EvaByteLM(cfg)
+    net.initialize()
+    tok = NDArray(jnp.zeros((1, 768), jnp.int32))
+    lab = NDArray(jnp.zeros((1, 768, 8), jnp.int32))
+    step = parallel.TrainStep(
+        net, None, mx.optimizer.AdamW(learning_rate=1e-3), mesh=None,
+        forward_fn=lambda net, t, l: net.loss(t, l, heads=True))
+    was = pallas_ops._INTERPRET
+    pallas_ops._INTERPRET = True
+    try:
+        text = step.lower(tok, lab).compile().as_text()
+    finally:
+        pallas_ops._INTERPRET = was
+    return set(re.findall(r'op_name="(jit\(step\)/[^"]*)"', text))
+
+
+@pytest.mark.parametrize("scope", ["eva_prep", "eva_local", "eva_remote",
+                                   "eva_merge"])
+def test_eva_step_carries_the_attentions_four_parts(eva_names, scope):
+    # every part lies under ``eva`` under the block's ``attention``,
+    # first forward and backward (``eva_attn_device_pct.train`` reads
+    # ``eva``, ``eva_remote_device_pct.train`` the three that the
+    # summaries cost)
+    fwd = "jit(step)/jvp(forward)/layer1/attention/eva/%s/" % scope
+    assert any(n.startswith(fwd) for n in eva_names), scope
+    assert any(n.startswith("jit(step)/transpose(jvp(forward))/")
+               and "/layer1/checkpoint/attention/eva/%s/" % scope in n
+               for n in eva_names), scope
+    # nothing of the attention lies outside ``eva`` but projections and
+    # rotary
+    assert not any("/%s/" % scope in n and "/eva/" not in n
+                   for n in eva_names)
+
+
+def test_eva_step_carries_mbp_loss(eva_names):
+    fwd = "jit(step)/jvp(forward)/mbp_loss/"
+    assert any(n.startswith(fwd) and "dot_general" in n for n in eva_names)
+    assert any(n.startswith("jit(step)/transpose(jvp(forward))/mbp_loss/")
+               for n in eva_names)
+    assert not any("/mbp_loss/" in n and "/layer" in n for n in eva_names)
+
+
+@pytest.mark.parametrize("kernel,part,phase,there", [
+    ("flash_fwd", "eva_local", "jit(step)/jvp(forward)/layer", True),
+    ("flash_fwd", "eva_remote", "jit(step)/jvp(forward)/layer", True),
+    ("flash_fwd", "eva_local", "/rematted_computation/", False),
+    ("flash_fwd", "eva_remote", "/rematted_computation/", False),
+    ("flash_bwd_dq", "eva_local", "/checkpoint/attention/", True),
+    ("flash_bwd_dkv", "eva_remote", "/checkpoint/attention/", True)])
+def test_eva_step_keeps_the_flash_kernels_names(eva_names, kernel, part,
+                                                phase, there):
+    # the kernels keep their names under the tile they run, under the
+    # part that calls them; a marked block keeps every call's output and
+    # row sums, so none is among the recomputed ops
+    hits = [n for n in eva_names
+            if re.search(r"/attention/eva/%s/tiles_q\d+_k\d+/%s\)*/"
+                         % (part, kernel), n) and phase in n]
+    assert bool(hits) == there, (kernel, part, phase, hits[:3])
+    again = {n for n in eva_names if "/rematted_computation/" in n}
+    assert again and any("/feed_forward/" in n for n in again)
+
+
 def test_block_scope_names():
     net = gluon.nn.HybridSequential()
     net.add(gluon.nn.Dense(4, in_units=3), gluon.nn.Activation("relu"))
