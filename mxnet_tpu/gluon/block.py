@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import contextlib
 import re
+import threading
 from collections import OrderedDict
 
 import jax
@@ -56,6 +57,40 @@ def swapped_params(handles, arrays):
             if h._data is not a:
                 written.append((i, h._data))
             h._data = orig
+
+
+class _Keeping(threading.local):
+    """What the trace of a training step says of its marked blocks, set
+    by whoever drives the trace (:func:`keeping`) and read by
+    ``Block._forward_recomputed``."""
+    kept = frozenset()  # ids of the marked blocks that are not made again
+    seen = None         # {id(block): [applied in the step's own trace?]}
+    trace = None        # the trace the step's forward runs in
+
+
+_KEEPING = _Keeping()
+
+
+@contextlib.contextmanager
+def keeping(kept=(), seen=None):
+    """Round the forward of a training step as ``parallel.TrainStep``
+    traces it: inside, a marked block whose id is in ``kept`` is not
+    made again (its forward runs as an unmarked block's would, with the
+    random keys it would have drawn under the checkpoint), every other
+    marked block is.  Only a block applied in the trace this is opened
+    in can be kept: one under a ``lax.scan`` or another block's
+    checkpoint would hold its interior once a trip, so it is made again
+    whatever ``kept`` says.  With ``seen`` (a dict) every application
+    of a marked block notes under ``id(block)``, in the order of the
+    forward, whether it stood in that trace: what a plan is made
+    from."""
+    was = _KEEPING.kept, _KEEPING.seen, _KEEPING.trace
+    _KEEPING.kept, _KEEPING.seen = frozenset(kept), seen
+    _KEEPING.trace = jax.core.get_opaque_trace_state()
+    try:
+        yield
+    finally:
+        _KEEPING.kept, _KEEPING.seen, _KEEPING.trace = was
 
 
 class Block:
@@ -150,18 +185,22 @@ class Block:
             child.hybridize(active, **kwargs)
 
     def recompute(self, active=True):
-        """Mark this block for recomputation: inside a traced training
-        step (``parallel.TrainStep``, a hybridized parent under
-        ``autograd.record``) its forward runs again in the backward
-        (``jax.checkpoint`` round this block's call).  Kept are its
-        inputs and, of its interior, only what an op names as dear to
-        make again (``_recompute_keeps``): the flash attention kernel's
-        output and row sums, so that the kernel runs once — two tensors
-        the size of the block's input an attention call where the
-        unmarked block holds every activation.  A block with no such op
-        inside keeps its inputs alone.  Values and gradients do not
-        change.  Outside a trace, and in inference, the mark does
-        nothing."""
+        """Mark this block as one that may be made again: inside a traced
+        training step (``parallel.TrainStep``, a hybridized parent under
+        ``autograd.record``) its forward runs under ``jax.checkpoint``
+        and what the backward needs of its interior is computed a second
+        time.  Kept are its inputs and what an op names as dear to make
+        again (``_recompute_keeps``): the flash attention kernel's
+        output and row sums, so that the kernel runs once.  Whether the
+        block *is* made again is ``TrainStep``'s to say, from the memory
+        the device has left beside the compiled step: as many of the
+        marked blocks as fit, the last of the forward first, are not
+        checkpointed at all and hold their interior like unmarked ones
+        (:func:`keeping`).  Without such a plan — a hybridized parent,
+        a device that does not report its memory, a block applied under
+        a ``lax.scan`` — every marked block is made again.  Values,
+        gradients and random draws do not change with the plan.  Outside
+        a trace, and in inference, the mark does nothing."""
         self._recompute = bool(active)
         return self
 
@@ -376,12 +415,22 @@ class Block:
         arguments (``swapped_params``), so that nothing of the block's
         interior is closed over.  A handle written inside
         (running statistics) comes out as an output and is written
-        back.  Random keys derive from one key drawn outside."""
+        back.  Random keys derive from one key drawn outside.  A block
+        the trace's driver keeps (:func:`keeping`) runs ``forward`` bare,
+        under the key it would have been given."""
+        key = _random.new_key() if _random._STATE.trace_stack else None
+        if _KEEPING.trace is not None:
+            own = jax.core.get_opaque_trace_state() == _KEEPING.trace
+            if _KEEPING.seen is not None:
+                _KEEPING.seen.setdefault(id(self), []).append(own)
+            if own and id(self) in _KEEPING.kept:
+                with _random.trace_scope(key) if key is not None \
+                        else contextlib.nullcontext():
+                    return self.forward(*args, **kwargs)
         handles = list({id(p._data): p._data
                         for p in self.collect_params().values()
                         if p._data is not None}.values())
         where = [i for i, a in enumerate(args) if isinstance(a, NDArray)]
-        key = _random.new_key() if _random._STATE.trace_stack else None
         meta = {}
 
         def pure(arrays, key, *xs):

@@ -20,8 +20,10 @@ parts that are merged by logsumexp, each part a call of the flash
 kernels (``ops/pallas_ops.py``): the local part is the causal kernel
 over the windows as so many more heads, the remote part the non-causal
 kernel of a window's queries against the summaries before it.  Every
-block is marked for recomputation and keeps each kernel call's output
-and row sums (``Block._recompute_keeps``).
+block is marked as one that may be made again (``Block.recompute``): a
+block that is keeps each kernel call's output and row sums
+(``Block._recompute_keeps``), and ``parallel.TrainStep`` spares as many
+blocks, the last first, as it finds memory for on the device.
 """
 from __future__ import annotations
 

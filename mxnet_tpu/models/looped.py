@@ -14,10 +14,13 @@ arXiv:2510.25741, first-stage objective).
 every pass.  The parameters do not depend on ``passes``; with one pass
 and no sandwich the logits are :class:`~.transformer.TransformerLM`'s.
 Every block application is a call of the same gluon block, so a weight's
-gradient is the sum over its uses.  Each block is marked for
-recomputation (``Block.recompute``): a training step keeps, a block
+gradient is the sum over its uses.  Each block is marked as one that
+may be made again (``Block.recompute``): a training step keeps, a block
 application, its input and the attention kernel's output and row sums
 (so the kernel runs once), and makes the rest of the interior again.
+Under the scan a block is always made again (``parallel.TrainStep``
+spares only blocks applied in the step's own trace: the single-pass
+model's); kept, its interior would be stacked once a pass.
 The head never makes whole logits in the loss
 (``ops.nn.chunked_softmax_cross_entropy``).
 """

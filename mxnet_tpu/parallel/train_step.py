@@ -16,6 +16,10 @@ the reference's server-side update sharding (``kvstore_dist_server.h:346``).
 from __future__ import annotations
 
 import contextlib
+import hashlib
+import json
+import os
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -24,13 +28,38 @@ from jax.sharding import NamedSharding, PartitionSpec
 from .. import _tape
 from .. import fault as _fault
 from .. import profiler as _profiler
-from ..gluon.block import swapped_params
+from ..gluon.block import keeping, swapped_params
 from ..ndarray.ndarray import NDArray
 from ..numpy import random as _random
+from ..utils import compile_cache
+from ..utils.serialization import atomic_write
 from .mesh import mesh_scope
 from .sharding import _valid_spec, param_sharding
 
 P = PartitionSpec
+
+#: device memory a recomputation plan leaves unasked.  The step's
+#: temporaries are one allocation of several GB that must find a
+#: contiguous range in a heap the caller's arrays have been made in and
+#: freed from, and whoever compiles the lowered step again beside the
+#: running one (a memory report does) loads a second copy of its code.
+_RESERVE_BYTES = 512 << 20
+#: programs a plan may compile for one batch signature: the step with
+#: every marked block made again (the reading the others are measured
+#: from, and what runs when nothing more fits) and two tries.  Each is a
+#: compile of the whole step, most of a minute at a model's real size.
+_COMPILES = 3
+
+
+#: what the runtime raises when a program does not fit the device: a v5e
+#: refuses to load the step with a plain ``ValueError`` ("RESOURCE_EXHAUSTED:
+#: Error loading program 'jit_step': Attempting to reserve 5.41G at the
+#: bottom of memory ..."; chip run, PR 33), the compiler with jax's own
+_DEVICE_ERRORS = (jax.errors.JaxRuntimeError, ValueError)
+
+
+def _out_of_memory(error):
+    return "RESOURCE_EXHAUSTED" in str(error)
 
 
 def _is_ndarray(x):
@@ -39,6 +68,78 @@ def _is_ndarray(x):
 
 def _raw(x):
     return x._data if isinstance(x, NDArray) else x
+
+
+def _signature(arrays):
+    return tuple((tuple(a.shape), str(a.dtype)) for a in arrays)
+
+
+def _marked_blocks(net):
+    """``[(path, block)]`` of the blocks under ``net`` that carry
+    ``Block.recompute()``'s mark, in the order of the tree."""
+    found, met = [], set()
+
+    def walk(block, path):
+        if block._recompute and id(block) not in met:
+            met.add(id(block))
+            found.append((path or type(block).__name__, block))
+        for name, child in block._children.items():
+            walk(child, "%s.%s" % (path, name) if path else name)
+
+    walk(net, "")
+    return found
+
+
+def _device_memory(device):
+    """``(bytes_limit, bytes_in_use)`` of a device that reports them,
+    else None (the CPU; a described topology has no device at all)."""
+    stats = device.memory_stats()
+    if not stats or "bytes_limit" not in stats \
+            or "bytes_in_use" not in stats:
+        return None
+    return int(stats["bytes_limit"]), int(stats["bytes_in_use"])
+
+
+def blocks_to_spare(readings, n, room):
+    """The next number of blocks to try sparing, or None when the search
+    is over.  A step has ``n`` marked blocks that can be spared the
+    recomputation, the last of the forward first; ``readings[k]`` is
+    what the compiled step that spares the last ``k`` needs for its
+    temporaries and code (None where the compiler or the device refused
+    it), ``readings[0]`` always there; ``room`` is what the device has
+    for them.  Everything is tried first; after a try that does not fit,
+    the number a straight line between the largest that fits and the
+    smallest that does not puts at ``room`` (the midpoint where the
+    latter gave no reading).  The first block spared costs least (it
+    takes the place of what its own backward held anyway), so the line
+    errs towards fewer."""
+    if readings[0] is None or readings[0] > room:
+        return None
+    lo = max(k for k, need in readings.items()
+             if need is not None and need <= room)
+    over = [k for k in readings if k > lo]
+    hi = min(over) if over else n + 1
+    if hi - lo <= 1:
+        return None
+    if not over:
+        return n
+    if readings[hi] is None:
+        return (lo + hi) // 2
+    a_block = (readings[hi] - readings[lo]) / (hi - lo)
+    return min(max(lo + int((room - readings[lo]) / a_block), lo + 1),
+               hi - 1)
+
+
+class _Planned:
+    """The step as planned for batches of one signature."""
+
+    def __init__(self, jitted, run, plan, candidates, readings):
+        self.jitted = jitted            # what ``lower()`` lowers
+        self.run = run                  # its executable, which runs
+        self.plan = plan                # ``TrainStep.recompute_plan``
+        self.candidates = candidates    # paths of the blocks it could spare
+        self.readings = readings        # ``blocks_to_spare``'s
+        self.ran = False        # the device has run a plan of this signature
 
 
 class TrainStep:
@@ -59,11 +160,37 @@ class TrainStep:
         the way (per-exit losses, statistics to log): the step then
         returns ``(loss, aux)``, the aux not differentiated.
 
-    A block marked with ``Block.recompute()`` runs again in the backward
-    and keeps its input and what its ops name as dear to make again (a
-    flash attention kernel's output and row sums), nothing else of its
-    interior.  Parameters and optimizer states are donated to the step
-    and updated in place.
+    A block marked with ``Block.recompute()`` may be made again in the
+    backward: it then keeps its input and what its ops name as dear to
+    make again (a flash attention kernel's output and row sums).
+    Whether it is, is decided here, from what only the step can observe.
+    On one device that reports its memory, the first call with batches
+    of a signature compiles the step ahead of time with every marked
+    block made again, reads the compiled step's temporaries and code
+    and the device's ``bytes_limit`` and ``bytes_in_use``, and spares as
+    many marked blocks as fit in what is left after ``_RESERVE_BYTES``,
+    the last of the forward first (what a later block holds it holds
+    for the shorter time): a spared block is not checkpointed at all.
+    Candidates are the marked blocks the trace met in the step's own
+    trace; one under a ``lax.scan`` is always made again.  How many fit
+    is searched (``blocks_to_spare``) with each try compiled and its own
+    ``memory_analysis()`` held to the room, ``_COMPILES`` programs at
+    most; what runs is the largest try that fits, else the step first
+    compiled.  The plan is kept beside the persistent compile cache
+    (``mx_recompute_plan_<key>.json``, keyed by shapes, marked blocks,
+    optimizer, device and jax version), so that a later start compiles
+    or loads the planned step alone, and still verifies it; a plan is
+    kept for every batch signature met, so a shape that comes back
+    runs what it ran before.  A planned step the device refuses for
+    want of memory, at any call, is planned again from the device's
+    memory as it is then, while its arguments are still there (the plan
+    file follows only where the refused step had never run).
+    ``recompute_plan`` holds the last plan's record; ``lower()`` lowers
+    the program that runs.  A step with no marked block, under a mesh,
+    against a described topology or on a device without
+    ``memory_stats()`` is built and compiled as if there were no plan.
+    Parameters and optimizer states are donated to the step and updated
+    in place.
     """
 
     def __init__(self, net, loss_fn, optimizer, mesh=None, param_rules=None,
@@ -93,8 +220,11 @@ class TrainStep:
                            if p.grad_req != "null"]
         self._t = 0
         self._batch_spec = batch_spec
-        self._jitted = None
-        self._cold = True   # the next call builds and compiles
+        self._jitted = None     # every marked block made again: jit's own
+        self._plan_device = None  # whose memory plans are made from, if any
+        self._planned = {}      # batch signature -> _Planned
+        #: how the last plan chose the blocks to spare (None: not planned)
+        self.recompute_plan = None
         self._states = None
         self._shardings = None
         self._setup()
@@ -136,7 +266,11 @@ class TrainStep:
         return self._shardings[name].spec
 
     # -- the pure step -----------------------------------------------------
-    def _build(self, batch_arrays):
+    def _build(self, batch_arrays, kept=(), seen=None):
+        """The jitted step.  ``kept`` and ``seen`` are
+        ``gluon.block.keeping``'s: the ids of the marked blocks that are
+        not made again, and where the trace notes the marked blocks it
+        met."""
         net, params, trainable = self.net, self._params, self._trainable
         opt = self.optimizer
         loss_fn, forward_fn = self.loss_fn, self.forward_fn
@@ -146,7 +280,8 @@ class TrainStep:
             with swapped_params(
                     [p._data for _, p in params],
                     [all_arrays[name] for name, _ in params]) as written, \
-                    _tape.suspend_recording(), _random.trace_scope(key):
+                    _tape.suspend_recording(), _random.trace_scope(key), \
+                    keeping(kept, seen):
                 _tape.set_training(True)
                 try:
                     aux = None
@@ -281,33 +416,207 @@ class TrainStep:
             # step-boundary peer health (mx.fault.dist): detect a hung
             # peer before launching the next cross-process program
             _fault._DIST_HEARTBEAT.beat(step=self._t)
-        # first call: the program is built here and compiled in the
-        # dispatch the build span encloses
-        build = _profiler.span("mx.train.step.build") if self._cold \
+        batch_arrays = self._batch_arrays(batch)
+        # first call (where the step plans: with batches of a signature):
+        # the program is built here and compiled in the dispatch the
+        # build span encloses, or ahead of it under the plan span
+        build = _profiler.span("mx.train.step.build") \
+            if self._built(batch_arrays) is None \
             else contextlib.nullcontext()
         with _profiler.step_span("mx.train.step", self._t + 1), build:
-            loss = self._step(batch)
-        self._cold = False
+            loss = self._step(batch_arrays)
         return loss
 
-    def _step(self, batch):
-        batch_arrays = tuple(b._data if isinstance(b, NDArray)
-                             else jnp.asarray(b) for b in batch)
+    @staticmethod
+    def _batch_arrays(batch):
+        return tuple(b._data if isinstance(b, NDArray) else jnp.asarray(b)
+                     for b in batch)
+
+    def _args(self, batch_arrays, t):
+        return ({name: p._data._data for name, p in self._params},
+                self._states, jnp.int32(t),
+                jnp.float32(self.optimizer.learning_rate),
+                _random.new_key()) + batch_arrays
+
+    def _built(self, batch_arrays):
+        """What runs batches of these shapes, if it is built yet: the
+        jitted step, which keeps a program a shape itself, or where the
+        step plans, the plan of this signature."""
+        if self._jitted is None or self._plan_device is None:
+            return self._jitted
+        return self._planned.get(_signature(batch_arrays))
+
+    def _ensure_built(self, batch_arrays, args=None):
         if self._jitted is None:
             self._jitted = self._build(batch_arrays)
+            self._plan_device = self._device_to_plan_for()
+        built = self._built(batch_arrays)
+        if built is None:
+            built = self._plan(batch_arrays, args or self._args(
+                batch_arrays, max(self._t, 1)))
+        return built
+
+    def _step(self, batch_arrays):
         self._t += 1
         self.optimizer.num_update = self._t
-        lr = jnp.float32(self.optimizer.learning_rate)
-        key = _random.new_key()
-        param_arrays = {name: p._data._data for name, p in self._params}
-        with _profiler.span("mx.train.step.dispatch"):
-            loss, new_params, new_states = self._jitted(
-                param_arrays, self._states, jnp.int32(self._t), lr, key,
-                *batch_arrays)
+        args = self._args(batch_arrays, self._t)
+        built = self._ensure_built(batch_arrays, args)
+        with _profiler.span("mx.train.step.dispatch") as span:
+            loss, new_params, new_states = self._dispatch(
+                built, args, batch_arrays, span)
         for name, p in self._params:
             p._data._data = new_params[name]
         self._states = new_states
         return jax.tree_util.tree_map(NDArray, loss)
+
+    def _dispatch(self, built, args, batch_arrays, span):
+        if not isinstance(built, _Planned):
+            return built(*args)
+        try:
+            out = built.run(*args)
+            built.ran = True
+            return out
+        except _DEVICE_ERRORS as e:
+            # memory_analysis() is the plan's reading of what the device
+            # reserves for a program, and the caller may have put more on
+            # the device since the plan was made.  A step refused for
+            # want of memory fails before it runs, its arguments not yet
+            # given up: then the device's word stands and the plan is
+            # made again with fewer blocks spared, down to the step the
+            # parent would have run, which raises what it raises.  Only
+            # a plan refused before it ever ran is written to the plan
+            # file: one that ran was a sound plan for the memory a start
+            # finds.
+            spared = built.plan["spared"]
+            if not spared or not _out_of_memory(e) or any(
+                    a.is_deleted() for a in
+                    jax.tree_util.tree_leaves(args[:2])):
+                raise
+            warnings.warn(
+                "the device refused the training step that spares %s the "
+                "recomputation (%s); planning again"
+                % (" ".join(spared), str(e).splitlines()[0][:200]))
+        span.set(refused=len(spared))
+        return self._dispatch(self._plan(batch_arrays, args, refused=built),
+                              args, batch_arrays, span)
+
+    # -- which marked blocks are made again ---------------------------------
+    def _device_to_plan_for(self):
+        """The device from whose memory this step plans, or None where
+        nothing is known: no marked block, a mesh, a described topology,
+        a device that does not report its memory."""
+        if self.mesh is not None or self.aot \
+                or not _marked_blocks(self.net):
+            return None
+        device = next(iter(self._params[0][1]._data._data.devices()))
+        return device if _device_memory(device) is not None else None
+
+    def _plan(self, batch_arrays, args, refused=None):
+        """Choose the marked blocks to spare from the device's free
+        memory (class docstring) and note the planned step, compiled,
+        under the batch's signature.  ``refused`` is the plan of this
+        signature that the device would not run."""
+        limit, in_use = _device_memory(self._plan_device)
+        # what a compiled step's temporaries and code may take
+        room = limit - in_use - _RESERVE_BYTES
+        blocks = dict(_marked_blocks(self.net))
+        key = self._plan_key(blocks, batch_arrays, limit)
+        where = compile_cache.cache_dir_in_force()
+        file = where and os.path.join(
+            where, "mx_recompute_plan_%s.json" % key[:32])
+        compiles = 0
+
+        def compiled(candidates, k, seen=None):
+            """``(k, jitted, executable, temporaries + code)`` of the
+            step that spares the last ``k`` of ``candidates``; without
+            the last two where the compiler refuses it."""
+            nonlocal compiles
+            compiles += 1
+            jitted = self._build(
+                batch_arrays, [id(blocks[path]) for path in
+                               candidates[len(candidates) - k:]], seen)
+            with compile_cache.stable_locations():
+                lowered = jitted.lower(*args)
+            try:
+                executable = lowered.compile()
+            except _DEVICE_ERRORS as e:
+                if not k or not _out_of_memory(e):
+                    raise
+                return k, jitted, None, None    # no room even to compile
+            ma = executable.memory_analysis()
+            return k, jitted, executable, int(
+                ma.temp_size_in_bytes + ma.generated_code_size_in_bytes)
+
+        def fits(tried):
+            return tried[3] is not None and tried[3] <= room
+
+        with _profiler.span("mx.train.step.plan") as span:
+            candidates, readings, best = None, {}, None
+            hint = None if refused else _read_plan(file, key, blocks)
+            if refused:
+                # the device's word stands over memory_analysis()'s
+                candidates = refused.candidates
+                readings = dict(refused.readings)
+                readings[len(refused.plan["spared"])] = None
+            elif hint is not None:
+                # a start that finds a plan traces and loads that step
+                # only; the file is a hint: the executable has to fit
+                tried = compiled(hint["candidates"], len(hint["spared"]))
+                candidates, readings = hint["candidates"], {tried[0]: tried[3]}
+                if fits(tried) or not hint["spared"]:
+                    best, readings = tried, {**hint["readings"], **readings}
+                del tried
+            if best is None:
+                if 0 not in readings:
+                    seen = {}
+                    best = compiled([], 0, seen)
+                    paths = {id(b): path for path, b in blocks.items()}
+                    candidates = [paths[i] for i, own in seen.items()
+                                  if i in paths and all(own)]
+                    readings[0] = best[3]
+                while compiles < _COMPILES:
+                    k = blocks_to_spare(readings, len(candidates), room)
+                    if k is None:
+                        break
+                    tried = compiled(candidates, k)
+                    readings[k] = tried[3]
+                    if fits(tried):
+                        best = tried
+                    del tried
+                if best is None:    # refused, and no try of this round fits
+                    best = compiled(candidates, max(
+                        [k for k, need in readings.items()
+                         if need is not None and need <= room] or [0]))
+            k, jitted, executable, need = best
+            spared = candidates[len(candidates) - k:]
+            plan = {"free_bytes": room - readings[0],
+                    "temp_bytes_rung0": readings[0], "temp_bytes": need,
+                    "spared": spared,
+                    "made_again": [p for p in blocks if p not in spared],
+                    "compiles": compiles,
+                    "from_file": hint is not None and compiles == 1}
+            # (a span's arguments are joined by , and = in the trace)
+            span.set(**dict(plan, spared=" ".join(spared),
+                            made_again=" ".join(plan["made_again"])))
+        if not plan["from_file"] and not (refused and refused.ran):
+            _write_plan(file, {
+                "key": key, "candidates": candidates, "spared": spared,
+                "readings": readings, "plan": plan})
+        self.recompute_plan = plan
+        built = self._planned[_signature(batch_arrays)] = _Planned(
+            jitted, executable, plan, candidates, readings)
+        built.ran = bool(refused and refused.ran)
+        return built
+
+    def _plan_key(self, blocks, batch_arrays, limit):
+        """Everything a plan depends on that is known before any trace."""
+        return hashlib.sha256(json.dumps([
+            jax.__version__, self._plan_device.device_kind, limit,
+            type(self.optimizer).__name__,
+            [(n, tuple(p.shape), str(p.dtype)) for n, p in self._params],
+            sorted((n, _signature(st)) for n, st in self._states.items()),
+            _signature(batch_arrays), list(blocks),
+        ]).encode()).hexdigest()
 
     def save_checkpoint(self, path):
         """Sharded checkpoint of the FULL training state — params,
@@ -403,7 +712,7 @@ class TrainStep:
         """
         self.mesh = mesh
         self._jitted = None
-        self._cold = True
+        self._planned = {}
         self._setup()
         if checkpoint is not None:
             self.load_checkpoint(checkpoint)
@@ -411,10 +720,7 @@ class TrainStep:
 
     def compile(self, *batch):
         """Warm the compile cache without stepping."""
-        batch_arrays = tuple(b._data if isinstance(b, NDArray)
-                             else jnp.asarray(b) for b in batch)
-        if self._jitted is None:
-            self._jitted = self._build(batch_arrays)
+        self._ensure_built(self._batch_arrays(batch))
         return self
 
     def lower(self, *batch):
@@ -428,15 +734,15 @@ class TrainStep:
         reference's analog is its per-op profiler dump
         (``src/profiler/profiler.cc``); here the whole train step is one
         XLA program, so the compiled artifact itself is inspectable.
+        It is the program ``__call__`` runs: where the step plans what
+        its marked blocks keep, the planned one.
         """
-        batch_arrays = tuple(b._data if isinstance(b, NDArray)
-                             else jnp.asarray(b) for b in batch)
-        if self._jitted is None:
-            self._jitted = self._build(batch_arrays)
-        param_arrays = {name: p._data._data for name, p in self._params}
-        lr = jnp.float32(self.optimizer.learning_rate)
-        args = (param_arrays, self._states, jnp.int32(max(self._t, 1)),
-                lr, _random.new_key()) + batch_arrays
+        batch_arrays = self._batch_arrays(batch)
+        built = self._ensure_built(batch_arrays)
+        args = self._args(batch_arrays, max(self._t, 1))
+        if isinstance(built, _Planned):
+            with compile_cache.stable_locations():   # as it was compiled
+                return built.jitted.lower(*args)
         if self.aot:
             # topology-mesh lowering: hand jit avals, not host-placed
             # arrays (a compile-only client has no buffers to match the
@@ -445,3 +751,35 @@ class TrainStep:
                 lambda a: jax.ShapeDtypeStruct(jnp.shape(a), a.dtype),
                 args)
         return self._jitted.lower(*args)
+
+
+def _read_plan(path, key, blocks):
+    """The plan file's content if it is there, whole, of this key and of
+    these marked blocks."""
+    if not path:
+        return None
+    try:
+        with open(path) as f:
+            hint = json.load(f)
+        if hint["key"] != key:
+            return None
+        candidates, spared = hint["candidates"], hint["spared"]
+        if any(path not in blocks for path in candidates) \
+                or spared != candidates[len(candidates) - len(spared):]:
+            return None
+        hint["readings"] = {int(k): need
+                            for k, need in hint["readings"].items()}
+        hint["readings"][0] + 0     # the reading everything starts from
+        return hint
+    except (OSError, ValueError, KeyError, TypeError):
+        return None
+
+
+def _write_plan(path, content):
+    if not path:
+        return
+    try:
+        with atomic_write(path, "w") as f:
+            json.dump(content, f)
+    except OSError:
+        pass   # a cache that cannot be written is a cache that misses

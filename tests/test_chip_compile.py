@@ -99,6 +99,32 @@ def test_flash_grad_compiles(topo, on_chip, T):
     assert _kernels(_flash_grad, *_qkv(T, one_chip)) == 3
 
 
+def test_a_kernels_bytes_do_not_depend_on_who_called_it_first(topo, on_chip):
+    # jax keeps a kernel's trace from the first time it met it, and the
+    # kernel's serialized body carries its ops' locations: with the call
+    # stack in them the same function lowers to other bytes after
+    # another caller (and misses the persistent cache);
+    # utils.compile_cache.stable_locations() leaves the stack out
+    from mxnet_tpu.utils import compile_cache
+    avals = _qkv(2048, SingleDeviceSharding(topo.devices[0]))
+
+    def deeper(*a):
+        return _flash_grad(*a)
+
+    def texts():
+        out = []
+        for first in (_flash_grad, deeper):
+            jax.clear_caches()
+            jax.jit(first).lower(*avals)
+            out.append(jax.jit(_flash_grad).lower(*avals).as_text())
+        return out
+    a, b = texts()
+    assert a != b
+    with compile_cache.stable_locations():
+        a, b = texts()
+    assert a == b and a.count("tpu_custom_call") == 3
+
+
 def _paged_avals(sh):
     S, pages, psz, MP = 8, 64, 128, 9
     pool = jax.ShapeDtypeStruct((pages, HKV, psz, D), jnp.bfloat16,

@@ -3,6 +3,8 @@ reads or writes it (ROADMAP D16): a network that is only ever stepped by
 ``parallel.TrainStep``, which differentiates inside its own program,
 never holds one; the tape, ``grad()``, ``Trainer`` and ``zero_grad``
 behave as they did."""
+import gc
+
 import jax
 import jax.numpy as jnp
 import numpy as onp
@@ -31,6 +33,9 @@ def _batch():
 
 
 def _live_bytes():
+    # (a TrainStep and its jitted step refer to each other: what earlier
+    # tests of this process left is freed only when the collector runs)
+    gc.collect()
     return sum(a.nbytes for a in jax.live_arrays())
 
 

@@ -438,6 +438,63 @@ def test_train_step_spans(tmp_path):
     assert dispatches[0] in _children(spans, builds[0])   # the compile
 
 
+def test_train_step_plan_span(tmp_path, monkeypatch):
+    # a step with marked blocks on a device that reports its memory
+    # (the CPU does not: the numbers are given) plans which of them are
+    # made again under mx.train.step.plan, inside the build span of the
+    # first call with batches of a signature and before its dispatch;
+    # the span's arguments are the plan's record
+    from mxnet_tpu.models import TransformerLM
+    from mxnet_tpu.ndarray.ndarray import NDArray
+    from mxnet_tpu.parallel import train_step as ts
+    monkeypatch.setattr(ts, "_device_memory", lambda device: (
+        (1 << 40) + ts._RESERVE_BYTES, (1 << 40) - (1 << 30)))
+    net = TransformerLM(tiny_config(dim=64, n_heads=2, n_kv_heads=2,
+                                    hidden_dim=96, n_layers=2,
+                                    vocab_size=256, max_seq_len=32))
+    for blk in net.layers:
+        blk.recompute()
+    net.initialize()
+    tok = NDArray(jnp.zeros((1, 32), jnp.int32))
+    wide = NDArray(jnp.zeros((2, 32), jnp.int32))
+    step = parallel.TrainStep(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(),
+        mx.optimizer.AdamW(learning_rate=1e-3), mesh=None)
+    with jax.profiler.trace(str(tmp_path)):
+        for batch in (tok, tok, wide, tok):
+            step(batch, batch)
+    spans = _mx_spans(tmp_path)
+    builds = [s for s in spans if s[0] == "mx.train.step.build"]
+    plans = [s for s in spans if s[0] == "mx.train.step.plan"]
+    dispatches = [s for s in spans if s[0] == "mx.train.step.dispatch"]
+    # a plan a signature, none for the shape that came back
+    assert len(builds) == len(plans) == 2 and len(dispatches) == 4
+    for build, plan, dispatch in zip(builds, plans,
+                                     (dispatches[0], dispatches[2])):
+        assert plan in _children(spans, build)
+        assert dispatch in _children(spans, build)
+        assert plan[2] <= dispatch[1]           # planned, then run
+    record = step.recompute_plan
+    assert record["spared"] == ["layer0", "layer1"]
+    args = plans[1][3]
+    assert args["spared"] == "layer0 layer1"
+    assert not args.get("made_again")       # (an empty argument is not kept)
+    for key in ("free_bytes", "temp_bytes_rung0", "temp_bytes", "compiles"):
+        assert int(args[key]) == record[key], key
+    assert str(args["from_file"]) in ("False", "0")
+    assert "refused" not in dispatches[0][3]
+    # a step with no marked block plans nothing
+    assert not [s for s in _mx_spans_of(_tiny_step, tmp_path / "plain")
+                if s[0] == "mx.train.step.plan"]
+
+
+def _mx_spans_of(make, trace_dir):
+    step, x, y = make()
+    with jax.profiler.trace(str(trace_dir)):
+        step(x, y)
+    return _mx_spans(trace_dir)
+
+
 def test_data_loader_spans(tmp_path):
     data = gluon.data.ArrayDataset(onp.arange(12, dtype="float32")
                                    .reshape(6, 2))
