@@ -21,8 +21,14 @@ application, its input and the attention kernel's output and row sums
 Under the scan a block is always made again (``parallel.TrainStep``
 spares only blocks applied in the step's own trace: the single-pass
 model's); kept, its interior would be stacked once a pass.
-The head never makes whole logits in the loss
-(``ops.nn.chunked_softmax_cross_entropy``).
+The head never makes whole logits in the loss, and makes each chunk of
+them once a step: the loss is linear in the exits' cross-entropies with
+weights the gate has made before the head runs, so ``loss`` forms the
+head's and the hidden states' gradients where the logits are alive
+(``ops.nn.weighted_chunked_softmax_cross_entropy``).  ``exit_parts``
+gives the per-token values for callers with cotangents of their own
+(``ops.nn.chunked_softmax_cross_entropy``, which makes the logits again
+in its backward).
 """
 from __future__ import annotations
 
@@ -31,7 +37,8 @@ import jax.numpy as jnp
 
 from ..gluon.nn import Dense
 from ..ndarray.ndarray import NDArray, apply_op
-from ..ops.nn import chunked_softmax_cross_entropy
+from ..ops.nn import (chunked_softmax_cross_entropy,
+                      weighted_chunked_softmax_cross_entropy)
 from .transformer import LlamaConfig, TransformerLM
 
 
@@ -124,27 +131,49 @@ class LoopedLM(TransformerLM):
         with jax.named_scope("exit_loss"):
             ce = apply_op(ces, [self.output.weight.data(), labels] + states,
                           name="exit_cross_entropy")
-            gates = [self.exit_gate(h) for h in states]
-            log_p = apply_op(
-                lambda *zs: exit_log_probs(
-                    jnp.stack([z.reshape(-1) for z in zs])),
-                gates, name="exit_log_probs")
+            log_p = self._exit_log_probs(states)
         return ce, log_p
+
+    def _exit_log_probs(self, states):
+        return apply_op(
+            lambda *zs: exit_log_probs(
+                jnp.stack([z.reshape(-1) for z in zs])),
+            [self.exit_gate(h) for h in states], name="exit_log_probs")
 
     def loss(self, tokens, labels, beta=0.05, chunk=2048, exits=False):
         """The scalar training loss; for ``TrainStep(forward_fn=...)``.
         With ``exits`` also what a training loop logs of the exits,
         ``(loss, {"ce": (P,), "p": (P,)})``: every exit's mean
         cross-entropy and mean exit probability (``TrainStep`` hands
-        such a pair through as the step's aux)."""
-        ce, log_p = self.exit_parts(tokens, labels, chunk)
+        such a pair through as the step's aux).
+
+        ``expected_exit_loss(*exit_parts(...))`` in value and gradients,
+        computed as ``sum_tn (p_t(n) / N) CE_t(n) + beta mean_n sum_t p_t
+        log p_t``: the gate runs first, and the four exits' rows go
+        through the head as one weighted sum, whose gradients are formed
+        in the forward (one call, so that the head's gradient is summed
+        in float32 over all exits and cast once).  The gate's gradient
+        comes through the weights and through the entropy term."""
+        states = self.hidden_states(tokens)
+        dim = self.cfg.dim
+
+        def expected(head, y, lp, *hs):
+            p = jnp.exp(lp)
+            total, ce = weighted_chunked_softmax_cross_entropy(
+                jnp.stack(hs).reshape(-1, dim), head,
+                jnp.tile(y.reshape(-1), len(hs)),
+                (p / lp.shape[1]).reshape(-1), chunk)
+            return (total + beta * jnp.mean(jnp.sum(p * lp, axis=0)),
+                    jnp.mean(ce.reshape(lp.shape), axis=1))
+
         with jax.named_scope("exit_loss"):
-            loss = apply_op(lambda c, lp: expected_exit_loss(c, lp, beta),
-                            [ce, log_p], name="expected_exit_loss")
+            log_p = self._exit_log_probs(states)
+            loss, mean_ce = apply_op(
+                expected, [self.output.weight.data(), labels, log_p] + states,
+                name="expected_exit_loss")
             if not exits:
                 return loss
             return loss, {
-                "ce": apply_op(lambda c: jnp.mean(c, axis=1), [ce],
-                               name="exit_mean_ce"),
+                "ce": mean_ce,
                 "p": apply_op(lambda lp: jnp.mean(jnp.exp(lp), axis=1),
                               [log_p], name="exit_mean_p")}

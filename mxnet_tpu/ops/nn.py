@@ -416,6 +416,31 @@ def _chunks(x, n, chunk):
     return x.reshape((n, chunk) + x.shape[1:])
 
 
+def _chunk_ce(logits, y):
+    """A chunk's cross-entropies and row logsumexps from its logits."""
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    picked = jnp.take_along_axis(logits, y[:, None], axis=1)[:, 0]
+    return lse - picked, lse
+
+
+def _chunk_dlogits(logits, lse, y, gc, dtype):
+    """``(softmax - onehot) * gc`` of a chunk from its float32 logits,
+    row logsumexps and row cotangents ``gc``, cast to ``dtype``."""
+    p = jnp.exp(logits - lse[:, None])
+    hit = lax.broadcasted_iota(jnp.int32, p.shape, 1) == y[:, None]
+    return ((p - hit.astype(p.dtype)) * gc[:, None]).astype(dtype)
+
+
+def _chunk_head_grads(dlogits, h, head):
+    """What a chunk gives the rows' gradient, ``dlogits @ head``, and
+    the head's, ``dlogits.T @ h`` in float32."""
+    dh = jnp.matmul(dlogits, head,
+                    preferred_element_type=jnp.float32).astype(h.dtype)
+    dw = lax.dot_general(dlogits, h, (((0,), (0,)), ((), ())),
+                         preferred_element_type=jnp.float32)
+    return dh, dw
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _chunked_ce(hidden, head, labels, chunk):
     return _chunked_ce_fwd(hidden, head, labels, chunk)[0]
@@ -427,10 +452,7 @@ def _chunked_ce_fwd(hidden, head, labels, chunk):
 
     def one(args):
         h, y = args
-        logits = _chunk_logits(h, head)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(logits, y[:, None], axis=1)[:, 0]
-        return lse - picked, lse
+        return _chunk_ce(_chunk_logits(h, head), y)
 
     ce, lse = lax.map(one, (_chunks(hidden, n, chunk),
                             _chunks(labels, n, chunk)))
@@ -445,14 +467,9 @@ def _chunked_ce_bwd(chunk, res, g):
     def one(dw, args):
         h, y, l, gc = args
         # the chunk's logits again: the only copy alive in the backward
-        p = jnp.exp(_chunk_logits(h, head) - l[:, None])
-        hit = lax.broadcasted_iota(jnp.int32, p.shape, 1) == y[:, None]
-        dlogits = ((p - hit.astype(p.dtype)) * gc[:, None]).astype(h.dtype)
-        dh = jnp.matmul(dlogits, head,
-                        preferred_element_type=jnp.float32).astype(h.dtype)
-        dw = dw + lax.dot_general(dlogits, h, (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        return dw, dh
+        dlogits = _chunk_dlogits(_chunk_logits(h, head), l, y, gc, h.dtype)
+        dh, dw_chunk = _chunk_head_grads(dlogits, h, head)
+        return dw + dw_chunk, dh
 
     dw, dh = lax.scan(one, jnp.zeros(head.shape, jnp.float32),
                       (_chunks(hidden, n, chunk), _chunks(labels, n, chunk),
@@ -471,9 +488,83 @@ def chunked_softmax_cross_entropy(hidden, head, labels, chunk=2048):
     worked on, and are computed again in the backward (which keeps the
     per-token logsumexp and sums the head's gradient in float32).
     Equals ``SoftmaxCrossEntropyLoss`` on the whole logits; ``chunk`` need
-    not divide N."""
+    not divide N.
+
+    For a caller that needs the per-token values under cotangents of its
+    own, which only the backward knows: four vocabulary-wide products a
+    chunk.  A loss that is a weighted sum of them with weights it has
+    before the head runs calls
+    :func:`weighted_chunked_softmax_cross_entropy`, which needs three."""
     return _chunked_ce(hidden, head, labels.astype(jnp.int32),
                        int(min(chunk, hidden.shape[0])))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _weighted_ce(hidden, head, labels, weights, chunk):
+    ce = _chunked_ce_fwd(hidden, head, labels, chunk)[0]
+    return jnp.sum(weights * ce), ce
+
+
+def _weighted_ce_fwd(hidden, head, labels, weights, chunk):
+    N = hidden.shape[0]
+    n = -(-N // chunk)
+
+    def one(dw, args):
+        h, y, w = args
+        logits = _chunk_logits(h, head)
+        ce, lse = _chunk_ce(logits, y)
+        # the rows' cotangents are the weights (times the sum's own, a
+        # scalar the backward applies): the gradients are formed here,
+        # while the chunk's logits are alive.  dlogits is written once:
+        # left to itself XLA computes it in the prologue of both
+        # products, tile after tile, from the float32 logits
+        with jax.named_scope("head_grad"):
+            dlogits = lax.optimization_barrier(
+                _chunk_dlogits(logits, lse, y, w, h.dtype))
+            dh, dw_chunk = _chunk_head_grads(dlogits, h, head)
+        return dw + dw_chunk, (ce, dh)
+
+    dw, (ce, dh) = lax.scan(
+        one, jnp.zeros(head.shape, jnp.float32),
+        (_chunks(hidden, n, chunk), _chunks(labels, n, chunk),
+         _chunks(weights, n, chunk)))
+    ce = ce.reshape(-1)[:N]
+    dh = dh.reshape(-1, hidden.shape[1])[:N]
+    return (jnp.sum(weights * ce), ce), (dh, dw.astype(head.dtype), ce)
+
+
+def _weighted_ce_bwd(chunk, res, cts):
+    dh, dw, ce = res
+    g = cts[0]      # the per-token output carries no gradient
+    return (g * dh).astype(dh.dtype), (g * dw).astype(dw.dtype), None, g * ce
+
+
+_weighted_ce.defvjp(_weighted_ce_fwd, _weighted_ce_bwd)
+
+
+def weighted_chunked_softmax_cross_entropy(hidden, head, labels, weights,
+                                           chunk=2048):
+    """``(sum_n weights[n] * CE_n, CE)`` of ``softmax(hidden @ head.T)``
+    without the whole logits: ``hidden`` (N, d), ``head`` (vocab, d),
+    ``labels`` (N,), float32 ``weights`` (N,) -> a float32 scalar and the
+    per-token cross-entropies (N,) as a value that carries no gradient
+    (for logging).  The arithmetic, chunk by chunk, is
+    :func:`chunked_softmax_cross_entropy`'s.
+
+    For a loss that is linear in the cross-entropies with weights known
+    before the head runs (``LoopedLM.loss``: the exit probabilities over
+    the number of tokens).  A row's cotangent is then its weight, so a
+    differentiated call forms ``dlogits = (softmax - onehot) * weights``
+    and from it the gradients to ``hidden`` and ``head`` in the forward,
+    from the chunk's logits while they are alive: three vocabulary-wide
+    products a chunk where the per-token function's forward and backward
+    make four.  The backward scales the two by the sum's cotangent; the
+    gradient to ``weights`` is that cotangent times the cross-entropies.
+    An undifferentiated call runs the per-token function's forward."""
+    total, ce = _weighted_ce(hidden, head, labels.astype(jnp.int32),
+                             weights.astype(jnp.float32),
+                             int(min(chunk, hidden.shape[0])))
+    return total, lax.stop_gradient(ce)
 
 
 def masked_softmax(x, mask, axis=-1, temperature=1.0):

@@ -1,6 +1,8 @@
 """The looped decoder (``mx.models.LoopedLM``), recomputation by block
 (``Block.recompute``) and the head whose logits are never whole
-(``chunked_softmax_cross_entropy``), at toy widths on the CPU, against
+(``chunked_softmax_cross_entropy`` and, for a loss that is a weighted
+sum, ``weighted_chunked_softmax_cross_entropy``), at toy widths on the
+CPU, against
 the plain reference the benchmark keeps
 (``benchmark/chip/reference/looped_decoder.py``, which imports nothing of
 ``mxnet_tpu``)."""
@@ -17,8 +19,9 @@ from mxnet_tpu import gluon, parallel
 from mxnet_tpu.models import (LoopedLM, TransformerLM, exit_log_probs,
                               expected_exit_loss, ouro_2p6b_config,
                               tiny_config)
-from mxnet_tpu.ndarray.ndarray import NDArray
-from mxnet_tpu.ops.nn import chunked_softmax_cross_entropy
+from mxnet_tpu.ndarray.ndarray import NDArray, apply_op
+from mxnet_tpu.ops.nn import (chunked_softmax_cross_entropy,
+                              weighted_chunked_softmax_cross_entropy)
 
 CHIP = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark", "chip")
@@ -73,12 +76,17 @@ def _step(net, chunk=CHUNK, **kw):
         net.loss(t, l, beta=MODEL["beta"], chunk=chunk), **kw)
 
 
+def _gradients(step):
+    """Every parameter's gradient of a step's one call, read from Adam's
+    first moment: ``m1 = (1 - beta1) g``."""
+    return {n: st[0] / (1 - MODEL["optimizer"]["beta1"])
+            for n, st in step._states.items()}
+
+
 def _first_gradients(step, tok, lab):
-    """The loss and every parameter's gradient of one step, read from
-    Adam's first moment: ``m1 = (1 - beta1) g``."""
+    """The loss and every parameter's gradient of one step."""
     loss = float(step(NDArray(tok), NDArray(lab)))
-    return loss, {n: st[0] / (1 - MODEL["optimizer"]["beta1"])
-                  for n, st in step._states.items()}
+    return loss, _gradients(step)
 
 
 @pytest.fixture(scope="module")
@@ -165,6 +173,62 @@ def test_the_step_hands_the_exits_through_as_aux(weights):
     for (name, p), q in zip(plain.collect_params().items(),
                             logged.collect_params().values()):
         assert bool(jnp.all(p.data()._data == q.data()._data)), name
+
+
+def _loss_from_parts(net, tokens, labels, exits):
+    """The loss as its parts spell it: per-token cross-entropies whose
+    cotangents the backward brings (the head's logits made again)."""
+    ce, log_p = net.exit_parts(tokens, labels, chunk=CHUNK)
+    loss = apply_op(lambda c, lp: expected_exit_loss(c, lp, MODEL["beta"]),
+                    [ce, log_p])
+    if not exits:
+        return loss
+    return loss, {"ce": apply_op(lambda c: c.mean(1), [ce]),
+                  "p": apply_op(lambda lp: jnp.exp(lp).mean(1), [log_p])}
+
+
+@pytest.mark.parametrize("exits", [False, True], ids=["loss", "with_exits"])
+def test_the_loss_is_the_expected_exit_loss_of_its_parts(weights, exits):
+    """``loss`` forms the head's gradients in the forward, from weights
+    the gate has already made: value, aux and every leaf's gradient are
+    those of ``expected_exit_loss(*exit_parts(...))``."""
+    tok, lab = _batch()
+    got, want = {}, {}
+    for out, forward in (
+            (got, lambda net, t, l: net.loss(
+                t, l, beta=MODEL["beta"], chunk=CHUNK, exits=exits)),
+            (want, lambda net, t, l: _loss_from_parts(net, t, l, exits))):
+        step = parallel.TrainStep(_net(weights), None, _adamw(), mesh=None,
+                                  forward_fn=forward)
+        res = step(NDArray(tok), NDArray(lab))
+        out["loss"], out["aux"] = res if exits else (res, {})
+        out["grads"] = _gradients(step)
+    onp.testing.assert_allclose(float(got["loss"]), float(want["loss"]),
+                                rtol=1e-6)
+    assert set(got["aux"]) == set(want["aux"]) == \
+        ({"ce", "p"} if exits else set())
+    for k, v in want["aux"].items():
+        assert got["aux"][k].shape == (4,)
+        onp.testing.assert_allclose(got["aux"][k]._data, v._data, rtol=1e-6)
+    assert set(got["grads"]) == set(want["grads"])
+    for name, v in want["grads"].items():
+        # the same float32 products summed in another order
+        assert float(jnp.linalg.norm(got["grads"][name] - v)
+                     / jnp.linalg.norm(v)) < 1e-5, name
+
+
+def test_the_eager_tape_gives_the_traced_steps_gradients(weights):
+    tok, lab = _batch()
+    loss, traced = _first_gradients(_step(_net(weights)), tok, lab)
+    net = _net(weights)
+    with mx.autograd.record():
+        eager = net.loss(NDArray(tok), NDArray(lab), beta=MODEL["beta"],
+                         chunk=CHUNK)
+    eager.backward()
+    onp.testing.assert_allclose(float(eager), loss, rtol=1e-6)
+    for name, p in net.collect_params().items():
+        assert float(jnp.linalg.norm(p.grad()._data - traced[name])
+                     / jnp.linalg.norm(traced[name])) < 1e-5, name
 
 
 def test_a_shared_weight_gets_the_sum_of_its_four_uses(weights):
@@ -292,24 +356,105 @@ def test_chunked_cross_entropy_is_softmax_cross_entropy(chunk):
         onp.testing.assert_allclose(g, v, rtol=1e-4, atol=1e-6)
 
 
-def test_chunked_cross_entropy_holds_one_chunk_of_logits():
+def _weighted_sum(h, w, y, chunk=256):
+    return weighted_chunked_softmax_cross_entropy(
+        h, w, y, jnp.ones(h.shape[:1], jnp.float32), chunk)[0]
+
+
+@pytest.mark.parametrize("fn", [
+    lambda h, w, y: chunked_softmax_cross_entropy(h, w, y, 256).sum(),
+    _weighted_sum], ids=["per_token", "weighted_sum"])
+def test_chunked_cross_entropy_holds_one_chunk_of_logits(fn):
     # 4,096 tokens over a 4,096-word vocabulary: whole float32 logits
     # are 67 MB, a chunk of 256 is 4 MB; forward and backward together
-    # stay far under the whole logits
+    # stay far under the whole logits (the weighted sum, which forms
+    # the gradients in its forward, holds the head's summed gradient
+    # and the rows' besides: 1 MB each here)
     h = jax.ShapeDtypeStruct((4096, 64), jnp.float32)
     w = jax.ShapeDtypeStruct((4096, 64), jnp.float32)
     y = jax.ShapeDtypeStruct((4096,), jnp.int32)
 
     def temp(fn):
-        return jax.jit(jax.grad(lambda h, w, y: fn(h, w, y).sum(), (0, 1))) \
+        return jax.jit(jax.grad(fn, (0, 1))) \
             .lower(h, w, y).compile().memory_analysis().temp_size_in_bytes
 
-    chunked = temp(lambda h, w, y: chunked_softmax_cross_entropy(h, w, y,
-                                                                 256))
     whole = temp(lambda h, w, y: -jnp.take_along_axis(
-        jax.nn.log_softmax(h @ w.T), y[:, None], 1)[:, 0])
+        jax.nn.log_softmax(h @ w.T), y[:, None], 1)[:, 0].sum())
     assert whole > 4096 * 4096 * 4
-    assert chunked < 4096 * 4096 * 4 / 4
+    assert temp(fn) < 4096 * 4096 * 4 / 4
+
+
+@pytest.mark.parametrize("cotangent", [1.0, -2.5], ids=["one", "scaled"])
+@pytest.mark.parametrize("chunk", [24, 64, 100], ids=["ragged", "whole",
+                                                      "larger"])
+def test_weighted_cross_entropy_is_the_weighted_sum_of_the_per_token(
+        chunk, cotangent):
+    rs = onp.random.RandomState(5)
+    h = jnp.asarray(rs.randn(64, 32), jnp.float32)
+    w = jnp.asarray(rs.randn(200, 32) * 0.3, jnp.float32)
+    y = jnp.asarray(rs.randint(0, 200, 64))
+    weight = jnp.asarray(rs.rand(64), jnp.float32)
+
+    def early(h, w, weight):
+        return cotangent * weighted_chunked_softmax_cross_entropy(
+            h, w, y, weight, chunk)[0]
+
+    def late(h, w, weight):
+        return cotangent * (chunked_softmax_cross_entropy(h, w, y, chunk)
+                            * weight).sum()
+
+    total, ce = weighted_chunked_softmax_cross_entropy(h, w, y, weight,
+                                                       chunk)
+    onp.testing.assert_allclose(cotangent * total, late(h, w, weight),
+                                rtol=1e-6)
+    onp.testing.assert_allclose(
+        ce, chunked_softmax_cross_entropy(h, w, y, chunk), rtol=1e-6)
+    value, got = jax.value_and_grad(early, (0, 1, 2))(h, w, weight)
+    onp.testing.assert_allclose(value, cotangent * total, rtol=1e-6)
+    for g, v in zip(got, jax.grad(late, (0, 1, 2))(h, w, weight)):
+        assert g.shape == v.shape
+        onp.testing.assert_allclose(g, v, rtol=1e-5, atol=1e-6)
+    # the per-token output is a value: nothing comes back through it
+    through = jax.grad(lambda h: weighted_chunked_softmax_cross_entropy(
+        h, w, y, weight, chunk)[1].sum())(h)
+    assert not bool(jnp.any(through))
+
+
+def _products(jaxpr, width):
+    """``dot_general``s of a jaxpr, loops' bodies counted once, that
+    have an operand or a result ``width`` wide."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general" and any(
+                width in v.aval.shape for v in eqn.invars + eqn.outvars):
+            n += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _products(sub, width)
+    return n
+
+
+def test_weighted_cross_entropy_makes_a_chunks_logits_once():
+    """Three vocabulary-wide products a chunk (logits, the rows'
+    gradient, the head's), all in the forward rule; the per-token
+    function makes four, three of them in its backward."""
+    rs = onp.random.RandomState(1)
+    h = jnp.asarray(rs.randn(64, 32), jnp.float32)
+    w = jnp.asarray(rs.randn(200, 32), jnp.float32)
+    y = jnp.asarray(rs.randint(0, 200, 64))
+    weight = jnp.asarray(rs.rand(64), jnp.float32)
+
+    def early(h, w, weight):
+        return weighted_chunked_softmax_cross_entropy(h, w, y, weight, 16)[0]
+
+    def late(h, w, weight):
+        return (chunked_softmax_cross_entropy(h, w, y, 16) * weight).sum()
+
+    for fn, whole, backward in ((early, 3, 0), (late, 4, 3)):
+        assert _products(jax.make_jaxpr(jax.grad(fn, (0, 1, 2)))(
+            h, w, weight).jaxpr, 200) == whole
+        pullback = jax.vjp(fn, h, w, weight)[1]
+        assert _products(jax.make_jaxpr(pullback)(jnp.float32(1)).jaxpr,
+                         200) == backward
 
 
 def test_npx_chunked_cross_entropy_records_on_the_tape():
@@ -370,7 +515,9 @@ def test_an_unmarked_network_lowers_as_before():
     # again is no mark
     text = _lowered(False).as_text(debug_info=True)
     assert "checkpoint" not in text and "rematted" not in text
-    assert "optimization_barrier" not in text
+    # the one barrier left is the exit loss's own, round dlogits
+    assert text.count("stablehlo.optimization_barrier") == 1
+    assert 'loc("head_grad/optimization_barrier"' in text
     marked = _lowered(True).as_text(debug_info=True)
     assert "rematted_computation" in marked
     mx.np.random.seed(11)

@@ -187,6 +187,21 @@ def test_looped_step_carries_loop_exit_loss_and_block_names(looped_names):
                and n.endswith("/norm/rsqrt") for n in looped_names)
     assert any(n.startswith(fwd + "exit_loss/exit_gate/")
                for n in looped_names)
+    # the loss forms the head's and the hidden states' gradients in the
+    # forward, chunk by chunk, under a scope of their own; what is left
+    # under the backward's exit_loss is the gate's and the exit
+    # distribution's backward and two scalings: no product of the head
+    grads = {n for n in looped_names if "/head_grad/" in n}
+    assert grads and all(n.startswith(fwd + "exit_loss/while/body/")
+                         for n in grads)
+    assert any(n.endswith("/head_grad/dot_general") for n in grads)
+    assert any(n.startswith(fwd + "exit_loss/while/body/")
+               and n.endswith("/dot_general") and n not in grads
+               for n in looped_names)          # the chunk's logits
+    assert not any(n.startswith(bwd + "exit_loss/while/")
+                   for n in looped_names)
+    assert any(n.startswith(bwd + "exit_loss/exit_gate/")
+               for n in looped_names)
     scoped = sum(n.startswith((fwd + "loop/", bwd + "loop/",
                                fwd + "exit_loss/", bwd + "exit_loss/",
                                fwd + "tok_embeddings/",
