@@ -17,6 +17,10 @@ Architecture (see SURVEY.md for the full mapping):
 """
 from __future__ import annotations
 
+import time as _time
+
+_IMPORT_T0 = _time.monotonic()    # mx.start.import, recorded below
+
 __version__ = "2.0.0.tpu0"
 
 import os as _os
@@ -45,6 +49,9 @@ from . import ops
 # tolerate partial builds while the framework grows.
 from . import base  # noqa: E402
 from .util import is_np_array, is_np_shape, set_np, use_np  # noqa: E402
+from . import profiler  # noqa: E402  (ndarray has imported it already)
+
+profiler.record_build_span("mx.start.import", _IMPORT_T0, module=__name__)
 
 
 def __getattr__(name):
@@ -60,7 +67,6 @@ def __getattr__(name):
         "io": ".io",
         "parallel": ".parallel",
         "amp": ".amp",
-        "profiler": ".profiler",
         "telemetry": ".telemetry",
         "flightrec": ".flightrec",
         "fault": ".fault",

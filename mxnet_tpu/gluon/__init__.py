@@ -1,4 +1,8 @@
 """``mx.gluon`` — the user-facing NN API (reference: ``python/mxnet/gluon/``)."""
+import time as _time
+
+_IMPORT_T0 = _time.monotonic()    # mx.start.import, recorded below
+
 from . import loss, utils
 from .block import Block, HybridBlock
 from .parameter import Constant, Parameter, DeferredInitializationError
@@ -6,6 +10,10 @@ from .symbol_block import SymbolBlock
 from .trainer import Trainer
 from . import nn
 from . import rnn
+from .. import profiler as _profiler
+
+_profiler.record_build_span("mx.start.import", _IMPORT_T0,
+                            module=__name__)
 
 
 def __getattr__(name):

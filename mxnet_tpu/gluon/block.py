@@ -93,6 +93,12 @@ def keeping(kept=(), seen=None):
         _KEEPING.kept, _KEEPING.seen, _KEEPING.trace = was
 
 
+def _placed_bytes(params):
+    """Bytes of the parameters of ``params`` that hold an array."""
+    return sum(p._data._data.nbytes for p in params.values()
+               if p._data is not None)
+
+
 class Block:
     """Base class for all neural network layers and models."""
 
@@ -170,12 +176,15 @@ class Block:
     def initialize(self, init=None, device=None, ctx=None, verbose=False,
                    force_reinit=False):
         default_init = init or init_mod.Uniform()
-        for name, p in self.collect_params().items():
-            if p._name in ("param",):
-                p._name = name
-            p.initialize(init=p.init, ctx=device if device is not None
-                         else ctx, default_init=default_init,
-                         force_reinit=force_reinit)
+        params = self.collect_params()
+        with _profiler.build_span("mx.gluon.initialize") as span:
+            for name, p in params.items():
+                if p._name in ("param",):
+                    p._name = name
+                p.initialize(init=p.init, ctx=device if device is not None
+                             else ctx, default_init=default_init,
+                             force_reinit=force_reinit)
+            span.set(params=len(params), bytes=_placed_bytes(params))
 
     def hybridize(self, active=True, **kwargs):
         """Plain Blocks cascade to children (reference ``block.py``
@@ -215,10 +224,11 @@ class Block:
     reset_device = reset_ctx
 
     def cast(self, dtype):
-        for p in self.collect_params().values():
-            p.cast(dtype)
-        for child in self._children.values():
-            pass  # params already covered by collect_params
+        params = self.collect_params()
+        with _profiler.build_span("mx.gluon.cast") as span:
+            for p in params.values():
+                p.cast(dtype)
+            span.set(params=len(params), bytes=_placed_bytes(params))
         return self
 
     def apply(self, fn):
@@ -385,7 +395,6 @@ class Block:
 
     # -- call -------------------------------------------------------------
     def __call__(self, *args, **kwargs):
-        prof_t0 = _profiler._now_us() if _profiler._STEP else None
         for hook in self._forward_pre_hooks.values():
             hook(self, args)
         with jax.named_scope(self._scope_name or type(self).__name__):
@@ -397,10 +406,6 @@ class Block:
                 out = self.forward(*args, **kwargs)
         for hook in self._forward_hooks.values():
             hook(self, args, out)
-        if prof_t0 is not None:
-            _profiler.record_duration(
-                "forward::%s" % type(self).__name__, "gluon", prof_t0,
-                _profiler._now_us() - prof_t0)
         return out
 
     def forward(self, *args, **kwargs):

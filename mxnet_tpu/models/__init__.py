@@ -4,6 +4,10 @@ The reference's model zoo stops at CNN-era vision models plus fused-RNN NLP
 primitives; BASELINE.json's stretch config (Llama-3-8B long-context) needs a
 transformer LM with TP/SP/CP shardings — that lives here.
 """
+import time as _time
+
+_IMPORT_T0 = _time.monotonic()    # mx.start.import, recorded below
+
 from .bert import (BertConfig, BERTForPretrain, BERTModel, bert_base_config,
                    bert_tiny_config)
 from .transformer import (TransformerLM, TransformerBlock, LlamaConfig,
@@ -12,3 +16,7 @@ from .transformer import (TransformerLM, TransformerBlock, LlamaConfig,
 from .looped import LoopedLM, exit_log_probs, expected_exit_loss
 from .evabyte import EvaByteLM, chunk_summaries, eva_attention
 from .kv_cache import CacheSpec, CacheView, init_pools
+from .. import profiler as _profiler
+
+_profiler.record_build_span("mx.start.import", _IMPORT_T0,
+                            module=__name__)

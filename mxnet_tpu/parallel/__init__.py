@@ -17,6 +17,10 @@ collectives over a ``jax.sharding.Mesh`` — and parallelism strategies are
 - pipeline parallel  = stage-sharded ``shard_map`` microbatch loop over
   the ``pp`` axis (``mxnet_tpu.parallel.pipeline``)
 """
+import time as _time
+
+_IMPORT_T0 = _time.monotonic()    # mx.start.import, recorded below
+
 from .mesh import (create_mesh, current_mesh, mesh_scope, local_mesh,
                    shrink_mesh, grow_mesh)
 from .sharding import (P, apply_sharding_rules, kernel_shard,
@@ -29,3 +33,7 @@ from . import seq_data
 from .seq_data import SeqShardLoader, make_sequence_array, EpochPlan
 from .pipeline import pipeline_apply, pipeline_vjp
 from .moe import switch_moe, moe_param_specs
+from .. import profiler as _profiler
+
+_profiler.record_build_span("mx.start.import", _IMPORT_T0,
+                            module=__name__)

@@ -210,14 +210,6 @@ class TrainStep:
         # produce the exact TPU executable text a real slice would run,
         # which is what tools/hlo_snapshot.py pins; ``__call__`` raises.
         self.aot = aot
-        self._params = list(net.collect_params().items())
-        for name, p in self._params:
-            if p._data is None:
-                raise ValueError(
-                    "TrainStep requires initialized parameters; %s is not "
-                    "(run one forward or pass concrete shapes)" % name)
-        self._trainable = [name for name, p in self._params
-                           if p.grad_req != "null"]
         self._t = 0
         self._batch_spec = batch_spec
         self._jitted = None     # every marked block made again: jit's own
@@ -227,7 +219,20 @@ class TrainStep:
         self.recompute_plan = None
         self._states = None
         self._shardings = None
-        self._setup()
+        with _profiler.build_span("mx.train.step.init") as span:
+            self._params = list(net.collect_params().items())
+            for name, p in self._params:
+                if p._data is None:
+                    raise ValueError(
+                        "TrainStep requires initialized parameters; %s is "
+                        "not (run one forward or pass concrete shapes)"
+                        % name)
+            self._trainable = [name for name, p in self._params
+                               if p.grad_req != "null"]
+            self._setup()
+            span.set(params=len(self._params), state_bytes=sum(
+                a.nbytes for arrays in self._states.values()
+                for a in arrays))
 
     # -- sharding & states -------------------------------------------------
     def _setup(self):
@@ -420,7 +425,10 @@ class TrainStep:
         # first call (where the step plans: with batches of a signature):
         # the program is built here and compiled in the dispatch the
         # build span encloses, or ahead of it under the plan span
-        build = _profiler.span("mx.train.step.build") \
+        build = _profiler.build_span(
+            "mx.train.step.build", signature=" ".join(
+                "%s:%s" % ("x".join(map(str, shape)), dtype)
+                for shape, dtype in _signature(batch_arrays))) \
             if self._built(batch_arrays) is None \
             else contextlib.nullcontext()
         with _profiler.step_span("mx.train.step", self._t + 1), build:
@@ -450,11 +458,20 @@ class TrainStep:
         if self._jitted is None:
             self._jitted = self._build(batch_arrays)
             self._plan_device = self._device_to_plan_for()
+            if self._plan_device is None:
+                return self._first_call
         built = self._built(batch_arrays)
         if built is None:
             built = self._plan(batch_arrays, args or self._args(
                 batch_arrays, max(self._t, 1)))
         return built
+
+    def _first_call(self, *args):
+        """The step that does not plan, the first time: jit traces,
+        lowers and compiles (or loads) inside this call."""
+        with _profiler.build_span("mx.train.step.trace", program="step") \
+                .compiles_as("mx.train.step.compile"):
+            return self._jitted(*args)
 
     def _step(self, batch_arrays):
         self._t += 1
@@ -532,13 +549,19 @@ class TrainStep:
             the last two where the compiler refuses it."""
             nonlocal compiles
             compiles += 1
-            jitted = self._build(
-                batch_arrays, [id(blocks[path]) for path in
-                               candidates[len(candidates) - k:]], seen)
-            with compile_cache.stable_locations():
-                lowered = jitted.lower(*args)
+            program = "step.spare%d" % k
+            with _profiler.build_span("mx.train.step.trace",
+                                      program=program):
+                jitted = self._build(
+                    batch_arrays, [id(blocks[path]) for path in
+                                   candidates[len(candidates) - k:]], seen)
+                with compile_cache.stable_locations():
+                    lowered = jitted.lower(*args)
             try:
-                executable = lowered.compile()
+                with _profiler.build_span("mx.train.step.compile",
+                                          program=program) as made:
+                    executable = lowered.compile()
+                    made.set(from_cache=bool(made.cache_loads))
             except _DEVICE_ERRORS as e:
                 if not k or not _out_of_memory(e):
                     raise
@@ -550,7 +573,7 @@ class TrainStep:
         def fits(tried):
             return tried[3] is not None and tried[3] <= room
 
-        with _profiler.span("mx.train.step.plan") as span:
+        with _profiler.build_span("mx.train.step.plan") as span:
             candidates, readings, best = None, {}, None
             hint = None if refused else _read_plan(file, key, blocks)
             if refused:

@@ -31,7 +31,13 @@ Two recording planes, on two clocks:
    ``mx.profiler`` itself runs, the ``mx.*`` spans emit events with real
    begin/end timestamps; ``dump()`` writes them as valid
    chrome://tracing JSON (``ph:"X"`` complete events plus ``ph:"C"``
-   counter events) next to the XLA trace dir.
+   counter events) next to the XLA trace dir.  **The spans of the build
+   path record there in every process**, profiler running or not
+   (:func:`build_span`): what a process enters only when it builds
+   something — importing the package, placing parameters, making the
+   optimizer's state, tracing, lowering and compiling a step — and
+   never once a step.  :func:`build_spans` hands them out on
+   ``time.monotonic``'s axis; the record is bounded.
 
 Hot paths are gated by module-level flags (``_IMPERATIVE``, ``_KVSTORE``,
 ``_STEP``, ``_DATA``, ``_MEMORY``, ``_SPAN``) recomputed on every config/state
@@ -62,6 +68,8 @@ import jax
 
 # epoch for all host-plane timestamps: microseconds since module import
 _EPOCH = time.perf_counter()
+# the same instant on time.monotonic's axis, which build_spans() answers on
+_MONO_EPOCH = time.monotonic()
 
 
 def _now_us():
@@ -84,6 +92,7 @@ _state = {
                       # ("i", name, cat, ts_us, args|None)
     "counters": {},   # name -> latest cumulative value (exported at dump)
     "dropped": 0,     # events discarded after the buffer cap was hit
+    "build_spans": 0,  # build-path spans among the events (cat "build")
 }
 
 # One recorder lock for every ``_state`` touch.  The host plane is fed
@@ -109,6 +118,7 @@ def _append(ev):
                 _write_trace(_state["config"].get("filename",
                                                   "profile.json"))
                 events.clear()
+                _state["build_spans"] = 0
             else:
                 _state["dropped"] += 1
                 return
@@ -116,7 +126,7 @@ def _append(ev):
 
 # -- fast gating flags (one attribute read on the instrumented hot path) --
 _IMPERATIVE = False   # per-op dispatch timing in ndarray.apply_op
-_STEP = False         # Trainer phases, Block forward, autograd backward
+_STEP = False         # Trainer phases, autograd backward
 _KVSTORE = False      # KVStore byte/time counters
 _DATA = False         # DataLoader / DataIter throughput
 _MEMORY = False       # device memory_stats() counter sampling
@@ -379,6 +389,7 @@ def dumps(reset=False, format="table"):  # noqa: A002
             _state["counters"].clear()
             _state["events"].clear()
             _state["dropped"] = 0
+            _state["build_spans"] = 0
         return "\n".join(lines)
 
 
@@ -389,6 +400,7 @@ def reset():
         _state["counters"].clear()
         _state["events"].clear()
         _state["dropped"] = 0
+        _state["build_spans"] = 0
 
 
 class _Scope:
@@ -583,6 +595,185 @@ def step_span(name, step, **args):
     ``step_num`` for the profiler's per-step analysis."""
     return _Scope(name, cat="step", args=dict(args, step_num=step),
                   step=True, gated=True)
+
+
+# ----------------------------------------------------------------------
+# the build path: spans that record in every process
+# ----------------------------------------------------------------------
+#: build-path spans the recorder keeps; later ones count as dropped
+_BUILD_SPANS = 4096
+
+
+class _Building(threading.local):
+    """A thread's open build spans, the outermost first, and whether jax
+    has just said that the program it is compiling came from the
+    persistent cache."""
+
+    def __init__(self):
+        self.open = []
+        self.loaded = False
+
+
+_building = _Building()
+
+#: what jax times of its own tracing, lowering, compiling and loading
+#: from the persistent cache, by the key a build span sums it under
+_JAX_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+
+
+def _record_build(name, t0_us, t1_us, args):
+    with _rec_lock:
+        if _state["build_spans"] >= _BUILD_SPANS:
+            _state["dropped"] += 1
+            return
+        _state["build_spans"] += 1
+        record_duration(name, "build", t0_us, t1_us - t0_us, args or None)
+
+
+class _BuildSpan:
+    """A span of the build path: a ``TraceAnnotation`` like
+    :func:`span`'s, and a host-plane record whether or not a profiler
+    runs.  While it is open on a thread, what jax traces, lowers,
+    compiles or loads from the persistent cache on that thread is added
+    to its sums (and to those of the build spans round it), which join
+    its arguments at exit: ``trace_s``, ``lower_s``, ``compile_s``,
+    ``cache_load_s`` (each the union of jax's own intervals: a jitted
+    function traced inside another's trace counts once), ``compiles``,
+    ``cache_loads``.  A span that saw none carries none, and an argument
+    the span's own code has set under one of these names stands."""
+
+    def __init__(self, name, args):
+        self._name = name
+        self._args = args
+        self._seen = {}         # phase -> [(t0_us, t1_us)], disjoint
+        self._compiles_as = None
+
+    def compiles_as(self, name):
+        """Record each program jax compiles or loads right inside this
+        span as a child span ``name`` (``program``, ``from_cache``),
+        from jax's own timing of it: for a ``jax.jit`` that compiles
+        inside its first call, where no code of ours stands round the
+        compile."""
+        self._compiles_as = name
+        return self
+
+    def __enter__(self):
+        self._ann = jax.profiler.TraceAnnotation(self._name, **self._args)
+        self._ann.__enter__()
+        _building.open.append(self)
+        self._t0 = _now_us()
+        return self
+
+    def set(self, **args):
+        self._ann.set_metadata(**args)
+        self._args = dict(self._args, **args)
+
+    @property
+    def cache_loads(self):
+        """Programs jax has loaded from the persistent cache inside this
+        span so far."""
+        return len(self._seen.get("cache_load", ()))
+
+    def _saw(self, phase, t0_us, t1_us):
+        seen = self._seen.setdefault(phase, [])
+        while seen and seen[-1][0] >= t0_us:   # it ran inside this one
+            seen.pop()
+        seen.append((t0_us, t1_us))
+
+    def __exit__(self, *exc):
+        t1 = _now_us()
+        _building.open.pop()
+        if self._seen:
+            sums = {phase + "_s": sum(
+                b - a for a, b in self._seen.get(phase, ())) * 1e-6
+                for phase in _JAX_PHASES.values()}
+            sums["compiles"] = len(self._seen.get("compile", ()))
+            sums["cache_loads"] = self.cache_loads
+            # (the plan span's own ``compiles``, the programs it tried,
+            # stands)
+            self.set(**{k: v for k, v in sums.items()
+                        if k not in self._args})
+        self._ann.__exit__(*exc)
+        _record_build(self._name, self._t0, t1, self._args)
+
+
+def _on_jax_duration(event, duration, fun_name=None, **_):
+    """jax's monitoring listener: called when jax has traced, lowered,
+    compiled or loaded something, never by a cached call."""
+    phase = _JAX_PHASES.get(event)
+    if phase is None:
+        return
+    if phase == "cache_load":
+        # jax times the whole of a program's compile-or-load next, on
+        # this thread: that event is the load, key and all
+        _building.loaded = True
+        return
+    if phase == "compile" and _building.loaded:
+        _building.loaded = False
+        phase = "cache_load"
+    is_program = phase in ("compile", "cache_load")
+    open_ = _building.open
+    if not open_:
+        if is_program:      # the benchmark's own jits, a user's
+            counter_bump("start::other_compile_s", duration)
+            counter_bump("start::other_programs", 1)
+        return
+    t1 = _now_us()
+    t0 = t1 - duration * 1e6
+    for span_ in open_:
+        span_._saw(phase, t0, t1)
+    if is_program and open_[-1]._compiles_as:
+        _record_build(open_[-1]._compiles_as, t0, t1,
+                      {"program": fun_name,
+                       "from_cache": phase == "cache_load"})
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def build_span(name, **args):
+    """:func:`span` for the build path — what a process enters only when
+    it builds something, never once a step: recorded on the host plane
+    in every process, bounded (``_BUILD_SPANS``, then counted with the
+    dropped events), whether or not ``mx.profiler`` or a ``jax.profiler``
+    session runs; ``dump()`` writes it with the rest, :func:`build_spans`
+    reads it back.  See :class:`_BuildSpan` for what it sums."""
+    return _BuildSpan(name, args)
+
+
+def record_build_span(name, t0, **args):
+    """The build-path span ``name`` from ``t0`` (``time.monotonic()``
+    seconds) to now: for what cannot stand in a ``with`` — a package's
+    import (``mx.start.import``, ``module``), whose clock is read before
+    this module is there."""
+    _record_build(name, (t0 - _MONO_EPOCH) * 1e6, _now_us(), args)
+
+
+def build_spans():
+    """The build-path spans recorded so far, by start: ``[{"name", "t0",
+    "t1", "parent", "args"}]``, ``t0`` and ``t1`` in ``time.monotonic()``
+    seconds, ``parent`` the index in this list of the build-path span
+    of the same thread that encloses it (None at the top)."""
+    with _rec_lock:
+        events = [e for e in _state["events"]
+                  if e[0] == "X" and e[2] == "build"]
+    events.sort(key=lambda e: (e[3], -e[4]))
+    spans, open_ = [], {}
+    for _, name, _, ts, dur, tid, args in events:
+        stack = open_.setdefault(tid, [])
+        while stack and stack[-1][1] < ts + dur:
+            stack.pop()
+        spans.append({"name": name, "t0": _MONO_EPOCH + ts * 1e-6,
+                      "t1": _MONO_EPOCH + (ts + dur) * 1e-6,
+                      "parent": stack[-1][0] if stack else None,
+                      "args": dict(args or {})})
+        stack.append((len(spans) - 1, ts + dur))
+    return spans
 
 
 # reference parity: MXNET_PROFILER_AUTOSTART starts the profiler in the

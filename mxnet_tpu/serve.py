@@ -1197,7 +1197,7 @@ class WarmPool:
         f32 = lambda *shape: aval(shape, jnp.float32, shard_rep)  # noqa: E731
 
         def compiled(program, fn, donate, *avals):
-            with _profiler.span("mx.serve.compile", program=program):
+            with _profiler.build_span("mx.serve.compile", program=program):
                 return jax.jit(fn, donate_argnums=donate).lower(
                     *avals).compile()
 

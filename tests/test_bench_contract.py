@@ -98,6 +98,27 @@ def test_the_resnet_cell_is_found_by_its_names():
     assert limits["limits"]
 
 
+def test_every_per_layer_metric_has_its_file_and_its_reader():
+    bench = common.load_json(ROOT, "BENCHMARK.json")
+    cells = {w["name"] for w in bench["workloads"]}
+    for m in bench["per_layer"]:
+        spec = common.load_json(CHIP, "metrics", m["name"] + ".json")
+        mod, fn = spec["reader"].split(".")
+        assert callable(getattr(common.module("readers", mod), fn)), m
+        assert set(m["workloads"]) <= cells
+    setup = [m for m in bench["per_layer"] if m["moves"] == "setup_s"]
+    assert [m["name"] for m in setup] == [
+        "import_s.setup", "state_s.setup", "step_trace_s.setup",
+        "step_compile_s.setup", "step_programs.setup"]
+    for m in setup:
+        # the program's own record, read in every cell
+        assert m["source"] == "program_span" and m["better"] == "lower"
+        assert set(m["workloads"]) == cells
+        spec = common.load_json(CHIP, "metrics", m["name"] + ".json")
+        assert spec["reader"].startswith("start.") and spec["match_note"]
+        assert spec["until"] == "mx.train.step.build"
+
+
 def test_resnet_model_flops_are_the_published_count():
     """What ``model_mfu_pct.train`` divides by: three times the forward
     of 4.089 GMAC an image (He et al.'s network with the stride on the

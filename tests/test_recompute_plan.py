@@ -539,6 +539,76 @@ def test_a_start_that_finds_a_fitting_plan_compiles_one_program(
     assert len(_plan_files(plan_dir)) == 2
 
 
+def test_the_plan_nests_its_tries_as_build_spans(monkeypatch, plan_dir):
+    # with no profiler running: plan > a trace and a compile a step
+    # tried, inside the build span of the first call with a signature
+    from mxnet_tpu import profiler
+    assert profiler.state() == "stop"
+    _device_with(monkeypatch, 1 << 30)
+
+    def start(*batches):
+        profiler.reset()
+        step, x, y = _eva(2)
+        for _ in batches or (0,):
+            step(x, y)
+        spans = profiler.build_spans()
+        return step, spans, lambda name: [s for s in spans
+                                          if s["name"] == name]
+
+    step, spans, named = start(0, 1)    # the second call builds nothing
+    (build,), (plan,) = named("mx.train.step.build"), \
+        named("mx.train.step.plan")
+    assert spans[plan["parent"]] is build
+    traces, compiles = named("mx.train.step.trace"), \
+        named("mx.train.step.compile")
+    assert [t["args"]["program"] for t in traces] \
+        == [c["args"]["program"] for c in compiles] \
+        == ["step.spare0", "step.spare2"]
+    for t, c in zip(traces, compiles):
+        assert spans[t["parent"]] is plan and spans[c["parent"]] is plan
+        assert t["t1"] <= c["t0"]                   # lowered, then compiled
+        assert t["args"]["trace_s"] > 0 and t["args"]["lower_s"] > 0
+        assert t["args"]["compiles"] + t["args"]["cache_loads"] == 0
+        assert c["args"]["compiles"] + c["args"]["cache_loads"] == 1
+        assert c["args"]["from_cache"] == bool(c["args"]["cache_loads"])
+    # the plan's own record stands in its arguments: the programs it
+    # tried; what jax made of them is the build span's
+    assert plan["args"]["compiles"] == step.recompute_plan["compiles"] == 2
+    assert plan["args"]["from_file"] is False
+    args = build["args"]
+    assert args["compiles"] + args["cache_loads"] == 2
+    assert args["trace_s"] == pytest.approx(
+        sum(t["args"]["trace_s"] for t in traces))
+    assert 0 < args["compile_s"] + args["cache_load_s"] \
+        <= sum(c["t1"] - c["t0"] for c in compiles)
+
+    # the next start finds the plan: one program
+    step, spans, named = start()
+    (plan,) = named("mx.train.step.plan")
+    assert plan["args"]["from_file"] is True and plan["args"]["compiles"] == 1
+    (trace,), (compiled,) = named("mx.train.step.trace"), \
+        named("mx.train.step.compile")
+    assert trace["args"]["program"] == "step.spare2"
+    (build,) = named("mx.train.step.build")
+    assert build["args"]["compiles"] + build["args"]["cache_loads"] == 1
+
+    # a new signature appends one build, a signature that comes back none
+    x, y = _tokens((2, 48), seed=1, high=320), _tokens((2, 48, 8), seed=2,
+                                                       high=320)
+    small = step._batch_arrays((_tokens((1, 48), high=320),
+                                _tokens((1, 48, 8), high=320)))
+    step(x, y)
+    assert len([s for s in profiler.build_spans()
+                if s["name"] == "mx.train.step.build"]) == 2
+    step(*[NDArray(a) for a in small])
+    step(x, y)
+    builds = [s for s in profiler.build_spans()
+              if s["name"] == "mx.train.step.build"]
+    assert [b["args"]["signature"] for b in builds] \
+        == ["1x48:int32 1x48x8:int32", "2x48:int32 2x48x8:int32"]
+    profiler.reset()
+
+
 def test_a_plan_that_no_longer_fits_is_made_again_and_overwritten(
         monkeypatch, plan_dir):
     _device_with(monkeypatch, 1 << 30)

@@ -431,6 +431,9 @@ def test_warm_pool_compiles_sit_under_compile_spans(tmp_path):
     programs = [s[3]["program"] for s in _mx_spans(tmp_path)
                 if s[0] == "mx.serve.compile"]
     assert programs == ["decode", "prefill16", "prefill32", "copy"]
+    # build-path spans: on the host plane too, no mx.profiler running
+    assert [s["args"]["program"] for s in profiler.build_spans()
+            if s["name"] == "mx.serve.compile"][-4:] == programs
 
 
 # ----------------------------------------------------------------------
@@ -451,6 +454,14 @@ def test_train_step_spans(tmp_path):
     for s, d in zip(steps, dispatches):
         assert d in _children(spans, s)
     assert dispatches[0] in _children(spans, builds[0])   # the compile
+    # jit traces, lowers and compiles inside the first dispatch
+    (trace,) = [s for s in spans if s[0] == "mx.train.step.trace"]
+    assert trace in _children(spans, dispatches[0])
+    assert trace[3]["program"] == "step"
+    assert builds[0][3]["signature"] == "2x3x32x32:float32 2:int32"
+    assert float(builds[0][3]["trace_s"]) > 0
+    assert int(builds[0][3]["compiles"]) \
+        + int(builds[0][3]["cache_loads"]) == 1
 
 
 def test_train_step_plan_span(tmp_path, monkeypatch):
@@ -489,6 +500,16 @@ def test_train_step_plan_span(tmp_path, monkeypatch):
         assert plan in _children(spans, build)
         assert dispatch in _children(spans, build)
         assert plan[2] <= dispatch[1]           # planned, then run
+    traces = [s for s in spans if s[0] == "mx.train.step.trace"]
+    compiles = [s for s in spans if s[0] == "mx.train.step.compile"]
+    assert len(traces) == len(compiles) == 4        # two tries a plan
+    for plan in plans:
+        inside = _children(spans, plan)
+        assert [s[0] for s in inside] == ["mx.train.step.trace",
+                                         "mx.train.step.compile"] * 2
+        assert [s[3]["program"] for s in inside] \
+            == ["step.spare0"] * 2 + ["step.spare2"] * 2
+    assert all("from_cache" in c[3] for c in compiles)
     record = step.recompute_plan
     assert record["spared"] == ["layer0", "layer1"]
     args = plans[1][3]
@@ -508,6 +529,21 @@ def _mx_spans_of(make, trace_dir):
     with jax.profiler.trace(str(trace_dir)):
         step(x, y)
     return _mx_spans(trace_dir)
+
+
+@pytest.mark.parametrize("name", ["mx.gluon.initialize", "mx.gluon.cast",
+                                  "mx.train.step.init"])
+def test_build_path_spans_are_annotations_in_a_session(tmp_path, name):
+    with jax.profiler.trace(str(tmp_path)):
+        step, x, y = _tiny_step()
+        step.net.cast("float32")
+    (span,) = [s for s in _mx_spans(tmp_path) if s[0] == name]
+    assert int(span[3]["params"]) == 98
+    key = "state_bytes" if name.endswith("init") else "bytes"
+    assert int(span[3][key]) > 40e6
+    # and on the host plane all the same
+    assert [s for s in profiler.build_spans() if s["name"] == name]
+    profiler.reset()
 
 
 def test_data_loader_spans(tmp_path):
