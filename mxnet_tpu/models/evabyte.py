@@ -15,15 +15,15 @@ its causal chunked form, with a head of several byte predictors.
 
 The stack is :class:`~.transformer.TransformerBlock` as it is: the
 configuration selects the attention (``attn_impl="eva"``), the norms'
-unit offset and the float32 residual stream.  The attention is made of
-parts that are merged by logsumexp, each part a call of the flash
-kernels (``ops/pallas_ops.py``): the local part is the causal kernel
-over the windows as so many more heads, the remote part the non-causal
-kernel of a window's queries against the summaries before it.  Every
-block is marked as one that may be made again (``Block.recompute``): a
-block that is keeps each kernel call's output and row sums
-(``Block._recompute_keeps``), and ``parallel.TrainStep`` spares as many
-blocks, the last first, as it finds memory for on the device.
+unit offset and the float32 residual stream.  The attention is one
+call of EVA's own kernels (``ops/pallas_ops.py``
+``eva_flash_attention``): a query tile walks the summaries its window
+sees and then its window's keys, under one running softmax; a single
+window is the causal flash kernel.  Every block is marked as one that
+may be made again (``Block.recompute``): a block that is keeps the
+kernel's output and row sums (``Block._recompute_keeps``), and
+``parallel.TrainStep`` spares as many blocks, the last first, as it
+finds memory for on the device.
 """
 from __future__ import annotations
 
@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ndarray.ndarray import apply_op
-from ..ops.pallas_ops import flash_attention_with_lse, merge_attention_parts
+from ..ops.pallas_ops import eva_flash_attention, flash_attention
 from .transformer import LlamaConfig, TransformerLM
 
 
@@ -59,41 +59,23 @@ def eva_attention(q, k, v, mu, phi, window, chunk, shard=None):
     """EVA attention on (B, H, T, D), ``q`` and ``k`` after RoPE.  ``T``
     is at most ``window`` (one window: plain causal attention) or a
     multiple of it; ``window`` is a multiple of ``chunk``."""
-    B, H, T, D = q.shape
+    T = q.shape[2]
     if T <= window:
         window = T
     if T % window or window % chunk:
         raise ValueError(
             "eva attention: %d tokens are not whole windows of %d, or the "
             "window not whole chunks of %d" % (T, window, chunk))
-    nw, per = T // window, window // chunk
     with jax.named_scope("eva"):
-        with jax.named_scope("eva_local"):
-            # contiguous in T: a window is one more head, at no copy
-            local = [a.reshape(B, H * nw, window, D) for a in (q, k, v)]
-            o, lse = flash_attention_with_lse(*local, causal=True,
-                                              shard=shard)
-            o = o.reshape(B, H, nw, window, D)
-            lse = lse.reshape(B, H, nw, window)
-        if nw == 1:
-            return o.reshape(B, H, T, D)
+        if T == window:
+            with jax.named_scope("eva_flash"):
+                return flash_attention(q, k, v, causal=True, shard=shard)
         with jax.named_scope("eva_prep"):
             # the last window's chunks are seen by nobody
             ks, vs = chunk_summaries(k[:, :, :T - window],
                                      v[:, :, :T - window], mu, phi, chunk)
-        qw = q.reshape(B, H, nw, window, D)
-        merged = [o[:, :, 0]]
-        for w in range(1, nw):
-            with jax.named_scope("eva_remote"):
-                o_r, lse_r = flash_attention_with_lse(
-                    qw[:, :, w], ks[:, :, :per * w], vs[:, :, :per * w],
-                    causal=False, shard=shard)
-            with jax.named_scope("eva_merge"):
-                merged.append(merge_attention_parts(
-                    o[:, :, w], lse[:, :, w], o_r, lse_r)[0]
-                    .astype(q.dtype))
-        with jax.named_scope("eva_merge"):
-            return jnp.stack(merged, axis=2).reshape(B, H, T, D)
+        with jax.named_scope("eva_flash"):
+            return eva_flash_attention(q, k, v, ks, vs, window, shard=shard)
 
 
 class EvaByteLM(TransformerLM):

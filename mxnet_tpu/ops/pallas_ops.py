@@ -688,8 +688,8 @@ def _chunked_with_lse(q, k, v, q_off, k_off, causal, scale, cq, ck):
 def merge_attention_parts(acc_o, acc_lse, o_s, lse_s):
     """Exact combine of two normalized partial attentions over disjoint
     key sets: o = (o1·e^l1 + o2·e^l2)/(e^l1+e^l2), max-shifted; float32
-    ``(o, lse)``.  What the ring does a step (``parallel/ring.py``) and
-    EVA attention does once a window (``models/evabyte.py``)."""
+    ``(o, lse)``.  What the ring does a step (``parallel/ring.py``);
+    EVA attention runs one softmax instead (``eva_flash_attention``)."""
     m = jnp.maximum(acc_lse, lse_s)
     m_safe = jnp.where(jnp.isneginf(m), 0.0, m)
     w1 = jnp.where(jnp.isneginf(acc_lse), 0.0, jnp.exp(acc_lse - m_safe))
@@ -1009,3 +1009,360 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         spec = P(shard[1], shard[2], None, None)
         kernel = _per_shard(kernel, shard, (spec,) * 3, spec)
     return kernel(q, k, v)
+
+
+# ---------------------------------------------------------------------------
+# EVA attention: a window's keys and the summaries before it, one softmax
+# ---------------------------------------------------------------------------
+#
+# ``models/evabyte.py``'s kernels.  The query at i, in window w = i // W,
+# sees under one softmax the summaries [0, per * w) — ``per`` = W / chunk
+# of them a window, one a chunk of every earlier window — and the keys of
+# its own window up to itself.  A head's summaries are one (S, D) row,
+# S = per * (nw - 1), resident beside its K and V rows.  The forward and
+# dq kernels walk, for a query tile, the summary tiles its window sees
+# and then its window's key tiles up to the diagonal, into one running
+# softmax; dkv walks a head's key blocks and then its summary blocks,
+# each against the query tiles that see it: its own window's from the
+# diagonal on, or every later window's.  A summary tile lies within one
+# window's chunks where 128 divides ``per`` and is then seen whole or
+# not at all; otherwise the summaries are one tile, masked.  Every key
+# tile a window walks is masked, as in the flash kernels: the mask paid
+# on the diagonal's tiles alone, in a loop of their own, measured 2-7%
+# slower in all three kernels (PERF.md section 6, PR 38).
+
+def _eva_tiles(kind, W, per, S, D, dtype, block_q, block_k):
+    """(bq, bk, bs): the query and key tiles of the walk over a window,
+    as the flash kernels pick them for rows of ``W``, and the summary
+    tile: the largest multiple of 128 up to 512 that divides ``per``,
+    else all ``S`` as one."""
+    bq, bk = _pick_tiles(kind, W, W, D, dtype, block_q, block_k)
+    bs = next((c for c in range(min(per, 512) // 128 * 128, 127, -128)
+               if per % c == 0), S)
+    return bq, bk, bs
+
+
+def _softmax_tile(carry, qblk, kblk, vblk, scale, seen):
+    """One key tile into the forward's running softmax; ``seen`` masks
+    it (None: every key is seen)."""
+    acc, m_prev, l_prev = carry
+    s = _dot(qblk, kblk, _NT) * scale
+    if seen is not None:
+        s = jnp.where(seen, s, NEG_INF)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
+    alpha = jnp.where(jnp.isneginf(m_prev), 0.0, jnp.exp(m_prev - m_safe))
+    p = jnp.exp(s - m_safe)
+    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+    return (acc * alpha + _dot(p.astype(vblk.dtype), vblk, _NN), m_new,
+            l_new)
+
+
+def _dq_tile(acc, qblk, doblk, lse_b, dlt_b, kblk, vblk, scale, seen):
+    """One key tile's part of the query tile's dq (unscaled)."""
+    s = _dot(qblk, kblk, _NT) * scale
+    if seen is not None:
+        s = jnp.where(seen, s, NEG_INF)
+    p = jnp.exp(s - lse_b[:, None])
+    ds = p * (_dot(doblk, vblk, _NT) - dlt_b[:, None])
+    return acc + _dot(ds.astype(kblk.dtype), kblk, _NN)
+
+
+def _eva_query_walk(W, per, bq, bk, bs, refs, tile, carry):
+    """Inside the forward or dq kernel: ``tile(carry, kblk, vblk, seen)``
+    over what the grid step's query tile sees — the summary tiles of its
+    window, then its window's key tiles up to the diagonal."""
+    from jax.experimental import pallas as pl
+    k_ref, v_ref, ks_ref, vs_ref = refs
+    qpw, kpw = W // bq, W // bk
+    i = pl.program_id(1)
+    w = i // qpw                   # the query tile's window
+    q0 = (i - w * qpw) * bq        # its first query, within the window
+
+    def summaries(j, c):
+        at = pl.multiple_of(j * bs, bs)
+        seen = None if per % bs == 0 else at + jax.lax.broadcasted_iota(
+            jnp.int32, (bq, bs), 1) < per * w
+        return tile(c, ks_ref[0, pl.ds(at, bs), :],
+                    vs_ref[0, pl.ds(at, bs), :], seen)
+
+    def keys(j, c):
+        at = pl.multiple_of(w * W + j * bk, bk)
+        return tile(c, k_ref[0, pl.ds(at, bk), :],
+                    v_ref[0, pl.ds(at, bk), :],
+                    _visible(q0, j * bk, (bq, bk), 0))
+
+    carry = jax.lax.fori_loop(0, (per * w + bs - 1) // bs, summaries, carry)
+    return _walk(keys, carry, 0, _visited(q0 + bq - 1, 0, bk, kpw), kpw)
+
+
+def _eva_specs(T, S, D, bq):
+    """In-specs of the forward and dq kernels: the query tile, the
+    head's K and V rows and its summaries, resident."""
+    from jax.experimental import pallas as pl
+    return [pl.BlockSpec((1, bq, D), lambda h, i: (h, i, 0)),
+            pl.BlockSpec((1, T, D), lambda h, i: (h, 0, 0)),
+            pl.BlockSpec((1, T, D), lambda h, i: (h, 0, 0)),
+            pl.BlockSpec((1, S, D), lambda h, i: (h, 0, 0)),
+            pl.BlockSpec((1, S, D), lambda h, i: (h, 0, 0))]
+
+
+def _eva_fwd_call(q, k, v, ks, vs, W, scale, bq=None, bk=None):
+    from jax.experimental import pallas as pl
+
+    BH, T, D = q.shape
+    S = ks.shape[1]
+    per = S // (T // W - 1)
+    bq, bk, bs = _eva_tiles("fwd", W, per, S, D, k.dtype, bq, bk)
+
+    def kernel(q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, lse_ref):
+        qblk = q_ref[0]
+        acc, m, l = _eva_query_walk(
+            W, per, bq, bk, bs, (k_ref, v_ref, ks_ref, vs_ref),
+            lambda c, kb, vb, seen: _softmax_tile(c, qblk, kb, vb, scale,
+                                                  seen),
+            (jnp.zeros((bq, D), jnp.float32),
+             jnp.full((bq, 1), NEG_INF, jnp.float32),
+             jnp.zeros((bq, 1), jnp.float32)))
+        o_ref[0] = (acc / l).astype(o_ref.dtype)  # a query sees itself
+        lse_ref[0, 0] = (m + jnp.log(l))[:, 0]
+
+    with jax.named_scope("tiles_q%d_k%d_s%d" % (bq, bk, bs)):
+        return pl.pallas_call(
+            kernel,
+            name="eva_flash_fwd",
+            out_shape=(jax.ShapeDtypeStruct((BH, T, D), q.dtype),
+                       jax.ShapeDtypeStruct((BH, 1, T), jnp.float32)),
+            grid=(BH, T // bq),
+            in_specs=_eva_specs(T, S, D, bq),
+            out_specs=(pl.BlockSpec((1, bq, D), lambda h, i: (h, i, 0)),
+                       pl.BlockSpec((1, 1, bq), lambda h, i: (h, 0, i))),
+            compiler_params=_row_params("fwd", T + S, D, k.dtype, bq,
+                                        max(bk, bs)),
+            interpret=_INTERPRET,
+        )(q, k, v, ks, vs)
+
+
+def _eva_bwd_dq_call(q, k, v, ks, vs, do, lse, delta, W, scale, bq=None,
+                     bk=None):
+    from jax.experimental import pallas as pl
+
+    BH, T, D = q.shape
+    S = ks.shape[1]
+    per = S // (T // W - 1)
+    bq, bk, bs = _eva_tiles("dq", W, per, S, D, k.dtype, bq, bk)
+
+    def kernel(q_ref, k_ref, v_ref, ks_ref, vs_ref, do_ref, lse_ref,
+               delta_ref, dq_ref):
+        qblk, doblk = q_ref[0], do_ref[0]
+        lse_b, dlt_b = lse_ref[0, 0], delta_ref[0, 0]
+        acc = _eva_query_walk(
+            W, per, bq, bk, bs, (k_ref, v_ref, ks_ref, vs_ref),
+            lambda acc, kb, vb, seen: _dq_tile(acc, qblk, doblk, lse_b,
+                                               dlt_b, kb, vb, scale, seen),
+            jnp.zeros((bq, D), jnp.float32))
+        dq_ref[0] = (acc * scale).astype(dq_ref.dtype)
+
+    with jax.named_scope("tiles_q%d_k%d_s%d" % (bq, bk, bs)):
+        return pl.pallas_call(
+            kernel,
+            name="eva_flash_bwd_dq",
+            out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
+            grid=(BH, T // bq),
+            in_specs=_eva_specs(T, S, D, bq) + [
+                pl.BlockSpec((1, bq, D), lambda h, i: (h, i, 0)),
+                pl.BlockSpec((1, 1, bq), lambda h, i: (h, 0, i)),
+                pl.BlockSpec((1, 1, bq), lambda h, i: (h, 0, i))],
+            out_specs=pl.BlockSpec((1, bq, D), lambda h, i: (h, i, 0)),
+            compiler_params=_row_params("dq", T + S, D, k.dtype, bq,
+                                        max(bk, bs)),
+            interpret=_INTERPRET,
+        )(q, k, v, ks, vs, do, lse, delta)
+
+
+def _eva_bwd_dkv_call(q, k, v, ks, vs, do, lse, delta, W, scale, bq=None,
+                      bk=None):
+    """(dk, dv, dks, dvs).  The grid walks a head's key blocks, then its
+    summary blocks: the blocks of the kind not walked stay where they
+    were, unwritten, so each output block is written once."""
+    from jax.experimental import pallas as pl
+
+    BH, T, D = q.shape
+    S = ks.shape[1]
+    per = S // (T // W - 1)
+    bq, bk, bs = _eva_tiles("dkv", W, per, S, D, q.dtype, bq, bk)
+    nq, nk, ns = T // bq, T // bk, S // bs
+    qpw, kpw = W // bq, W // bk
+    exact = per % bs == 0     # a summary block is seen whole, or not
+
+    def kernel(q_ref, k_ref, v_ref, ks_ref, vs_ref, do_ref, lse_ref,
+               delta_ref, dk_ref, dv_ref, dks_ref, dvs_ref):
+        j = pl.program_id(1)
+
+        # keys down, queries across, as in the flash dkv kernel
+        def walk(kblk, vblk, seen, lower, n):
+            def tile(i, carry):
+                dk_acc, dv_acc = carry
+                at = pl.multiple_of(i * bq, bq)
+                qblk = q_ref[0, pl.ds(at, bq), :]
+                doblk = do_ref[0, pl.ds(at, bq), :]
+                st = _dot(kblk, qblk, _NT) * scale
+                if seen is not None:
+                    st = jnp.where(seen(i), st, NEG_INF)
+                pt = jnp.exp(st - lse_ref[0, :, pl.ds(at, bq)])
+                dv_acc = dv_acc + _dot(pt.astype(doblk.dtype), doblk, _NN)
+                dst = pt * (_dot(vblk, doblk, _NT)
+                            - delta_ref[0, :, pl.ds(at, bq)])
+                return (dk_acc + _dot(dst.astype(qblk.dtype), qblk, _NN),
+                        dv_acc)
+
+            zero = jnp.zeros((kblk.shape[0], D), jnp.float32)
+            dk_acc, dv_acc = jax.lax.fori_loop(lower, n, tile, (zero, zero))
+            return dk_acc * scale, dv_acc
+
+        @pl.when(j < nk)
+        def _keys():
+            k0 = j * bk
+            # from the diagonal to the end of the key's window
+            dk_acc, dv_acc = walk(
+                k_ref[0], v_ref[0],
+                lambda i: _visible(i * bq, k0, (bk, bq), 1), k0 // bq,
+                (j // kpw + 1) * qpw)
+            dk_ref[0] = dk_acc.astype(dk_ref.dtype)
+            dv_ref[0] = dv_acc.astype(dv_ref.dtype)
+
+        @pl.when(j >= nk)
+        def _summaries():
+            s0 = (j - nk) * bs
+            # every query tile of the windows after the block's first
+            # chunk's window
+            dk_acc, dv_acc = walk(
+                ks_ref[0], vs_ref[0], None if exact else
+                lambda i: s0 + jax.lax.broadcasted_iota(
+                    jnp.int32, (bs, bq), 0) < per * (i // qpw),
+                (s0 // per + 1) * qpw, nq)
+            dks_ref[0] = dk_acc.astype(dks_ref.dtype)
+            dvs_ref[0] = dv_acc.astype(dvs_ref.dtype)
+
+    def key_block(h, j):
+        return h, jnp.minimum(j, nk - 1), 0
+
+    def summary_block(h, j):
+        return h, jnp.maximum(j - nk, 0), 0
+
+    with jax.named_scope("tiles_q%d_k%d_s%d" % (bq, bk, bs)):
+        return pl.pallas_call(
+            kernel,
+            name="eva_flash_bwd_dkv",
+            out_shape=(jax.ShapeDtypeStruct((BH, T, D), k.dtype),
+                       jax.ShapeDtypeStruct((BH, T, D), v.dtype),
+                       jax.ShapeDtypeStruct((BH, S, D), ks.dtype),
+                       jax.ShapeDtypeStruct((BH, S, D), vs.dtype)),
+            grid=(BH, nk + ns),
+            in_specs=[
+                pl.BlockSpec((1, T, D), lambda h, j: (h, 0, 0)),
+                pl.BlockSpec((1, bk, D), key_block),
+                pl.BlockSpec((1, bk, D), key_block),
+                pl.BlockSpec((1, bs, D), summary_block),
+                pl.BlockSpec((1, bs, D), summary_block),
+                pl.BlockSpec((1, T, D), lambda h, j: (h, 0, 0)),
+                pl.BlockSpec((1, 1, T), lambda h, j: (h, 0, 0)),
+                pl.BlockSpec((1, 1, T), lambda h, j: (h, 0, 0)),
+            ],
+            out_specs=(pl.BlockSpec((1, bk, D), key_block),
+                       pl.BlockSpec((1, bk, D), key_block),
+                       pl.BlockSpec((1, bs, D), summary_block),
+                       pl.BlockSpec((1, bs, D), summary_block)),
+            compiler_params=_row_params("dkv", T, D, q.dtype, bq,
+                                        max(bk, bs)),
+            interpret=_INTERPRET,
+        )(q, k, v, ks, vs, do, lse, delta)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _eva_flash(q, k, v, ks, vs, window, scale, bq, bk):
+    return _eva_flash_fwd(q, k, v, ks, vs, window, scale, bq, bk)[0]
+
+
+def _eva_flash_fwd(q, k, v, ks, vs, window, scale, bq, bk):
+    B, H, T, D = q.shape
+    o, lse = _eva_fwd_call(*(a.reshape(B * H, -1, D)
+                             for a in (q, k, v, ks, vs)),
+                           window, scale, bq, bk)
+    # named for a checkpoint policy, as the flash forward's
+    o = checkpoint_name(o.reshape(B, H, T, D), ATTENTION_KERNEL_OUT)
+    lse = checkpoint_name(lse.reshape(B, H, T), ATTENTION_KERNEL_OUT)
+    return o, (q, k, v, ks, vs, o, lse)
+
+
+def _eva_flash_bwd(window, scale, bq, bk, res, do):
+    q, k, v, ks, vs, o, lse = res
+    B, H, T, D = q.shape
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    rows = [a.reshape(B * H, -1, D) for a in (q, k, v, ks, vs)]
+    rows += [do.reshape(B * H, T, D).astype(q.dtype),
+             lse.reshape(B * H, 1, T), delta.reshape(B * H, 1, T)]
+    dq = _eva_bwd_dq_call(*rows, window, scale, bq, bk)
+    grads = _eva_bwd_dkv_call(*rows, window, scale, bq, bk)
+    return tuple(g.reshape(B, H, -1, D) for g in (dq,) + grads)
+
+
+_eva_flash.defvjp(_eva_flash_fwd, _eva_flash_bwd)
+
+
+def _eva_dense(q, k, v, ks, vs, window, scale):
+    """XLA stand-in of the EVA kernels (runs anywhere): each window's
+    scores against the summaries and its own keys, one softmax."""
+    B, H, T, D = q.shape
+    nw, S = T // window, ks.shape[2]
+    per = S // (nw - 1)
+    qw, kw, vw = (a.reshape(B, H, nw, window, D) for a in (q, k, v))
+    far = jnp.einsum("bhnqd,bhsd->bhnqs", qw, ks,
+                     preferred_element_type=jnp.float32) * scale
+    near = jnp.einsum("bhnqd,bhnkd->bhnqk", qw, kw,
+                      preferred_element_type=jnp.float32) * scale
+    seen = jnp.arange(S)[None, :] < per * jnp.arange(nw)[:, None]
+    causal = jnp.tril(jnp.ones((window, window), bool))
+    s = jnp.concatenate([jnp.where(seen[:, None, :], far, NEG_INF),
+                         jnp.where(causal, near, NEG_INF)], axis=-1)
+    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)  # a query sees itself
+    o = jnp.einsum("bhnqs,bhsd->bhnqd", p[..., :S], vs,
+                   preferred_element_type=jnp.float32) \
+        + jnp.einsum("bhnqk,bhnkd->bhnqd", p[..., S:], vw,
+                     preferred_element_type=jnp.float32)
+    return o.reshape(B, H, T, D).astype(q.dtype)
+
+
+def eva_flash_attention(q, k, v, ks, vs, window, scale=None, block_q=None,
+                        block_k=None, shard=None):
+    """EVA attention's one softmax on (B, H, T, D), ``T`` two or more
+    whole windows: the query at i sees the keys of its window up to
+    itself and the first ``per * (i // window)`` summaries, ``ks``,
+    ``vs`` (B, H, S, D) with S = per * (T / window - 1) — the chunks of
+    every window but the last.  Pallas forward and backward (one call
+    each of ``eva_flash_fwd``, ``eva_flash_bwd_dq``,
+    ``eva_flash_bwd_dkv``); gradients reach the summaries.  With
+    ``shard`` (``parallel.sharding.kernel_shard``) the kernels run per
+    shard of batch and heads.  Off the TPU, or at a window the kernels
+    do not take (a multiple of 128), the XLA stand-in."""
+    B, H, T, D = q.shape
+    nw = T // window
+    if k.shape != q.shape or v.shape != q.shape or nw < 2 \
+            or T % window or ks.shape[2] % (nw - 1) \
+            or ks.shape != vs.shape or ks.shape[:2] != (B, H):
+        raise ValueError(
+            "eva_flash_attention: q, k, v %s and summaries %s are not two "
+            "or more windows of %d with a head's summaries each"
+            % (q.shape, ks.shape, window))
+    if scale is None:
+        scale = D ** -0.5
+    if not _pallas_available() or window % 128 or D not in (64, 128, 256):
+        return _eva_dense(q, k, v, ks, vs, window, scale)
+
+    def kernel(q, k, v, ks, vs):
+        return _eva_flash(q, k, v, ks, vs, window, scale, block_q, block_k)
+
+    if shard is not None:
+        spec = P(shard[1], shard[2], None, None)
+        kernel = _per_shard(kernel, shard, (spec,) * 5, spec)
+    return kernel(q, k, v, ks, vs)

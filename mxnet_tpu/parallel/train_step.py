@@ -16,6 +16,7 @@ the reference's server-side update sharding (``kvstore_dist_server.h:346``).
 from __future__ import annotations
 
 import contextlib
+import functools
 import hashlib
 import json
 import os
@@ -632,8 +633,12 @@ class TrainStep:
         return built
 
     def _plan_key(self, blocks, batch_arrays, limit):
-        """Everything a plan depends on that is known before any trace."""
+        """Everything a plan depends on that is known before any trace,
+        the library's own code among it: another version of a block
+        keeps other values for its backward (PERF.md section 6, PR
+        38), and two versions may share one cache directory."""
         return hashlib.sha256(json.dumps([
+            _library_digest(),
             jax.__version__, self._plan_device.device_kind, limit,
             type(self.optimizer).__name__,
             [(n, tuple(p.shape), str(p.dtype)) for n, p in self._params],
@@ -774,6 +779,20 @@ class TrainStep:
                 lambda a: jax.ShapeDtypeStruct(jnp.shape(a), a.dtype),
                 args)
         return self._jitted.lower(*args)
+
+
+@functools.cache
+def _library_digest():
+    """sha256 of the package's Python sources, path and content."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    digest = hashlib.sha256()
+    for where, _, files in sorted(os.walk(root)):
+        for name in sorted(f for f in files if f.endswith(".py")):
+            path = os.path.join(where, name)
+            digest.update(os.path.relpath(path, root).encode())
+            with open(path, "rb") as f:
+                digest.update(f.read())
+    return digest.hexdigest()
 
 
 def _read_plan(path, key, blocks):

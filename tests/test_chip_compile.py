@@ -321,11 +321,10 @@ def test_looped_step_compiles_with_flash_under_block_recompute(topo,
 
 def test_eva_attention_compiles_at_the_byte_cells_shape(topo, on_chip):
     """``evabyte_6p5b_train_1x8192``'s attention, (1, 32, 8192, 128) bf16
-    with a head's two pooling vectors, forward and gradient: the causal
-    kernel once over the 4 x 32 windows (rows of 2,048) and the
-    non-causal one three times (2,048 queries against 128, 256 and 384
-    summaries), each of them forward, dq and dkv, under the part that
-    calls it and the tile the picker gives it."""
+    with a head's two pooling vectors, forward and gradient: one call
+    each of the fused kernels (four windows of 2,048; 384 summaries, 128
+    a window), under ``eva/eva_flash`` and the tiles the picker gives
+    them, and no op of the attention outside ``eva``."""
     from mxnet_tpu.models import eva_attention
     one_chip = SingleDeviceSharding(topo.devices[0])
     row = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16,
@@ -337,16 +336,44 @@ def test_eva_attention_compiles_at_the_byte_cells_shape(topo, on_chip):
                         .astype(jnp.float32).sum(), range(5))(
                             q, k, v, mu, phi)
 
-    lines = _kernel_lines(grad, row, row, row, vec, vec)
-    assert len(lines) == 4 * 3
-    for part, n in (("eva_local", 1), ("eva_remote", 3)):
-        for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
-            named = [ln for ln in lines if re.search(
-                r'op_name="[^"]*\beva\)*/%s/tiles_q\d+_k\d+/%s/pallas_call"'
-                % (part, name), ln)]
-            assert len(named) == n, (part, name, len(named))
-    bq, bk = pallas_ops._pick_tiles("fwd", 2048, 384, 128, jnp.bfloat16)
-    assert (bq, bk) == (1024, 384)           # a short row is one tile
+    text = jax.jit(grad).lower(row, row, row, vec, vec).compile().as_text()
+    lines = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(lines) == 3
+    for kind in ("fwd", "dq", "dkv"):
+        tiles = pallas_ops._eva_tiles(kind, 2048, 128, 384, 128,
+                                      jnp.bfloat16, None, None)
+        assert tiles == (512, 512, 128), kind
+        name = "eva_flash_" + ("fwd" if kind == "fwd" else "bwd_" + kind)
+        named = [ln for ln in lines if re.search(
+            r'op_name="[^"]*\beva\)*/eva_flash/tiles_q%d_k%d_s%d/%s/'
+            r'pallas_call"' % (tiles + (name,)), ln)]
+        assert len(named) == 1, (name, lines)
+    # every op is the attention's, under ``eva``, but the loss this
+    # test puts on it: the cast, the sum and its cotangent
+    for op in set(re.findall(r'op_name="(jit\(grad\)/[^"]*)"', text)):
+        assert re.match(r"jit\(grad\)/(transpose\()?jvp\(eva\)", op) \
+            or re.fullmatch(r"jit\(grad\)/(transpose\()?jvp\(\)\)?/"
+                            r"(convert_element_type|reduce_sum|"
+                            r"broadcast_in_dim)", op), op
+
+
+def test_the_token_cells_flash_kernels_keep_their_names_and_tiles(
+        topo, on_chip):
+    """``ouro_2p6b_train_2x4096``'s attention, (2, 16, 4096, 128) bf16
+    causal, forward and gradient: the three flash kernels the parent
+    compiled, by name and tile — EVA's kernels are a path of their
+    own."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    row = jax.ShapeDtypeStruct((2, 16, 4096, 128), jnp.bfloat16,
+                               sharding=one_chip)
+    lines = _kernel_lines(_flash_grad, row, row, row)
+    assert len(lines) == 3
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        named = [ln for ln in lines if re.search(
+            r'op_name="[^"]*\btiles_q512_k512\)*/%s/pallas_call"' % name,
+            ln)]
+        assert len(named) == 1, (name, lines)
+    assert not any("eva" in ln for ln in lines)
 
 
 def test_decode_program_carries_its_scopes(topo, on_chip):

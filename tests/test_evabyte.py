@@ -1,5 +1,5 @@
-"""EvaByte (``mx.models.EvaByteLM``): EVA attention merged from parts by
-logsumexp, the norms' unit offset, the float32 residual stream and the
+"""EvaByte (``mx.models.EvaByteLM``): EVA attention under one softmax
+(the kernels, and the XLA stand-in), the norms' unit offset, the float32 residual stream and the
 eight byte heads, at toy widths on the CPU (window 32, chunk 4, four
 windows, two layers), against the plain reference the benchmark keeps
 (``benchmark/chip/reference/eva_decoder.py``, which imports nothing of
@@ -19,6 +19,7 @@ from mxnet_tpu.models import (EvaByteLM, TransformerLM, chunk_summaries,
                               eva_attention, evabyte_6p5b_config)
 from mxnet_tpu.ndarray.ndarray import NDArray
 from mxnet_tpu.ops.nn import dot_product_attention
+from mxnet_tpu.ops.pallas_ops import eva_flash_attention
 
 CHIP = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark", "chip")
@@ -95,8 +96,8 @@ def reference_step(weights):
 
 # ----------------------------------------------------------------------
 # the model against the plain reference.  float32: both sides compute in
-# float32 but sum in different orders (parts merged by logsumexp against
-# one masked softmax): 2e-5 is some tens of float32 roundings of a unit
+# float32 but sum in different orders (windows of 32: the XLA stand-in's
+# softmax over a window's keys and summaries against one masked softmax): 2e-5 is some tens of float32 roundings of a unit
 # value.  bf16: parameters, matmuls and attention in bf16 over the
 # float32 stream against the float32 reference on the same (bf16) leaves
 # ----------------------------------------------------------------------
@@ -183,8 +184,9 @@ def test_the_stream_is_float32_and_the_branches_bf16(weights):
 
 
 # ----------------------------------------------------------------------
-# the attention alone: parts merged by logsumexp against ONE dense
-# masked softmax over [tokens | summaries], values and gradients
+# the attention alone: one softmax over a window's keys and the
+# summaries before it, against ONE dense masked softmax over [tokens |
+# summaries], values and all five gradients
 # ----------------------------------------------------------------------
 def _dense_eva(q, k, v, mu, phi, window, chunk):
     """One masked softmax over the concatenated key set, written out."""
@@ -214,33 +216,90 @@ def _qkv(shape, seed=0):
     return (q, k, v, mu, phi), ct
 
 
-def _check_merged_against_dense(shape, window, chunk, tol):
+def _check_against_dense(shape, window, chunk, tol, attention=None):
+    """``attention(q, k, v, mu, phi)`` (``eva_attention`` by default)
+    against ``_dense_eva``: the output to ``tol`` and each of the five
+    gradients to ``tol`` of its norm."""
+    attention = attention or (lambda *a: eva_attention(*a, window, chunk))
     args, ct = _qkv(shape)
-    got = eva_attention(*args, window, chunk)
-    want = _dense_eva(*args, window, chunk)
-    onp.testing.assert_allclose(got, want, rtol=0, atol=tol)
-    g_got = jax.grad(lambda *a: (eva_attention(*a, window, chunk)
-                                 * ct).sum(), range(5))(*args)
+    onp.testing.assert_allclose(attention(*args),
+                                _dense_eva(*args, window, chunk), rtol=0,
+                                atol=tol)
+    g_got = jax.grad(lambda *a: (attention(*a) * ct).sum(), range(5))(*args)
     g_want = jax.grad(lambda *a: (_dense_eva(*a, window, chunk)
                                   * ct).sum(), range(5))(*args)
     for name, a, b in zip("q k v mu phi".split(), g_got, g_want):
-        # gradients flow through both parts' lse into the merge
         assert float(jnp.linalg.norm(a - b)) \
             <= tol * float(jnp.linalg.norm(b)), name
-        assert float(jnp.linalg.norm(b)) > 0, name
+        # one window: no summary, so mu and phi have none
+        assert float(jnp.linalg.norm(b)) > 0 or shape[2] == window, name
 
 
 def test_merged_parts_are_one_dense_masked_softmax():
-    # the XLA stand-ins for the kernels, float32: to 1e-5
-    _check_merged_against_dense((2, 3, 128, 16), 32, 4, 1e-5)
+    # the XLA stand-in for the kernels (windows of 32 are under the
+    # kernels' 128), float32: to 1e-5
+    _check_against_dense((2, 3, 128, 16), 32, 4, 1e-5)
 
 
 def test_merged_parts_through_the_kernels(interpret_kernels):
-    # the kernels' own code in the interpreter at the smallest shapes
-    # they take (rows of 128 keys: three windows of 256, chunks of 2);
-    # the kernels feed the MXU in one bf16 pass on the chip, full
-    # float32 here
-    _check_merged_against_dense((1, 2, 768, 64), 256, 2, 1e-5)
+    # the kernels' own code in the interpreter, three windows of 256
+    # and chunks of 2: 128 summaries a window, whole summary tiles; the
+    # kernels feed the MXU in one bf16 pass on the chip, full float32
+    # here
+    _check_against_dense((1, 2, 768, 64), 256, 2, 1e-5)
+
+
+def _with_tiles(window, chunk, block_q, block_k):
+    def attention(q, k, v, mu, phi):
+        T = q.shape[2]
+        ks, vs = chunk_summaries(k[:, :, :T - window], v[:, :, :T - window],
+                                 mu, phi, chunk)
+        return eva_flash_attention(q, k, v, ks, vs, window,
+                                   block_q=block_q, block_k=block_k)
+    return attention
+
+
+@pytest.mark.parametrize("shape,window,chunk,tiles", [
+    # one window: the causal flash kernel, no summary
+    ((1, 2, 128, 64), 128, 4, None),
+    # the smallest row the kernels take: two windows of 128, 32
+    # summaries, one tile of them
+    ((1, 2, 256, 64), 128, 4, None),
+    # four windows: 64 summaries a window, 192 in one tile masked by
+    # the window that reads it
+    ((1, 2, 512, 64), 128, 2, None),
+    # three windows of 256 in tiles of 128: two query and key tiles a
+    # window, the diagonal inside the window, 128 summaries a tile
+    ((1, 2, 768, 64), 256, 2, (128, 128)),
+    # tiles of unequal sides: 128 queries against 256 keys, and back
+    ((2, 1, 768, 64), 256, 2, (128, 256)),
+    ((1, 1, 1024, 128), 256, 2, (256, 128))])
+def test_the_fused_kernels_are_one_dense_masked_softmax(
+        interpret_kernels, shape, window, chunk, tiles):
+    _check_against_dense(shape, window, chunk, 1e-5, tiles and _with_tiles(
+        window, chunk, *tiles))
+
+
+def test_the_fused_kernels_run_per_shard_under_a_mesh(interpret_kernels):
+    # batch rows over dp, heads over tp: a head's summaries travel with
+    # its keys, so every shard's softmax is whole
+    mesh = parallel.create_mesh(dp=2, tp=2)
+    args, ct = _qkv((2, 4, 256, 64))
+
+    def loss(*a):
+        shard = parallel.kernel_shard(2, 4)
+        return (eva_attention(*a, 128, 4, shard=shard) * ct).sum()
+
+    with parallel.mesh_scope(mesh):
+        lowered = jax.jit(jax.value_and_grad(loss, range(5))).lower(*args)
+        assert "sdy.manual_computation" in lowered.as_text()
+        got, grads = lowered.compile()(*args)
+    want, wants = jax.value_and_grad(lambda *a: (
+        _dense_eva(*a, 128, 4) * ct).sum(), range(5))(*args)
+    onp.testing.assert_allclose(got, want, rtol=1e-5)
+    for name, a, b in zip("q k v mu phi".split(), grads, wants):
+        assert float(jnp.linalg.norm(a - b)) \
+            <= 1e-5 * float(jnp.linalg.norm(b)), name
 
 
 def test_window_zero_is_plain_causal_attention():
@@ -310,11 +369,11 @@ def test_the_published_configuration():
 
 
 # ----------------------------------------------------------------------
-# what a marked block keeps: every kernel call's output and row sums
-# (one local call and one remote call a later window), so none runs
-# again in the backward
+# what a marked block keeps: the fused kernel's output and row sums, so
+# it does not run again in the backward; one forward, dq and dkv call a
+# block, and nothing merged or stacked
 # ----------------------------------------------------------------------
-@pytest.mark.parametrize("bare,forwards", [(False, 3), (True, 6)])
+@pytest.mark.parametrize("bare,forwards", [(False, 1), (True, 2)])
 def test_a_marked_block_runs_each_attention_kernel_once(
         interpret_kernels, monkeypatch, bare, forwards):
     from mxnet_tpu.models.transformer import TransformerBlock
@@ -332,11 +391,18 @@ def test_a_marked_block_runs_each_attention_kernel_once(
             return blk(NDArray(x))._data.sum()
 
     text = str(jax.make_jaxpr(jax.grad(loss))(jnp.ones((1, 768, 128))))
-    # three windows: the causal kernel once, the non-causal one twice
-    assert text.count("name=flash_fwd") == forwards
-    assert text.count("name=flash_bwd_dq") == 3
-    assert text.count("name=flash_bwd_dkv") == 3
+    # three windows, one call of each kernel
+    assert text.count("name=eva_flash_fwd") == forwards
+    assert text.count("name=eva_flash_bwd_dq") == 1
+    assert text.count("name=eva_flash_bwd_dkv") == 1
+    assert "name=flash_" not in text
     assert "remat2" in text
+    # the attention alone stacks no windows (rotary's halves aside)
+    args, _ = _qkv((1, 2, 768, 64))
+    alone = str(jax.make_jaxpr(jax.grad(
+        lambda *a: eva_attention(*a, 256, 2).sum(), range(5)))(*args))
+    assert "name=eva_flash_bwd_dkv" in alone
+    assert "concatenate" not in alone
 
 
 def test_the_marked_steps_lowering_holds_no_kernel_under_the_recomputed_part(
@@ -349,8 +415,8 @@ def test_the_marked_steps_lowering_holds_no_kernel_under_the_recomputed_part(
     lab = NDArray(jnp.zeros((1, 768, K), jnp.int32))
     text = _step(net).lower(tok, lab).as_text(debug_info=True)
     names = [line for line in text.splitlines() if "flash_" in line]
-    assert any("eva_local" in n and "flash_fwd" in n for n in names)
-    assert any("eva_remote" in n and "flash_fwd" in n for n in names)
-    assert any("flash_bwd_dkv" in n for n in names)
+    assert any("eva/eva_flash/" in n and "eva_flash_fwd" in n
+               for n in names)
+    assert any("eva_flash_bwd_dkv" in n for n in names)
     assert "rematted_computation" in text
     assert not [n for n in names if "rematted_computation" in n]

@@ -539,6 +539,23 @@ def test_a_start_that_finds_a_fitting_plan_compiles_one_program(
     assert len(_plan_files(plan_dir)) == 2
 
 
+def test_a_plan_made_by_other_library_code_is_not_read(monkeypatch,
+                                                      plan_dir):
+    # two versions of the library may share one cache directory (a
+    # parent and its change, run in turn): a block of the other keeps
+    # other values, so its plan is no hint
+    _device_with(monkeypatch, 1 << 30)
+    step, x, y = STEPS["eva"](2)
+    step(x, y)
+    assert len(_plan_files(plan_dir)) == 1
+    assert len(ts._library_digest()) == 64
+    monkeypatch.setattr(ts, "_library_digest", lambda: "another")
+    step, x, y = STEPS["eva"](2)
+    step(x, y)
+    assert step.recompute_plan["from_file"] is False
+    assert len(_plan_files(plan_dir)) == 2
+
+
 def test_the_plan_nests_its_tries_as_build_spans(monkeypatch, plan_dir):
     # with no profiler running: plan > a trace and a compile a step
     # tried, inside the build span of the first call with a signature

@@ -248,7 +248,7 @@ def test_looped_step_keeps_the_flash_kernels_names(looped_names, kernel,
 
 @pytest.fixture(scope="module")
 def eva_names():
-    """Op names of the compiled EvaByte training step, the flash kernels
+    """Op names of the compiled EvaByte training step, the EVA kernels
     interpreted: three windows of 256 bytes, chunks of 2, heads of 64."""
     from mxnet_tpu.models import EvaByteLM, evabyte_6p5b_config
     from mxnet_tpu.ndarray.ndarray import NDArray
@@ -272,21 +272,26 @@ def eva_names():
     return set(re.findall(r'op_name="(jit\(step\)/[^"]*)"', text))
 
 
-@pytest.mark.parametrize("scope", ["eva_prep", "eva_local", "eva_remote",
-                                   "eva_merge"])
-def test_eva_step_carries_the_attentions_four_parts(eva_names, scope):
-    # every part lies under ``eva`` under the block's ``attention``,
-    # first forward and backward (``eva_attn_device_pct.train`` reads
-    # ``eva``, ``eva_remote_device_pct.train`` the three that the
-    # summaries cost)
-    fwd = "jit(step)/jvp(forward)/layer1/attention/eva/%s/" % scope
-    assert any(n.startswith(fwd) for n in eva_names), scope
-    assert any(n.startswith("jit(step)/transpose(jvp(forward))/")
-               and "/layer1/checkpoint/attention/eva/%s/" % scope in n
-               for n in eva_names), scope
+@pytest.mark.parametrize("scope,phase", [
+    ("eva_prep", "first forward"), ("eva_prep", "backward"),
+    ("eva_flash", "first forward"), ("eva_flash", "backward")])
+def test_eva_step_carries_the_attentions_four_parts(eva_names, scope, phase):
+    # both parts lie under ``eva`` under the block's ``attention``, in
+    # the first forward and in the backward (``eva_attn_device_pct.train``
+    # reads ``eva``, ``eva_remote_device_pct.train`` ``eva_prep``, the
+    # pooling); the parts the fused kernel ended are gone
+    if phase == "first forward":
+        where = "jit(step)/jvp(forward)/layer1/attention/eva/%s/" % scope
+        assert any(n.startswith(where) for n in eva_names), scope
+    else:
+        assert any(n.startswith("jit(step)/transpose(jvp(forward))/")
+                   and "/layer1/checkpoint/attention/eva/%s/" % scope in n
+                   for n in eva_names), scope
     # nothing of the attention lies outside ``eva`` but projections and
     # rotary
     assert not any("/%s/" % scope in n and "/eva/" not in n
+                   for n in eva_names)
+    assert not any(re.search(r"/eva_(local|remote|merge)/", n)
                    for n in eva_names)
 
 
@@ -298,22 +303,23 @@ def test_eva_step_carries_mbp_loss(eva_names):
     assert not any("/mbp_loss/" in n and "/layer" in n for n in eva_names)
 
 
-@pytest.mark.parametrize("kernel,part,phase,there", [
-    ("flash_fwd", "eva_local", "jit(step)/jvp(forward)/layer", True),
-    ("flash_fwd", "eva_remote", "jit(step)/jvp(forward)/layer", True),
-    ("flash_fwd", "eva_local", "/rematted_computation/", False),
-    ("flash_fwd", "eva_remote", "/rematted_computation/", False),
-    ("flash_bwd_dq", "eva_local", "/checkpoint/attention/", True),
-    ("flash_bwd_dkv", "eva_remote", "/checkpoint/attention/", True)])
-def test_eva_step_keeps_the_flash_kernels_names(eva_names, kernel, part,
-                                                phase, there):
-    # the kernels keep their names under the tile they run, under the
-    # part that calls them; a marked block keeps every call's output and
-    # row sums, so none is among the recomputed ops
+@pytest.mark.parametrize("kernel,phase,there", [
+    ("eva_flash_fwd", "jit(step)/jvp(forward)/layer", True),
+    ("eva_flash_fwd", "/rematted_computation/", False),
+    ("eva_flash_bwd_dq", "/checkpoint/attention/", True),
+    ("eva_flash_bwd_dkv", "/checkpoint/attention/", True),
+    ("eva_flash_bwd_dq", "/rematted_computation/", False),
+    ("eva_flash_bwd_dkv", "/rematted_computation/", False)])
+def test_eva_step_keeps_the_flash_kernels_names(eva_names, kernel, phase,
+                                                there):
+    # the kernels are named under the tiles they run (query, key and
+    # summary), under the fused part that calls them; a marked block
+    # keeps the forward's output and row sums, so none is among the
+    # recomputed ops
     hits = [n for n in eva_names
-            if re.search(r"/attention/eva/%s/tiles_q\d+_k\d+/%s\)*/"
-                         % (part, kernel), n) and phase in n]
-    assert bool(hits) == there, (kernel, part, phase, hits[:3])
+            if re.search(r"/attention/eva/eva_flash/tiles_q\d+_k\d+_s\d+/"
+                         r"%s\)*/" % kernel, n) and phase in n]
+    assert bool(hits) == there, (kernel, phase, hits[:3])
     again = {n for n in eva_names if "/rematted_computation/" in n}
     assert again and any("/feed_forward/" in n for n in again)
 
