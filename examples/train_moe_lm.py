@@ -1,9 +1,10 @@
 """Train a Mixture-of-Experts TransformerLM with expert parallelism.
 
 Beyond-parity capability (the reference has no MoE, SURVEY.md §2.3):
-every second block routes tokens through a top-1 switch FFN whose expert
-weights are sharded over the ``ep`` mesh axis; the Switch-Transformer
-load-balance aux loss joins the cross-entropy inside the same trace.
+every second block routes each token to the top 2 of 4 SwiGLU experts
+(``models/experts.py``: dropless, sorted by expert, a grouped matmul);
+the router's load-balance loss joins the cross-entropy inside the same
+trace (``TransformerLM.loss``).
 
 Run on real chips or a virtual mesh:
 
@@ -18,7 +19,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 import numpy as onp
 
 import mxnet_tpu as mx
-from mxnet_tpu import gluon, parallel
+from mxnet_tpu import parallel
 from mxnet_tpu.models import TransformerLM, tiny_config
 
 
@@ -26,22 +27,16 @@ def main():
     mx.np.random.seed(0)
     cfg = tiny_config(n_layers=4, dim=128, hidden_dim=256, n_heads=4,
                       n_kv_heads=2, vocab_size=512,
-                      moe_num_experts=4, moe_every=2,
-                      moe_capacity_factor=1.25)
+                      moe_num_experts=4, moe_every=2, moe_top_k=2)
     net = TransformerLM(cfg)
     net.initialize()
     print("params: %.2fM (moe blocks: %d/%d)"
           % (net.num_params() / 1e6,
-             sum(type(b.feed_forward).__name__ == "MoEFeedForward"
+             sum(type(b.feed_forward).__name__ == "RoutedExperts"
                  for b in net.layers), cfg.n_layers))
 
-    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
-
     def fwd(net, tokens, labels):
-        logits = net.forward(tokens)
-        ce = loss_fn(logits.reshape(-1, logits.shape[-1]),
-                     labels.reshape(-1)).mean()
-        return ce + 0.01 * net.moe_aux_loss()
+        return net.loss(tokens, labels)[0]
 
     # a toy copy task: predict the previous token
     rs = onp.random.RandomState(0)

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 from .. import numpy_extension as npx
 from ..gluon.block import HybridBlock
-from ..gluon.nn import Dense, Embedding, RMSNorm
+from ..gluon.nn import Dense, Embedding, LayerNorm, RMSNorm
 from ..gluon.parameter import Parameter
 from ..initializer import Normal
 from ..ndarray.ndarray import NDArray, apply_op
@@ -39,6 +39,10 @@ class LlamaConfig:
     rope_theta: float = 500000.0
     norm_eps: float = 1e-5
     dtype: str = "bfloat16"
+    # a head's width where it is not dim // n_heads
+    head_dim: int = None
+    # an RMSNorm over each query and key head before the rotary
+    qk_norm: bool = False
     # flash is the default: the Pallas kernel fires on TPU for
     # 128-aligned seq and D in {64,128,256}, and transparently falls
     # back to dense XLA attention elsewhere (ops/pallas_ops.py gating) —
@@ -46,14 +50,26 @@ class LlamaConfig:
     # eva (``models/evabyte.py``): exact causal attention inside a window
     # of ``window_size`` tokens, one softmax shared with a learned summary
     # of every ``chunk_size``-token chunk of all earlier windows
-    attn_impl: str = "flash"  # dense | flash | ring | eva
+    # dsa (``models/dsa.py``): each query attends to the ``index_topk``
+    # earlier keys an indexer of ``index_heads`` heads of
+    # ``index_head_dim`` scores highest
+    attn_impl: str = "flash"  # dense | flash | ring | eva | dsa
     cp_axis: str = "cp"       # mesh axis for ring attention
-    # mixture-of-experts (0 = dense FFN everywhere): every
-    # ``moe_every``-th block uses a switch-MoE FFN with this many
-    # experts, sharded over the 'ep' mesh axis (parallel/moe.py)
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # routed experts (0 = dense FFN everywhere): every ``moe_every``-th
+    # block's FFN is ``moe_top_k`` of ``moe_num_experts`` SwiGLU experts
+    # of width ``moe_hidden_dim`` (``models/experts.py``), of which the
+    # ``moe_held`` from ``moe_first_held`` are here (0: all); the
+    # router's balance loss weighs ``moe_aux_coef``
     moe_num_experts: int = 0
     moe_every: int = 2
-    moe_capacity_factor: float = 1.25
+    moe_top_k: int = 1
+    moe_hidden_dim: int = 0
+    moe_held: int = 0
+    moe_first_held: int = 0
+    moe_aux_coef: float = 0.001
     # sandwich norms: each branch's output is normed too, before the
     # residual add (four RMSNorms a block, not two)
     sandwich_norm: bool = False
@@ -113,6 +129,26 @@ def evabyte_6p5b_config(**over):
     return cfg
 
 
+def keye_vl2_30b_a3b_config(**over):
+    """Keye-VL-2.0-30B-A3B's language model (``config.json`` of
+    ``Kwai-Keye/Keye-VL-2.0-30B-A3B``): 48 layers of DeepSeek Sparse
+    Attention (32 query and 4 K/V heads of 128 with q/k norms; an indexer
+    of 16 heads of 64 keeps 2,048 keys a query) and routed experts (top 8
+    of 128 SwiGLU experts of 768, no shared expert), hidden 2,048, a
+    151,936-id untied vocabulary.  Text tokens: its M-RoPE is 1-D rotary.
+    For :class:`~.transformer.TransformerLM`."""
+    cfg = LlamaConfig(vocab_size=151936, dim=2048, n_layers=48, n_heads=32,
+                      n_kv_heads=4, head_dim=128, hidden_dim=6144,
+                      max_seq_len=262144, rope_theta=10000000.0,
+                      norm_eps=1e-6, qk_norm=True, attn_impl="dsa",
+                      index_heads=16, index_head_dim=64, index_topk=2048,
+                      moe_num_experts=128, moe_every=1, moe_top_k=8,
+                      moe_hidden_dim=768, moe_aux_coef=0.001)
+    for k, v in over.items():
+        setattr(cfg, k, v)
+    return cfg
+
+
 def tiny_config(**over):
     cfg = LlamaConfig(vocab_size=256, dim=64, n_layers=2, n_heads=4,
                       n_kv_heads=2, hidden_dim=128, max_seq_len=128,
@@ -167,12 +203,29 @@ def _norm(cfg):
                    out_dtype=cfg.dtype if cfg.residual_dtype else None)
 
 
+class Indexer(HybridBlock):
+    """DSA's lightning indexer (``models/dsa.py``): ``wq`` makes
+    ``index_heads`` query heads of ``index_head_dim``, ``wk`` one key
+    head (LayerNorm ``k_norm``), ``weights_proj`` a weight per head."""
+
+    def __init__(self, cfg: LlamaConfig):
+        super().__init__()
+        h, d = cfg.index_heads, cfg.index_head_dim
+        self.wq = Dense(h * d, use_bias=False, flatten=False,
+                        in_units=cfg.dim, dtype=cfg.dtype)
+        self.wk = Dense(d, use_bias=False, flatten=False, in_units=cfg.dim,
+                        dtype=cfg.dtype)
+        self.k_norm = LayerNorm(epsilon=1e-6, in_channels=d)
+        self.weights_proj = Dense(h, use_bias=False, flatten=False,
+                                  in_units=cfg.dim, dtype=cfg.dtype)
+
+
 class Attention(HybridBlock):
     def __init__(self, cfg: LlamaConfig, layer_idx=0):
         super().__init__()
         self.cfg = cfg
         self.layer_idx = layer_idx
-        head_dim = cfg.dim // cfg.n_heads
+        head_dim = cfg.head_dim or cfg.dim // cfg.n_heads
         self.head_dim = head_dim
         # Megatron TP: qkv column-parallel, out row-parallel
         self.wq = Dense(cfg.n_heads * head_dim, use_bias=False,
@@ -187,6 +240,11 @@ class Attention(HybridBlock):
         self.wk.weight.shard(("tp", None))
         self.wv.weight.shard(("tp", None))
         self.wo.weight.shard((None, "tp"))
+        if cfg.qk_norm:
+            self.q_norm = RMSNorm(epsilon=cfg.norm_eps, in_channels=head_dim)
+            self.k_norm = RMSNorm(epsilon=cfg.norm_eps, in_channels=head_dim)
+        if cfg.attn_impl == "dsa":
+            self.indexer = Indexer(cfg)
         if cfg.attn_impl == "eva":
             # a head's two pooling vectors: a chunk's keys are weighed by
             # softmax(k . mu), its values by softmax(k . phi)
@@ -207,13 +265,19 @@ class Attention(HybridBlock):
         v = self.wv(x)
         hd, nh, nkv = self.head_dim, cfg.n_heads, cfg.n_kv_heads
         impl, theta, cp_axis = cfg.attn_impl, cfg.rope_theta, cfg.cp_axis
+        if cfg.qk_norm:
+            q = self.q_norm(q.reshape(B, T, nh, hd)).reshape(B, T, nh * hd)
+            k = self.k_norm(k.reshape(B, T, nkv, hd)).reshape(B, T, nkv * hd)
         if cache is not None:
-            if impl == "eva":
+            if impl in ("eva", "dsa"):
                 raise NotImplementedError(
-                    "eva attention has no cached path: a slot's state "
+                    "%s attention has no cached path: a slot's state "
                     "would be its window's K/V and the summaries of the "
-                    "windows before it (ROADMAP N7)")
+                    "windows before it (eva, ROADMAP N7), or its keys' "
+                    "index keys beside its K/V (dsa, ROADMAP N8)" % impl)
             return self._forward_cached(x, q, k, v, cache)
+        if impl == "dsa":
+            return self._forward_dsa(x, q, k, v)
 
         def attn(q, k, v, *pool):
             q = q.reshape(B, T, nh, hd)
@@ -270,6 +334,66 @@ class Attention(HybridBlock):
             if impl == "eva" else []
         o = apply_op(attn, [q, k, v] + pool, name="attention")
         return self.wo(o)
+
+    def _index_inputs(self, x):
+        """The indexer's ``(qi (B, T, heads, d), ki (B, T, d), w (B, T,
+        heads))`` of ``x``, detached: its queries and key after their
+        norm and half rotary, the weights with both of the scores' scales
+        folded in (``models/dsa.py``)."""
+        cfg, ix = self.cfg, self.indexer
+        B, T, _ = x.shape
+        ih, idim, theta = cfg.index_heads, cfg.index_head_dim, cfg.rope_theta
+        xd = apply_op(jax.lax.stop_gradient, [x], name="indexer_input")
+
+        def prep(qi, ki, wi, gamma, beta):
+            from ..ops.nn import layer_norm
+            from .dsa import rope_half
+            pos = jnp.arange(T)
+            qi = rope_half(qi.reshape(B, T, ih, idim), pos, theta)
+            ki = layer_norm(ki.astype(jnp.float32), gamma.astype(jnp.float32),
+                            beta.astype(jnp.float32), eps=1e-6)
+            ki = rope_half(ki[:, :, None, :], pos, theta)[:, :, 0]
+            wi = wi.astype(jnp.float32) * (ih * idim) ** -0.5
+            return qi, ki.astype(qi.dtype), wi
+
+        return apply_op(prep, [ix.wq(xd), ix.wk(xd), ix.weights_proj(xd),
+                               ix.k_norm.gamma.data(), ix.k_norm.beta.data()],
+                        n_out=3, name="indexer_inputs")
+
+    def selection(self, x):
+        """The keys DSA selects for each query of ``x`` (B, T, dim), the
+        attention's normed input: ``idx`` (B, T, index_topk), query t's
+        first ``min(index_topk, t + 1)`` slots (``models/dsa.select``)."""
+        topk = self.cfg.index_topk
+
+        def sel(qi, ki, wi):
+            from .dsa import select
+            return jnp.stack([select(jnp.swapaxes(qi[b], 0, 1), ki[b], wi[b],
+                                     topk)[0] for b in range(qi.shape[0])])
+
+        return apply_op(sel, list(self._index_inputs(x)), name="selection")
+
+    def _forward_dsa(self, x, q, k, v):
+        """DeepSeek Sparse Attention (``models/dsa.py``): gives ``(out,
+        {"index_loss": the indexer's KL})``.  The indexer reads ``x``
+        detached: its loss is its leaves' only gradient."""
+        cfg = self.cfg
+        B, T, _ = x.shape
+        hd, nh, nkv = self.head_dim, cfg.n_heads, cfg.n_kv_heads
+        theta, topk = cfg.rope_theta, cfg.index_topk
+
+        def attn(q, k, v, qi, ki, wi):
+            from .dsa import dsa_attention
+            pos = jnp.arange(T)
+            q = _rope(q.reshape(B, T, nh, hd), pos, theta)
+            k = _rope(k.reshape(B, T, nkv, hd), pos, theta)
+            o, loss = dsa_attention(q, k, v.reshape(B, T, nkv, hd), qi, ki,
+                                    wi, topk)
+            return o.reshape(B, T, nh * hd), loss
+
+        o, loss = apply_op(attn, [q, k, v] + list(self._index_inputs(x)),
+                           n_out=2, name="attention")
+        return self.wo(o), {"index_loss": loss}
 
     def _forward_cached(self, x, q, k, v, cache):
         """Prefill/decode through a paged KV cache (``mx.serve``).
@@ -409,43 +533,6 @@ class FeedForward(HybridBlock):
         return self.w2(npx.activation(self.w1(x), "silu") * self.w3(x))
 
 
-class MoEFeedForward(HybridBlock):
-    """Switch-MoE FFN (beyond-parity EP capability, ``parallel/moe.py``):
-    top-1 routing, static capacity, experts sharded over 'ep'.  The
-    load-balance aux loss of the LAST forward is kept as a traced scalar
-    in ``last_aux_loss`` for the training loss to consume (same trace)."""
-
-    def __init__(self, cfg: LlamaConfig):
-        super().__init__()
-        from ..parallel.moe import moe_param_specs
-        spec = moe_param_specs()  # single source of truth for the layout
-        E, D, H = cfg.moe_num_experts, cfg.dim, cfg.hidden_dim
-        self.gate = Parameter(shape=(D, E), dtype=cfg.dtype, name="gate")
-        self.experts_w1 = Parameter(shape=(E, D, H), dtype=cfg.dtype,
-                                    name="experts_w1").shard(spec["w1"])
-        self.experts_w2 = Parameter(shape=(E, H, D), dtype=cfg.dtype,
-                                    name="experts_w2").shard(spec["w2"])
-        self._capacity = cfg.moe_capacity_factor
-        self.last_aux_loss = None
-
-    def forward(self, x):
-        from ..parallel.moe import switch_moe
-        cap = self._capacity
-
-        def f(a, gw, w1, w2):
-            B, T, D = a.shape
-            out, aux = switch_moe(a.reshape(B * T, D), gw, w1, w2,
-                                  capacity_factor=cap)
-            return out.reshape(B, T, D), aux
-
-        out, aux = apply_op(f, [x, self.gate.data(),
-                                self.experts_w1.data(),
-                                self.experts_w2.data()], n_out=2,
-                            name="switch_moe")
-        self.last_aux_loss = aux
-        return out
-
-
 class TransformerBlock(HybridBlock):
     def __init__(self, cfg: LlamaConfig, layer_idx=0):
         super().__init__()
@@ -454,7 +541,9 @@ class TransformerBlock(HybridBlock):
         self.ffn_norm = _norm(cfg)
         use_moe = (cfg.moe_num_experts > 0
                    and layer_idx % max(1, cfg.moe_every) == 0)
-        self.feed_forward = MoEFeedForward(cfg) if use_moe \
+        if use_moe:
+            from .experts import RoutedExperts
+        self.feed_forward = RoutedExperts(cfg) if use_moe \
             else FeedForward(cfg)
         self._sandwich = cfg.sandwich_norm
         if cfg.sandwich_norm:
@@ -462,10 +551,23 @@ class TransformerBlock(HybridBlock):
             self.ffn_post_norm = _norm(cfg)
 
     def forward(self, x, cache=None):
-        a = self.attention(self.attention_norm(x), cache=cache)
+        """The block's output; where its attention or FFN gives losses
+        and counts of its own (DSA's indexer, routed experts) ``(out,
+        {name: value})``."""
+        a, aux = _split_aux(self.attention(self.attention_norm(x),
+                                           cache=cache), {})
         x = x + (self.attention_post_norm(a) if self._sandwich else a)
-        f = self.feed_forward(self.ffn_norm(x))
-        return x + (self.ffn_post_norm(f) if self._sandwich else f)
+        f, aux = _split_aux(self.feed_forward(self.ffn_norm(x)), aux)
+        out = x + (self.ffn_post_norm(f) if self._sandwich else f)
+        return (out, aux) if aux else out
+
+
+def _split_aux(out, aux):
+    """``(value, aux + what the layer gave beside its value)``."""
+    if isinstance(out, tuple):
+        out, more = out
+        aux = dict(aux, **more)
+    return out, aux
 
 
 class TransformerLM(HybridBlock):
@@ -504,20 +606,52 @@ class TransformerLM(HybridBlock):
         a prefill (write the prompt's K/V into the view's pages) or a
         decode step (one token per slot, O(1) in generated length) —
         the view carries the updated pools back out."""
-        # drop aux losses stashed by a PREVIOUS trace so moe_aux_loss()
-        # can never return a stale (escaped) tracer
-        for blk in self.layers:
-            ff = blk.feed_forward
-            if isinstance(ff, MoEFeedForward):
-                ff.last_aux_loss = None
         return self.output(self.hidden(tokens, cache=cache))
 
     def hidden(self, tokens, cache=None):
         """The final norm's output (B, T, dim), what the head reads."""
-        h = self._embed(tokens)
+        return self.hidden_with_aux(tokens, cache=cache)[0]
+
+    def hidden_with_aux(self, tokens, cache=None):
+        """``(hidden, aux)``: ``aux`` sums over the blocks what each gives
+        beside its output (``{"index_loss", "router_loss", "held_pairs"}``
+        where its layers have them; empty otherwise)."""
+        h, aux = self._embed(tokens), {}
         for blk in self.layers:
-            h = blk(h, cache=cache)
-        return self.norm(h)
+            h, more = _split_aux(blk(h, cache=cache), {})
+            for k, v in more.items():
+                aux[k] = aux[k] + v if k in aux else v
+        return self.norm(h), aux
+
+    def loss(self, tokens, labels, chunk=2048):
+        """The training loss of next-token prediction over ``labels`` (B,
+        T): the mean cross-entropy (``ops.nn``'s chunked form: the logits
+        are never whole), plus what the layers add: every DSA layer's
+        indexer loss, and the routed experts' balance losses averaged over
+        their layers.  Gives ``(loss, parts)``: ``ce`` (1,) and those
+        terms, ``held_pairs`` the (token, expert) pairs routed to experts
+        held here, summed over the layers (a step's aux output)."""
+        from ..ops.nn import weighted_chunked_softmax_cross_entropy
+        z, aux = self.hidden_with_aux(tokens)
+        n_moe = sum(not isinstance(b.feed_forward, FeedForward)
+                    for b in self.layers)
+        with jax.named_scope("lm_loss"):
+            def ce_of(z, head, y):
+                N = y.size
+                total, _ = weighted_chunked_softmax_cross_entropy(
+                    z.reshape(N, -1), head, y.reshape(N),
+                    jnp.full((N,), 1.0 / N, jnp.float32), chunk)
+                return total
+
+            ce = apply_op(ce_of, [z, self.output.weight.data(), labels],
+                          name="lm_ce")
+            loss = ce
+            if "index_loss" in aux:
+                loss = loss + aux["index_loss"]
+            if "router_loss" in aux:
+                aux["router_loss"] = aux["router_loss"] / n_moe
+                loss = loss + aux["router_loss"]
+        return loss, dict(aux, ce=ce.reshape(1))
 
     def _embed(self, tokens):
         h = self.tok_embeddings(tokens)
@@ -535,19 +669,3 @@ class TransformerLM(HybridBlock):
                     n *= d
                 total += n
         return total
-
-    def moe_aux_loss(self):
-        """Sum of the MoE load-balance aux losses from the LAST forward —
-        traced scalars, so add it to the training loss INSIDE the same
-        ``forward_fn`` trace (0.0 when the model has no MoE blocks)."""
-        aux = None
-        for blk in self.layers:
-            ff = blk.feed_forward
-            if isinstance(ff, MoEFeedForward) and \
-                    ff.last_aux_loss is not None:
-                aux = ff.last_aux_loss if aux is None \
-                    else aux + ff.last_aux_loss
-        if aux is None:
-            from .. import numpy as mnp
-            return mnp.array(0.0)
-        return aux

@@ -1366,3 +1366,378 @@ def eva_flash_attention(q, k, v, ks, vs, window, scale=None, block_q=None,
         spec = P(shard[1], shard[2], None, None)
         kernel = _per_shard(kernel, shard, (spec,) * 5, spec)
     return kernel(q, k, v, ks, vs)
+
+
+# ---------------------------------------------------------------------------
+# sparse attention over each query's own selected keys (DeepSeek Sparse
+# Attention): the query at i attends to the rows idx[i, :n_valid[i]] of K/V
+# ---------------------------------------------------------------------------
+#
+# ``models/dsa.py``'s kernels.  A query's keys are a list of its own, so
+# no tile of keys is shared by two queries: the chunk's selected K/V rows
+# are gathered (one XLA gather of the K and V rows of every group, a row
+# a selected key) and the kernels walk each query's own rows, a (query
+# block, kv group) a grid step, the group's query heads read against the
+# group's rows as they are (GQA without repeated K/V).  The backward
+# kernel writes each selected row's dK/dV, which an XLA scatter adds into
+# the keys' gradient.  The work of a query is its selection's size,
+# wherever the selected keys lie.
+
+#: queries a grid step of the sparse attention kernels
+_DSA_BLOCK_Q = 8
+
+
+def _dsa_specs(bq, K, R, D):
+    from jax.experimental import pallas as pl
+    return {"bias": pl.BlockSpec((bq, K), lambda i, g: (i, 0)),
+            "q": pl.BlockSpec((bq, R, D), lambda i, g: (i, g, 0)),
+            "kv": pl.BlockSpec((bq, K, 2 * D), lambda i, g: (i, 0, g)),
+            "stat": pl.BlockSpec((1, bq, R), lambda i, g: (g, i, 0)),
+            "row": pl.BlockSpec((1, bq, K), lambda i, g: (g, i, 0))}
+
+
+def _dsa_params(bq, K, D, dtype):
+    """The pipeline double-buffers a step's (bq, K, 2D) rows; the
+    (bq, R, K) float32 scores live beside them four at a time."""
+    from jax.experimental.pallas import tpu as pltpu
+    need = 2 * 2 * bq * K * 2 * D * jnp.dtype(dtype).itemsize \
+        + 8 * bq * 8 * K * 4 + (4 << 20)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel"),
+        vmem_limit_bytes=int(min(max(need, _VMEM_DEFAULT), _VMEM_MAX)))
+
+
+_BMM = (((2,), (2,)), ((0,), (0,)))   # (b, m, d) x (b, n, d) -> (b, m, n)
+_BMN = (((2,), (1,)), ((0,), (0,)))   # (b, m, n) x (b, n, d) -> (b, m, d)
+_BTN = (((1,), (1,)), ((0,), (0,)))   # (b, m, n) x (b, m, d) -> (b, n, d)
+
+
+def _bdot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _dsa_fwd_call(q, kv, bias, scale, bq):
+    """One chunk: ``q`` (N, H, D); ``kv`` (N, K, 2 G D), query i's
+    selected rows, group g's K then V at columns [2 g D, 2 (g + 1) D);
+    ``bias`` (N, K) float32, 0 on a key and -inf on an empty slot.
+    Returns ``o`` (N, H, D), ``lse`` (G, N, R) and ``pbar`` (G, N, K),
+    group g's heads' softmax weights summed and divided by H."""
+    from jax.experimental import pallas as pl
+    N, H, D = q.shape
+    K = kv.shape[1]
+    G = kv.shape[2] // (2 * D)
+    R = H // G
+    sp = _dsa_specs(bq, K, R, D)
+
+    def kernel(bias_ref, q_ref, kv_ref, o_ref, lse_ref, pbar_ref):
+        kv = kv_ref[...]
+        k, v = kv[:, :, :D], kv[:, :, D:]
+        s = _bdot(q_ref[...], k, _BMM) * scale \
+            + bias_ref[...][:, None, :]                 # (bq, R, K)
+        m = jnp.max(s, axis=2, keepdims=True)  # a query has a key
+        p = jnp.exp(s - m)
+        l = jnp.sum(p, axis=2, keepdims=True)
+        o_ref[...] = (_bdot(p.astype(v.dtype), v, _BMN) / l) \
+            .astype(o_ref.dtype)
+        lse_ref[0] = (m + jnp.log(l))[:, :, 0]
+        pbar_ref[0] = jnp.sum(p / l, axis=1) * (1.0 / H)
+
+    with jax.named_scope("tiles_q%d_k%d" % (bq, K)):
+        return pl.pallas_call(
+            kernel,
+            name="dsa_fwd",
+            out_shape=(jax.ShapeDtypeStruct((N, H, D), q.dtype),
+                       jax.ShapeDtypeStruct((G, N, R), jnp.float32),
+                       jax.ShapeDtypeStruct((G, N, K), jnp.float32)),
+            grid=(N // bq, G),
+            in_specs=[sp["bias"], sp["q"], sp["kv"]],
+            out_specs=(sp["q"], sp["stat"], sp["row"]),
+            compiler_params=_dsa_params(bq, K, D, kv.dtype),
+            interpret=_INTERPRET,
+        )(bias, q, kv)
+
+
+def _dsa_bwd_call(q, kv, bias, do, lse, delta, scale, bq):
+    """One chunk's ``dq`` (N, H, D) and every selected row's ``dK`` and
+    ``dV`` (N, K, 2 G D), laid out as ``kv``; ``lse`` and ``delta``
+    (G, N, R)."""
+    from jax.experimental import pallas as pl
+    N, H, D = q.shape
+    K = kv.shape[1]
+    G = kv.shape[2] // (2 * D)
+    R = H // G
+    sp = _dsa_specs(bq, K, R, D)
+
+    def kernel(bias_ref, q_ref, kv_ref, do_ref, lse_ref, dlt_ref, dq_ref,
+               dkv_ref):
+        kv = kv_ref[...]
+        k, v = kv[:, :, :D], kv[:, :, D:]
+        q, do = q_ref[...], do_ref[...]
+        s = _bdot(q, k, _BMM) * scale + bias_ref[...][:, None, :]
+        p = jnp.exp(s - lse_ref[0][:, :, None])      # 0 on an empty slot
+        dp = _bdot(do, v, _BMM)
+        ds = p * (dp - dlt_ref[0][:, :, None])  # the scale waits for the sum
+        dq_ref[...] = (_bdot(ds.astype(k.dtype), k, _BMN) * scale) \
+            .astype(dq_ref.dtype)
+        dkv_ref[:, :, :D] = (_bdot(ds.astype(q.dtype), q, _BTN) * scale) \
+            .astype(dkv_ref.dtype)
+        dkv_ref[:, :, D:] = _bdot(p.astype(do.dtype), do, _BTN) \
+            .astype(dkv_ref.dtype)
+
+    with jax.named_scope("tiles_q%d_k%d" % (bq, K)):
+        return pl.pallas_call(
+            kernel,
+            name="dsa_bwd",
+            out_shape=(jax.ShapeDtypeStruct((N, H, D), q.dtype),
+                       jax.ShapeDtypeStruct(kv.shape, kv.dtype)),
+            grid=(N // bq, G),
+            in_specs=[sp["bias"], sp["q"], sp["kv"], sp["q"], sp["stat"],
+                      sp["stat"]],
+            out_specs=(sp["q"], sp["kv"]),
+            compiler_params=_dsa_params(bq, K, D, kv.dtype),
+            interpret=_INTERPRET,
+        )(bias, q, kv, do, lse, delta)
+
+
+def _dsa_fwd_dense(q, kv, bias, scale, bq=None):
+    """XLA stand-in of ``_dsa_fwd_call`` (same arguments and results)."""
+    N, H, D = q.shape
+    K = kv.shape[1]
+    G = kv.shape[2] // (2 * D)
+    R = H // G
+    kv = kv.reshape(N, K, G, 2, D)
+    qg = q.reshape(N, G, R, D)
+    s = jnp.einsum("ngrd,nkgd->ngrk", qg, kv[:, :, :, 0],
+                   preferred_element_type=jnp.float32) * scale \
+        + bias[:, None, None, :]
+    lse = jax.nn.logsumexp(s, axis=-1)
+    p = jnp.exp(s - lse[..., None])
+    o = jnp.einsum("ngrk,nkgd->ngrd", p.astype(kv.dtype), kv[:, :, :, 1],
+                   preferred_element_type=jnp.float32)
+    return (o.reshape(N, H, D).astype(q.dtype), jnp.swapaxes(lse, 0, 1),
+            jnp.swapaxes(jnp.sum(p, axis=2), 0, 1) / H)
+
+
+def _dsa_bwd_dense(q, kv, bias, do, lse, delta, scale, bq=None):
+    """XLA stand-in of ``_dsa_bwd_call``."""
+    N, H, D = q.shape
+    K = kv.shape[1]
+    G = kv.shape[2] // (2 * D)
+    R = H // G
+    kv5 = kv.reshape(N, K, G, 2, D)
+    k, v = kv5[:, :, :, 0], kv5[:, :, :, 1]
+    qg, dog = q.reshape(N, G, R, D), do.reshape(N, G, R, D)
+    s = jnp.einsum("ngrd,nkgd->ngrk", qg, k,
+                   preferred_element_type=jnp.float32) * scale \
+        + bias[:, None, None, :]
+    p = jnp.exp(s - jnp.swapaxes(lse, 0, 1)[..., None])
+    dp = jnp.einsum("ngrd,nkgd->ngrk", dog, v,
+                    preferred_element_type=jnp.float32)
+    ds = p * (dp - jnp.swapaxes(delta, 0, 1)[..., None])
+    dq = jnp.einsum("ngrk,nkgd->ngrd", ds.astype(k.dtype), k,
+                    preferred_element_type=jnp.float32) * scale
+    dk = jnp.einsum("ngrk,ngrd->nkgd", ds.astype(q.dtype), qg,
+                    preferred_element_type=jnp.float32) * scale
+    dv = jnp.einsum("ngrk,ngrd->nkgd", p.astype(do.dtype), dog,
+                    preferred_element_type=jnp.float32)
+    dkv = jnp.stack([dk, dv], axis=3).reshape(kv.shape)
+    return dq.reshape(N, H, D).astype(q.dtype), dkv.astype(kv.dtype)
+
+
+def _dsa_rows(k, v):
+    """(N, G, D) K and V -> (N, 2 G D): a key's row, group g's K then V
+    at columns [2 g D, 2 (g + 1) D)."""
+    N, G, D = k.shape
+    return jnp.stack([k, v], axis=2).reshape(N, 2 * G * D)
+
+
+def _dsa_bias(n_valid, K):
+    return jnp.where(jnp.arange(K)[None, :] < n_valid[:, None], 0.0,
+                     NEG_INF).astype(jnp.float32)
+
+
+def _dsa_calls():
+    if _pallas_available():
+        return _dsa_fwd_call, _dsa_bwd_call
+    return _dsa_fwd_dense, _dsa_bwd_dense
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _dsa(q, k, v, idx, n_valid, scale, chunk, bq):
+    return _dsa_fwd(q, k, v, idx, n_valid, scale, chunk, bq)[0]
+
+
+def _chunked(a, chunk):
+    return a.reshape((a.shape[0] // chunk, chunk) + a.shape[1:])
+
+
+def _dsa_fwd(q, k, v, idx, n_valid, scale, chunk, bq):
+    N, H, D = q.shape
+    G, K = k.shape[1], idx.shape[1]
+    fwd_call = _dsa_calls()[0]
+    rows = _dsa_rows(k, v)
+
+    def one(_, c):
+        qc, ic, nc = c
+        with jax.named_scope("gather"):
+            sel = jnp.take(rows, ic, axis=0)
+        return None, fwd_call(qc, sel, _dsa_bias(nc, K), scale, bq)
+
+    _, (o, lse, pbar) = jax.lax.scan(
+        one, None, (_chunked(q, chunk), _chunked(idx, chunk),
+                    _chunked(n_valid, chunk)))
+    o = checkpoint_name(o.reshape(N, H, D), ATTENTION_KERNEL_OUT)
+    lse = checkpoint_name(lse, ATTENTION_KERNEL_OUT)    # (n, G, C, R)
+    pbar = checkpoint_name(jnp.sum(pbar, axis=1).reshape(N, K),
+                           ATTENTION_KERNEL_OUT)
+    return (o, pbar), (q, k, v, idx, n_valid, o, lse)
+
+
+def _dsa_bwd(scale, chunk, bq, res, cot):
+    q, k, v, idx, n_valid, o, lse = res
+    do = cot[0]                 # the weights' mean is read unrolled
+    N, H, D = q.shape
+    G, K = k.shape[1], idx.shape[1]
+    R = H // G
+    bwd_call = _dsa_calls()[1]
+    rows = _dsa_rows(k, v)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
+    delta = jnp.swapaxes(delta.reshape(N // chunk, chunk, G, R), 1, 2)
+
+    def one(acc, c):
+        qc, dc, ic, nc, lc, tc = c
+        with jax.named_scope("gather"):
+            sel = jnp.take(rows, ic, axis=0)
+        dq, dsel = bwd_call(qc, sel, _dsa_bias(nc, K), dc, lc, tc, scale,
+                            bq)
+        with jax.named_scope("scatter"):
+            acc = acc.at[ic].add(dsel.astype(jnp.float32))
+        return acc, dq
+
+    acc, dq = jax.lax.scan(
+        one, jnp.zeros(rows.shape, jnp.float32),
+        (_chunked(q, chunk), _chunked(do.astype(q.dtype), chunk),
+         _chunked(idx, chunk), _chunked(n_valid, chunk), lse, delta))
+    acc = acc.reshape(N, G, 2, D)
+    import numpy as onp
+    zero = onp.zeros(idx.shape, jax.dtypes.float0)
+    return (dq.reshape(N, H, D), acc[:, :, 0].astype(k.dtype),
+            acc[:, :, 1].astype(v.dtype), zero,
+            onp.zeros(n_valid.shape, jax.dtypes.float0))
+
+
+_dsa.defvjp(_dsa_fwd, _dsa_bwd)
+
+
+def sparse_attention(q, k, v, idx, n_valid, scale=None, chunk=256,
+                     block_q=None):
+    """Attention of each query over its own selected keys, token-major:
+    ``q`` (N, H, D), ``k`` / ``v`` (N, G, D) with G dividing H (GQA: head
+    h reads group h // (H / G)), ``idx`` (N, K) int rows of ``k`` / ``v``
+    and ``n_valid`` (N,) int: query i attends to ``idx[i, :n_valid[i]]``
+    (at least one), the other slots are empty.  Returns ``o`` (N, H, D)
+    and ``pbar`` (N, K) float32, the mean over the H heads of each
+    slot's softmax weight (0 on an empty slot), which has no gradient.
+
+    The queries go ``chunk`` at a time (it divides N): their selected
+    K/V rows are gathered, the kernels (``dsa_fwd``; in the backward
+    ``dsa_bwd``, whose rows' dK/dV are added into the keys' by a
+    scatter) walk them.  Off the TPU the same chunks in XLA."""
+    N, H, D = q.shape
+    G = k.shape[1]
+    if H % G or k.shape != v.shape or k.shape[0] != N \
+            or idx.shape[0] != N or N % min(chunk, N):
+        raise ValueError(
+            "sparse_attention: q %s, k/v %s, idx %s: heads a multiple of "
+            "the groups, a row of idx a query, and the chunk dividing the "
+            "queries" % (q.shape, k.shape, idx.shape))
+    if scale is None:
+        scale = D ** -0.5
+    bq = block_q or min(_DSA_BLOCK_Q, N)
+    return _dsa(q, k, v, idx.astype(jnp.int32),
+                n_valid.astype(jnp.int32), float(scale), min(chunk, N), bq)
+
+
+# ---------------------------------------------------------------------------
+# the lightning indexer's scores: I[t, s] = sum_j w[t, j] relu(q[j, t] . k[s])
+# ---------------------------------------------------------------------------
+
+#: the (queries, keys) tile of the index score kernel
+_INDEX_TILE = 512
+
+
+def _index_call(q, k, w, q_off, causal, bq, bk):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    Hi, Tq, Di = q.shape
+    Tk = k.shape[0]
+
+    def kernel(qo_ref, q_ref, k_ref, w_ref, o_ref):
+        q0 = qo_ref[0] + pl.program_id(0) * bq
+        k0 = pl.program_id(1) * bk
+
+        def scores():
+            kblk = k_ref[...]
+            w = w_ref[...]
+            acc = jnp.zeros((bq, bk), jnp.float32)
+            for j in range(Hi):
+                s = _dot(q_ref[j], kblk, _NT)
+                acc = acc + jnp.maximum(s, 0.0) * w[:, j:j + 1]
+            if causal:
+                acc = jnp.where(_visible(q0, k0, (bq, bk), 0), acc,
+                                NEG_INF)
+            o_ref[...] = acc
+
+        if causal:
+            pl.when(k0 <= q0 + bq - 1)(scores)
+
+            @pl.when(k0 > q0 + bq - 1)
+            def _future():
+                o_ref[...] = jnp.full((bq, bk), NEG_INF, jnp.float32)
+        else:
+            scores()
+
+    return pl.pallas_call(
+        kernel,
+        name="dsa_index",
+        out_shape=jax.ShapeDtypeStruct((Tq, Tk), jnp.float32),
+        grid=(Tq // bq, Tk // bk),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec((Hi, bq, Di), lambda i, j: (0, i, 0)),
+                  pl.BlockSpec((bk, Di), lambda i, j: (j, 0)),
+                  pl.BlockSpec((bq, Hi), lambda i, j: (i, 0))],
+        out_specs=pl.BlockSpec((bq, bk), lambda i, j: (i, j)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=_INTERPRET,
+    )(q_off, q, k, w)
+
+
+def index_scores_dense(q, k, w, q0=0, causal=True):
+    """XLA form of :func:`index_scores` (and its gradient)."""
+    s = jnp.einsum("jtd,sd->jts", q, k, preferred_element_type=jnp.float32)
+    out = jnp.einsum("jts,tj->ts", jnp.maximum(s, 0.0),
+                     w.astype(jnp.float32))
+    if causal:
+        qpos = q0 + jnp.arange(q.shape[1])[:, None]
+        out = jnp.where(jnp.arange(k.shape[0])[None, :] <= qpos, out,
+                        NEG_INF)
+    return out
+
+
+def index_scores(q, k, w, q0=0, causal=True):
+    """The indexer's scores ``I[t, s] = sum_j w[t, j] relu(q[j, t] .
+    k[s])``, float32 (Tq, Tk), -inf where ``s`` lies after the query's
+    position ``q0 + t`` (``causal``; ``q0`` may be traced).  ``q``
+    (heads, Tq, d), ``k`` (Tk, d), ``w`` (Tq, heads).  No gradient: the
+    indexer's loss differentiates :func:`index_scores_dense`.  One
+    Pallas call (``dsa_index``, 512 x 512 tiles, the future's tiles
+    skipped) where the tiles divide the rows."""
+    Hi, Tq, Di = q.shape
+    Tk = k.shape[0]
+    b = _INDEX_TILE
+    if not _pallas_available() or Tq % b or Tk % b or Di % 64:
+        return index_scores_dense(q, k, w, q0, causal)
+    q_off = jnp.reshape(jnp.asarray(q0, jnp.int32), (1,))
+    return _index_call(q, k, w.astype(jnp.float32), q_off, causal, b, b)

@@ -197,7 +197,8 @@ class ServeConfig:
         from .models.kv_cache import CacheSpec
         return CacheSpec(
             n_layers=cfg.n_layers, n_kv_heads=cfg.n_kv_heads,
-            head_dim=cfg.dim // cfg.n_heads, slots=self.slots,
+            head_dim=cfg.head_dim or cfg.dim // cfg.n_heads,
+            slots=self.slots,
             pages=self.pages, page_size=self.page_size,
             max_pages_per_slot=self.max_pages_per_slot, dtype=cfg.dtype)
 

@@ -376,6 +376,51 @@ def test_the_token_cells_flash_kernels_keep_their_names_and_tiles(
     assert not any("eva" in ln for ln in lines)
 
 
+def test_sparse_attention_compiles_at_the_sparse_cells_shape(topo, on_chip):
+    """``keye_vl2_30b_a3b_train_1x32768``'s sparse attention at its
+    widths, two chunks of 256 queries (32 heads and 4 K/V groups of 128,
+    2,048 selected keys a query, bf16), forward and gradient: ``dsa_fwd``
+    and ``dsa_bwd`` each under the tile they run."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    N, K = 512, 2048
+
+    def av(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def grad(q, k, v, idx, n_valid):
+        return jax.grad(lambda *a: pallas_ops.sparse_attention(
+            *a, idx, n_valid)[0].astype(jnp.float32).sum(), range(3))(
+                q, k, v)
+
+    lines = _kernel_lines(grad, av((N, 32, 128)), av((N, 4, 128)),
+                          av((N, 4, 128)), av((N, K), jnp.int32),
+                          av((N,), jnp.int32))
+    assert len(lines) == 2
+    for name in ("dsa_fwd", "dsa_bwd"):
+        assert any(re.search(r'op_name="[^"]*\btiles_q8_k2048\)*/%s/'
+                             r'pallas_call"' % name, ln) for ln in lines), \
+            (name, lines)
+
+
+def test_index_kernel_and_grouped_matmul_compile_at_the_cells_widths(
+        topo, on_chip):
+    """The indexer's scores of 512 queries against 32,768 keys (16 heads
+    of 64) and the held experts' grouped matmul (16 experts of 2,048 x
+    768 over 4,096 rows): one ``dsa_index`` call, megablox's kernels."""
+    from mxnet_tpu.models.experts import grouped_matmul
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def av(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lines = _kernel_lines(
+        lambda q, k, w: pallas_ops.index_scores(q, k, w, q0=32256),
+        av((16, 512, 64)), av((32768, 64)), av((512, 16), jnp.float32))
+    assert len(lines) == 1 and "dsa_index" in lines[0]
+    assert _kernels(grouped_matmul, av((4096, 2048)), av((16, 2048, 768)),
+                    av((17,), jnp.int32)) >= 1
+
+
 def test_decode_program_carries_its_scopes(topo, on_chip):
     """The serving decode program at one layer of 128-wide heads: the
     K/V write, the attention read (the paged kernel under it, by name)

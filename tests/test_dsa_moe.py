@@ -1,0 +1,240 @@
+"""DeepSeek Sparse Attention (``models/dsa.py``, the ``dsa_fwd`` /
+``dsa_bwd`` / ``dsa_index`` kernels of ``ops/pallas_ops.py``) and routed
+experts (``models/experts.py``) against straightforward float32
+``jax.numpy`` at toy size on the CPU."""
+import jax
+import jax.numpy as jnp
+import numpy as onp
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu import parallel
+from mxnet_tpu.models import TransformerLM, dsa, experts, tiny_config
+from mxnet_tpu.ops import pallas_ops
+
+
+def _normal(key, *shape):
+    return jax.random.normal(jax.random.key(key), shape, jnp.float32)
+
+
+def _dense_dsa(q, k, v, qi, ki, w, topk):
+    """One sequence: the scores of every pair, the top ``topk`` of each
+    row by ``lax.top_k``, one dense softmax masked to them, and the
+    indexer's KL against the heads' mean weight."""
+    T, H, D = q.shape
+    rep = H // k.shape[1]
+    s = jnp.einsum("tjs,tj->ts", jax.nn.relu(jnp.einsum("tjd,sd->tjs", qi,
+                                                        ki)), w)
+    causal = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
+    s = jnp.where(causal, s, -jnp.inf)
+    _, idx = jax.lax.top_k(s, topk)
+    n_valid = jnp.minimum(topk, jnp.arange(T) + 1)
+    sel = jnp.zeros((T, T), bool).at[jnp.arange(T)[:, None], idx].set(
+        jnp.arange(topk)[None, :] < n_valid[:, None])
+    a = jnp.einsum("thd,shd->hts", q, jnp.repeat(k, rep, 1)) / jnp.sqrt(D)
+    a = jax.nn.softmax(jnp.where(sel[None], a, -jnp.inf), -1)
+    o = jnp.einsum("hts,shd->thd", a, jnp.repeat(v, rep, 1))
+    p = jax.lax.stop_gradient(jnp.where(sel, jnp.mean(a, 0), 0.0))
+    logq = jnp.where(sel, jax.nn.log_softmax(jnp.where(sel, s, -jnp.inf)),
+                     0.0)
+    kl = jnp.sum(jnp.where(sel, jax.scipy.special.xlogy(p, p) - p * logq,
+                           0.0), -1)
+    return o, jnp.mean(kl), sel
+
+
+def _dsa_inputs(T=64, H=4, G=2, D=16, Hi=2, Di=8):
+    q, k, v = _normal(1, T, H, D), _normal(2, T, G, D), _normal(3, T, G, D)
+    qi, ki, w = _normal(4, T, Hi, Di), _normal(5, T, Di), _normal(6, T, Hi)
+    return q, k, v, qi, ki, w
+
+
+def test_dsa_output_loss_and_every_gradient_match_the_dense_form():
+    args = _dsa_inputs()
+    r = _normal(7, *args[0].shape)
+
+    def program(q, k, v, qi, ki, w):
+        o, loss = dsa.dsa_attention(q[None], k[None], v[None], qi[None],
+                                    ki[None], w[None], 16)
+        return jnp.sum(o[0] * r) + loss
+
+    def plain(q, k, v, qi, ki, w):
+        o, loss, _ = _dense_dsa(q, k, v, qi, ki, w, 16)
+        return jnp.sum(o * r) + loss
+
+    with jax.default_matmul_precision("highest"):
+        assert abs(float(program(*args)) - float(plain(*args))) < 1e-4
+        got = jax.jit(jax.grad(program, argnums=range(6)))(*args)
+        want = jax.jit(jax.grad(plain, argnums=range(6)))(*args)
+    for name, g, w_ in zip("q k v qi ki w".split(), got, want):
+        scale = float(jnp.max(jnp.abs(w_)))
+        assert scale > 0, name
+        assert float(jnp.max(jnp.abs(g - w_))) < 1e-4 * max(scale, 1), name
+
+
+def test_the_indexer_loss_reaches_only_the_indexer():
+    """The selection is discrete: the attention's output carries no
+    gradient to the indexer, and the indexer's loss none to q, k, v."""
+    args = _dsa_inputs()
+
+    def part(which):
+        def f(*a):
+            o, loss = dsa.dsa_attention(*(x[None] for x in a), 16)
+            return jnp.sum(o) if which == "out" else loss
+        return jax.grad(f, argnums=range(6))(*args)
+
+    out, loss = part("out"), part("loss")
+    for i in range(3):
+        assert float(jnp.abs(out[i]).max()) > 0
+        assert float(jnp.abs(loss[i]).max()) == 0
+    for i in range(3, 6):
+        assert float(jnp.abs(out[i]).max()) == 0
+        assert float(jnp.abs(loss[i]).max()) > 0
+
+
+def test_the_sparse_kernels_interpreted_match_attention_masked_to_the_set(
+        monkeypatch):
+    monkeypatch.setattr(pallas_ops, "_INTERPRET", True)
+    T, K = 32, 8
+    q, k, v = _normal(1, T, 4, 128), _normal(2, T, 2, 128), \
+        _normal(3, T, 2, 128)
+    s = jnp.where(jnp.arange(T)[None, :] <= jnp.arange(T)[:, None],
+                  _normal(4, T, T), -jnp.inf)
+    _, idx = jax.lax.top_k(s, K)
+    n_valid = jnp.minimum(K, jnp.arange(T) + 1)
+    sel = jnp.zeros((T, T), bool).at[jnp.arange(T)[:, None], idx].set(
+        jnp.arange(K)[None, :] < n_valid[:, None])
+    r = _normal(5, T, 4, 128)
+
+    def kernel(q, k, v):
+        return jnp.sum(pallas_ops.sparse_attention(q, k, v, idx, n_valid,
+                                                   chunk=16)[0] * r)
+
+    def masked(q, k, v):
+        a = jnp.einsum("thd,shd->hts", q, jnp.repeat(k, 2, 1)) / jnp.sqrt(
+            128.0)
+        a = jax.nn.softmax(jnp.where(sel[None], a, -jnp.inf), -1)
+        return jnp.sum(jnp.einsum("hts,shd->thd", a, jnp.repeat(v, 2, 1))
+                       * r)
+
+    with jax.default_matmul_precision("highest"):
+        assert abs(float(kernel(q, k, v)) - float(masked(q, k, v))) < 1e-3
+        for g, w in zip(jax.grad(kernel, (0, 1, 2))(q, k, v),
+                        jax.grad(masked, (0, 1, 2))(q, k, v)):
+            assert float(jnp.max(jnp.abs(g - w))) < 1e-4
+
+
+def test_the_index_kernel_interpreted_matches_its_xla_form(monkeypatch):
+    monkeypatch.setattr(pallas_ops, "_INTERPRET", True)
+    qi, ki, w = _normal(1, 2, 512, 64), _normal(2, 1024, 64), \
+        _normal(3, 512, 2)
+    with jax.default_matmul_precision("highest"):
+        got = pallas_ops.index_scores(qi, ki, w, q0=512)
+        want = pallas_ops.index_scores_dense(qi, ki, w, q0=512)
+    seen = jnp.isfinite(want)
+    assert bool(jnp.all(jnp.isfinite(got) == seen))
+    assert float(jnp.max(jnp.abs(jnp.where(seen, got - want, 0.0)))) < 1e-3
+
+
+@pytest.mark.parametrize("L,segment", [(64, 16), (96, 32), (40, 16)])
+def test_the_two_stage_top_k_is_the_top_k_ties_to_the_lower_position(
+        L, segment):
+    # integer scores: many ties
+    s = jnp.floor(_normal(9, 6, L) * 3)
+    v1, i1 = dsa.exact_top_k(s, 8, segment)
+    v2, i2 = jax.lax.top_k(s, 8)
+    assert bool(jnp.all(jnp.sort(i1, -1) == jnp.sort(i2, -1)))
+    assert bool(jnp.all(jnp.sort(v1, -1) == jnp.sort(v2, -1)))
+
+
+def _experts(E=8, D=16, F=8, key=0):
+    return (_normal(key, E, D) * 0.5, _normal(key + 1, E, D, F) * 0.3,
+            _normal(key + 2, E, D, F) * 0.3, _normal(key + 3, E, F, D) * 0.3)
+
+
+def _plain_experts(x, router, w1, w3, w2, top_k, held):
+    """Every token through every expert; a token's output the gated sum
+    of the outputs of its top_k experts that are held."""
+    probs = jax.nn.softmax(x @ router.T, -1)
+    top_p, top_e = jax.lax.top_k(probs, top_k)
+    gates = top_p / jnp.sum(top_p, -1, keepdims=True)
+    h = jax.nn.silu(jnp.einsum("td,edf->tef", x, w1)) \
+        * jnp.einsum("td,edf->tef", x, w3)
+    out = jnp.einsum("tef,efd->ted", h, w2)
+    g = jnp.zeros(probs.shape).at[jnp.arange(x.shape[0])[:, None],
+                                  top_e].add(gates)
+    g = g * jnp.isin(jnp.arange(router.shape[0]), jnp.asarray(list(held)))
+    return jnp.einsum("te,ted->td", g, out)
+
+
+def test_eight_shares_of_the_experts_add_up_to_the_whole_layer():
+    router, w1, w3, w2 = _experts()
+    x = _normal(10, 24, 16)
+    with jax.default_matmul_precision("highest"):
+        whole, aux, n = experts.routed_experts(x, router, w1, w3, w2, 0, 3)
+        parts = [experts.routed_experts(x, router, w1[i:i + 1],
+                                        w3[i:i + 1], w2[i:i + 1], i, 3)
+                 for i in range(8)]
+        plain = _plain_experts(x, router, w1, w3, w2, 3, range(8))
+    assert float(jnp.max(jnp.abs(sum(p[0] for p in parts) - whole))) < 1e-5
+    assert float(jnp.max(jnp.abs(whole - plain))) < 1e-5
+    assert int(n) == 24 * 3 == sum(int(p[2]) for p in parts)
+    for p in parts:     # the router's term is the whole model's
+        assert abs(float(p[1]) - float(aux)) < 1e-6
+
+
+def test_no_token_is_dropped_when_routing_piles_onto_one_expert():
+    router, w1, w3, w2 = _experts()
+    router = router.at[5].set(router[5] + 40.0 * jnp.ones(16))
+    x = jnp.abs(_normal(11, 64, 16)) + 0.1
+    with jax.default_matmul_precision("highest"):
+        probs, top_e, _ = experts.route(x, router, 2)
+        assert bool(jnp.all(top_e[:, 0] == 5))
+        got, _, n = experts.routed_experts(x, router, w1[4:8], w3[4:8],
+                                           w2[4:8], 4, 2)
+        want = _plain_experts(x, router, w1, w3, w2, 2, range(4, 8))
+        grads = jax.grad(lambda a: jnp.sum(experts.routed_experts(
+            a, router, w1[4:8], w3[4:8], w2[4:8], 4, 2)[0] ** 2))(x)
+        want_g = jax.grad(lambda a: jnp.sum(_plain_experts(
+            a, router, w1, w3, w2, 2, range(4, 8)) ** 2))(x)
+    assert int(n) >= 64      # every token's pair with expert 5
+    assert float(jnp.max(jnp.abs(got - want))) < 1e-5
+    assert float(jnp.max(jnp.abs(grads - want_g))) < 1e-4
+
+
+def test_the_model_s_indexer_loss_moves_the_indexer_alone():
+    """A step whose loss is the indexer's KL alone (plain SGD, no decay)
+    moves the indexer's leaves and nothing else: its input is detached
+    and the selection is discrete."""
+    cfg = tiny_config(n_layers=1, attn_impl="dsa", qk_norm=True,
+                      index_heads=2, index_head_dim=16, index_topk=8,
+                      head_dim=32, vocab_size=64)
+    net = TransformerLM(cfg)
+    net.initialize()
+    before = {k: onp.asarray(p.data()._data)
+              for k, p in net.collect_params().items()}
+    step = parallel.TrainStep(
+        net, None, mx.optimizer.SGD(learning_rate=1.0, wd=0.0), mesh=None,
+        forward_fn=lambda net, x, y: net.hidden_with_aux(x)[1][
+            "index_loss"])
+    toks = mx.np.array(onp.random.RandomState(0).randint(0, 64, (2, 32))
+                       .astype("int32"))
+    step(toks, toks)
+    moved = {k for k, p in net.collect_params().items()
+             if not onp.array_equal(onp.asarray(p.data()._data), before[k])}
+    assert moved and all(".indexer." in k for k in moved), moved
+    assert any("indexer.wk" in k for k in moved)
+
+
+def test_head_dim_is_its_own_field():
+    cfg = tiny_config(head_dim=48, n_heads=4, n_kv_heads=2, qk_norm=True)
+    net = TransformerLM(cfg)
+    ps = net.collect_params()
+    assert ps["layer0.attention.wq.weight"].shape == (4 * 48, cfg.dim)
+    assert ps["layer0.attention.wk.weight"].shape == (2 * 48, cfg.dim)
+    assert ps["layer0.attention.q_norm.gamma"].shape == (48,)
+    net.initialize()
+    out = net(mx.np.array(onp.zeros((1, 16), "int32")))
+    assert out.shape == (1, 16, cfg.vocab_size)
+    from mxnet_tpu import serve
+    spec = serve.ServeConfig(slots=2, pages=8, page_size=16).cache_spec(cfg)
+    assert spec.head_dim == 48

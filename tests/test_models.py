@@ -107,34 +107,33 @@ def test_graft_entry_dryrun():
 
 def test_transformer_lm_moe_trains_with_aux_loss():
     """MoE TransformerLM: moe_num_experts routes every moe_every-th block
-    through the ep-shardable switch FFN; aux loss joins the training loss
-    inside the same trace and the model still learns."""
+    through the routed-expert FFN (top moe_top_k, dropless); the router's
+    balance loss joins the training loss inside the same trace
+    (``TransformerLM.loss``) and the model still learns."""
     from mxnet_tpu.models import TransformerLM, tiny_config
     mx.np.random.seed(0)
     cfg = tiny_config(n_layers=2, moe_num_experts=4, moe_every=2,
-                      vocab_size=64)
+                      moe_top_k=2, vocab_size=64)
     net = TransformerLM(cfg)
     net.initialize()
-    from mxnet_tpu.models.transformer import MoEFeedForward, FeedForward
+    from mxnet_tpu.models.experts import RoutedExperts
+    from mxnet_tpu.models.transformer import FeedForward
     kinds = [type(blk.feed_forward) for blk in net.layers]
-    assert kinds == [MoEFeedForward, FeedForward]
-
-    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
-
-    def fwd(net, tokens, labels):
-        logits = net.forward(tokens)
-        ce = loss_fn(logits.reshape(-1, logits.shape[-1]),
-                     labels.reshape(-1)).mean()
-        return ce + 0.01 * net.moe_aux_loss()
+    assert kinds == [RoutedExperts, FeedForward]
 
     onp.random.seed(0)
     toks = mx.np.array(onp.random.randint(0, 64, (4, 16)).astype("int32"))
     labs = mx.np.array(onp.random.randint(0, 64, (4, 16)).astype("int32"))
     step = parallel.TrainStep(net, None,
                               mx.optimizer.AdamW(learning_rate=1e-2),
-                              mesh=None, forward_fn=fwd)
-    l0 = float(step(toks, labs))
+                              mesh=None,
+                              forward_fn=lambda net, x, y: net.loss(x, y))
+    l0, parts = step(toks, labs)
+    # every pair is routed to an expert held here: 4 x 16 tokens, top 2
+    assert int(parts["held_pairs"]._data) == 4 * 16 * 2
+    assert float(parts["router_loss"]._data) > 0
     for _ in range(8):
-        ln = float(step(toks, labs))
+        ln, _ = step(toks, labs)
+    l0, ln = float(l0), float(ln)
     assert onp.isfinite(l0) and onp.isfinite(ln)
     assert ln < l0  # memorizes the fixed batch

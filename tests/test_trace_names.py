@@ -324,6 +324,82 @@ def test_eva_step_keeps_the_flash_kernels_names(eva_names, kernel, phase,
     assert again and any("/feed_forward/" in n for n in again)
 
 
+@pytest.fixture(scope="module")
+def dsa_names():
+    """Op names of the compiled training step of a sparse-attention MoE
+    decoder (``keye_vl2_30b_a3b_config`` at toy widths), every block
+    marked for recomputation, the kernels interpreted: 512 tokens, so
+    that the index kernel's tiles and the grouped matmul's rows run."""
+    from mxnet_tpu.models import TransformerLM, keye_vl2_30b_a3b_config
+    from mxnet_tpu.ndarray.ndarray import NDArray
+    from mxnet_tpu.ops import pallas_ops
+    cfg = keye_vl2_30b_a3b_config(
+        vocab_size=256, dim=128, n_layers=2, n_heads=4, n_kv_heads=2,
+        head_dim=128, index_heads=2, index_head_dim=64, index_topk=64,
+        moe_num_experts=8, moe_top_k=2, moe_hidden_dim=128, moe_held=4,
+        max_seq_len=1024)
+    net = TransformerLM(cfg)
+    net.initialize()
+    for blk in net.layers:
+        blk.recompute()
+    tok = NDArray(jnp.zeros((1, 512), jnp.int32))
+    step = parallel.TrainStep(
+        net, None, mx.optimizer.AdamW(learning_rate=1e-3), mesh=None,
+        forward_fn=lambda net, t, l: net.loss(t, l, chunk=256))
+    was = pallas_ops._INTERPRET
+    pallas_ops._INTERPRET = True
+    try:
+        text = step.lower(tok, tok).compile().as_text()
+    finally:
+        pallas_ops._INTERPRET = was
+    return set(re.findall(r'op_name="(jit\(step\)/[^"]*)"', text))
+
+
+FIRST = "jit(step)/jvp(forward)/layer1/"
+BACK = "jit(step)/transpose(jvp(forward))/"
+
+
+@pytest.mark.parametrize("path,backward", [
+    ("attention/indexer/", False), ("attention/indexer/indexer_loss/", True),
+    ("attention/sparse_attn/", False), ("attention/sparse_attn/", True),
+    ("feed_forward/experts/router/", False),
+    ("feed_forward/experts/dispatch/", True),
+    ("feed_forward/experts/gmm/", False), ("feed_forward/experts/gmm/", True),
+    ("feed_forward/experts/combine/", False),
+    ("feed_forward/experts/combine/", True)])
+def test_dsa_moe_step_carries_indexer_sparse_attn_and_experts(
+        dsa_names, path, backward):
+    # dsa_indexer_device_pct.train, sparse_attn_device_pct.train and
+    # experts_device_pct.train read these scopes
+    if backward:
+        assert any(n.startswith(BACK) and "/layer1/checkpoint/" + path in n
+                   for n in dsa_names), path
+    else:
+        assert any(n.startswith(FIRST + path) for n in dsa_names), path
+
+
+@pytest.mark.parametrize("kernel,where,there", [
+    ("dsa_index", FIRST + "attention/indexer/", True),
+    ("dsa_fwd", FIRST + "attention/sparse_attn/", True),
+    ("dsa_bwd", "/checkpoint/attention/sparse_attn/", True),
+    ("dsa_index", "/rematted_computation/", False),
+    ("dsa_fwd", "/rematted_computation/", False),
+    ("dsa_bwd", "/rematted_computation/", False)])
+def test_dsa_moe_step_keeps_its_kernels_names(dsa_names, kernel, where,
+                                              there):
+    # the sparse kernels under the tile they run (queries a grid step,
+    # keys a query); a marked block keeps the selection and the forward
+    # kernel's output, so neither the scoring nor the forward kernel is
+    # among the recomputed ops
+    tile = r"tiles_q\d+_k\d+/" if kernel != "dsa_index" else ""
+    hits = [n for n in dsa_names
+            if re.search(r"%s%s\)*/" % (tile, kernel), n) and where in n]
+    assert bool(hits) == there, (kernel, where, hits[:3])
+    again = {n for n in dsa_names if "/rematted_computation/" in n}
+    assert any("/feed_forward/experts/gmm/" in n for n in again)
+    assert not any("/indexer/" in n and "top_k" in n for n in again)
+
+
 def test_block_scope_names():
     net = gluon.nn.HybridSequential()
     net.add(gluon.nn.Dense(4, in_units=3), gluon.nn.Activation("relu"))
