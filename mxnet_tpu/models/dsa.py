@@ -193,7 +193,9 @@ def dsa_attention(q, k, v, qi, ki, w, topk, scale=None):
     (B, T, heads) float32 with 1 / sqrt(heads d_I) folded in.  Returns
     ``(o (B, T, H, D), L^I averaged over the sequences)``.  Named scopes:
     ``indexer`` (scoring, top-k, ``indexer_loss``) and ``sparse_attn``
-    (the gather, the kernels, the scatter)."""
+    (the forward's gather of the selected rows, the kernels; the
+    backward's scatter of dK/dV only where a group's K/V and dK/dV do
+    not fit the backward kernel's VMEM)."""
     B, T, H, D = q.shape
     sel = []
     with jax.named_scope("indexer"):

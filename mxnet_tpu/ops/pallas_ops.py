@@ -1374,17 +1374,34 @@ def eva_flash_attention(q, k, v, ks, vs, window, scale=None, block_q=None,
 # ---------------------------------------------------------------------------
 #
 # ``models/dsa.py``'s kernels.  A query's keys are a list of its own, so
-# no tile of keys is shared by two queries: the chunk's selected K/V rows
-# are gathered (one XLA gather of the K and V rows of every group, a row
-# a selected key) and the kernels walk each query's own rows, a (query
-# block, kv group) a grid step, the group's query heads read against the
-# group's rows as they are (GQA without repeated K/V).  The backward
-# kernel writes each selected row's dK/dV, which an XLA scatter adds into
-# the keys' gradient.  The work of a query is its selection's size,
-# wherever the selected keys lie.
+# no tile of keys is shared by two queries: the kernels walk each query's
+# own rows, a (query block, kv group) a grid step, the group's query
+# heads read against the group's rows as they are (GQA without repeated
+# K/V).  The work of a query is its selection's size, wherever the
+# selected keys lie.
+#
+# The forward goes a chunk of queries at a time: the chunk's selected K/V
+# rows are gathered by XLA (one gather of the K and V rows of every
+# group, a row a selected key) and ``dsa_fwd`` walks them.  The backward,
+# where a KV group's K and V over the whole sequence and its float32
+# dK/dV fit in VMEM beside a block's working set (``_dsa_resident``: to
+# some 47k tokens at 128-wide bf16 groups), is one call of ``dsa_bwd``
+# over (group, query block) that moves the rows itself: the group's K/V
+# is copied in once, each selected row is read from there by its index
+# (the indices reach the scalar core a query block at a time), and every
+# selected row's float32 dK/dV is added into the group's resident
+# accumulator, written out when the group's last block is done.  No
+# selected row passes through HBM there.  Past that length the backward
+# goes by chunks too, its rows' dK/dV added into the keys' gradient by an
+# XLA scatter.  (A resident forward ran twice as fast as the chunks on a
+# v5e, but its ``pbar`` stays live beside the copy ``jax.checkpoint``
+# saves of it, 0.27 GB a layer at 32k, and the sparse cell's step then
+# does not load.)
 
 #: queries a grid step of the sparse attention kernels
 _DSA_BLOCK_Q = 8
+#: selected rows a step of the resident backward kernel's row loops
+_DSA_UNROLL = 8
 
 
 def _dsa_specs(bq, K, R, D):
@@ -1415,6 +1432,18 @@ _BTN = (((1,), (1,)), ((0,), (0,)))   # (b, m, n) x (b, m, d) -> (b, n, d)
 def _bdot(a, b, dims):
     return jax.lax.dot_general(a, b, dims,
                                preferred_element_type=jnp.float32)
+
+
+def _dsa_bwd_block(q, k, v, bias, do, lse, delta, scale):
+    """``dq`` (bq, R, D) and the rows' ``dk``, ``dv`` (bq, K, D), all
+    float32; ``lse`` and ``delta`` (bq, R)."""
+    s = _bdot(q, k, _BMM) * scale + bias[:, None, :]
+    p = jnp.exp(s - lse[:, :, None])            # 0 on an empty slot
+    dp = _bdot(do, v, _BMM)
+    ds = p * (dp - delta[:, :, None])   # the scale waits for the sum
+    return (_bdot(ds.astype(k.dtype), k, _BMN) * scale,
+            _bdot(ds.astype(q.dtype), q, _BTN) * scale,
+            _bdot(p.astype(do.dtype), do, _BTN))
 
 
 def _dsa_fwd_call(q, kv, bias, scale, bq):
@@ -1472,18 +1501,12 @@ def _dsa_bwd_call(q, kv, bias, do, lse, delta, scale, bq):
     def kernel(bias_ref, q_ref, kv_ref, do_ref, lse_ref, dlt_ref, dq_ref,
                dkv_ref):
         kv = kv_ref[...]
-        k, v = kv[:, :, :D], kv[:, :, D:]
-        q, do = q_ref[...], do_ref[...]
-        s = _bdot(q, k, _BMM) * scale + bias_ref[...][:, None, :]
-        p = jnp.exp(s - lse_ref[0][:, :, None])      # 0 on an empty slot
-        dp = _bdot(do, v, _BMM)
-        ds = p * (dp - dlt_ref[0][:, :, None])  # the scale waits for the sum
-        dq_ref[...] = (_bdot(ds.astype(k.dtype), k, _BMN) * scale) \
-            .astype(dq_ref.dtype)
-        dkv_ref[:, :, :D] = (_bdot(ds.astype(q.dtype), q, _BTN) * scale) \
-            .astype(dkv_ref.dtype)
-        dkv_ref[:, :, D:] = _bdot(p.astype(do.dtype), do, _BTN) \
-            .astype(dkv_ref.dtype)
+        dq, dk, dv = _dsa_bwd_block(
+            q_ref[...], kv[:, :, :D], kv[:, :, D:], bias_ref[...],
+            do_ref[...], lse_ref[0], dlt_ref[0], scale)
+        dq_ref[...] = dq.astype(dq_ref.dtype)
+        dkv_ref[:, :, :D] = dk.astype(dkv_ref.dtype)
+        dkv_ref[:, :, D:] = dv.astype(dkv_ref.dtype)
 
     with jax.named_scope("tiles_q%d_k%d" % (bq, K)):
         return pl.pallas_call(
@@ -1545,6 +1568,175 @@ def _dsa_bwd_dense(q, kv, bias, do, lse, delta, scale, bq=None):
     return dq.reshape(N, H, D).astype(q.dtype), dkv.astype(kv.dtype)
 
 
+# -- the resident backward kernel: a group's K/V and dK/dV in VMEM ----------
+
+def _dsa_words(k, v):
+    """(G, N, W) 32-bit words a key a group: bf16 K and V of one dim in
+    one uint32 (K in the low half; W = D), float32 K then V (W = 2 D)."""
+    if k.dtype == jnp.bfloat16:
+        def bits(a):
+            return jax.lax.bitcast_convert_type(a, jnp.uint16) \
+                .astype(jnp.uint32)
+        return jnp.swapaxes(bits(k) | (bits(v) << 16), 0, 1)
+    return jnp.swapaxes(jnp.concatenate([k, v], axis=-1), 0, 1)
+
+
+def _dsa_split(w, dtype, D):
+    """K and V in ``dtype`` from the words of :func:`_dsa_words`: a bf16
+    is the high half of the float32 with its bits, so each half moved
+    there converts exactly."""
+    if dtype == jnp.bfloat16:
+        def half(bits):
+            return jax.lax.bitcast_convert_type(bits, jnp.float32) \
+                .astype(dtype)
+        return half(w << 16), half(w & jnp.uint32(0xFFFF0000))
+    return w[..., :D], w[..., D:]
+
+
+def _dsa_resident_bytes(N, R, D, K, bq, dtype):
+    """VMEM of the resident backward kernel: the group's K/V words
+    (N, W) and float32 dK/dV (N, 2D), a block's fetched rows (bq, K, W)
+    and their float32 dK/dV (bq, K, 2D), six (bq, R, K) float32 tiles,
+    the double-buffered query blocks and 4 MiB for the compiler's own
+    values (the rows' halves as they are split).  Only the first two
+    grow with N: a v5e compile of the cell's widths (bq 8, K 2,048, 4
+    groups of 128) needs 31-32 MiB at N 1,024 where this reads 33.0,
+    and 98-99 at N 46,592 where it reads 99.75;
+    tests/test_chip_compile.py compiles the longest N it admits."""
+    W = D if dtype == jnp.bfloat16 else 2 * D
+    return (4 * (N + bq * K) * (W + 2 * D) + 6 * bq * R * K * 4
+            + 16 * bq * R * D * 4 + (4 << 20))
+
+
+def _dsa_resident(q, k, K, bq):
+    """True where the resident backward kernel takes the call: bf16 or
+    float32 K/V of the queries' dtype, whole query blocks and steps of
+    the row loops, and its VMEM (``_dsa_resident_bytes``) within
+    ``_VMEM_MAX``."""
+    N, H, D = q.shape
+    return (k.dtype in (jnp.bfloat16, jnp.float32) and q.dtype == k.dtype
+            and N % bq == 0 and K % _DSA_UNROLL == 0
+            and _dsa_resident_bytes(N, H // k.shape[1], D, K, bq, k.dtype)
+            <= _VMEM_MAX)
+
+
+def _dsa_count(path):
+    """Bump the profiler's counter of the path a call was traced on."""
+    from .. import profiler
+    profiler.counter_bump("sparse_attn::" + path, 1)
+
+
+def _dsa_resident_params(N, R, D, K, bq, dtype):
+    from jax.experimental.pallas import tpu as pltpu
+    need = _dsa_resident_bytes(N, R, D, K, bq, dtype)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=int(min(max(need, _VMEM_DEFAULT), _VMEM_MAX)))
+
+
+def _dsa_resident_specs(bq, K, R, D):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    return {"idx": pl.BlockSpec((bq, K), lambda g, i: (i, 0),
+                                memory_space=pltpu.SMEM),
+            "nv": pl.BlockSpec((bq, 1), lambda g, i: (i, 0)),
+            "q": pl.BlockSpec((bq, R, D), lambda g, i: (i, g, 0)),
+            "stat": pl.BlockSpec((1, bq, R), lambda g, i: (g, i, 0)),
+            "hbm": pl.BlockSpec(memory_space=pl.ANY)}
+
+
+def _dsa_copy(src, dst, sem):
+    from jax.experimental.pallas import tpu as pltpu
+    cp = pltpu.make_async_copy(src, dst, sem)
+    cp.start()
+    cp.wait()
+
+
+def _dsa_each_slot(bq, K, body):
+    """``body(r, j)`` for each of a block's ``bq`` queries and each of
+    its ``K`` slots, in order, ``_DSA_UNROLL`` slots a loop step.  The
+    queries are unrolled: with ``r`` a loop index the resident backward
+    ran 13.40 s a step where it runs 11.58 on a v5e at the sparse cell's
+    widths, though it lowers one loop body where this lowers ``bq``."""
+    from jax.experimental import pallas as pl
+    U = _DSA_UNROLL
+    for r in range(bq):
+        def step(j, carry, r=r):
+            j0 = pl.multiple_of(j * U, U)
+            for u in range(U):
+                body(r, j0 + u)
+            return carry
+        jax.lax.fori_loop(0, K // U, step, 0)
+
+
+def _dsa_resident_bias(nv_ref, bq, K):
+    return jnp.where(jax.lax.broadcasted_iota(jnp.int32, (bq, K), 1)
+                     < nv_ref[...], 0.0, NEG_INF)
+
+
+def _dsa_resident_bwd_call(q, words, idx, n_valid, do, lse, delta, scale,
+                           bq):
+    """``dq`` (N, H, D) and the keys' float32 ``dK`` and ``dV`` of each
+    group, (G, N, 2 D) with dK first: every selected row's added in
+    VMEM, query block after query block."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    N, H, D = q.shape
+    G, _, W = words.shape
+    K = idx.shape[1]
+    R = H // G
+    nq = N // bq
+    sp = _dsa_resident_specs(bq, K, R, D)
+
+    def kernel(idx_ref, nv_ref, q_ref, do_ref, lse_ref, dlt_ref, words_hbm,
+               dq_ref, dkv_hbm, group, rows, dsel, acc, sem):
+        g, i = pl.program_id(0), pl.program_id(1)
+
+        @pl.when(i == 0)
+        def _():
+            acc[...] = jnp.zeros(acc.shape, acc.dtype)
+            _dsa_copy(words_hbm.at[g], group, sem)
+
+        def fetch(r, j):    # query r's j-th selected row, by its index
+            rows[r, pl.ds(j, 1), :] = group[pl.ds(idx_ref[r, j], 1), :]
+
+        _dsa_each_slot(bq, K, fetch)
+        k, v = _dsa_split(rows[...], q.dtype, D)
+        dq, dsel[:, :, :D], dsel[:, :, D:] = _dsa_bwd_block(
+            q_ref[...], k, v, _dsa_resident_bias(nv_ref, bq, K),
+            do_ref[...], lse_ref[0], dlt_ref[0], scale)
+        dq_ref[...] = dq.astype(dq_ref.dtype)
+
+        def add(r, j):   # one add after the other: two slots may name a key
+            key = pl.ds(idx_ref[r, j], 1)
+            acc[key, :] += dsel[r, pl.ds(j, 1), :]
+
+        _dsa_each_slot(bq, K, add)
+
+        @pl.when(i == nq - 1)
+        def _():
+            _dsa_copy(acc, dkv_hbm.at[g], sem)
+
+    with jax.named_scope("tiles_q%d_k%d" % (bq, K)):
+        return pl.pallas_call(
+            kernel,
+            name="dsa_bwd",
+            out_shape=(jax.ShapeDtypeStruct((N, H, D), q.dtype),
+                       jax.ShapeDtypeStruct((G, N, 2 * D), jnp.float32)),
+            grid=(G, nq),
+            in_specs=[sp["idx"], sp["nv"], sp["q"], sp["q"], sp["stat"],
+                      sp["stat"], sp["hbm"]],
+            out_specs=(sp["q"], sp["hbm"]),
+            scratch_shapes=[pltpu.VMEM((N, W), words.dtype),
+                            pltpu.VMEM((bq, K, W), words.dtype),
+                            pltpu.VMEM((bq, K, 2 * D), jnp.float32),
+                            pltpu.VMEM((N, 2 * D), jnp.float32),
+                            pltpu.SemaphoreType.DMA(())],
+            compiler_params=_dsa_resident_params(N, R, D, K, bq, q.dtype),
+            interpret=_INTERPRET,
+        )(idx, n_valid, q, do, lse, delta, words)
+
+
 def _dsa_rows(k, v):
     """(N, G, D) K and V -> (N, 2 G D): a key's row, group g's K then V
     at columns [2 g D, 2 (g + 1) D)."""
@@ -1563,6 +1755,48 @@ def _dsa_calls():
     return _dsa_fwd_dense, _dsa_bwd_dense
 
 
+def _dsa_resident_bwd(q, k, v, idx, n_valid, do, lse, delta, scale, bq):
+    """``dq`` and the keys' float32 ``dk``, ``dv`` (N, G, D) by the
+    resident kernel; ``lse`` as the forward saved it, ``delta`` (N, H)."""
+    N, H, D = q.shape
+    G = k.shape[1]
+    dq, dkv = _dsa_resident_bwd_call(
+        q, _dsa_words(k, v), idx, n_valid[:, None], do,
+        jnp.moveaxis(lse, 1, 0).reshape(G, N, H // G),
+        jnp.swapaxes(delta.reshape(N, G, H // G), 0, 1), scale, bq)
+    dkv = jnp.swapaxes(dkv, 0, 1)                      # (N, G, 2 D)
+    return dq, dkv[..., :D], dkv[..., D:]
+
+
+def _dsa_chunked_bwd(q, k, v, idx, n_valid, do, lse, delta, scale, chunk,
+                     bq):
+    """``dq`` and the keys' float32 ``dk``, ``dv`` (N, G, D) a chunk of
+    queries at a time: the chunk's selected rows gathered, ``dsa_bwd``
+    (or its XLA stand-in) over them, their dK/dV scattered into the
+    keys'."""
+    N, H, D = q.shape
+    G, K = k.shape[1], idx.shape[1]
+    bwd_call = _dsa_calls()[1]
+    rows = _dsa_rows(k, v)
+    delta = jnp.swapaxes(delta.reshape(N // chunk, chunk, G, H // G), 1, 2)
+
+    def one(acc, c):
+        qc, dc, ic, nc, lc, tc = c
+        with jax.named_scope("gather"):
+            sel = jnp.take(rows, ic, axis=0)
+        dq, dsel = bwd_call(qc, sel, _dsa_bias(nc, K), dc, lc, tc, scale, bq)
+        with jax.named_scope("scatter"):
+            acc = acc.at[ic].add(dsel.astype(jnp.float32))
+        return acc, dq
+
+    acc, dq = jax.lax.scan(
+        one, jnp.zeros(rows.shape, jnp.float32),
+        (_chunked(q, chunk), _chunked(do, chunk), _chunked(idx, chunk),
+         _chunked(n_valid, chunk), lse, delta))
+    acc = acc.reshape(N, G, 2, D)
+    return dq.reshape(N, H, D), acc[:, :, 0], acc[:, :, 1]
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def _dsa(q, k, v, idx, n_valid, scale, chunk, bq):
     return _dsa_fwd(q, k, v, idx, n_valid, scale, chunk, bq)[0]
@@ -1574,7 +1808,7 @@ def _chunked(a, chunk):
 
 def _dsa_fwd(q, k, v, idx, n_valid, scale, chunk, bq):
     N, H, D = q.shape
-    G, K = k.shape[1], idx.shape[1]
+    K = idx.shape[1]
     fwd_call = _dsa_calls()[0]
     rows = _dsa_rows(k, v)
 
@@ -1597,33 +1831,17 @@ def _dsa_fwd(q, k, v, idx, n_valid, scale, chunk, bq):
 def _dsa_bwd(scale, chunk, bq, res, cot):
     q, k, v, idx, n_valid, o, lse = res
     do = cot[0]                 # the weights' mean is read unrolled
-    N, H, D = q.shape
-    G, K = k.shape[1], idx.shape[1]
-    R = H // G
-    bwd_call = _dsa_calls()[1]
-    rows = _dsa_rows(k, v)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
-    delta = jnp.swapaxes(delta.reshape(N // chunk, chunk, G, R), 1, 2)
-
-    def one(acc, c):
-        qc, dc, ic, nc, lc, tc = c
-        with jax.named_scope("gather"):
-            sel = jnp.take(rows, ic, axis=0)
-        dq, dsel = bwd_call(qc, sel, _dsa_bias(nc, K), dc, lc, tc, scale,
-                            bq)
-        with jax.named_scope("scatter"):
-            acc = acc.at[ic].add(dsel.astype(jnp.float32))
-        return acc, dq
-
-    acc, dq = jax.lax.scan(
-        one, jnp.zeros(rows.shape, jnp.float32),
-        (_chunked(q, chunk), _chunked(do.astype(q.dtype), chunk),
-         _chunked(idx, chunk), _chunked(n_valid, chunk), lse, delta))
-    acc = acc.reshape(N, G, 2, D)
+    args = (q, k, v, idx, n_valid, do.astype(q.dtype), lse, delta, scale)
+    resident = _dsa_resident(q, k, idx.shape[1], bq)
+    _dsa_count("resident_bwd" if resident else "chunked_bwd")
+    if resident and _pallas_available():
+        dq, dk, dv = _dsa_resident_bwd(*args, bq)
+    else:           # off the TPU the chunks' XLA stand-in, whichever path
+        dq, dk, dv = _dsa_chunked_bwd(*args, chunk, bq)
     import numpy as onp
     zero = onp.zeros(idx.shape, jax.dtypes.float0)
-    return (dq.reshape(N, H, D), acc[:, :, 0].astype(k.dtype),
-            acc[:, :, 1].astype(v.dtype), zero,
+    return (dq, dk.astype(k.dtype), dv.astype(v.dtype), zero,
             onp.zeros(n_valid.shape, jax.dtypes.float0))
 
 
@@ -1636,14 +1854,24 @@ def sparse_attention(q, k, v, idx, n_valid, scale=None, chunk=256,
     ``q`` (N, H, D), ``k`` / ``v`` (N, G, D) with G dividing H (GQA: head
     h reads group h // (H / G)), ``idx`` (N, K) int rows of ``k`` / ``v``
     and ``n_valid`` (N,) int: query i attends to ``idx[i, :n_valid[i]]``
-    (at least one), the other slots are empty.  Returns ``o`` (N, H, D)
-    and ``pbar`` (N, K) float32, the mean over the H heads of each
-    slot's softmax weight (0 on an empty slot), which has no gradient.
+    (at least one), the other slots are empty; every slot names a row
+    of ``k``.  Returns ``o`` (N, H, D) and ``pbar`` (N, K) float32, the
+    mean over the H heads of each slot's softmax weight (0 on an empty
+    slot), which has no gradient.
 
-    The queries go ``chunk`` at a time (it divides N): their selected
-    K/V rows are gathered, the kernels (``dsa_fwd``; in the backward
-    ``dsa_bwd``, whose rows' dK/dV are added into the keys' by a
-    scatter) walk them.  Off the TPU the same chunks in XLA."""
+    The queries go ``chunk`` at a time (it divides N) in the forward:
+    their selected rows are gathered (scope ``gather``) and the kernel
+    ``dsa_fwd`` walks them.  Where a group's K/V and float32 dK/dV fit
+    in VMEM (the shape and dtype decide: to some 47k tokens at 128-wide
+    bf16 groups) the backward is one call of ``dsa_bwd`` that walks the
+    whole sequence a group at a time, reads each selected row from the
+    group's resident K/V and adds its dK/dV into the group's resident
+    float32 accumulator; otherwise it goes by chunks too, the rows' dK/dV
+    added into the keys' by a scatter (scope ``scatter``).  The
+    profiler's counters ``sparse_attn::resident_bwd`` and
+    ``sparse_attn::chunked_bwd`` count the backward calls traced on each
+    path.  Off the TPU the same in XLA, by chunks: the gather, the dense
+    products and the scatter."""
     N, H, D = q.shape
     G = k.shape[1]
     if H % G or k.shape != v.shape or k.shape[0] != N \

@@ -378,11 +378,13 @@ def test_the_token_cells_flash_kernels_keep_their_names_and_tiles(
 
 def test_sparse_attention_compiles_at_the_sparse_cells_shape(topo, on_chip):
     """``keye_vl2_30b_a3b_train_1x32768``'s sparse attention at its
-    widths, two chunks of 256 queries (32 heads and 4 K/V groups of 128,
+    widths and length (32,768 queries, 32 heads and 4 K/V groups of 128,
     2,048 selected keys a query, bf16), forward and gradient: ``dsa_fwd``
-    and ``dsa_bwd`` each under the tile they run."""
+    and ``dsa_bwd`` under the tile they run, one call of ``dsa_bwd`` with
+    a group's K/V and float32 dK/dV resident in VMEM, and neither a
+    gather nor a scatter of rows left to XLA in the backward."""
     one_chip = SingleDeviceSharding(topo.devices[0])
-    N, K = 512, 2048
+    N, K = 32768, 2048
 
     def av(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -392,14 +394,18 @@ def test_sparse_attention_compiles_at_the_sparse_cells_shape(topo, on_chip):
             *a, idx, n_valid)[0].astype(jnp.float32).sum(), range(3))(
                 q, k, v)
 
-    lines = _kernel_lines(grad, av((N, 32, 128)), av((N, 4, 128)),
-                          av((N, 4, 128)), av((N, K), jnp.int32),
-                          av((N,), jnp.int32))
+    text = jax.jit(grad).lower(
+        av((N, 32, 128)), av((N, 4, 128)), av((N, 4, 128)),
+        av((N, K), jnp.int32), av((N,), jnp.int32)).compile().as_text()
+    lines = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert len(lines) == 2
     for name in ("dsa_fwd", "dsa_bwd"):
         assert any(re.search(r'op_name="[^"]*\btiles_q8_k2048\)*/%s/'
                              r'pallas_call"' % name, ln) for ln in lines), \
             (name, lines)
+    assert not re.search(r'op_name="[^"]*/scatter/', text)
+    assert not re.search(r"= \S+ scatter\(", text)
+    assert not re.search(r'op_name="[^"]*transpose\([^"]*/gather/', text)
 
 
 def test_index_kernel_and_grouped_matmul_compile_at_the_cells_widths(
@@ -440,3 +446,40 @@ def test_decode_program_carries_its_scopes(topo, on_chip):
                  if "tpu_custom_call" in ln]
     assert re.search(r'op_name="jit\(decode\)/layer0/attention/attention/'
                      r'[^"]*paged_attention/pallas_call"', kernel)
+
+
+def test_the_resident_sparse_backward_compiles_at_the_longest_sequence_it_admits(
+        topo, on_chip):
+    """The gate of the resident backward (``_dsa_resident``) at its edge,
+    at the sparse cell's widths: the longest whole number of 256-query
+    chunks it admits compiles for the described v5e with the VMEM its
+    estimate asks, and one chunk more goes by the chunks."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    K = 2048
+
+    def av(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def admits(N):
+        return pallas_ops._dsa_resident(av((N, 32, 128)), av((N, 4, 128)),
+                                        K, pallas_ops._DSA_BLOCK_Q)
+
+    N = 32768
+    while admits(N + 256):
+        N += 256
+    assert N > 32768
+
+    def grad(q, k, v, idx, n_valid):
+        return jax.grad(lambda *a: pallas_ops.sparse_attention(
+            *a, idx, n_valid)[0].astype(jnp.float32).sum(), range(3))(
+                q, k, v)
+
+    before = mx.profiler.get_counters().get("sparse_attn::resident_bwd", 0)
+    text = jax.jit(grad).lower(
+        av((N, 32, 128)), av((N, 4, 128)), av((N, 4, 128)),
+        av((N, K), jnp.int32), av((N,), jnp.int32)).compile().as_text()
+    assert mx.profiler.get_counters()["sparse_attn::resident_bwd"] \
+        == before + 1
+    assert any(re.search(r'op_name="[^"]*\btiles_q8_k2048\)*/dsa_bwd/'
+                         r'pallas_call"', ln) for ln in text.splitlines())
+    assert not re.search(r'op_name="[^"]*/scatter/', text)

@@ -91,36 +91,107 @@ def test_the_indexer_loss_reaches_only_the_indexer():
         assert float(jnp.abs(loss[i]).max()) > 0
 
 
-def test_the_sparse_kernels_interpreted_match_attention_masked_to_the_set(
-        monkeypatch):
-    monkeypatch.setattr(pallas_ops, "_INTERPRET", True)
-    T, K = 32, 8
-    q, k, v = _normal(1, T, 4, 128), _normal(2, T, 2, 128), \
-        _normal(3, T, 2, 128)
+def _selection(T, K, hot=False, ragged=False):
+    """A causal top-``K`` of seeded scores: ``(idx, n_valid, the (T, T)
+    mask of the selected pairs)``.  ``hot``: every query keeps key 0;
+    ``ragged``: many queries keep fewer than they could, so their last
+    slots are empty (their indices still name rows)."""
     s = jnp.where(jnp.arange(T)[None, :] <= jnp.arange(T)[:, None],
                   _normal(4, T, T), -jnp.inf)
+    if hot:
+        s = s.at[:, 0].set(1e9)
     _, idx = jax.lax.top_k(s, K)
     n_valid = jnp.minimum(K, jnp.arange(T) + 1)
+    if ragged:
+        n_valid = jnp.maximum(1, n_valid - jnp.arange(T) % 5)
     sel = jnp.zeros((T, T), bool).at[jnp.arange(T)[:, None], idx].set(
         jnp.arange(K)[None, :] < n_valid[:, None])
-    r = _normal(5, T, 4, 128)
+    return idx, n_valid, sel
+
+
+@pytest.mark.parametrize("G,K,hot,ragged,paths", [
+    (2, 8, False, False, ("resident", "chunked")),
+    (2, 8, True, False, ("resident", "chunked")),      # many adds, one row
+    (2, 16, False, True, ("resident", "chunked")),     # empty slots
+    (1, 8, False, False, ("resident", "chunked")),
+    (4, 8, True, True, ("resident", "chunked")),
+    (2, 12, False, False, ("chunked",)),   # K not whole row-loop steps
+], ids=["base", "hot_key", "empty_slots", "g1", "g4", "fallback"])
+def test_the_sparse_kernels_interpreted_match_attention_masked_to_the_set(
+        monkeypatch, G, K, hot, ragged, paths):
+    """Both backward paths (the kernel that fetches each row from a group
+    held in VMEM and adds dK/dV there; the chunks of XLA-gathered rows
+    and their scatter) against one softmax masked to the selection:
+    output and all three gradients."""
+    monkeypatch.setattr(pallas_ops, "_INTERPRET", True)
+    T, H = 32, 4
+    q, k, v = _normal(1, T, H, 128), _normal(2, T, G, 128), \
+        _normal(3, T, G, 128)
+    idx, n_valid, sel = _selection(T, K, hot, ragged)
+    r = _normal(5, T, H, 128)
+    vmem = {"resident": pallas_ops._VMEM_MAX, "chunked": 1}
 
     def kernel(q, k, v):
         return jnp.sum(pallas_ops.sparse_attention(q, k, v, idx, n_valid,
                                                    chunk=16)[0] * r)
 
     def masked(q, k, v):
-        a = jnp.einsum("thd,shd->hts", q, jnp.repeat(k, 2, 1)) / jnp.sqrt(
-            128.0)
+        a = jnp.einsum("thd,shd->hts", q, jnp.repeat(k, H // G, 1)) \
+            / jnp.sqrt(128.0)
         a = jax.nn.softmax(jnp.where(sel[None], a, -jnp.inf), -1)
-        return jnp.sum(jnp.einsum("hts,shd->thd", a, jnp.repeat(v, 2, 1))
-                       * r)
+        return jnp.sum(jnp.einsum("hts,shd->thd", a,
+                                  jnp.repeat(v, H // G, 1)) * r)
 
     with jax.default_matmul_precision("highest"):
-        assert abs(float(kernel(q, k, v)) - float(masked(q, k, v))) < 1e-3
-        for g, w in zip(jax.grad(kernel, (0, 1, 2))(q, k, v),
-                        jax.grad(masked, (0, 1, 2))(q, k, v)):
-            assert float(jnp.max(jnp.abs(g - w))) < 1e-4
+        want = float(masked(q, k, v))
+        want_g = jax.grad(masked, (0, 1, 2))(q, k, v)
+        for path in paths:
+            monkeypatch.setattr(pallas_ops, "_VMEM_MAX", vmem[path])
+            jax.clear_caches()     # the path is chosen while tracing
+            before = mx.profiler.get_counters()
+            assert abs(float(kernel(q, k, v)) - want) < 1e-3, path
+            got_g = jax.grad(kernel, (0, 1, 2))(q, k, v)
+            moved = {n: c - before.get(n, 0) for n, c in
+                     mx.profiler.get_counters().items()
+                     if n.startswith("sparse_attn::")
+                     and c != before.get(n, 0)}
+            assert moved == {"sparse_attn::%s_bwd" % path: 1}, moved
+            for name, g, w in zip("qkv", got_g, want_g):
+                assert float(jnp.max(jnp.abs(g - w))) < 1e-4, (path, name)
+
+
+def test_the_counters_name_the_path_each_sparse_call_took(monkeypatch):
+    """The backward takes the resident kernel
+    (``sparse_attn::resident_bwd``) where a group's K/V and dK/dV fit its
+    VMEM and the chunks (``sparse_attn::chunked_bwd``) where they do not
+    — the shape decides, at trace time, once a call; the forward, which
+    has one path, counts nothing."""
+    T, K = 64, 8
+    q, k, v = _normal(1, T, 4, 128), _normal(2, T, 2, 128), \
+        _normal(3, T, 2, 128)
+    idx, n_valid, _ = _selection(T, K)
+
+    def moved(fn):
+        jax.clear_caches()     # the path is chosen while tracing
+        before = mx.profiler.get_counters()
+        jax.make_jaxpr(fn)(q, k, v)
+        return {n: c - before.get(n, 0) for n, c in
+                mx.profiler.get_counters().items()
+                if n.startswith("sparse_attn::") and c != before.get(n, 0)}
+
+    def forward(q, k, v):
+        return pallas_ops.sparse_attention(q, k, v, idx, n_valid)[0]
+
+    def grad(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(forward(*a)), (0, 1, 2))(q, k, v)
+
+    assert moved(forward) == {}
+    assert moved(grad) == {"sparse_attn::resident_bwd": 1}
+    need = pallas_ops._dsa_resident_bytes(T, 2, 128, K, 8, jnp.float32)
+    monkeypatch.setattr(pallas_ops, "_VMEM_MAX", need - 1)
+    assert moved(grad) == {"sparse_attn::chunked_bwd": 1}
+    monkeypatch.setattr(pallas_ops, "_VMEM_MAX", need)
+    assert moved(grad) == {"sparse_attn::resident_bwd": 1}
 
 
 def test_the_index_kernel_interpreted_matches_its_xla_form(monkeypatch):
