@@ -11,7 +11,8 @@ _IMPORT_T0 = _time.monotonic()    # mx.start.import, recorded below
 from .bert import (BertConfig, BERTForPretrain, BERTModel, bert_base_config,
                    bert_tiny_config)
 from .transformer import (TransformerLM, TransformerBlock, LlamaConfig,
-                          evabyte_6p5b_config, keye_vl2_30b_a3b_config,
+                          LayerSpec, evabyte_6p5b_config,
+                          keye_vl2_30b_a3b_config, laguna_s21_config,
                           llama3_8b_config, ouro_2p6b_config, tiny_config)
 from .looped import LoopedLM, exit_log_probs, expected_exit_loss
 from .evabyte import EvaByteLM, chunk_summaries, eva_attention

@@ -121,17 +121,42 @@ def grouped_matmul(x, w, sizes):
     return jax.lax.ragged_dot(x, w, sizes[:-1]).astype(x.dtype)
 
 
-def routed_experts(x, router_w, w1, w3, w2, first, top_k):
+def sigmoid_route(x, router_w, top_k, scale):
+    """``route`` with sigmoid scores (DeepSeek-V3's router, Laguna's):
+    ``r = sigmoid(W_r u)``, the top_k's gates ``scale * r / sum_{E_t} r``;
+    ``probs`` the scores normalised over all experts, which is what the
+    balance loss reads as ``P_e``."""
+    from .dsa import checkpoint_keep
+    logits = jax.lax.dot_general(x, router_w, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+    scores = jax.nn.sigmoid(logits)
+    # a block made again for its backward routes as its forward did:
+    # recomputed scores can differ in the last bit and flip a near tie
+    top_e = checkpoint_keep(jax.lax.top_k(scores, top_k)[1])
+    # the top k's scores by a masked sum: a gather along the experts
+    # takes 0.8 ms a call on a v5e
+    picked = top_e[..., None] == jnp.arange(scores.shape[-1],
+                                            dtype=top_e.dtype)
+    top_s = jnp.sum(jnp.where(picked, scores[:, None, :], 0.0), axis=-1)
+    gates = scale * top_s / jnp.sum(top_s, axis=-1, keepdims=True)
+    return scores / jnp.sum(scores, axis=-1, keepdims=True), top_e, gates
+
+
+def routed_experts(x, router_w, w1, w3, w2, first, top_k, score="softmax",
+                   scale=1.0):
     """One layer's routed experts on ``x`` (T, D): ``router_w`` (E, D)
     over all experts, ``w1``, ``w3`` (held, D, F) and ``w2`` (held, F, D)
-    of experts ``first .. first + held - 1``.  Returns ``(y (T, D),
-    balance loss, pairs routed to held experts)``.  Named scopes
-    ``router``, ``dispatch``, ``gmm`` (the grouped matmuls) and
-    ``combine``."""
+    of experts ``first .. first + held - 1``; the router's ``score``,
+    softmax (``route``) or sigmoid (``sigmoid_route``, whose gates are
+    times ``scale``).  Returns ``(y (T, D), balance loss, pairs
+    routed to held experts)``.  Named scopes ``router``, ``dispatch``,
+    ``gmm`` (the grouped matmuls) and ``combine``."""
     T, D = x.shape
     held = w1.shape[0]
     with jax.named_scope("router"):
-        probs, experts, gates = route(x, router_w, top_k)
+        probs, experts, gates = route(x, router_w, top_k) \
+            if score == "softmax" else sigmoid_route(x, router_w, top_k,
+                                                     scale)
         aux = balance_loss(probs, experts)
     with jax.named_scope("dispatch"):
         local = experts - first
@@ -158,11 +183,19 @@ class RoutedExperts(HybridBlock):
     (``LlamaConfig.moe_num_experts``): a router over all
     ``moe_num_experts`` experts, ``moe_top_k`` a token, and the SwiGLU
     weights of the ``moe_held`` experts from ``moe_first_held`` (all of
-    them where ``moe_held`` is 0).  ``forward`` gives ``(y, {"router_loss":
-    moe_aux_coef * balance loss, "held_pairs": pairs routed here})``."""
+    them where ``moe_held`` is 0); the layer's ``LayerSpec`` gives the
+    router's scores and scaling and the width of a shared expert, which
+    every token passes through ungated beside the routed ones.  ``forward``
+    gives ``(y, {"router_loss": moe_aux_coef * balance loss,
+    "held_pairs": pairs routed here})``; the routed experts run under the
+    scope ``experts``, the shared one beside them under ``shared_expert``.
+    """
 
-    def __init__(self, cfg):
+    def __init__(self, cfg, spec=None):
         super().__init__()
+        from .transformer import FeedForward, LayerSpec
+        spec = spec or LayerSpec()
+        self._score, self._scale = spec.router_score, spec.routed_scale
         E = cfg.moe_num_experts
         held = cfg.moe_held or E
         F = cfg.moe_hidden_dim or cfg.hidden_dim
@@ -184,15 +217,19 @@ class RoutedExperts(HybridBlock):
         self.experts_w2 = Parameter(shape=(held, F, D), dtype=cfg.dtype,
                                     name="experts_w2").shard(("ep", None,
                                                               None))
+        self._shared = bool(spec.shared_hidden_dim)
+        if self._shared:
+            self.shared_expert = FeedForward(cfg, spec.shared_hidden_dim)
 
     def forward(self, x):
         first, top_k, coef = self._first, self._top_k, self._coef
+        score, scale = self._score, self._scale
 
         def f(a, r, w1, w3, w2):
             B, T, D = a.shape
             with jax.named_scope("experts"):
                 y, aux, n = routed_experts(a.reshape(B * T, D), r, w1, w3,
-                                           w2, first, top_k)
+                                           w2, first, top_k, score, scale)
             return y.reshape(B, T, D), coef * aux, n
 
         y, aux, n = apply_op(f, [x, self.router.weight.data(),
@@ -200,8 +237,10 @@ class RoutedExperts(HybridBlock):
                                  self.experts_w3.data(),
                                  self.experts_w2.data()], n_out=3,
                              name="routed_experts")
+        if self._shared:
+            y = y + self.shared_expert(x)
         return y, {"router_loss": aux, "held_pairs": n}
 
 
-__all__ = ["RoutedExperts", "routed_experts", "route", "balance_loss",
-           "grouped_matmul"]
+__all__ = ["RoutedExperts", "routed_experts", "route", "sigmoid_route",
+           "balance_loss", "grouped_matmul"]

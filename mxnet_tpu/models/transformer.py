@@ -27,6 +27,39 @@ from ..initializer import Normal
 from ..ndarray.ndarray import NDArray, apply_op
 
 
+@dataclass(frozen=True)
+class LayerSpec:
+    """What one layer is, where a model's layers differ (``LlamaConfig.
+    layers``).  A field left None takes the model-wide field of the same
+    meaning."""
+    # query heads (None: ``n_heads``)
+    n_heads: int = None
+    # 0: causal over every earlier key; w > 0: the query at t sees the
+    # keys t - w < s <= t (``ops.pallas_ops.window_attention``)
+    window: int = 0
+    # rotary: theta (None: ``rope_theta``), the share of a head's dims
+    # that rotate (its first ones), and YaRN's (factor, original context,
+    # beta_fast, beta_slow, attention_factor) where the frequencies are
+    # scaled (``rope_frequencies``)
+    rope_theta: float = None
+    rope_fraction: float = 1.0
+    rope_yarn: tuple = None
+    # each head's output times sigmoid of a projection of the layer's
+    # input, before ``wo`` (head-wise gated attention)
+    head_gate: bool = False
+    # "dense" or "experts" (None: routed experts every ``moe_every``-th
+    # layer where ``moe_num_experts``); the experts' router scores
+    # ("softmax" or "sigmoid"), the factor on their gates, and the width
+    # of a shared expert beside them (0: none)
+    ffn: str = None
+    router_score: str = "softmax"
+    routed_scale: float = 1.0
+    shared_hidden_dim: int = 0
+
+
+_FLAT_LAYER = LayerSpec()
+
+
 @dataclass
 class LlamaConfig:
     vocab_size: int = 32000
@@ -87,6 +120,12 @@ class LlamaConfig:
     # the residual stream's dtype where it is not ``dtype``: branches
     # compute in ``dtype`` and are added to the stream in this one
     residual_dtype: str = None
+    # one LayerSpec a layer, where the layers differ (None: every layer
+    # is what the fields above make it)
+    layers: tuple = None
+
+    def layer_spec(self, i):
+        return self.layers[i] if self.layers is not None else _FLAT_LAYER
 
 
 def llama3_8b_config(**over):
@@ -149,6 +188,63 @@ def keye_vl2_30b_a3b_config(**over):
     return cfg
 
 
+def laguna_layers(layer_types, heads, mlp_types, window, rope,
+                  shared_hidden_dim, routed_scale):
+    """A Laguna model's LayerSpecs from its ``config.json``'s lists:
+    ``layer_types`` (``full_attention`` / ``sliding_attention``), query
+    heads a layer, ``mlp_layer_types`` (``dense`` / ``sparse``), the
+    sliding window, ``rope_parameters`` by layer type; every head gated,
+    sparse layers sigmoid-scored experts beside a shared one."""
+    specs = []
+    for kind, h, mlp in zip(layer_types, heads, mlp_types):
+        r = rope[kind]
+        yarn = (r["factor"], r["original_max_position_embeddings"],
+                r["beta_fast"], r["beta_slow"], r["attention_factor"]) \
+            if r["rope_type"] == "yarn" else None
+        specs.append(LayerSpec(
+            n_heads=h, window=window if kind == "sliding_attention" else 0,
+            rope_theta=float(r["rope_theta"]),
+            rope_fraction=r["partial_rotary_factor"], rope_yarn=yarn,
+            head_gate=True, ffn="experts" if mlp == "sparse" else "dense",
+            router_score="sigmoid", routed_scale=routed_scale,
+            shared_hidden_dim=shared_hidden_dim))
+    return tuple(specs)
+
+
+def laguna_s21_config(**over):
+    """Laguna-S-2.1 (``config.json`` of ``poolside/Laguna-S-2.1``): 48
+    layers, a full-attention layer and then three of a 512-token sliding
+    window, repeated; 48 query heads on full layers and 72 on sliding
+    ones, 8 K/V heads of 128, every head's output gated; YaRN rotary
+    (theta 5e5, factor 128 over 8,192) on half of each head's dims on
+    full layers, plain rotary (theta 1e4) on all of them on sliding ones;
+    a dense SwiGLU of 12,288 in layer 0 and in every other layer the top
+    10 of 256 sigmoid-scored SwiGLU experts of 1,024, gates scaled by
+    2.5, beside a shared expert of 1,024; hidden 3,072, a 100,352-id
+    untied vocabulary.  ``n_layers`` keeps the first layers.  For
+    :class:`~.transformer.TransformerLM`."""
+    n = over.pop("n_layers", 48)
+    kinds = ["full_attention"] + ["sliding_attention"] * 3
+    layers = laguna_layers(
+        (kinds * 12)[:n], ([48] + [72] * 3) * 12, ["dense"] + ["sparse"] * 47,
+        512, {"full_attention": {
+            "rope_type": "yarn", "rope_theta": 500000, "factor": 128,
+            "original_max_position_embeddings": 8192, "beta_fast": 32,
+            "beta_slow": 1, "attention_factor": 1.4852030263919618,
+            "partial_rotary_factor": 0.5},
+            "sliding_attention": {"rope_type": "default", "rope_theta": 10000,
+                                  "partial_rotary_factor": 1}},
+        1024, 2.5)
+    cfg = LlamaConfig(vocab_size=100352, dim=3072, n_layers=n, n_heads=48,
+                      n_kv_heads=8, head_dim=128, hidden_dim=12288,
+                      max_seq_len=1048576, rope_theta=500000.0,
+                      norm_eps=1e-6, moe_num_experts=256, moe_top_k=10,
+                      moe_hidden_dim=1024, moe_aux_coef=0.001, layers=layers)
+    for k, v in over.items():
+        setattr(cfg, k, v)
+    return cfg
+
+
 def tiny_config(**over):
     cfg = LlamaConfig(vocab_size=256, dim=64, n_layers=2, n_heads=4,
                       n_kv_heads=2, hidden_dim=128, max_seq_len=128,
@@ -174,6 +270,60 @@ def _rope(x, positions, theta):
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
                           axis=-1)
     return out.astype(x.dtype)
+
+
+def rope_frequencies(head_dim, theta, fraction=1.0, yarn=None):
+    """``(inverse frequencies (rot / 2,), scale on cos and sin)`` of a
+    rotary over the first ``rot = head_dim * fraction`` dims of a head.
+    With ``yarn = (factor, original context, beta_fast, beta_slow,
+    attention_factor)`` the frequencies are YaRN's (Peng et al. 2023, as
+    ``transformers``' ``_compute_yarn_parameters`` computes them: each
+    blended between the original and the original over ``factor`` by a
+    ramp over the dims whose wavelength lies between ``beta_fast`` and
+    ``beta_slow`` turns of the original context, its ends truncated) and
+    the scale is ``attention_factor``."""
+    import numpy as onp
+    rot = int(head_dim * fraction)
+    inv = 1.0 / theta ** (onp.arange(0, rot, 2, dtype=onp.float64) / rot)
+    if yarn is None:
+        return inv.astype(onp.float32), 1.0
+    factor, original, fast, slow, attention_factor = yarn
+
+    def dim_of(turns):
+        return rot * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(fast)), 0)
+    high = min(math.ceil(dim_of(slow)), rot - 1)
+    ramp = onp.clip((onp.arange(rot // 2) - low)
+                    / ((high - low) or 0.001), 0, 1)
+    inv = inv / factor * ramp + inv * (1 - ramp)
+    return inv.astype(onp.float32), float(attention_factor)
+
+
+def _rope_scaled(x, positions, inv, scale):
+    """``_rope`` over a head's first ``2 * len(inv)`` dims at the given
+    inverse frequencies, cos and sin times ``scale``; the other dims pass
+    through.  (B, T, H, D), ``positions`` (T,)."""
+    rot = 2 * inv.shape[0]
+    angles = positions.astype(jnp.float32)[:, None] * jnp.asarray(inv)
+    cos = (jnp.cos(angles) * scale)[None, :, None, :]
+    sin = (jnp.sin(angles) * scale)[None, :, None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2, rest = xf[..., :rot // 2], xf[..., rot // 2:rot], xf[..., rot:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos, rest],
+                          axis=-1)
+    return out.astype(x.dtype)
+
+
+def _gate_heads(o, g):
+    """Head-wise gated attention (Qiu et al. 2025, arXiv:2505.06708): head
+    h's output (B, T, H * D) times sigmoid of its gate logit ``g`` (B, T,
+    H), in float32."""
+    B, T, H = g.shape
+    gated = o.reshape(B, T, H, -1).astype(jnp.float32) \
+        * jax.nn.sigmoid(g.astype(jnp.float32))[..., None]
+    return gated.reshape(o.shape).astype(o.dtype)
 
 
 def _sp_constraint(x, spec):
@@ -225,21 +375,32 @@ class Attention(HybridBlock):
         super().__init__()
         self.cfg = cfg
         self.layer_idx = layer_idx
+        spec = cfg.layer_spec(layer_idx)
+        self.spec = spec
         head_dim = cfg.head_dim or cfg.dim // cfg.n_heads
         self.head_dim = head_dim
+        self.n_heads = nh = spec.n_heads or cfg.n_heads
+        if spec.window and cfg.attn_impl != "flash":
+            raise ValueError("a sliding-window layer runs the window "
+                             "kernels: attn_impl must be flash, not %r"
+                             % cfg.attn_impl)
         # Megatron TP: qkv column-parallel, out row-parallel
-        self.wq = Dense(cfg.n_heads * head_dim, use_bias=False,
+        self.wq = Dense(nh * head_dim, use_bias=False,
                         flatten=False, in_units=cfg.dim, dtype=cfg.dtype)
         self.wk = Dense(cfg.n_kv_heads * head_dim, use_bias=False,
                         flatten=False, in_units=cfg.dim, dtype=cfg.dtype)
         self.wv = Dense(cfg.n_kv_heads * head_dim, use_bias=False,
                         flatten=False, in_units=cfg.dim, dtype=cfg.dtype)
         self.wo = Dense(cfg.dim, use_bias=False, flatten=False,
-                        in_units=cfg.n_heads * head_dim, dtype=cfg.dtype)
+                        in_units=nh * head_dim, dtype=cfg.dtype)
         self.wq.weight.shard(("tp", None))
         self.wk.weight.shard(("tp", None))
         self.wv.weight.shard(("tp", None))
         self.wo.weight.shard((None, "tp"))
+        if spec.head_gate:
+            self.head_gate = Dense(nh, use_bias=False, flatten=False,
+                                   in_units=cfg.dim, dtype=cfg.dtype)
+            self.head_gate.weight.shard(("tp", None))
         if cfg.qk_norm:
             self.q_norm = RMSNorm(epsilon=cfg.norm_eps, in_channels=head_dim)
             self.k_norm = RMSNorm(epsilon=cfg.norm_eps, in_channels=head_dim)
@@ -263,8 +424,12 @@ class Attention(HybridBlock):
         q = self.wq(x)
         k = self.wk(x)
         v = self.wv(x)
-        hd, nh, nkv = self.head_dim, cfg.n_heads, cfg.n_kv_heads
+        hd, nh, nkv = self.head_dim, self.n_heads, cfg.n_kv_heads
         impl, theta, cp_axis = cfg.attn_impl, cfg.rope_theta, cfg.cp_axis
+        spec, window = self.spec, self.spec.window
+        if spec.rope_theta is not None:
+            theta = spec.rope_theta
+        scaled = spec.rope_fraction != 1.0 or spec.rope_yarn is not None
         if cfg.qk_norm:
             q = self.q_norm(q.reshape(B, T, nh, hd)).reshape(B, T, nh * hd)
             k = self.k_norm(k.reshape(B, T, nkv, hd)).reshape(B, T, nkv * hd)
@@ -275,17 +440,32 @@ class Attention(HybridBlock):
                     "would be its window's K/V and the summaries of the "
                     "windows before it (eva, ROADMAP N7), or its keys' "
                     "index keys beside its K/V (dsa, ROADMAP N8)" % impl)
+            if window or scaled or spec.head_gate or theta != cfg.rope_theta:
+                raise NotImplementedError(
+                    "layer %d has no cached path: the paged cache keeps "
+                    "every key and rotates whole heads at the model's "
+                    "theta, so sliding-window layers (whose cache is a "
+                    "ring of the window's pages), a per-layer, scaled or "
+                    "partial rotary and gated heads are the training "
+                    "path's alone (ROADMAP N5)" % self.layer_idx)
             return self._forward_cached(x, q, k, v, cache)
         if impl == "dsa":
             return self._forward_dsa(x, q, k, v)
+        if scaled:
+            inv, rscale = rope_frequencies(hd, theta, spec.rope_fraction,
+                                           spec.rope_yarn)
 
         def attn(q, k, v, *pool):
             q = q.reshape(B, T, nh, hd)
             k = k.reshape(B, T, nkv, hd)
             v = v.reshape(B, T, nkv, hd)
             pos = jnp.arange(T)
-            q = _rope(q, pos, theta)
-            k = _rope(k, pos, theta)
+            if scaled:
+                q = _rope_scaled(q, pos, inv, rscale)
+                k = _rope_scaled(k, pos, inv, rscale)
+            else:
+                q = _rope(q, pos, theta)
+                k = _rope(k, pos, theta)
             # GQA: the flash kernel reads kv groups natively (no HBM
             # materialization of repeated heads); dense/ring paths
             # repeat here
@@ -313,6 +493,12 @@ class Attention(HybridBlock):
                 else:
                     from ..ops.nn import dot_product_attention
                     o = dot_product_attention(q, k, v, causal=True)
+            elif impl == "flash" and window:
+                from ..ops.pallas_ops import window_attention
+                from ..parallel.sharding import kernel_shard
+                with jax.named_scope("window_attn"):
+                    o = window_attention(q, k, v, window,
+                                         shard=kernel_shard(B, nkv))
             elif impl == "flash":
                 from ..ops.pallas_ops import flash_attention
                 from ..parallel.sharding import kernel_shard
@@ -333,6 +519,10 @@ class Attention(HybridBlock):
         pool = [self.adaptive_mu_k.data(), self.adaptive_phi.data()] \
             if impl == "eva" else []
         o = apply_op(attn, [q, k, v] + pool, name="attention")
+        if spec.head_gate:
+            with jax.named_scope("attn_gate"):
+                o = apply_op(_gate_heads, [o, self.head_gate(x)],
+                             name="attn_gate")
         return self.wo(o)
 
     def _index_inputs(self, x):
@@ -379,7 +569,7 @@ class Attention(HybridBlock):
         detached: its loss is its leaves' only gradient."""
         cfg = self.cfg
         B, T, _ = x.shape
-        hd, nh, nkv = self.head_dim, cfg.n_heads, cfg.n_kv_heads
+        hd, nh, nkv = self.head_dim, self.n_heads, cfg.n_kv_heads
         theta, topk = cfg.rope_theta, cfg.index_topk
 
         def attn(q, k, v, qi, ki, wi):
@@ -410,7 +600,7 @@ class Attention(HybridBlock):
         """
         cfg = self.cfg
         B, T, _ = x.shape
-        hd, nh, nkv = self.head_dim, cfg.n_heads, cfg.n_kv_heads
+        hd, nh, nkv = self.head_dim, self.n_heads, cfg.n_kv_heads
         theta, layer = cfg.rope_theta, self.layer_idx
         psz, mode = cache.page_size, cache.mode
         from . import kv_cache as _kvc
@@ -517,14 +707,15 @@ class Attention(HybridBlock):
 
 
 class FeedForward(HybridBlock):
-    def __init__(self, cfg: LlamaConfig):
+    def __init__(self, cfg: LlamaConfig, hidden_dim=None):
         super().__init__()
-        self.w1 = Dense(cfg.hidden_dim, use_bias=False, flatten=False,
+        hidden = hidden_dim or cfg.hidden_dim
+        self.w1 = Dense(hidden, use_bias=False, flatten=False,
                         in_units=cfg.dim, dtype=cfg.dtype)  # gate
-        self.w3 = Dense(cfg.hidden_dim, use_bias=False, flatten=False,
+        self.w3 = Dense(hidden, use_bias=False, flatten=False,
                         in_units=cfg.dim, dtype=cfg.dtype)  # up
         self.w2 = Dense(cfg.dim, use_bias=False, flatten=False,
-                        in_units=cfg.hidden_dim, dtype=cfg.dtype)  # down
+                        in_units=hidden, dtype=cfg.dtype)  # down
         self.w1.weight.shard(("tp", None))
         self.w3.weight.shard(("tp", None))
         self.w2.weight.shard((None, "tp"))
@@ -539,11 +730,13 @@ class TransformerBlock(HybridBlock):
         self.attention_norm = _norm(cfg)
         self.attention = Attention(cfg, layer_idx=layer_idx)
         self.ffn_norm = _norm(cfg)
-        use_moe = (cfg.moe_num_experts > 0
-                   and layer_idx % max(1, cfg.moe_every) == 0)
+        spec = cfg.layer_spec(layer_idx)
+        use_moe = spec.ffn == "experts" if spec.ffn else (
+            cfg.moe_num_experts > 0
+            and layer_idx % max(1, cfg.moe_every) == 0)
         if use_moe:
             from .experts import RoutedExperts
-        self.feed_forward = RoutedExperts(cfg) if use_moe \
+        self.feed_forward = RoutedExperts(cfg, spec) if use_moe \
             else FeedForward(cfg)
         self._sandwich = cfg.sandwich_norm
         if cfg.sandwich_norm:
@@ -584,6 +777,9 @@ class TransformerLM(HybridBlock):
             raise ValueError("%s runs its layers once; passes=%d needs "
                              "models.LoopedLM"
                              % (type(self).__name__, cfg.passes))
+        if cfg.layers is not None and len(cfg.layers) != cfg.n_layers:
+            raise ValueError("%d layer specs for %d layers"
+                             % (len(cfg.layers), cfg.n_layers))
         self.cfg = cfg
         self.tok_embeddings = Embedding(cfg.vocab_size, cfg.dim,
                                         dtype=cfg.dtype)

@@ -1969,3 +1969,402 @@ def index_scores(q, k, w, q0=0, causal=True):
         return index_scores_dense(q, k, w, q0, causal)
     q_off = jnp.reshape(jnp.asarray(q0, jnp.int32), (1,))
     return _index_call(q, k, w.astype(jnp.float32), q_off, causal, b, b)
+
+
+# ---------------------------------------------------------------------------
+# sliding-window attention: the query at t sees the keys t - window < s <= t
+# ---------------------------------------------------------------------------
+#
+# ``window_attention``'s kernels.  Where the flash kernels keep a head's
+# whole K/V (or Q/dO) row resident and walk it to the diagonal, the band
+# touches a few tiles of the opposite row: each grid step is handed those
+# tiles as blocks of their own, one ``slot`` each, which the pipeline
+# fetches, so no row is resident and a step moves its tiles alone.  The
+# forward and dq kernels' slots are the key tiles from the first one the
+# query tile's earliest query sees up to the diagonal; dkv's are the query
+# tiles from the key tile's own diagonal to the last that still sees it.
+# A slot past that range (at the rows' ends) repeats the last tile and is
+# skipped; a tile that lies inside the band is taken unmasked, one that
+# straddles either of its edges masked.  Everything else is the flash
+# kernels' arithmetic.
+
+def _band_keys(i, bq, bk, window, xp):
+    """The first and last key tile of which query tile ``i`` sees any."""
+    q0 = i * bq
+    return xp.maximum(q0 - window + 1, 0) // bk, (q0 + bq - 1) // bk
+
+
+def _band_queries(j, bq, bk, window, T, xp):
+    """The first and last query tile that sees any key of key tile
+    ``j``."""
+    k0 = j * bk
+    return k0 // bq, xp.minimum(k0 + bk + window - 2, T - 1) // bq
+
+
+import types  # noqa: E402  (here: the older kernels' lines stay put)
+
+#: ``_band_keys`` and ``_band_queries`` on Python ints
+_INTS = types.SimpleNamespace(maximum=max, minimum=min)
+
+
+def _band_slots(T, window, bq, bk):
+    """(key tiles a query tile sees, query tiles that see a key tile), at
+    most: the slots of the forward and dq kernels and of dkv."""
+    keys = [_band_keys(i, bq, bk, window, _INTS) for i in range(T // bq)]
+    queries = [_band_queries(j, bq, bk, window, T, _INTS)
+               for j in range(T // bk)]
+    return (max(hi - lo for lo, hi in keys) + 1,
+            max(hi - lo for lo, hi in queries) + 1)
+
+
+def _band_visited(T, window, bq, bk):
+    """The (query tile, key tile) pairs the band visits, a head."""
+    return sum(hi - lo + 1 for lo, hi in (
+        _band_keys(i, bq, bk, window, _INTS) for i in range(T // bq)))
+
+
+def _in_band(q0, k0, shape, q_axis, window):
+    qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    return (kpos <= qpos) & (kpos > qpos - window)
+
+
+def _band_case(q0, k0, bq, bk, window, seen):
+    """The branch a slot takes: 0 not in the band (skipped), 1 inside it
+    (unmasked), 2 across an edge (masked)."""
+    whole = (k0 + bk - 1 <= q0) & (k0 >= q0 + bq - window)
+    return jnp.where(seen, jnp.where(whole, 1, 2), 0)
+
+
+def _swa_tiles(T, window, block_q=None, block_k=None):
+    """(block_q, block_k): the largest of 512, 256 and 128 that divides
+    the row and is no wider than the window (128 at the least); an
+    explicit block wins and has to divide the row."""
+    def pick(explicit):
+        if explicit is not None:
+            if T % explicit or explicit % 128:
+                raise ValueError(
+                    "window_attention: a block of %d does not divide a row "
+                    "of %d tokens in multiples of 128" % (explicit, T))
+            return explicit
+        return next(b for b in (512, 256, 128)
+                    if T % b == 0 and (b <= window or b == 128))
+    return pick(block_q), pick(block_k)
+
+
+def _swa_params(kind, slots, bq, bk, D, dtype):
+    """Compiler params: a slot's blocks double-buffered beside the tile
+    loop's working set (``_tile_bytes``)."""
+    from jax.experimental.pallas import tpu as pltpu
+    per_slot = 2 * 2 * max(bq, bk) * D * jnp.dtype(dtype).itemsize
+    need = slots * per_slot + _tile_bytes(kind, bq, bk, D)
+    if need <= _VMEM_DEFAULT:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=min(need, _VMEM_MAX))
+
+
+def _softmax_step(carry, qblk, kblk, vblk, scale, mask):
+    """One key tile into a query tile's running softmax; ``mask`` (or
+    None: every pair seen) may leave a row with no key yet."""
+    acc, m_prev, l_prev = carry
+    s = _dot(qblk, kblk, _NT) * scale
+    if mask is not None:
+        s = jnp.where(mask, s, NEG_INF)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
+    alpha = jnp.where(jnp.isneginf(m_prev), 0.0, jnp.exp(m_prev - m_safe))
+    p = jnp.exp(s - m_safe)
+    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc = acc * alpha + _dot(p.astype(vblk.dtype), vblk, _NN)
+    return acc, m_new, l_new
+
+
+def _band_walk(n, case_of, step, carry):
+    """``step(b, masked)(carry)`` over the ``n`` slots, each by its case
+    (``case_of(b)``: skip, unmasked, masked)."""
+    for b in range(n):
+        carry = jax.lax.switch(case_of(b), [lambda c: c, step(b, False),
+                                            step(b, True)], carry)
+    return carry
+
+
+def _key_slots(n, bq, bk, window, rep, D):
+    """The forward and dq kernels' ``n`` key-tile slots over a grid of
+    (query head, query tile): slot b holds the b-th key tile the query
+    tile sees, the last one where it sees fewer."""
+    from jax.experimental import pallas as pl
+
+    def slot(b):
+        def index(bh, i):
+            lo, hi = _band_keys(i, bq, bk, window, jnp)
+            return bh // rep, jnp.minimum(lo + b, hi), 0
+        return pl.BlockSpec((1, bk, D), index)
+
+    return [slot(b) for b in range(n)]
+
+
+def _swa_fwd_call(q, k, v, window, scale, bq, bk):
+    from jax.experimental import pallas as pl
+
+    BH, T, D = q.shape
+    rep = BH // k.shape[0]
+    nb, _ = _band_slots(T, window, bq, bk)
+
+    def kernel(q_ref, *refs):
+        ks, vs, (o_ref, lse_ref) = refs[:nb], refs[nb:2 * nb], refs[2 * nb:]
+        q0 = pl.program_id(1) * bq
+        lo, hi = _band_keys(pl.program_id(1), bq, bk, window, jnp)
+        qblk = q_ref[0]
+
+        def step(b, masked):
+            def run(carry):
+                mask = _in_band(q0, (lo + b) * bk, (bq, bk), 0, window) \
+                    if masked else None
+                return _softmax_step(carry, qblk, ks[b][0], vs[b][0], scale,
+                                     mask)
+            return run
+
+        acc, m, l = _band_walk(
+            nb, lambda b: _band_case(q0, (lo + b) * bk, bq, bk, window,
+                                     lo + b <= hi), step,
+            (jnp.zeros((bq, D), jnp.float32),
+             jnp.full((bq, 1), NEG_INF, jnp.float32),
+             jnp.zeros((bq, 1), jnp.float32)))
+        o_ref[0] = (acc / l).astype(o_ref.dtype)    # a query sees itself
+        lse_ref[0, 0] = (m + jnp.log(l))[:, 0]
+
+    row = pl.BlockSpec((1, bq, D), lambda bh, i: (bh, i, 0))
+    with jax.named_scope("tiles_q%d_k%d" % (bq, bk)):
+        return pl.pallas_call(
+            kernel,
+            name="swa_fwd",
+            out_shape=(jax.ShapeDtypeStruct((BH, T, D), q.dtype),
+                       jax.ShapeDtypeStruct((BH, 1, T), jnp.float32)),
+            grid=(BH, T // bq),
+            in_specs=[row] + _key_slots(nb, bq, bk, window, rep, D) * 2,
+            out_specs=(row, pl.BlockSpec((1, 1, bq),
+                                         lambda bh, i: (bh, 0, i))),
+            compiler_params=_swa_params("fwd", 2 * nb, bq, bk, D, k.dtype),
+            interpret=_INTERPRET,
+        )(q, *[k] * nb, *[v] * nb)
+
+
+def _swa_bwd_dq_call(q, k, v, do, lse, delta, window, scale, bq, bk):
+    from jax.experimental import pallas as pl
+
+    BH, T, D = q.shape
+    rep = BH // k.shape[0]
+    nb, _ = _band_slots(T, window, bq, bk)
+
+    def kernel(q_ref, do_ref, lse_ref, delta_ref, *refs):
+        ks, vs, dq_ref = refs[:nb], refs[nb:2 * nb], refs[2 * nb]
+        q0 = pl.program_id(1) * bq
+        lo, hi = _band_keys(pl.program_id(1), bq, bk, window, jnp)
+        qblk, doblk = q_ref[0], do_ref[0]
+        lse_b, dlt_b = lse_ref[0, 0], delta_ref[0, 0]    # (bq,)
+
+        def step(b, masked):
+            def run(acc):
+                kblk, vblk = ks[b][0], vs[b][0]
+                s = _dot(qblk, kblk, _NT) * scale
+                if masked:
+                    s = jnp.where(_in_band(q0, (lo + b) * bk, (bq, bk), 0,
+                                           window), s, NEG_INF)
+                p = jnp.exp(s - lse_b[:, None])
+                ds = p * (_dot(doblk, vblk, _NT) - dlt_b[:, None])
+                return acc + _dot(ds.astype(kblk.dtype), kblk, _NN)
+            return run
+
+        acc = _band_walk(
+            nb, lambda b: _band_case(q0, (lo + b) * bk, bq, bk, window,
+                                     lo + b <= hi), step,
+            jnp.zeros((bq, D), jnp.float32))
+        dq_ref[0] = (acc * scale).astype(dq_ref.dtype)
+
+    row = pl.BlockSpec((1, bq, D), lambda bh, i: (bh, i, 0))
+    stats = pl.BlockSpec((1, 1, bq), lambda bh, i: (bh, 0, i))
+    with jax.named_scope("tiles_q%d_k%d" % (bq, bk)):
+        return pl.pallas_call(
+            kernel,
+            name="swa_bwd_dq",
+            out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
+            grid=(BH, T // bq),
+            in_specs=[row, row, stats, stats]
+            + _key_slots(nb, bq, bk, window, rep, D) * 2,
+            out_specs=row,
+            compiler_params=_swa_params("dq", 2 * nb, bq, bk, D, k.dtype),
+            interpret=_INTERPRET,
+        )(q, do, lse, delta, *[k] * nb, *[v] * nb)
+
+
+def _swa_bwd_dkv_call(q, k, v, do, lse, delta, window, scale, bq, bk):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    BH, T, D = q.shape
+    BHkv = k.shape[0]
+    rep = BH // BHkv
+    _, nb = _band_slots(T, window, bq, bk)
+
+    def query_tile(b, shape, at):
+        def index(g, j, r):
+            lo, hi = _band_queries(j, bq, bk, window, T, jnp)
+            return at(g * rep + r, jnp.minimum(lo + b, hi))
+        return pl.BlockSpec(shape, index)
+
+    def kernel(k_ref, v_ref, *refs):
+        qs, dos, lses, dlts = (refs[n * nb:(n + 1) * nb] for n in range(4))
+        dk_ref, dv_ref, dk_s, dv_s = refs[4 * nb:]
+        r = pl.program_id(2)
+        k0 = pl.program_id(1) * bk
+        lo, hi = _band_queries(pl.program_id(1), bq, bk, window, T, jnp)
+        kblk, vblk = k_ref[0], v_ref[0]
+
+        # the flash dkv kernel's transposed tile: keys down, queries across
+        def step(b, masked):
+            def run(carry):
+                dk_acc, dv_acc = carry
+                qblk, doblk = qs[b][0], dos[b][0]
+                st = _dot(kblk, qblk, _NT) * scale      # (bk, bq)
+                if masked:
+                    st = jnp.where(_in_band((lo + b) * bq, k0, (bk, bq), 1,
+                                            window), st, NEG_INF)
+                pt = jnp.exp(st - lses[b][0])
+                dv_acc = dv_acc + _dot(pt.astype(doblk.dtype), doblk, _NN)
+                dst = pt * (_dot(vblk, doblk, _NT) - dlts[b][0])
+                return dk_acc + _dot(dst.astype(qblk.dtype), qblk, _NN), \
+                    dv_acc
+            return run
+
+        dk_acc, dv_acc = _band_walk(
+            nb, lambda b: _band_case((lo + b) * bq, k0, bq, bk, window,
+                                     lo + b <= hi), step,
+            (jnp.zeros((bk, D), jnp.float32),
+             jnp.zeros((bk, D), jnp.float32)))
+        dk_acc = dk_acc * scale
+
+        # a kv group's rep query heads add up in float32 scratch, as in
+        # the flash dkv kernel
+        @pl.when(r == 0)
+        def _init():
+            dk_s[...] = dk_acc
+            dv_s[...] = dv_acc
+
+        @pl.when(r > 0)
+        def _acc():
+            dk_s[...] += dk_acc
+            dv_s[...] += dv_acc
+
+        @pl.when(r == rep - 1)
+        def _flush():
+            dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
+            dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+
+    def rows(h, i):
+        return h, i, 0
+
+    def stats(h, i):
+        return h, 0, i
+
+    col = pl.BlockSpec((1, bk, D), lambda g, j, r: (g, j, 0))
+    with jax.named_scope("tiles_q%d_k%d" % (bq, bk)):
+        return pl.pallas_call(
+            kernel,
+            name="swa_bwd_dkv",
+            out_shape=(jax.ShapeDtypeStruct((BHkv, T, D), k.dtype),
+                       jax.ShapeDtypeStruct((BHkv, T, D), v.dtype)),
+            grid=(BHkv, T // bk, rep),
+            in_specs=[col, col]
+            + [query_tile(b, (1, bq, D), rows) for b in range(nb)] * 2
+            + [query_tile(b, (1, 1, bq), stats) for b in range(nb)] * 2,
+            out_specs=(col, col),
+            scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
+                            pltpu.VMEM((bk, D), jnp.float32)],
+            compiler_params=_swa_params("dkv", 2 * nb, bq, bk, D, q.dtype),
+            interpret=_INTERPRET,
+        )(k, v, *[q] * nb, *[do] * nb, *[lse] * nb, *[delta] * nb)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _swa(q, k, v, window, scale, bq, bk):
+    return _swa_fwd(q, k, v, window, scale, bq, bk)[0]
+
+
+def _swa_fwd(q, k, v, window, scale, bq, bk):
+    B, H, T, D = q.shape
+    Hkv = k.shape[1]
+    o, lse = _swa_fwd_call(q.reshape(B * H, T, D), k.reshape(B * Hkv, T, D),
+                           v.reshape(B * Hkv, T, D), window, scale, bq, bk)
+    # named for a checkpoint policy, as the flash forward's
+    o = checkpoint_name(o.reshape(B, H, T, D), ATTENTION_KERNEL_OUT)
+    lse = checkpoint_name(lse.reshape(B, H, T), ATTENTION_KERNEL_OUT)
+    return o, (q, k, v, o, lse)
+
+
+def _swa_bwd(window, scale, bq, bk, res, do):
+    q, k, v, o, lse = res
+    B, H, T, D = q.shape
+    Hkv = k.shape[1]
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    rows = (q.reshape(B * H, T, D), k.reshape(B * Hkv, T, D),
+            v.reshape(B * Hkv, T, D), do.reshape(B * H, T, D).astype(q.dtype),
+            lse.reshape(B * H, 1, T), delta.reshape(B * H, 1, T))
+    dq = _swa_bwd_dq_call(*rows, window, scale, bq, bk)
+    dk, dv = _swa_bwd_dkv_call(*rows, window, scale, bq, bk)
+    return (dq.reshape(B, H, T, D), dk.reshape(B, Hkv, T, D),
+            dv.reshape(B, Hkv, T, D))
+
+
+_swa.defvjp(_swa_fwd, _swa_bwd)
+
+
+def _swa_dense(q, k, v, window, scale):
+    """XLA stand-in of the window kernels (runs anywhere): one dense
+    softmax over the band."""
+    rep = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    t = jnp.arange(q.shape[2])
+    band = (t[None, :] <= t[:, None]) & (t[None, :] > t[:, None] - window)
+    p = jax.nn.softmax(jnp.where(band, s, NEG_INF), axis=-1)  # sees itself
+    o = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    return o.astype(q.dtype)
+
+
+def window_attention(q, k, v, window, scale=None, block_q=None, block_k=None,
+                     shard=None):
+    """Causal attention over a sliding window on (B, H, T, D): the query
+    at t sees the keys ``t - window < s <= t``.  k/v may carry fewer
+    (grouped) heads, read in place as the flash kernels read them.
+    Pallas forward and backward (``swa_fwd``, ``swa_bwd_dq``,
+    ``swa_bwd_dkv``) that visit only the tiles the band touches; with
+    ``shard`` (``parallel.sharding.kernel_shard``) they run per shard of
+    batch and heads.  Off the TPU, or at a shape the kernels do not take
+    (``_shapes_ok``), a dense masked softmax.  Each kernel call as traced
+    bumps the profiler's counters ``window_attn::calls`` and
+    ``window_attn::key_tiles`` (the (query tile, key tile) pairs its
+    forward visits over every head)."""
+    B, H, T, D = q.shape
+    if H % k.shape[1] or k.shape[2] != T or window < 1:
+        raise ValueError(
+            "window_attention: %d query heads over %d kv heads, %d keys for "
+            "%d queries, window %d" % (H, k.shape[1], k.shape[2], T, window))
+    if scale is None:
+        scale = D ** -0.5
+    if not _pallas_available() or not _shapes_ok(q, k):
+        return _swa_dense(q, k, v, window, scale)
+    bq, bk = _swa_tiles(T, window, block_q, block_k)
+    from .. import profiler
+    profiler.counter_bump("window_attn::calls", 1)
+    profiler.counter_bump("window_attn::key_tiles",
+                          B * H * _band_visited(T, window, bq, bk))
+
+    def kernel(q, k, v):
+        return _swa(q, k, v, window, scale, bq, bk)
+
+    if shard is not None:
+        spec = P(shard[1], shard[2], None, None)
+        kernel = _per_shard(kernel, shard, (spec,) * 3, spec)
+    return kernel(q, k, v)

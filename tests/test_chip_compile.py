@@ -408,6 +408,36 @@ def test_sparse_attention_compiles_at_the_sparse_cells_shape(topo, on_chip):
     assert not re.search(r'op_name="[^"]*transpose\([^"]*/gather/', text)
 
 
+@pytest.mark.parametrize("block", [None, 256, 128])
+def test_window_attention_compiles_at_the_laguna_cells_shape(topo, on_chip,
+                                                             block):
+    """``laguna_s21_train_1x8192``'s sliding layer, 72 query heads over 8
+    K/V heads of 128, 8,192 tokens, a 512-token window, bf16, forward and
+    gradient: one call each of ``swa_fwd``, ``swa_bwd_dq`` and
+    ``swa_bwd_dkv``, each right under the tile it runs (512 x 512 by
+    the picker), and no flash kernel."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def av(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def grad(q, k, v):
+        return jax.grad(lambda *a: pallas_ops.window_attention(
+            *a, 512, block_q=block, block_k=block).astype(jnp.float32)
+            .sum(), range(3))(q, k, v)
+
+    lines = _kernel_lines(grad, av((1, 72, 8192, 128)),
+                          av((1, 8, 8192, 128)), av((1, 8, 8192, 128)))
+    assert len(lines) == 3
+    tile = block or 512
+    for name in ("swa_fwd", "swa_bwd_dq", "swa_bwd_dkv"):
+        named = [ln for ln in lines if re.search(
+            r'op_name="[^"]*\btiles_q%d_k%d\)*/%s/pallas_call"'
+            % (tile, tile, name), ln)]
+        assert len(named) == 1, (name, lines)
+    assert not any("flash_" in ln for ln in lines)
+
+
 def test_index_kernel_and_grouped_matmul_compile_at_the_cells_widths(
         topo, on_chip):
     """The indexer's scores of 512 queries against 32,768 keys (16 heads

@@ -400,6 +400,84 @@ def test_dsa_moe_step_keeps_its_kernels_names(dsa_names, kernel, where,
     assert not any("/indexer/" in n and "top_k" in n for n in again)
 
 
+@pytest.fixture(scope="module")
+def laguna_names():
+    """Op names of the compiled training step of a window/full-attention
+    MoE decoder (``laguna_s21_config``'s first two layers, a full dense
+    and a sliding MoE one, at toy widths), every block marked for
+    recomputation, the kernels interpreted: 512 tokens, a window of 128."""
+    import dataclasses
+    from mxnet_tpu.models import laguna_s21_config
+    from mxnet_tpu.ndarray.ndarray import NDArray
+    from mxnet_tpu.ops import pallas_ops
+    cfg = laguna_s21_config(
+        n_layers=2, vocab_size=256, dim=128, n_kv_heads=1, head_dim=128,
+        hidden_dim=256, moe_num_experts=8, moe_top_k=2, moe_hidden_dim=128,
+        moe_held=4, max_seq_len=1024)
+    cfg.layers = tuple(dataclasses.replace(
+        s, n_heads=s.n_heads // 24, window=s.window and 128,
+        shared_hidden_dim=128) for s in cfg.layers)
+    net = TransformerLM(cfg)
+    net.initialize()
+    for blk in net.layers:
+        blk.recompute()
+    tok = NDArray(jnp.zeros((1, 512), jnp.int32))
+    step = parallel.TrainStep(
+        net, None, mx.optimizer.AdamW(learning_rate=1e-3), mesh=None,
+        forward_fn=lambda net, t, l: net.loss(t, l, chunk=256))
+    was = pallas_ops._INTERPRET
+    pallas_ops._INTERPRET = True
+    try:
+        text = step.lower(tok, tok).compile().as_text()
+    finally:
+        pallas_ops._INTERPRET = was
+    return set(re.findall(r'op_name="(jit\(step\)/[^"]*)"', text))
+
+
+@pytest.mark.parametrize("path,layer,backward", [
+    ("attention/window_attn/", 1, False), ("attention/window_attn/", 1, True),
+    ("attention/attn_gate/", 0, False), ("attention/attn_gate/", 1, True),
+    ("feed_forward/shared_expert/", 1, False),
+    ("feed_forward/shared_expert/", 1, True),
+    ("feed_forward/experts/gmm/", 1, False)])
+def test_laguna_step_carries_window_gate_and_shared_expert(
+        laguna_names, path, layer, backward):
+    # window_attn_device_pct.train and shared_expert_device_pct.train read
+    # these scopes; the shared expert lies beside the routed experts, so
+    # experts_device_pct.train keeps meaning the routed ones
+    inner = "/layer%d/checkpoint/" % layer + path
+    if backward:
+        assert any(n.startswith(BACK) and inner in n for n in laguna_names)
+    else:
+        assert any(n.startswith("jit(step)/jvp(forward)/layer%d/%s"
+                                % (layer, path)) for n in laguna_names)
+    assert not any("/experts/" in n and "/shared_expert/" in n
+                   for n in laguna_names)
+    # the full layer has no window
+    assert not any("/layer0/" in n and "/window_attn/" in n
+                   for n in laguna_names)
+
+
+@pytest.mark.parametrize("kernel,where,there", [
+    ("swa_fwd", "jit(step)/jvp(forward)/layer1/attention/window_attn/",
+     True),
+    ("swa_bwd_dq", "/layer1/checkpoint/attention/window_attn/", True),
+    ("swa_bwd_dkv", "/layer1/checkpoint/attention/window_attn/", True),
+    ("flash_fwd", "jit(step)/jvp(forward)/layer0/attention/", True),
+    ("swa_fwd", "/rematted_computation/", False),
+    ("flash_fwd", "/rematted_computation/", False),
+    ("swa_fwd", "/layer0/", False)])
+def test_laguna_step_keeps_its_kernels_names(laguna_names, kernel, where,
+                                             there):
+    # the window kernels right under the tile they run, under the sliding
+    # layer's window_attn; a marked block keeps the forward kernel's
+    # output and row sums, so it is not among the recomputed ops
+    hits = [n for n in laguna_names
+            if re.search(r"tiles_q\d+_k\d+/%s\)*/" % kernel, n)
+            and where in n]
+    assert bool(hits) == there, (kernel, where, hits[:3])
+
+
 def test_block_scope_names():
     net = gluon.nn.HybridSequential()
     net.add(gluon.nn.Dense(4, in_units=3), gluon.nn.Activation("relu"))
