@@ -24,7 +24,11 @@ scores against every key up to its last query are one call of the
 over their winners where the row is longer (the same set: a key in the
 row's top ``topk`` is in its segment's).  Chunks whose rows end in the
 same 8,192-key span are one ``lax.map``; the whole (T, T) score matrix
-never exists.  The attention is ``ops.pallas_ops.sparse_attention``.
+never exists.  Where the attention's backward walks the causal tiles
+(``ops.pallas_ops.sparse_attention_takes_bits``: the sparse cell's
+shape), the same chunk function also packs S_t as one bit a key, from
+the chunk's score rows and the top-k's last score.  The attention is
+``ops.pallas_ops.sparse_attention``.
 """
 from __future__ import annotations
 
@@ -34,7 +38,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.pallas_ops import (ATTENTION_KERNEL_OUT, index_scores,
-                              index_scores_dense, sparse_attention)
+                              index_scores_dense, pack_selection,
+                              sparse_attention, sparse_attention_takes_bits)
 
 #: queries a chunk of scoring, selection and the indexer loss
 SCORE_CHUNK = 512
@@ -80,33 +85,68 @@ def _chunk_of(a, c, chunk, axis):
     return jax.lax.dynamic_slice_in_dim(a, c * chunk, chunk, axis=axis)
 
 
-def select(qi, ki, w, topk, chunk=SCORE_CHUNK):
+def _order(x):
+    """float32 -> int32 in the total order ``lax.top_k`` sorts by (-0
+    below +0)."""
+    i = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return i ^ ((i >> 31) & 0x7FFFFFFF)
+
+
+def _selected(s, vals, idx, q0):
+    """A chunk's selection from its score rows ``s`` (queries at
+    ``q0``): the earlier keys whose score beats the top-k's last, tau,
+    or ties it at a position no later than the last tied key the top-k
+    took (ties go to the lower position).  A query with fewer than topk
+    earlier keys has tau -inf, its empty slots' indices future
+    positions."""
+    tau = _order(vals[:, -1:])
+    last = jnp.max(jnp.where(_order(vals) == tau, idx, -1), axis=1,
+                   keepdims=True)
+    key = jnp.arange(s.shape[1])[None, :]
+    o = _order(s)
+    sel = ((o > tau) | ((o == tau) & (key <= last))) \
+        & (key <= q0 + jnp.arange(s.shape[0])[:, None])
+    return jnp.pad(sel, ((0, 0), (0, -s.shape[1] % 256)))
+
+
+def select(qi, ki, w, topk, chunk=SCORE_CHUNK, bits=False):
     """One sequence's selection: ``(idx (T, topk) int32, vals (T, topk)
-    float32, n_valid (T,) int32)``, ``idx[t, :n_valid[t]]`` the positions
-    of S_t and ``vals`` their scores (the empty slots of a query with
-    fewer than ``topk`` earlier keys come last and read -inf).  ``qi``
-    (heads, T, d_I) and ``ki`` (T, d_I) after rotary, ``w`` (T, heads)
-    with both scales folded in."""
+    float32, n_valid (T,) int32, bits)``, ``idx[t, :n_valid[t]]`` the
+    positions of S_t and ``vals`` their scores (the empty slots of a
+    query with fewer than ``topk`` earlier keys come last and read
+    -inf).  ``bits``: the same sets one bit a (query, key), (T / 32, T)
+    uint32 in ``pallas_ops.pack_selection``'s layout, made from each
+    chunk's score rows beside its top-k (T whole bands of 256), else
+    None.  ``qi`` (heads, T, d_I) and ``ki`` (T, d_I) after rotary, ``w``
+    (T, heads) with both scales folded in."""
     T = ki.shape[0]
     chunk = min(chunk, T)
-    if T % chunk or topk > T:
-        raise ValueError("select: %d tokens are not whole chunks of %d or "
-                         "fewer than topk %d" % (T, chunk, topk))
+    if T % chunk or topk > T or (bits and T % 256):
+        raise ValueError("select: %d tokens are not whole chunks of %d "
+                         "(and, for bits, of 256) or fewer than topk %d"
+                         % (T, chunk, topk))
     # the scores select; their gradient is the indexer loss's own
     qi, ki, w = (jax.lax.stop_gradient(a) for a in (qi, ki, w))
-    vals, idx = [], []
+    vals, idx, words = [], [], []
     for lo, hi, keys in _spans(T, chunk, topk):
         def one(c, keys=keys):
             s = index_scores(_chunk_of(qi, c, chunk, 1), ki[:keys],
                              _chunk_of(w, c, chunk, 0), q0=c * chunk)
-            return exact_top_k(s, topk)
+            v, i = exact_top_k(s, topk)
+            if not bits:
+                return v, i
+            return v, i, pack_selection(_selected(s, v, i, c * chunk))
 
-        v, i = jax.lax.map(one, jnp.arange(lo, hi))
-        vals.append(v.reshape(-1, topk))
-        idx.append(i.reshape(-1, topk))
+        out = jax.lax.map(one, jnp.arange(lo, hi))
+        vals.append(out[0].reshape(-1, topk))
+        idx.append(out[1].reshape(-1, topk))
+        if bits:
+            cols = jnp.moveaxis(out[2], 0, 1).reshape(out[2].shape[1], -1)
+            words.append(jnp.pad(cols, ((0, T // 32 - cols.shape[0]),
+                                        (0, 0))))
     n_valid = jnp.minimum(topk, jnp.arange(T, dtype=jnp.int32) + 1)
     return (jnp.concatenate(idx).astype(jnp.int32), jnp.concatenate(vals),
-            n_valid)
+            n_valid, jnp.concatenate(words, axis=1) if bits else None)
 
 
 def _kl_parts(vals, p, n_valid):
@@ -192,25 +232,30 @@ def dsa_attention(q, k, v, qi, ki, w, topk, scale=None):
     ``qi`` (B, T, heads, d_I), ``ki`` (B, T, d_I) after theirs and ``w``
     (B, T, heads) float32 with 1 / sqrt(heads d_I) folded in.  Returns
     ``(o (B, T, H, D), L^I averaged over the sequences)``.  Named scopes:
-    ``indexer`` (scoring, top-k, ``indexer_loss``) and ``sparse_attn``
-    (the forward's gather of the selected rows, the kernels; the
-    backward's scatter of dK/dV only where a group's K/V and dK/dV do
-    not fit the backward kernel's VMEM)."""
+    ``indexer`` (scoring, top-k, the selection's bits where the
+    backward walks the causal tiles under them, ``indexer_loss``) and
+    ``sparse_attn`` (the forward's gather of the selected rows, the
+    kernels; the backward's scatter of dK/dV only where the sequence is
+    too long for the bits)."""
     B, T, H, D = q.shape
+    masked = sparse_attention_takes_bits(T, topk, D)
     sel = []
     with jax.named_scope("indexer"):
         for b in range(B):
-            idx, vals, n_valid = select(jnp.swapaxes(qi[b], 0, 1), ki[b],
-                                        w[b], topk)
+            idx, vals, n_valid, bits = select(
+                jnp.swapaxes(qi[b], 0, 1), ki[b], w[b], topk, bits=masked)
             sel.append((checkpoint_keep(idx), checkpoint_keep(vals),
-                        n_valid))
+                        n_valid, bits if bits is None
+                        else checkpoint_keep(bits)))
     with jax.named_scope("sparse_attn"):
         idx = jnp.concatenate([s[0] + b * T for b, s in enumerate(sel)])
         n_valid = jnp.concatenate([s[2] for s in sel])
+        bits = jnp.concatenate([s[3] for s in sel], axis=1) if masked \
+            else None
         o, pbar = sparse_attention(q.reshape(B * T, H, D),
                                    k.reshape(B * T, -1, D),
                                    v.reshape(B * T, -1, D), idx, n_valid,
-                                   scale=scale)
+                                   scale=scale, bits=bits)
     with jax.named_scope("indexer"), jax.named_scope("indexer_loss"):
         loss = sum(index_loss(jnp.swapaxes(qi[b], 0, 1), ki[b], w[b],
                               sel[b][0], sel[b][1],

@@ -1382,26 +1382,18 @@ def eva_flash_attention(q, k, v, ks, vs, window, scale=None, block_q=None,
 #
 # The forward goes a chunk of queries at a time: the chunk's selected K/V
 # rows are gathered by XLA (one gather of the K and V rows of every
-# group, a row a selected key) and ``dsa_fwd`` walks them.  The backward,
-# where a KV group's K and V over the whole sequence and its float32
-# dK/dV fit in VMEM beside a block's working set (``_dsa_resident``: to
-# some 47k tokens at 128-wide bf16 groups), is one call of ``dsa_bwd``
-# over (group, query block) that moves the rows itself: the group's K/V
-# is copied in once, each selected row is read from there by its index
-# (the indices reach the scalar core a query block at a time), and every
-# selected row's float32 dK/dV is added into the group's resident
-# accumulator, written out when the group's last block is done.  No
-# selected row passes through HBM there.  Past that length the backward
-# goes by chunks too, its rows' dK/dV added into the keys' gradient by an
-# XLA scatter.  (A resident forward ran twice as fast as the chunks on a
-# v5e, but its ``pbar`` stays live beside the copy ``jax.checkpoint``
-# saves of it, 0.27 GB a layer at 32k, and the sparse cell's step then
-# does not load.)
+# group, a row a selected key) and ``dsa_fwd`` walks them.  The backward
+# walks the causal tiles under the selection's bits where the caller
+# hands them over (the file's end; ``sparse_attention_takes_bits`` says
+# for which shapes).  Without bits it goes by chunks too, the rows'
+# dK/dV added into the keys' gradient by an XLA scatter.  (A forward
+# that fetched its own rows ran twice as fast as the chunks on a v5e,
+# but its ``pbar`` stays live beside the copy ``jax.checkpoint`` saves of
+# it, 0.27 GB a layer at 32k, and the sparse cell's step then does not
+# load.)
 
 #: queries a grid step of the sparse attention kernels
 _DSA_BLOCK_Q = 8
-#: selected rows a step of the resident backward kernel's row loops
-_DSA_UNROLL = 8
 
 
 def _dsa_specs(bq, K, R, D):
@@ -1568,173 +1560,10 @@ def _dsa_bwd_dense(q, kv, bias, do, lse, delta, scale, bq=None):
     return dq.reshape(N, H, D).astype(q.dtype), dkv.astype(kv.dtype)
 
 
-# -- the resident backward kernel: a group's K/V and dK/dV in VMEM ----------
-
-def _dsa_words(k, v):
-    """(G, N, W) 32-bit words a key a group: bf16 K and V of one dim in
-    one uint32 (K in the low half; W = D), float32 K then V (W = 2 D)."""
-    if k.dtype == jnp.bfloat16:
-        def bits(a):
-            return jax.lax.bitcast_convert_type(a, jnp.uint16) \
-                .astype(jnp.uint32)
-        return jnp.swapaxes(bits(k) | (bits(v) << 16), 0, 1)
-    return jnp.swapaxes(jnp.concatenate([k, v], axis=-1), 0, 1)
-
-
-def _dsa_split(w, dtype, D):
-    """K and V in ``dtype`` from the words of :func:`_dsa_words`: a bf16
-    is the high half of the float32 with its bits, so each half moved
-    there converts exactly."""
-    if dtype == jnp.bfloat16:
-        def half(bits):
-            return jax.lax.bitcast_convert_type(bits, jnp.float32) \
-                .astype(dtype)
-        return half(w << 16), half(w & jnp.uint32(0xFFFF0000))
-    return w[..., :D], w[..., D:]
-
-
-def _dsa_resident_bytes(N, R, D, K, bq, dtype):
-    """VMEM of the resident backward kernel: the group's K/V words
-    (N, W) and float32 dK/dV (N, 2D), a block's fetched rows (bq, K, W)
-    and their float32 dK/dV (bq, K, 2D), six (bq, R, K) float32 tiles,
-    the double-buffered query blocks and 4 MiB for the compiler's own
-    values (the rows' halves as they are split).  Only the first two
-    grow with N: a v5e compile of the cell's widths (bq 8, K 2,048, 4
-    groups of 128) needs 31-32 MiB at N 1,024 where this reads 33.0,
-    and 98-99 at N 46,592 where it reads 99.75;
-    tests/test_chip_compile.py compiles the longest N it admits."""
-    W = D if dtype == jnp.bfloat16 else 2 * D
-    return (4 * (N + bq * K) * (W + 2 * D) + 6 * bq * R * K * 4
-            + 16 * bq * R * D * 4 + (4 << 20))
-
-
-def _dsa_resident(q, k, K, bq):
-    """True where the resident backward kernel takes the call: bf16 or
-    float32 K/V of the queries' dtype, whole query blocks and steps of
-    the row loops, and its VMEM (``_dsa_resident_bytes``) within
-    ``_VMEM_MAX``."""
-    N, H, D = q.shape
-    return (k.dtype in (jnp.bfloat16, jnp.float32) and q.dtype == k.dtype
-            and N % bq == 0 and K % _DSA_UNROLL == 0
-            and _dsa_resident_bytes(N, H // k.shape[1], D, K, bq, k.dtype)
-            <= _VMEM_MAX)
-
-
 def _dsa_count(path):
     """Bump the profiler's counter of the path a call was traced on."""
     from .. import profiler
     profiler.counter_bump("sparse_attn::" + path, 1)
-
-
-def _dsa_resident_params(N, R, D, K, bq, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-    need = _dsa_resident_bytes(N, R, D, K, bq, dtype)
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"),
-        vmem_limit_bytes=int(min(max(need, _VMEM_DEFAULT), _VMEM_MAX)))
-
-
-def _dsa_resident_specs(bq, K, R, D):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    return {"idx": pl.BlockSpec((bq, K), lambda g, i: (i, 0),
-                                memory_space=pltpu.SMEM),
-            "nv": pl.BlockSpec((bq, 1), lambda g, i: (i, 0)),
-            "q": pl.BlockSpec((bq, R, D), lambda g, i: (i, g, 0)),
-            "stat": pl.BlockSpec((1, bq, R), lambda g, i: (g, i, 0)),
-            "hbm": pl.BlockSpec(memory_space=pl.ANY)}
-
-
-def _dsa_copy(src, dst, sem):
-    from jax.experimental.pallas import tpu as pltpu
-    cp = pltpu.make_async_copy(src, dst, sem)
-    cp.start()
-    cp.wait()
-
-
-def _dsa_each_slot(bq, K, body):
-    """``body(r, j)`` for each of a block's ``bq`` queries and each of
-    its ``K`` slots, in order, ``_DSA_UNROLL`` slots a loop step.  The
-    queries are unrolled: with ``r`` a loop index the resident backward
-    ran 13.40 s a step where it runs 11.58 on a v5e at the sparse cell's
-    widths, though it lowers one loop body where this lowers ``bq``."""
-    from jax.experimental import pallas as pl
-    U = _DSA_UNROLL
-    for r in range(bq):
-        def step(j, carry, r=r):
-            j0 = pl.multiple_of(j * U, U)
-            for u in range(U):
-                body(r, j0 + u)
-            return carry
-        jax.lax.fori_loop(0, K // U, step, 0)
-
-
-def _dsa_resident_bias(nv_ref, bq, K):
-    return jnp.where(jax.lax.broadcasted_iota(jnp.int32, (bq, K), 1)
-                     < nv_ref[...], 0.0, NEG_INF)
-
-
-def _dsa_resident_bwd_call(q, words, idx, n_valid, do, lse, delta, scale,
-                           bq):
-    """``dq`` (N, H, D) and the keys' float32 ``dK`` and ``dV`` of each
-    group, (G, N, 2 D) with dK first: every selected row's added in
-    VMEM, query block after query block."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    N, H, D = q.shape
-    G, _, W = words.shape
-    K = idx.shape[1]
-    R = H // G
-    nq = N // bq
-    sp = _dsa_resident_specs(bq, K, R, D)
-
-    def kernel(idx_ref, nv_ref, q_ref, do_ref, lse_ref, dlt_ref, words_hbm,
-               dq_ref, dkv_hbm, group, rows, dsel, acc, sem):
-        g, i = pl.program_id(0), pl.program_id(1)
-
-        @pl.when(i == 0)
-        def _():
-            acc[...] = jnp.zeros(acc.shape, acc.dtype)
-            _dsa_copy(words_hbm.at[g], group, sem)
-
-        def fetch(r, j):    # query r's j-th selected row, by its index
-            rows[r, pl.ds(j, 1), :] = group[pl.ds(idx_ref[r, j], 1), :]
-
-        _dsa_each_slot(bq, K, fetch)
-        k, v = _dsa_split(rows[...], q.dtype, D)
-        dq, dsel[:, :, :D], dsel[:, :, D:] = _dsa_bwd_block(
-            q_ref[...], k, v, _dsa_resident_bias(nv_ref, bq, K),
-            do_ref[...], lse_ref[0], dlt_ref[0], scale)
-        dq_ref[...] = dq.astype(dq_ref.dtype)
-
-        def add(r, j):   # one add after the other: two slots may name a key
-            key = pl.ds(idx_ref[r, j], 1)
-            acc[key, :] += dsel[r, pl.ds(j, 1), :]
-
-        _dsa_each_slot(bq, K, add)
-
-        @pl.when(i == nq - 1)
-        def _():
-            _dsa_copy(acc, dkv_hbm.at[g], sem)
-
-    with jax.named_scope("tiles_q%d_k%d" % (bq, K)):
-        return pl.pallas_call(
-            kernel,
-            name="dsa_bwd",
-            out_shape=(jax.ShapeDtypeStruct((N, H, D), q.dtype),
-                       jax.ShapeDtypeStruct((G, N, 2 * D), jnp.float32)),
-            grid=(G, nq),
-            in_specs=[sp["idx"], sp["nv"], sp["q"], sp["q"], sp["stat"],
-                      sp["stat"], sp["hbm"]],
-            out_specs=(sp["q"], sp["hbm"]),
-            scratch_shapes=[pltpu.VMEM((N, W), words.dtype),
-                            pltpu.VMEM((bq, K, W), words.dtype),
-                            pltpu.VMEM((bq, K, 2 * D), jnp.float32),
-                            pltpu.VMEM((N, 2 * D), jnp.float32),
-                            pltpu.SemaphoreType.DMA(())],
-            compiler_params=_dsa_resident_params(N, R, D, K, bq, q.dtype),
-            interpret=_INTERPRET,
-        )(idx, n_valid, q, do, lse, delta, words)
 
 
 def _dsa_rows(k, v):
@@ -1753,19 +1582,6 @@ def _dsa_calls():
     if _pallas_available():
         return _dsa_fwd_call, _dsa_bwd_call
     return _dsa_fwd_dense, _dsa_bwd_dense
-
-
-def _dsa_resident_bwd(q, k, v, idx, n_valid, do, lse, delta, scale, bq):
-    """``dq`` and the keys' float32 ``dk``, ``dv`` (N, G, D) by the
-    resident kernel; ``lse`` as the forward saved it, ``delta`` (N, H)."""
-    N, H, D = q.shape
-    G = k.shape[1]
-    dq, dkv = _dsa_resident_bwd_call(
-        q, _dsa_words(k, v), idx, n_valid[:, None], do,
-        jnp.moveaxis(lse, 1, 0).reshape(G, N, H // G),
-        jnp.swapaxes(delta.reshape(N, G, H // G), 0, 1), scale, bq)
-    dkv = jnp.swapaxes(dkv, 0, 1)                      # (N, G, 2 D)
-    return dq, dkv[..., :D], dkv[..., D:]
 
 
 def _dsa_chunked_bwd(q, k, v, idx, n_valid, do, lse, delta, scale, chunk,
@@ -1797,16 +1613,16 @@ def _dsa_chunked_bwd(q, k, v, idx, n_valid, do, lse, delta, scale, chunk,
     return dq.reshape(N, H, D), acc[:, :, 0], acc[:, :, 1]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _dsa(q, k, v, idx, n_valid, scale, chunk, bq):
-    return _dsa_fwd(q, k, v, idx, n_valid, scale, chunk, bq)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _dsa(q, k, v, idx, n_valid, bits, scale, chunk, bq):
+    return _dsa_fwd(q, k, v, idx, n_valid, bits, scale, chunk, bq)[0]
 
 
 def _chunked(a, chunk):
     return a.reshape((a.shape[0] // chunk, chunk) + a.shape[1:])
 
 
-def _dsa_fwd(q, k, v, idx, n_valid, scale, chunk, bq):
+def _dsa_fwd(q, k, v, idx, n_valid, bits, scale, chunk, bq):
     N, H, D = q.shape
     K = idx.shape[1]
     fwd_call = _dsa_calls()[0]
@@ -1825,53 +1641,49 @@ def _dsa_fwd(q, k, v, idx, n_valid, scale, chunk, bq):
     lse = checkpoint_name(lse, ATTENTION_KERNEL_OUT)    # (n, G, C, R)
     pbar = checkpoint_name(jnp.sum(pbar, axis=1).reshape(N, K),
                            ATTENTION_KERNEL_OUT)
-    return (o, pbar), (q, k, v, idx, n_valid, o, lse)
+    return (o, pbar), (q, k, v, idx, n_valid, bits, o, lse)
 
 
 def _dsa_bwd(scale, chunk, bq, res, cot):
-    q, k, v, idx, n_valid, o, lse = res
+    q, k, v, idx, n_valid, bits, o, lse = res
     do = cot[0]                 # the weights' mean is read unrolled
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
     args = (q, k, v, idx, n_valid, do.astype(q.dtype), lse, delta, scale)
-    resident = _dsa_resident(q, k, idx.shape[1], bq)
-    _dsa_count("resident_bwd" if resident else "chunked_bwd")
-    if resident and _pallas_available():
-        dq, dk, dv = _dsa_resident_bwd(*args, bq)
-    else:           # off the TPU the chunks' XLA stand-in, whichever path
+    if bits is None:
+        _dsa_count("chunked_bwd")
         dq, dk, dv = _dsa_chunked_bwd(*args, chunk, bq)
+    else:                  # the causal tiles under the selection's bits
+        _dsa_count("masked_bwd")
+        dq, dk, dv = _dsa_masked(*args, bits)
     import numpy as onp
     zero = onp.zeros(idx.shape, jax.dtypes.float0)
     return (dq, dk.astype(k.dtype), dv.astype(v.dtype), zero,
-            onp.zeros(n_valid.shape, jax.dtypes.float0))
+            onp.zeros(n_valid.shape, jax.dtypes.float0),
+            None if bits is None else onp.zeros(bits.shape, zero.dtype))
 
 
 _dsa.defvjp(_dsa_fwd, _dsa_bwd)
 
 
 def sparse_attention(q, k, v, idx, n_valid, scale=None, chunk=256,
-                     block_q=None):
+                     block_q=None, bits=None):
     """Attention of each query over its own selected keys, token-major:
     ``q`` (N, H, D), ``k`` / ``v`` (N, G, D) with G dividing H (GQA: head
     h reads group h // (H / G)), ``idx`` (N, K) int rows of ``k`` / ``v``
     and ``n_valid`` (N,) int: query i attends to ``idx[i, :n_valid[i]]``
     (at least one), the other slots are empty; every slot names a row
-    of ``k``.  Returns ``o`` (N, H, D) and ``pbar`` (N, K) float32, the
-    mean over the H heads of each slot's softmax weight (0 on an empty
-    slot), which has no gradient.
-
-    The queries go ``chunk`` at a time (it divides N) in the forward:
-    their selected rows are gathered (scope ``gather``) and the kernel
-    ``dsa_fwd`` walks them.  Where a group's K/V and float32 dK/dV fit
-    in VMEM (the shape and dtype decide: to some 47k tokens at 128-wide
-    bf16 groups) the backward is one call of ``dsa_bwd`` that walks the
-    whole sequence a group at a time, reads each selected row from the
-    group's resident K/V and adds its dK/dV into the group's resident
-    float32 accumulator; otherwise it goes by chunks too, the rows' dK/dV
-    added into the keys' by a scatter (scope ``scatter``).  The
-    profiler's counters ``sparse_attn::resident_bwd`` and
-    ``sparse_attn::chunked_bwd`` count the backward calls traced on each
-    path.  Off the TPU the same in XLA, by chunks: the gather, the dense
-    products and the scatter."""
+    of ``k``; ``bits``, where given, the same sets one bit a (query, key)
+    of each sequence (``pack_selection``).  Returns ``o`` (N, H, D) and
+    ``pbar`` (N, K) float32, the heads' mean of each slot's softmax weight
+    (0 on an empty slot), which has no gradient.  The forward goes
+    ``chunk`` queries at a time (it divides N): their selected rows are
+    gathered (scope ``gather``) and ``dsa_fwd`` walks them.  The backward
+    walks the causal tiles under the bits where they are given (sequences
+    of whole tiles and heads of 128 lanes: ``sparse_attention_takes_bits``
+    says where a caller should make them); else it goes by chunks too,
+    their rows' dK/dV scattered into the keys' (scope ``scatter``).
+    ``sparse_attn::masked_bwd`` / ``chunked_bwd`` count the calls traced
+    on each path.  Off the TPU the same in XLA."""
     N, H, D = q.shape
     G = k.shape[1]
     if H % G or k.shape != v.shape or k.shape[0] != N \
@@ -1880,11 +1692,19 @@ def sparse_attention(q, k, v, idx, n_valid, scale=None, chunk=256,
             "sparse_attention: q %s, k/v %s, idx %s: heads a multiple of "
             "the groups, a row of idx a query, and the chunk dividing the "
             "queries" % (q.shape, k.shape, idx.shape))
+    if bits is not None:
+        T = 32 * bits.shape[0]
+        if bits.shape[1] != N or N % T or _dsa_masked_tiles(T, D) is None:
+            raise ValueError(
+                "sparse_attention: bits %s are not (T / 32, %d) words of "
+                "whole sequences the masked backward walks (T whole tiles, "
+                "heads of D %% 128 == 0)" % (bits.shape, N))
+        bits = bits.astype(jnp.uint32)
     if scale is None:
         scale = D ** -0.5
     bq = block_q or min(_DSA_BLOCK_Q, N)
-    return _dsa(q, k, v, idx.astype(jnp.int32),
-                n_valid.astype(jnp.int32), float(scale), min(chunk, N), bq)
+    return _dsa(q, k, v, idx.astype(jnp.int32), n_valid.astype(jnp.int32),
+                bits, float(scale), min(chunk, N), bq)
 
 
 # ---------------------------------------------------------------------------
@@ -2368,3 +2188,295 @@ def window_attention(q, k, v, window, scale=None, block_q=None, block_k=None,
         spec = P(shard[1], shard[2], None, None)
         kernel = _per_shard(kernel, shard, (spec,) * 3, spec)
     return kernel(q, k, v)
+
+
+# ---------------------------------------------------------------------------
+# the sparse attention's masked backward (``sparse_attention`` with bits)
+# ---------------------------------------------------------------------------
+#
+# ``models/dsa.select`` can hand the selection out as one bit a (query,
+# key) of the sequence (``pack_selection``): ``bits`` (T / 32, N) uint32,
+# a column of words a query.  Keys go in bands of 256: key s is bit
+# (s % 256) // 8 of word row 8 (s // 256) + s % 8, so a band's 8 word rows
+# unpack, shift by shift, into 32 slabs of 8 keys stacked along the
+# sublanes (``_dsa_unpack``).  Two kernels walk the sequence's causal
+# tiles as the flash backward does, with every query head of a KV group
+# in one grid step (one unpacked mask and one K/V tile serve its R
+# heads): ``dsa_masked_dq`` over (group, query tile, key tile) and
+# ``dsa_masked_dkv`` over (group, key tile, query tile), each adding its
+# products into VMEM float32 along its last grid axis and writing once.
+# A tile in the future is skipped, its blocks' indices held at the last
+# one fetched, so the pipeline moves nothing for it.  An unselected
+# pair's probability is an exact zero: the set, the softmax under the
+# forward's lse and the float32 sums are the row kernels'; only the order
+# of the sums differs.  The work is every causal tile's wherever the
+# selected keys lie, so a caller hands the bits over by shape
+# (``sparse_attention_takes_bits``).
+
+#: (queries, keys) of a masked backward tile, the largest that divide T
+_DSA_MASKED_TILES = ((256, 128), (512, 256))
+
+
+def _dsa_masked_tiles(T, D):
+    """(bq, bk) of the masked backward for sequences of ``T`` and heads
+    of ``D``, or None where it cannot walk them: keys in whole bands of
+    256 and heads of whole 128-lane rows."""
+    bq = [b for b in _DSA_MASKED_TILES[0] if T % b == 0]
+    bk = [b for b in _DSA_MASKED_TILES[1] if T % b == 0]
+    return (bq[0], bk[0]) if bq and bk and D % 128 == 0 else None
+
+
+def sparse_attention_takes_bits(T, K, D):
+    """True where a caller of ``sparse_attention`` with sequences of
+    ``T``, ``K`` slots a query and heads of ``D`` should hand it the
+    selection's bits, so that the backward walks the causal tiles: the
+    tiles whole, and a query's bits (T / 8 bytes) no larger than its
+    slots' indices kept beside them (4 K).  Past that length the chunks
+    of rows stay."""
+    return _dsa_masked_tiles(T, D) is not None and T // 8 <= 4 * K
+
+
+def pack_selection(sel):
+    """(n, L) bool, L whole bands of 256 keys -> the (L / 32, n) uint32
+    words of the masked backward's layout."""
+    n, L = sel.shape
+    b = jnp.arange(32, dtype=jnp.uint32)[None, None, :, None]
+    w = jnp.sum(sel.reshape(n, L // 256, 32, 8).astype(jnp.uint32) << b,
+                axis=2, dtype=jnp.uint32)
+    return jnp.transpose(w, (1, 2, 0)).reshape(L // 32, n)
+
+
+def unpack_selection(bits):
+    """The (L, n) bool of :func:`pack_selection`'s words."""
+    nb = bits.shape[0] // 8
+    b = jnp.arange(32, dtype=jnp.uint32)[None, :, None, None]
+    return ((bits.reshape(nb, 1, 8, -1) >> b) & 1).reshape(nb * 256, -1) \
+        != 0
+
+
+def _dsa_unpack(words):
+    """A kernel's (bk / 32, bq) words -> (bk, bq) int32 0 / 1, slab by
+    slab along the sublanes."""
+    slabs = []
+    for m in range(words.shape[0] // 8):
+        w = words[8 * m:8 * m + 8]
+        slabs += [(w >> b) & 1 for b in range(32)]
+    return jnp.concatenate(slabs, axis=0).astype(jnp.int32)
+
+
+def _dsa_masked_params(bq, bk, R, D, dtype):
+    """Eight (bq, bk) float32 tiles alive at once, the double-buffered
+    query and K/V blocks, the float32 accumulators and 4 MiB for the
+    compiler."""
+    from jax.experimental.pallas import tpu as pltpu
+    need = (8 * bq * bk * 4
+            + 4 * (2 * R * bq + 2 * bk) * D * jnp.dtype(dtype).itemsize
+            + 2 * max(R * bq, bk) * D * 4 + (4 << 20))
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=int(min(max(need, _VMEM_DEFAULT), _VMEM_MAX)))
+
+
+def _dsa_masked_dq_call(q, k, v, bits, do, lse, delta, T, scale, bq, bk):
+    """``dq`` (N, H D) of the masked walk: ``q``, ``do`` (N, H D), ``k``,
+    ``v`` (N, G D), ``bits`` (T / 32, N), ``lse`` and ``delta`` (G,
+    N / bq, 1, R bq), a query tile's heads one after the other."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    N, HD = q.shape
+    G, nq, _, RB = lse.shape
+    R, D = RB // bq, HD // (G * RB // bq)
+    nqs, nks = T // bq, T // bk
+
+    def last(i):    # the last key tile query tile i sees any of
+        return ((i % nqs) * bq + bq - 1) // bk
+
+    def key_tile(g, i, j):
+        return (i // nqs) * nks + jnp.minimum(j, last(i)), g
+
+    def kernel(q_ref, k_ref, v_ref, bits_ref, do_ref, lse_ref, dlt_ref,
+               dq_ref, acc):
+        i, j = pl.program_id(1), pl.program_id(2)
+
+        @pl.when(j == 0)
+        def _():
+            acc[...] = jnp.zeros(acc.shape, acc.dtype)
+
+        @pl.when(j <= last(i))
+        def _():
+            kblk, vblk = k_ref[...], v_ref[...]
+            mask = _dsa_unpack(bits_ref[...]).T != 0           # (bq, bk)
+            for r in range(R):      # the group's heads share the mask
+                cols, at = pl.ds(r * D, D), pl.ds(r * bq, bq)
+                s = _dot(q_ref[:, cols], kblk, _NT) * scale
+                p = jnp.exp(jnp.where(mask, s, NEG_INF)
+                            - lse_ref[0, 0, 0, at][:, None])
+                dp = _dot(do_ref[:, cols], vblk, _NT)
+                ds = p * (dp - dlt_ref[0, 0, 0, at][:, None])
+                acc[at, :] += _dot(ds.astype(kblk.dtype), kblk, _NN)
+
+        @pl.when(j == nks - 1)
+        def _():
+            for r in range(R):
+                dq_ref[:, r * D:(r + 1) * D] = (
+                    acc[r * bq:(r + 1) * bq, :] * scale).astype(dq_ref.dtype)
+
+    rows = pl.BlockSpec((bq, R * D), lambda g, i, j: (i, g))
+    stat = pl.BlockSpec((1, 1, 1, RB), lambda g, i, j: (g, i, 0, 0))
+    return pl.pallas_call(
+        kernel,
+        name="dsa_masked_dq",
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        grid=(G, nq, nks),
+        in_specs=[rows, pl.BlockSpec((bk, D), key_tile),
+                  pl.BlockSpec((bk, D), key_tile),
+                  pl.BlockSpec((bk // 32, bq), lambda g, i, j: (
+                      jnp.minimum(j, last(i)), i)),
+                  rows, stat, stat],
+        out_specs=rows,
+        scratch_shapes=[pltpu.VMEM((RB, D), jnp.float32)],
+        compiler_params=_dsa_masked_params(bq, bk, R, D, q.dtype),
+        interpret=_INTERPRET,
+    )(q, k, v, bits, do, lse, delta)
+
+
+def _dsa_masked_dkv_call(q, k, v, bits, do, lse, delta, T, scale, bq, bk):
+    """``dk``, ``dv`` (N, G D) of the masked walk, in the K/V dtype;
+    arguments as :func:`_dsa_masked_dq_call`'s."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    N, HD = q.shape
+    G, nq, _, RB = lse.shape
+    R, D = RB // bq, HD // (G * RB // bq)
+    nqs, nks = T // bq, T // bk
+
+    def first(j):   # the first query tile that sees key tile j
+        return (j % nks) * bk // bq
+
+    def query_tile(j, i):
+        return (j // nks) * nqs + jnp.maximum(i, first(j))
+
+    def kernel(q_ref, k_ref, v_ref, bits_ref, do_ref, lse_ref, dlt_ref,
+               dk_ref, dv_ref, dk_s, dv_s):
+        j, i = pl.program_id(1), pl.program_id(2)
+
+        @pl.when(i == 0)
+        def _():
+            dk_s[...] = jnp.zeros(dk_s.shape, dk_s.dtype)
+            dv_s[...] = jnp.zeros(dv_s.shape, dv_s.dtype)
+
+        # keys down and a tile's queries across, head after head: lse and
+        # delta meet the tile as the rows they are stored as
+        @pl.when(i >= first(j))
+        def _():
+            kblk, vblk = k_ref[...], v_ref[...]
+            mask = _dsa_unpack(bits_ref[...]) != 0              # (bk, bq)
+            dk, dv = dk_s[...], dv_s[...]
+            for r in range(R):      # the group's heads share the mask
+                cols, at = pl.ds(r * D, D), pl.ds(r * bq, bq)
+                qh, doh = q_ref[:, cols], do_ref[:, cols]
+                st = _dot(kblk, qh, _NT) * scale
+                pt = jnp.exp(jnp.where(mask, st, NEG_INF)
+                             - lse_ref[0, 0, :, at])
+                dv = dv + _dot(pt.astype(doh.dtype), doh, _NN)
+                dst = pt * (_dot(vblk, doh, _NT) - dlt_ref[0, 0, :, at])
+                dk = dk + _dot(dst.astype(qh.dtype), qh, _NN)
+            dk_s[...], dv_s[...] = dk, dv
+
+        @pl.when(i == nqs - 1)
+        def _():
+            dk_ref[...] = (dk_s[...] * scale).astype(dk_ref.dtype)
+            dv_ref[...] = dv_s[...].astype(dv_ref.dtype)
+
+    rows = pl.BlockSpec((bq, R * D), lambda g, j, i: (query_tile(j, i), g))
+    stat = pl.BlockSpec((1, 1, 1, RB),
+                        lambda g, j, i: (g, query_tile(j, i), 0, 0))
+    keys = pl.BlockSpec((bk, D), lambda g, j, i: (j, g))
+    return pl.pallas_call(
+        kernel,
+        name="dsa_masked_dkv",
+        out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)),
+        grid=(G, N // bk, nqs),
+        in_specs=[rows, keys, keys,
+                  pl.BlockSpec((bk // 32, bq), lambda g, j, i: (
+                      j % nks, query_tile(j, i))),
+                  rows, stat, stat],
+        out_specs=(keys, keys),
+        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
+                        pltpu.VMEM((bk, D), jnp.float32)],
+        compiler_params=_dsa_masked_params(bq, bk, R, D, q.dtype),
+        interpret=_INTERPRET,
+    )(q, k, v, bits, do, lse, delta)
+
+
+def _dsa_masked_bwd(q, k, v, bits, do, lse, delta, scale):
+    """``dq`` and the keys' ``dk``, ``dv`` (N, G, D) by the masked walk;
+    ``lse`` as the forward saved it, ``delta`` (N, H)."""
+    N, H, D = q.shape
+    G = k.shape[1]
+    R, T = H // G, 32 * bits.shape[0]
+    bq, bk = _dsa_masked_tiles(T, D)
+
+    def stat(s):    # (N, H) -> (G, N / bq, 1, R bq)
+        return jnp.transpose(s.reshape(N // bq, bq, G, R), (2, 0, 3, 1)) \
+            .reshape(G, N // bq, 1, R * bq)
+
+    lse = jnp.swapaxes(lse, 1, 2).reshape(N, H)       # (n, G, C, R)
+    args = (q.reshape(N, H * D), k.reshape(N, G * D), v.reshape(N, G * D),
+            bits, do.reshape(N, H * D), stat(lse), stat(delta), T, scale,
+            bq, bk)
+    with jax.named_scope("tiles_q%d_k%d" % (bq, bk)):
+        dq = _dsa_masked_dq_call(*args)
+        dk, dv = _dsa_masked_dkv_call(*args)
+    return dq.reshape(N, H, D), dk.reshape(N, G, D), dv.reshape(N, G, D)
+
+
+def _dsa_masked_dense(q, k, v, bits, do, lse, delta, scale):
+    """XLA stand-in of :func:`_dsa_masked_bwd`: the same masked products
+    a chunk of 256 queries at a time against its sequence's keys, so no
+    (T, T) array exists."""
+    N, H, D = q.shape
+    G = k.shape[1]
+    R, T = H // G, 32 * bits.shape[0]
+    C = min(256, T)
+    lse = jnp.swapaxes(lse, 1, 2).reshape(N, H)
+
+    def one(acc, c):
+        qc, dc, lc, tc, words, c0 = c
+        s0 = c0 // T * T
+        kb = jax.lax.dynamic_slice_in_dim(k, s0, T)
+        vb = jax.lax.dynamic_slice_in_dim(v, s0, T)
+        qg, dg = qc.reshape(C, G, R, D), dc.reshape(C, G, R, D)
+        s = jnp.einsum("cgrd,sgd->cgrs", qg, kb,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(unpack_selection(words).T[:, None, None, :], s,
+                      NEG_INF)
+        p = jnp.exp(s - lc.reshape(C, G, R)[..., None])
+        dp = jnp.einsum("cgrd,sgd->cgrs", dg, vb,
+                        preferred_element_type=jnp.float32)
+        ds = p * (dp - tc.reshape(C, G, R)[..., None])
+        dq = jnp.einsum("cgrs,sgd->cgrd", ds.astype(k.dtype), kb,
+                        preferred_element_type=jnp.float32) * scale
+        dk = jnp.einsum("cgrs,cgrd->sgd", ds.astype(q.dtype), qg,
+                        preferred_element_type=jnp.float32) * scale
+        dv = jnp.einsum("cgrs,cgrd->sgd", p.astype(do.dtype), dg,
+                        preferred_element_type=jnp.float32)
+        part = jax.lax.dynamic_slice_in_dim(acc, s0, T) \
+            + jnp.stack([dk, dv], axis=2)
+        return jax.lax.dynamic_update_slice_in_dim(acc, part, s0, 0), \
+            dq.reshape(C, H, D).astype(q.dtype)
+
+    words = jnp.swapaxes(bits.reshape(bits.shape[0], N // C, C), 0, 1)
+    acc, dq = jax.lax.scan(
+        one, jnp.zeros((N, G, 2, D), jnp.float32),
+        (_chunked(q, C), _chunked(do, C), _chunked(lse, C),
+         _chunked(delta, C), words, jnp.arange(0, N, C)))
+    return dq.reshape(N, H, D), acc[:, :, 0], acc[:, :, 1]
+
+
+def _dsa_masked(q, k, v, idx, n_valid, do, lse, delta, scale, bits):
+    """The masked backward's ``dq``, ``dk``, ``dv``: the kernels, or off
+    the TPU their XLA stand-in."""
+    walk = _dsa_masked_bwd if _pallas_available() else _dsa_masked_dense
+    return walk(q, k, v, bits, do, lse, delta, scale)

@@ -379,33 +379,93 @@ def test_the_token_cells_flash_kernels_keep_their_names_and_tiles(
 def test_sparse_attention_compiles_at_the_sparse_cells_shape(topo, on_chip):
     """``keye_vl2_30b_a3b_train_1x32768``'s sparse attention at its
     widths and length (32,768 queries, 32 heads and 4 K/V groups of 128,
-    2,048 selected keys a query, bf16), forward and gradient: ``dsa_fwd``
-    and ``dsa_bwd`` under the tile they run, one call of ``dsa_bwd`` with
-    a group's K/V and float32 dK/dV resident in VMEM, and neither a
-    gather nor a scatter of rows left to XLA in the backward."""
+    2,048 selected keys a query, bf16), forward and gradient, with the
+    selection's bits as ``models/dsa.py`` hands them: ``dsa_fwd`` under
+    the tile it runs, the masked backward's ``dsa_masked_dq`` and
+    ``dsa_masked_dkv`` under theirs (one call each), no ``dsa_bwd``, and
+    neither a gather nor a scatter of rows left to XLA in the
+    backward."""
     one_chip = SingleDeviceSharding(topo.devices[0])
     N, K = 32768, 2048
 
     def av(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def grad(q, k, v, idx, n_valid):
+    def grad(q, k, v, idx, n_valid, bits):
         return jax.grad(lambda *a: pallas_ops.sparse_attention(
-            *a, idx, n_valid)[0].astype(jnp.float32).sum(), range(3))(
-                q, k, v)
+            *a, idx, n_valid, bits=bits)[0].astype(jnp.float32).sum(),
+            range(3))(q, k, v)
 
+    before = mx.profiler.get_counters().get("sparse_attn::masked_bwd", 0)
     text = jax.jit(grad).lower(
         av((N, 32, 128)), av((N, 4, 128)), av((N, 4, 128)),
-        av((N, K), jnp.int32), av((N,), jnp.int32)).compile().as_text()
+        av((N, K), jnp.int32), av((N,), jnp.int32),
+        av((N // 32, N), jnp.uint32)).compile().as_text()
+    assert mx.profiler.get_counters()["sparse_attn::masked_bwd"] \
+        == before + 1
     lines = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
-    assert len(lines) == 2
-    for name in ("dsa_fwd", "dsa_bwd"):
-        assert any(re.search(r'op_name="[^"]*\btiles_q8_k2048\)*/%s/'
-                             r'pallas_call"' % name, ln) for ln in lines), \
-            (name, lines)
+    assert len(lines) == 3
+    bq, bk = pallas_ops._dsa_masked_tiles(N, 128)
+    for name, tile in (("dsa_fwd", "q8_k2048"),
+                       ("dsa_masked_dq", "q%d_k%d" % (bq, bk)),
+                       ("dsa_masked_dkv", "q%d_k%d" % (bq, bk))):
+        assert any(re.search(r'op_name="[^"]*\btiles_%s\)*/%s/'
+                             r'pallas_call"' % (tile, name), ln)
+                   for ln in lines), (name, lines)
+    assert not any("dsa_bwd" in ln for ln in lines)
     assert not re.search(r'op_name="[^"]*/scatter/', text)
     assert not re.search(r"= \S+ scatter\(", text)
     assert not re.search(r'op_name="[^"]*transpose\([^"]*/gather/', text)
+
+
+def test_the_sparse_cells_step_asks_no_more_than_the_chip_has_loaded(
+        topo, on_chip, tmp_path):
+    """``keye_vl2_30b_a3b_train_1x32768``'s step (4 layers at published
+    widths, every block marked for recomputation, AdamW's float32
+    moments, 32,768 tokens) compiled for the described v5e from shapes
+    alone: the temporaries the chip reserves to load it (XLA's
+    memory-usage report, ``preallocated-temp``) stay at or under 10.41
+    GiB, a size the chip has loaded (10.54 GiB are free beside the
+    cell's state), with one masked backward a layer."""
+    from mxnet_tpu.models import TransformerLM, keye_vl2_30b_a3b_config
+    from mxnet_tpu.ndarray.ndarray import NDArray
+    from mxnet_tpu.numpy import random as _random
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def av(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    net = TransformerLM(keye_vl2_30b_a3b_config(
+        n_layers=4, vocab_size=18992, moe_held=16, dtype="bfloat16"))
+    net.cast("bfloat16")
+    for blk in net.layers:
+        blk.recompute()
+    params = net.collect_params()
+    for p in params.values():      # the step is lowered from shapes
+        p._data = NDArray(jnp.zeros((), p.dtype))
+    step = parallel.TrainStep(
+        net, None, mx.optimizer.SGD(learning_rate=3e-4), mesh=None,
+        forward_fn=lambda net, t, l: net.loss(t, l, chunk=2048))
+    step.optimizer = mx.optimizer.AdamW(learning_rate=3e-4, beta1=0.9,
+                                        beta2=0.95, epsilon=1e-8, wd=0.1)
+    tok = jnp.zeros((1, 32768), jnp.int32)
+    key = _random.new_key()
+    args = ({n: av(p.shape, p.dtype) for n, p in params.items()},
+            {n: (av(params[n].shape, jnp.float32),) * 2
+             for n in step._trainable},
+            av((), jnp.int32), av((), jnp.float32), av(key.shape, key.dtype),
+            av(tok.shape, tok.dtype), av(tok.shape, tok.dtype))
+    before = mx.profiler.get_counters().get("sparse_attn::masked_bwd", 0)
+    step._build((tok, tok)).lower(*args).compile(compiler_options={
+        "xla_dump_to": str(tmp_path), "xla_dump_hlo_module_re": "jit_step"})
+    assert mx.profiler.get_counters()["sparse_attn::masked_bwd"] \
+        == before + 4
+    report, = [f for f in os.listdir(tmp_path)
+               if f.endswith("after_optimizations-memory-usage-report.txt")]
+    sizes = [float(m) for m in re.findall(
+        r"size ([0-9.]+)GiB, preallocated-temp", open(
+            os.path.join(tmp_path, report)).read())]
+    assert sizes and max(sizes) <= 10.41, sizes
 
 
 @pytest.mark.parametrize("block", [None, 256, 128])
@@ -527,38 +587,37 @@ def test_decode_program_carries_its_scopes(topo, on_chip):
                      r'[^"]*paged_attention/pallas_call"', kernel)
 
 
-def test_the_resident_sparse_backward_compiles_at_the_longest_sequence_it_admits(
+def test_the_masked_sparse_backward_compiles_at_the_longest_sequence_the_rule_admits(
         topo, on_chip):
-    """The gate of the resident backward (``_dsa_resident``) at its edge,
-    at the sparse cell's widths: the longest whole number of 256-query
-    chunks it admits compiles for the described v5e with the VMEM its
-    estimate asks, and one chunk more goes by the chunks."""
+    """``sparse_attention_takes_bits`` at its edge, at the sparse cell's
+    widths: the longest whole number of 256-key bands it admits for
+    2,048 slots a query (65,536 tokens, whose bits weigh what the slots'
+    indices do) compiles for the described v5e with the masked backward's
+    two kernels and no row scatter, and one band more is not admitted."""
     one_chip = SingleDeviceSharding(topo.devices[0])
     K = 2048
 
     def av(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def admits(N):
-        return pallas_ops._dsa_resident(av((N, 32, 128)), av((N, 4, 128)),
-                                        K, pallas_ops._DSA_BLOCK_Q)
-
     N = 32768
-    while admits(N + 256):
+    while pallas_ops.sparse_attention_takes_bits(N + 256, K, 128):
         N += 256
-    assert N > 32768
+    assert N == 65536
 
-    def grad(q, k, v, idx, n_valid):
+    def grad(q, k, v, idx, n_valid, bits):
         return jax.grad(lambda *a: pallas_ops.sparse_attention(
-            *a, idx, n_valid)[0].astype(jnp.float32).sum(), range(3))(
-                q, k, v)
+            *a, idx, n_valid, bits=bits)[0].astype(jnp.float32).sum(),
+            range(3))(q, k, v)
 
-    before = mx.profiler.get_counters().get("sparse_attn::resident_bwd", 0)
+    before = mx.profiler.get_counters().get("sparse_attn::masked_bwd", 0)
     text = jax.jit(grad).lower(
         av((N, 32, 128)), av((N, 4, 128)), av((N, 4, 128)),
-        av((N, K), jnp.int32), av((N,), jnp.int32)).compile().as_text()
-    assert mx.profiler.get_counters()["sparse_attn::resident_bwd"] \
+        av((N, K), jnp.int32), av((N,), jnp.int32),
+        av((N // 32, N), jnp.uint32)).compile().as_text()
+    assert mx.profiler.get_counters()["sparse_attn::masked_bwd"] \
         == before + 1
-    assert any(re.search(r'op_name="[^"]*\btiles_q8_k2048\)*/dsa_bwd/'
-                         r'pallas_call"', ln) for ln in text.splitlines())
+    for name in ("dsa_masked_dq", "dsa_masked_dkv"):
+        assert any(re.search(r'op_name="[^"]*/%s/pallas_call"' % name, ln)
+                   for ln in text.splitlines()), name
     assert not re.search(r'op_name="[^"]*/scatter/', text)

@@ -1,5 +1,5 @@
 """DeepSeek Sparse Attention (``models/dsa.py``, the ``dsa_fwd`` /
-``dsa_bwd`` / ``dsa_index`` kernels of ``ops/pallas_ops.py``) and routed
+``dsa_bwd`` / ``dsa_masked_*`` / ``dsa_index`` kernels of ``ops/pallas_ops.py``) and routed
 experts (``models/experts.py``) against straightforward float32
 ``jax.numpy`` at toy size on the CPU."""
 import jax
@@ -109,31 +109,39 @@ def _selection(T, K, hot=False, ragged=False):
     return idx, n_valid, sel
 
 
-@pytest.mark.parametrize("G,K,hot,ragged,paths", [
-    (2, 8, False, False, ("resident", "chunked")),
-    (2, 8, True, False, ("resident", "chunked")),      # many adds, one row
-    (2, 16, False, True, ("resident", "chunked")),     # empty slots
-    (1, 8, False, False, ("resident", "chunked")),
-    (4, 8, True, True, ("resident", "chunked")),
-    (2, 12, False, False, ("chunked",)),   # K not whole row-loop steps
-], ids=["base", "hot_key", "empty_slots", "g1", "g4", "fallback"])
+@pytest.mark.parametrize("T,G,K,hot,ragged,path", [
+    (32, 2, 8, False, False, "chunked"),
+    (32, 2, 8, True, False, "chunked"),  # many adds into one row
+    (32, 2, 16, False, True, "chunked"),  # empty slots
+    (32, 1, 8, False, False, "chunked"),
+    (32, 4, 8, True, True, "chunked"),
+    (32, 2, 12, False, False, "chunked"),   # K no multiple of 8
+    (256, 2, 16, True, False, "masked"),
+    (512, 2, 16, False, True, "masked"),    # and future tiles skipped
+    (256, 1, 16, False, False, "masked"),
+    (256, 4, 16, True, True, "masked"),
+], ids=["base", "hot_key", "empty_slots", "g1", "g4", "fallback",
+        "masked_hot_key", "masked_empty_slots", "masked_g1", "masked_g4"])
 def test_the_sparse_kernels_interpreted_match_attention_masked_to_the_set(
-        monkeypatch, G, K, hot, ragged, paths):
-    """Both backward paths (the kernel that fetches each row from a group
-    held in VMEM and adds dK/dV there; the chunks of XLA-gathered rows
-    and their scatter) against one softmax masked to the selection:
-    output and all three gradients."""
+        monkeypatch, T, G, K, hot, ragged, path):
+    """The two backward paths (the walk over the causal tiles under the
+    selection's bits; the chunks of XLA-gathered rows and their scatter)
+    against one softmax masked to the selection: output and all three
+    gradients.  The masked walk at 128-query and 256-key tiles: one key
+    tile at 256 tokens, two at 512."""
     monkeypatch.setattr(pallas_ops, "_INTERPRET", True)
-    T, H = 32, 4
+    monkeypatch.setattr(pallas_ops, "_DSA_MASKED_TILES", ((128,), (256,)))
+    H = 4
     q, k, v = _normal(1, T, H, 128), _normal(2, T, G, 128), \
         _normal(3, T, G, 128)
     idx, n_valid, sel = _selection(T, K, hot, ragged)
+    bits = pallas_ops.pack_selection(sel) if path == "masked" else None
     r = _normal(5, T, H, 128)
-    vmem = {"resident": pallas_ops._VMEM_MAX, "chunked": 1}
 
     def kernel(q, k, v):
-        return jnp.sum(pallas_ops.sparse_attention(q, k, v, idx, n_valid,
-                                                   chunk=16)[0] * r)
+        return jnp.sum(pallas_ops.sparse_attention(
+            q, k, v, idx, n_valid, chunk=16 if bits is None else 128,
+            bits=bits)[0] * r)
 
     def masked(q, k, v):
         a = jnp.einsum("thd,shd->hts", q, jnp.repeat(k, H // G, 1)) \
@@ -145,53 +153,113 @@ def test_the_sparse_kernels_interpreted_match_attention_masked_to_the_set(
     with jax.default_matmul_precision("highest"):
         want = float(masked(q, k, v))
         want_g = jax.grad(masked, (0, 1, 2))(q, k, v)
-        for path in paths:
-            monkeypatch.setattr(pallas_ops, "_VMEM_MAX", vmem[path])
-            jax.clear_caches()     # the path is chosen while tracing
-            before = mx.profiler.get_counters()
-            assert abs(float(kernel(q, k, v)) - want) < 1e-3, path
-            got_g = jax.grad(kernel, (0, 1, 2))(q, k, v)
-            moved = {n: c - before.get(n, 0) for n, c in
-                     mx.profiler.get_counters().items()
-                     if n.startswith("sparse_attn::")
-                     and c != before.get(n, 0)}
-            assert moved == {"sparse_attn::%s_bwd" % path: 1}, moved
-            for name, g, w in zip("qkv", got_g, want_g):
-                assert float(jnp.max(jnp.abs(g - w))) < 1e-4, (path, name)
+        before = mx.profiler.get_counters()
+        got, got_g = jax.value_and_grad(kernel, (0, 1, 2))(q, k, v)
+        assert abs(float(got) - want) < 1e-3
+        moved = {n: c - before.get(n, 0) for n, c in
+                 mx.profiler.get_counters().items()
+                 if n.startswith("sparse_attn::") and c != before.get(n, 0)}
+        assert moved == {"sparse_attn::%s_bwd" % path: 1}, moved
+        for name, g, w in zip("qkv", got_g, want_g):
+            assert float(jnp.max(jnp.abs(g - w))) < 1e-4, name
 
 
-def test_the_counters_name_the_path_each_sparse_call_took(monkeypatch):
-    """The backward takes the resident kernel
-    (``sparse_attn::resident_bwd``) where a group's K/V and dK/dV fit its
-    VMEM and the chunks (``sparse_attn::chunked_bwd``) where they do not
-    — the shape decides, at trace time, once a call; the forward, which
-    has one path, counts nothing."""
-    T, K = 64, 8
+def test_the_counters_name_the_path_each_sparse_call_took():
+    """The backward walks the causal tiles (``sparse_attn::masked_bwd``)
+    where it is handed the selection's bits and goes by chunks
+    (``sparse_attn::chunked_bwd``) where it is not, once a call, at
+    trace time; the forward, which has one path, counts nothing."""
+    T, K = 256, 8
     q, k, v = _normal(1, T, 4, 128), _normal(2, T, 2, 128), \
         _normal(3, T, 2, 128)
-    idx, n_valid, _ = _selection(T, K)
+    idx, n_valid, sel = _selection(T, K)
 
     def moved(fn):
-        jax.clear_caches()     # the path is chosen while tracing
         before = mx.profiler.get_counters()
         jax.make_jaxpr(fn)(q, k, v)
         return {n: c - before.get(n, 0) for n, c in
                 mx.profiler.get_counters().items()
                 if n.startswith("sparse_attn::") and c != before.get(n, 0)}
 
-    def forward(q, k, v):
-        return pallas_ops.sparse_attention(q, k, v, idx, n_valid)[0]
+    def forward(bits):
+        return lambda q, k, v: pallas_ops.sparse_attention(
+            q, k, v, idx, n_valid, bits=bits)[0]
 
-    def grad(q, k, v):
-        return jax.grad(lambda *a: jnp.sum(forward(*a)), (0, 1, 2))(q, k, v)
+    def grad(bits):
+        return lambda *a: jax.grad(lambda *a: jnp.sum(forward(bits)(*a)),
+                                   (0, 1, 2))(*a)
 
-    assert moved(forward) == {}
-    assert moved(grad) == {"sparse_attn::resident_bwd": 1}
-    need = pallas_ops._dsa_resident_bytes(T, 2, 128, K, 8, jnp.float32)
-    monkeypatch.setattr(pallas_ops, "_VMEM_MAX", need - 1)
-    assert moved(grad) == {"sparse_attn::chunked_bwd": 1}
-    monkeypatch.setattr(pallas_ops, "_VMEM_MAX", need)
-    assert moved(grad) == {"sparse_attn::resident_bwd": 1}
+    bits = pallas_ops.pack_selection(sel)
+    assert moved(forward(None)) == {}
+    assert moved(forward(bits)) == {}
+    assert moved(grad(None)) == {"sparse_attn::chunked_bwd": 1}
+    assert moved(grad(bits)) == {"sparse_attn::masked_bwd": 1}
+
+
+@pytest.mark.parametrize("ties", [False, True], ids=["distinct", "ties"])
+def test_the_selections_bits_name_exactly_its_slots(monkeypatch, ties):
+    """``select``'s bits against its ``idx``: every query's bits name
+    exactly ``idx[t, :n_valid[t]]`` and count ``n_valid[t]`` — the first
+    queries with fewer than topk earlier keys (tau -inf), rows of 256
+    to 512 keys taken in two stages of 128-key segments, and with
+    ``ties`` keys that repeat seven rows, so that most rows tie at tau
+    and the lower positions are the ones taken."""
+    monkeypatch.setattr(dsa, "SEGMENT", 128)
+    monkeypatch.setattr(dsa, "exact_top_k", lambda s, k: _TOP_K(s, k, 128))
+    T, K = 512, 32
+    qi, ki, w = _normal(4, 2, T, 16), _normal(5, T, 16), _normal(6, T, 2)
+    if ties:
+        ki = ki[jnp.arange(T) % 7]
+    idx, vals, n_valid, bits = dsa.select(qi, ki, w, K, chunk=128,
+                                          bits=True)
+    assert bits.shape == (T // 32, T) and bits.dtype == jnp.uint32
+    got = pallas_ops.unpack_selection(bits).T          # (query, key)
+    want = jnp.zeros((T, T), bool).at[jnp.arange(T)[:, None], idx].set(
+        jnp.arange(K)[None, :] < n_valid[:, None])
+    assert bool(jnp.all(jnp.sum(got, 1) == n_valid))
+    assert bool(jnp.all(got == want))
+    if ties:    # the test is one of ties: tau repeats in most rows
+        assert int(jnp.sum(jnp.sum(vals == vals[:, -1:], 1) > 1)) > T // 2
+
+
+_TOP_K = dsa.exact_top_k
+
+
+def test_the_rule_takes_the_masked_walk_at_the_sparse_cells_shape():
+    """The sparse cell's shape (32,768 tokens, 2,048 slots, heads of 128)
+    makes the bits, and a call with them walks the causal tiles
+    (``sparse_attn::masked_bwd``); a call without them goes by chunks.
+    A sequence of 131,072 tokens, whose bits would outweigh its slots'
+    indices, makes none; nor do heads of no whole lane rows or a length
+    of no whole tiles, and bits handed over for those are refused."""
+    takes = pallas_ops.sparse_attention_takes_bits
+    assert takes(32768, 2048, 128) and takes(65536, 2048, 128)
+    assert not takes(131072, 2048, 128)
+    assert not takes(32768, 2048, 96) and not takes(32640, 2048, 128)
+
+    def moved(N, with_bits):
+        def grad(q, k, v, idx, n_valid, bits):
+            return jax.grad(lambda *a: jnp.sum(pallas_ops.sparse_attention(
+                *a, idx, n_valid, bits=bits if with_bits else None)[0]),
+                (0, 1, 2))(q, k, v)
+
+        av = jax.ShapeDtypeStruct
+        before = mx.profiler.get_counters()
+        jax.make_jaxpr(grad)(
+            av((N, 32, 128), jnp.bfloat16), av((N, 4, 128), jnp.bfloat16),
+            av((N, 4, 128), jnp.bfloat16), av((N, 2048), jnp.int32),
+            av((N,), jnp.int32), av((N // 32, N), jnp.uint32))
+        return {n: c - before.get(n, 0) for n, c in
+                mx.profiler.get_counters().items()
+                if n.startswith("sparse_attn::") and c != before.get(n, 0)}
+
+    assert moved(32768, True) == {"sparse_attn::masked_bwd": 1}
+    assert moved(32768, False) == {"sparse_attn::chunked_bwd": 1}
+    with pytest.raises(ValueError, match="bits"):
+        pallas_ops.sparse_attention(
+            *(jnp.zeros((256, h, 96), jnp.bfloat16) for h in (4, 2, 2)),
+            jnp.zeros((256, 8), jnp.int32), jnp.ones((256,), jnp.int32),
+            bits=jnp.zeros((8, 256), jnp.uint32))
 
 
 def test_the_index_kernel_interpreted_matches_its_xla_form(monkeypatch):

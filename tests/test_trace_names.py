@@ -325,11 +325,13 @@ def test_eva_step_keeps_the_flash_kernels_names(eva_names, kernel, phase,
 
 
 @pytest.fixture(scope="module")
-def dsa_names():
+def dsa_step():
     """Op names of the compiled training step of a sparse-attention MoE
     decoder (``keye_vl2_30b_a3b_config`` at toy widths), every block
     marked for recomputation, the kernels interpreted: 512 tokens, so
-    that the index kernel's tiles and the grouped matmul's rows run."""
+    that the index kernel's tiles and the grouped matmul's rows run and
+    the backward walks the causal tiles under the selection's bits; and
+    the ``sparse_attn::`` counters the step's trace moved."""
     from mxnet_tpu.models import TransformerLM, keye_vl2_30b_a3b_config
     from mxnet_tpu.ndarray.ndarray import NDArray
     from mxnet_tpu.ops import pallas_ops
@@ -348,11 +350,20 @@ def dsa_names():
         forward_fn=lambda net, t, l: net.loss(t, l, chunk=256))
     was = pallas_ops._INTERPRET
     pallas_ops._INTERPRET = True
+    before = profiler.get_counters()
     try:
         text = step.lower(tok, tok).compile().as_text()
     finally:
         pallas_ops._INTERPRET = was
-    return set(re.findall(r'op_name="(jit\(step\)/[^"]*)"', text))
+    moved = {n: c - before.get(n, 0) for n, c in profiler.get_counters()
+             .items() if n.startswith("sparse_attn::")
+             and c != before.get(n, 0)}
+    return set(re.findall(r'op_name="(jit\(step\)/[^"]*)"', text)), moved
+
+
+@pytest.fixture(scope="module")
+def dsa_names(dsa_step):
+    return dsa_step[0]
 
 
 FIRST = "jit(step)/jvp(forward)/layer1/"
@@ -390,16 +401,23 @@ def test_dsa_moe_step_carries_indexer_sparse_attn_and_experts(
 @pytest.mark.parametrize("kernel,where,there", [
     ("dsa_index", FIRST + "attention/indexer/", True),
     ("dsa_fwd", FIRST + "attention/sparse_attn/", True),
-    ("dsa_bwd", "/checkpoint/attention/sparse_attn/", True),
+    ("dsa_masked_dq", BACK + "layer1/jvp(forward)/layer1/checkpoint/"
+     "attention/sparse_attn/", True),
+    ("dsa_masked_dkv", BACK + "layer1/jvp(forward)/layer1/checkpoint/"
+     "attention/sparse_attn/", True),
+    ("dsa_bwd", "/checkpoint/attention/sparse_attn/", False),
     ("dsa_index", "/rematted_computation/", False),
     ("dsa_fwd", "/rematted_computation/", False),
+    ("dsa_masked_dq", "/rematted_computation/", False),
     ("dsa_bwd", "/rematted_computation/", False)])
 def test_dsa_moe_step_keeps_its_kernels_names(dsa_names, kernel, where,
                                               there):
-    # the sparse kernels under the tile they run (queries a grid step,
-    # keys a query); a marked block keeps the selection and the forward
-    # kernel's output, so neither the scoring nor the forward kernel is
-    # among the recomputed ops
+    # the sparse kernels under the tile they run (the forward: queries a
+    # grid step, keys a query; the masked backward: its tile of queries
+    # and keys); a marked block keeps the selection, its bits and the
+    # forward kernel's output, so neither the scoring nor the forward
+    # kernel is among the recomputed ops; the backward walks the causal
+    # tiles under the bits, and the row kernel does not run
     tile = r"tiles_q\d+_k\d+/" if kernel != "dsa_index" else ""
     hits = [n for n in dsa_names
             if re.search(r"%s%s\)*/" % (tile, kernel), n) and where in n]
@@ -411,6 +429,11 @@ def test_dsa_moe_step_keeps_its_kernels_names(dsa_names, kernel, where,
     assert not any("/feed_forward/experts/" in n and "/gmm/" in n
                    for n in again)
     assert not any("/indexer/" in n and "top_k" in n for n in again)
+
+
+def test_dsa_moe_step_counts_its_backward_path(dsa_step):
+    # one traced backward a layer on the masked walk (PERF.md section 3)
+    assert dsa_step[1] == {"sparse_attn::masked_bwd": 2}
 
 
 def _laguna_step_names(n_experts):
